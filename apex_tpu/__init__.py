@@ -30,14 +30,6 @@ Subpackages are imported lazily to keep ``import apex_tpu`` cheap.
 
 import importlib
 
-# Eager on purpose, although it pulls in jax: submodules reference the
-# modern jax surface at import time (e.g. ops/fused_update builds
-# pltpu.CompilerParams at module level), so the grafts must be installed
-# before ANY submodule import path runs — lazy installation per-subpackage
-# would have to cover every entry point and fail silently when one is
-# missed on an old jax.
-from apex_tpu import _jax_compat  # noqa: F401  (side effect: old-jax aliases)
-
 __version__ = "0.1.0"
 
 _SUBMODULES = (

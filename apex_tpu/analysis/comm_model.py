@@ -122,7 +122,7 @@ def _subjaxpr_items(eqn, axis_sizes: Optional[Dict[str, int]] = None,
       that take a max over the yields themselves (the peak-live walk —
       selecting by comm bytes there would just pick branch 0).
     """
-    import jax
+    import jax.extend.core as jex_core
 
     name = eqn.primitive.name
     if name == "scan":
@@ -145,7 +145,7 @@ def _subjaxpr_items(eqn, axis_sizes: Optional[Dict[str, int]] = None,
     for v in eqn.params.values():
         items = v if isinstance(v, (list, tuple)) else [v]
         for item in items:
-            if isinstance(item, (jax.core.Jaxpr, jax.core.ClosedJaxpr)):
+            if isinstance(item, (jex_core.Jaxpr, jex_core.ClosedJaxpr)):
                 yield item, 1
 
 
@@ -278,18 +278,18 @@ def peak_live_bytes(closed_jaxpr) -> int:
     the max of its branches' internal peaks as a transient at its
     position — nested intermediates don't outlive the eqn.
     """
-    import jax
+    import jax.extend.core as jex_core
 
     jaxpr = _open(closed_jaxpr)
     eqns = jaxpr.eqns
     last_use: dict = {}
     for i, eqn in enumerate(eqns):
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, jex_core.Literal):
                 last_use[v] = i
     n_eqns = len(eqns)
     for v in jaxpr.outvars:
-        if not isinstance(v, jax.core.Literal):
+        if not isinstance(v, jex_core.Literal):
             last_use[v] = n_eqns
 
     live = 0
@@ -309,7 +309,7 @@ def peak_live_bytes(closed_jaxpr) -> int:
         peak = max(peak, live + transient)
         # free everything whose last consumer was this eqn
         for v in list(eqn.invars) + list(eqn.outvars):
-            if not isinstance(v, jax.core.Literal) \
+            if not isinstance(v, jex_core.Literal) \
                     and last_use.get(v) == i and born_at.get(v, -1) <= i:
                 live -= _aval_bytes(v.aval)
                 last_use.pop(v)

@@ -45,6 +45,7 @@ ACCUM_PRIMS = {
 # op's body (they serialise the TPU pipeline or break AOT compilation).
 FORBIDDEN_PRIMS = {
     "pure_callback", "io_callback", "callback", "debug_callback",
+    "debug_print",          # what jax.debug.print binds since jax 0.5
     "outside_call", "device_put", "infeed", "outfeed",
     "copy_to_host_async",
 }
@@ -426,13 +427,13 @@ def op_specs() -> list:
 # ---------------------------------------------------------------------------
 
 def _subjaxprs(params: dict):
-    import jax
+    import jax.extend.core as jex_core
     for v in params.values():
         items = v if isinstance(v, (list, tuple)) else [v]
         for item in items:
-            if isinstance(item, jax.core.ClosedJaxpr):
+            if isinstance(item, jex_core.ClosedJaxpr):
                 yield item.jaxpr
-            elif isinstance(item, jax.core.Jaxpr):
+            elif isinstance(item, jex_core.Jaxpr):
                 yield item
 
 
@@ -446,6 +447,7 @@ def _iter_jaxprs(jaxpr):
 def _audit_jaxpr(closed) -> tuple:
     """-> (unexplained_upcast_count, forbidden_prim_names)"""
     import jax
+    import jax.extend.core as jex_core
     import jax.numpy as jnp
     unexplained = 0
     forbidden: list = []
@@ -453,7 +455,7 @@ def _audit_jaxpr(closed) -> tuple:
         consumers: dict = {}
         for eqn in jaxpr.eqns:
             for var in eqn.invars:
-                if not isinstance(var, jax.core.Literal):
+                if not isinstance(var, jex_core.Literal):
                     consumers.setdefault(var, []).append(eqn.primitive.name)
         for eqn in jaxpr.eqns:
             name = eqn.primitive.name

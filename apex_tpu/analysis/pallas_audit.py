@@ -342,11 +342,13 @@ def _prod(shape) -> int:
 
 
 def _record_from_eqn(eqn) -> KernelRecord:
-    import jax
+    import jax.extend.core as jex_core
 
     gm = eqn.params["grid_mapping"]
-    nsi = eqn.params.get("name_and_src_info")
-    kname = getattr(nsi, "name", None) or str(nsi).split(" at ")[0]
+    # the explicit pallas_call(name=) if any, else the kernel function's
+    # own name off the kernel jaxpr's debug info
+    kname = (eqn.params.get("name")
+             or eqn.params["jaxpr"].debug_info.func_name)
 
     npre = gm.num_index_operands
     prefetch = sum(_prod(sh.shape) * _itemsize(sh.dtype)
@@ -354,13 +356,14 @@ def _record_from_eqn(eqn) -> KernelRecord:
 
     blocks = []
     for i, bm in enumerate(gm.block_mappings):
-        full = bm.array_shape_dtype
-        # mapped/None dims contribute one element to the block
-        bshape = tuple(int(b) if isinstance(b, int) else 1
+        full = bm.array_aval
+        # Blocked(block_size=n) dims carry their size; squeezed/None
+        # dims contribute one element to the block
+        bshape = tuple(int(getattr(b, "block_size", 1))
                        for b in bm.block_shape)
         imj = bm.index_map_jaxpr
         constant = (not imj.jaxpr.eqns) and all(
-            isinstance(v, jax.core.Literal) for v in imj.jaxpr.outvars)
+            isinstance(v, jex_core.Literal) for v in imj.jaxpr.outvars)
         nondiv = tuple(
             d for d, (b, n) in enumerate(zip(bshape, full.shape))
             if b > 0 and int(n) % b)

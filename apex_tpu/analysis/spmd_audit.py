@@ -640,7 +640,7 @@ def _iter_jaxprs(jaxpr):
 def _collective_multiset(jaxpr) -> dict:
     """{(prim, axes): count} over a jaxpr INCLUDING nested jaxprs; scan
     bodies multiply by length (two psums == one psum scanned twice)."""
-    import jax
+    import jax.extend.core as jex_core
 
     out: Dict[tuple, int] = {}
 
@@ -657,8 +657,8 @@ def _collective_multiset(jaxpr) -> dict:
             for v in eqn.params.values():
                 items = v if isinstance(v, (list, tuple)) else [v]
                 for item in items:
-                    if isinstance(item, (jax.core.Jaxpr,
-                                         jax.core.ClosedJaxpr)):
+                    if isinstance(item, (jex_core.Jaxpr,
+                                         jex_core.ClosedJaxpr)):
                         walk(item, m)
 
     walk(jaxpr, 1)
@@ -686,7 +686,7 @@ class _Uniformity:
     # -- eqn transfer functions -----------------------------------------
 
     def run(self, jaxpr, seed: List[FrozenSet], checks: bool) -> list:
-        import jax
+        import jax.extend.core as jex_core
 
         vary: dict = {}
         open_j = getattr(jaxpr, "jaxpr", jaxpr)
@@ -696,7 +696,7 @@ class _Uniformity:
             vary[v] = frozenset()
 
         def vof(v):
-            if isinstance(v, jax.core.Literal):
+            if isinstance(v, jex_core.Literal):
                 return frozenset()
             return vary.get(v, frozenset())
 
@@ -837,12 +837,12 @@ class _Uniformity:
         """custom_vjp/jvp, remat, closed_call, ...: recurse for the
         CHECKS with conservative seeding; outputs stay the input
         union (already set by the caller)."""
-        import jax
+        import jax.extend.core as jex_core
 
         for v in eqn.params.values():
             items = v if isinstance(v, (list, tuple)) else [v]
             for item in items:
-                if isinstance(item, (jax.core.Jaxpr, jax.core.ClosedJaxpr)):
+                if isinstance(item, (jex_core.Jaxpr, jex_core.ClosedJaxpr)):
                     open_j = getattr(item, "jaxpr", item)
                     self.run(item, [invary] * len(open_j.invars), checks)
         return out
@@ -1308,15 +1308,18 @@ def _audit_exec(spec: ExecSpec) -> tuple:
                              f"binds only {sorted(bound)}")
 
     # APX212/APX213 — branch parity + replica-uniformity dataflow,
-    # seeded from each shard_map eqn's in_names
+    # seeded from each shard_map eqn's in_specs (one PartitionSpec per
+    # operand; an entry is None, an axis name or a tuple of them)
     uni = _Uniformity(spec, emit)
     for eqn in closed.jaxpr.eqns:
         if eqn.primitive.name != "shard_map":
             continue
         seed = []
-        for names in eqn.params["in_names"]:
+        for pspec in eqn.params["in_specs"]:
             seed.append(frozenset(
-                ax for axes in names.values() for ax in axes))
+                ax for entry in pspec if entry is not None
+                for ax in (entry if isinstance(entry, tuple)
+                           else (entry,))))
         uni.run(eqn.params["jaxpr"], seed, checks=True)
 
     # APX214 — donation verification on the lowered executable

@@ -54,8 +54,10 @@ CHIP_SPECS: Dict[str, ChipSpec] = {s.key: s for s in [
     ChipSpec("v6lite", 918.0, 1640.0, 32 * _GiB, 128 * _MiB),
 ]}
 
-#: the generation assumed when the device kind matches nothing (CPU
-#: dryruns, unknown tunnels) — the same v5e default the bench always had.
+#: the NOMINAL chip for trace-only analysis code that prices a program
+#: without a device (``pallas_audit``'s VMEM envelope, ``comm_model``'s
+#: roofline): callers ask for it by name through :func:`default_spec`.
+#: Never a fallback for a live device — see :func:`local_spec`.
 DEFAULT_CHIP = "v5e"
 
 
@@ -67,7 +69,7 @@ def match_spec(device_kind: Optional[str]) -> Optional[ChipSpec]:
     """The spec whose key substring-matches ``device_kind`` (the
     ``jax.Device.device_kind`` string, any case/spacing), or ``None``
     on a miss — the one matching loop; callers choose their own miss
-    policy (:func:`find_spec` defaults, bench's scrub bound takes the
+    policy (:func:`find_spec` raises, bench's scrub bound takes the
     largest capacity)."""
     kind = (device_kind or "").lower().replace(" ", "")
     for key, spec in CHIP_SPECS.items():
@@ -77,15 +79,23 @@ def match_spec(device_kind: Optional[str]) -> Optional[ChipSpec]:
 
 
 def find_spec(device_kind: Optional[str]) -> ChipSpec:
-    """Like :func:`match_spec`, but a miss resolves to the
-    :data:`DEFAULT_CHIP` spec."""
-    return match_spec(device_kind) or default_spec()
+    """Like :func:`match_spec`, but a miss is an error: a utilization
+    priced against the peak of a chip the program is not running on is
+    wrong by an unknown factor, so there is no default."""
+    spec = match_spec(device_kind)
+    if spec is None:
+        raise KeyError(
+            f"device_kind {device_kind!r} is not in apex_tpu.chip_specs."
+            f"CHIP_SPECS ({sorted(CHIP_SPECS)}); add its peaks with "
+            f"their source, or ask for the nominal chip by name "
+            f"(default_spec())")
+    return spec
 
 
 def local_spec() -> ChipSpec:
     """The spec of the first live jax device (initializes the backend;
-    host loops only — trace-only code passes a device_kind to
-    :func:`find_spec` or takes the default)."""
+    host loops only).  A device whose ``device_kind`` is not in the
+    table — the CPU platform included — raises."""
     import jax
 
     return find_spec(jax.devices()[0].device_kind)

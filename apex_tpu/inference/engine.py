@@ -451,7 +451,8 @@ class InferenceEngine:
             self.decode_fused = resolve_decode_fusion(
                 decode_fusion, paged=self.paged,
                 max_pages=self.max_pages_per_slot,
-                min_pages=fusion_min_pages)
+                min_pages=fusion_min_pages,
+                dims=self._fused_block_dims() if self.paged else None)
             self._fused_layers = (
                 models.fused_layer_params(kind, cfg, self.params)
                 if self.decode_fused else None)
@@ -515,6 +516,22 @@ class InferenceEngine:
                     in_specs=(cs, P(), sb, sb), out_specs=cs)
                 self._swap_in = jax.jit(self._swap_in_raw,
                                         donate_argnums=(0,))
+
+    def _fused_block_dims(self) -> dict:
+        """The per-rank layer geometry the fused block kernel would run
+        at — what :func:`resolve_decode_fusion` prices against VMEM."""
+        td = self.tp_dims
+        leaves = [x for x in jax.tree_util.tree_leaves(self.params)
+                  if jnp.issubdtype(x.dtype, jnp.floating)]
+        return dict(
+            kind=self.kind, hidden=self.cfg.hidden_size,
+            ffn=self.cfg.ffn // self.tp, heads=td["heads_local"],
+            kv_heads=td["kv_heads_local"], head_dim=td["head_dim"],
+            page_size=self.page_size,
+            itemsize=max(x.dtype.itemsize for x in leaves),
+            cache_itemsize=jnp.dtype(self.cache_dtype).itemsize,
+            # under tp the MLP runs outside the kernel, past the psum
+            fuse_mlp=self.tp == 1, partial_out=self.tp > 1)
 
     def _refresh_dispatch_counters(self) -> None:
         reg = obs.global_registry()
@@ -587,19 +604,24 @@ class InferenceEngine:
             # (kvh * rep — GQA/MQA replicate below tp); the k/v leaves
             # then shard over the kv-head dim, handing each rank
             # kv_heads_pool / tp heads of every page
-            cache = kv_cache.init_paged_cache(
-                self.num_pages, d["layers"],
-                self.tp_dims["kv_heads_pool"],
-                self.page_size, d["head_dim"], slots=self.slots,
-                max_pages_per_slot=self.max_pages_per_slot,
-                dtype=self.cache_dtype,
-                attn_max_pages=self.paged_attn_max_pages)
-            if self.tp > 1:
-                cache = jax.tree_util.tree_map(
-                    lambda x, s: jax.device_put(
-                        x, NamedSharding(self.mesh, s)),
-                    cache, self._cache_specs)
-            return cache
+            def build():
+                return kv_cache.init_paged_cache(
+                    self.num_pages, d["layers"],
+                    self.tp_dims["kv_heads_pool"],
+                    self.page_size, d["head_dim"], slots=self.slots,
+                    max_pages_per_slot=self.max_pages_per_slot,
+                    dtype=self.cache_dtype,
+                    attn_max_pages=self.paged_attn_max_pages)
+
+            if self.tp == 1:
+                return build()
+            # built ON the mesh: every rank allocates only its own
+            # kv-head shard, so a pool sized for tp chips never has to
+            # fit on one (building it whole and THEN resharding did)
+            shardings = jax.tree_util.tree_map(
+                lambda s: NamedSharding(self.mesh, s), self._cache_specs,
+                is_leaf=lambda s: isinstance(s, PartitionSpec))
+            return jax.jit(build, out_shardings=shardings)()
         return kv_cache.init_cache(
             self.slots, d["layers"], d["kv_heads"], self.max_seq,
             d["head_dim"], dtype=self.cache_dtype)

@@ -562,13 +562,14 @@ def insert_tokens(cache: PagedKVCache, slot, k, v, length, row,
     cold prefill, the shared-prefix coverage for a hit, a chunk
     boundary for chunked prefill.  ``length`` is the slot's TOTAL live
     length after this write (prefix + real suffix tokens).  Unlike
-    :func:`insert_pages`' page-granular slab scatter, every token row
-    targets ``(row[pos // page_size], pos % page_size)`` individually
-    (the :func:`_append_layer_paged` addressing, vectorized over the
-    slab) — positions past the reservation clamp into the trash page
-    exactly like the slab insert's bucket overhang, and rows mapping
-    into SHARED prefix pages never occur by contract (the scheduler
-    COWs the boundary page before admitting a mid-page suffix).
+    :func:`insert_pages`' page-aligned slab scatter, the slab may start
+    mid-page: the pages it touches are gathered, the slab's rows
+    (contiguous in those pages' row space) dropped in, and the pages
+    scattered back whole — positions past the reservation land in the
+    trash page exactly like the slab insert's bucket overhang, and
+    rows mapping into SHARED prefix pages never occur by contract (the
+    scheduler COWs the boundary page before admitting a mid-page
+    suffix, so every touched page is private or trash).
 
     The page-table row, lengths, and capacity update exactly as in
     :func:`insert_pages` (capacity derived in-program from the owned
@@ -594,23 +595,39 @@ def insert_tokens(cache: PagedKVCache, slot, k, v, length, row,
             f"{tuple(row.shape)}")
     slot = jnp.asarray(slot, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
-    pos = start + jnp.arange(s, dtype=jnp.int32)            # [s]
-    ordinal = jnp.minimum(pos // ps, jnp.int32(mpps - 1))
-    pages = jnp.take(row, ordinal)                          # [s]
-    # rows past the virtual window get an OUT-OF-BOUNDS page index so
-    # mode="drop" discards them — clamping them onto the last owned
-    # position would collide with (and clobber) the real last token
-    # whenever the prompt fills the whole window
-    pages = jnp.where(pos < jnp.int32(mpps * ps), pages,
-                      jnp.int32(cache.pages))
-    offs = jnp.minimum(pos - ordinal * ps, jnp.int32(ps - 1))
-    # [layers, kvh, s, d] -> [s, layers, kvh, d]: the advanced indices
-    # (pages, offs) lead, interior layer/head slices follow — one
-    # vectorized scatter per buffer, donation-safe like every .at[].set
-    rows_k = jnp.moveaxis(k, 2, 0).astype(cache.k.dtype)
-    rows_v = jnp.moveaxis(v, 2, 0).astype(cache.v.dtype)
-    new_k = cache.k.at[pages, :, :, offs, :].set(rows_k, mode="drop")
-    new_v = cache.v.at[pages, :, :, offs, :].set(rows_v, mode="drop")
+    # Read-modify-write of WHOLE pages, never a row scatter into the
+    # pool: a scatter indexed on (page, row-in-page) — dims 0 and 3 —
+    # makes XLA relayout the entire pool around it, one pool-sized
+    # temporary and two pool-sized copies per call (observed on the
+    # v5e, PR 21: 2 GiB of temporaries for a 4 GiB pool; a 24 GiB pool
+    # over tp=4 could not prefill).  The slab's rows are contiguous in
+    # the row space of the n pages it touches, so: gather those pages,
+    # drop the slab in with one dynamic_update_slice, and put the pages
+    # back with a scatter on the leading (page) dim only.
+    n = -(-s // ps) + 1           # pages s rows can touch, any alignment
+    ords = start // ps + jnp.arange(n, dtype=jnp.int32)
+    # ordinals past the virtual window get an OUT-OF-BOUNDS page index
+    # so mode="drop" discards them — clamping them onto the last owned
+    # page would clobber live rows whenever the prompt fills the window;
+    # ordinals past the reservation hold the trash page by construction
+    page_ids = jnp.where(
+        ords < jnp.int32(mpps),
+        jnp.take(row, jnp.minimum(ords, jnp.int32(mpps - 1))),
+        jnp.int32(cache.pages))
+
+    def write(pool, x):
+        layers, kvh, _, d = x.shape
+        slab = jnp.take(pool, page_ids, axis=0, mode="clip")
+        # [n, layers, kvh, ps, d] -> [layers, kvh, n * ps, d]: token t
+        # of the slab sits at row (start % ps) + t
+        flat = jnp.moveaxis(slab, 0, 2).reshape(layers, kvh, n * ps, d)
+        zero = jnp.int32(0)
+        flat = jax.lax.dynamic_update_slice(
+            flat, x.astype(pool.dtype), (zero, zero, start % ps, zero))
+        slab = jnp.moveaxis(flat.reshape(layers, kvh, n, ps, d), 2, 0)
+        return pool.at[page_ids].set(slab, mode="drop")
+
+    new_k, new_v = write(cache.k, k), write(cache.v, v)
     owned = jnp.sum((row != cache.null_page).astype(jnp.int32))
     zero = jnp.int32(0)
     return cache.replace(
@@ -719,11 +736,13 @@ def _append_layer_paged(cache: PagedKVCache, layer: int, k_tok,
                         v_tok) -> PagedKVCache:
     """Paged decode write for ONE layer: slot ``i``'s token row lands in
     page ``page_table[i, lengths[i] // page_size]`` at row
-    ``lengths[i] % page_size``.  One vectorized scatter per buffer
-    (every slot's ``(page, row)`` target derives from the traced
-    lengths/page table up front) — the paged analog of the dense
-    append's vmap, donation-safe like every ``.at[].set`` on a donated
-    operand.  At capacity the write clamps into the trash page / last
+    ``lengths[i] % page_size``.  One gather + one scatter of the
+    slots' current pages per buffer (every slot's ``(page, row)``
+    target derives from the traced lengths/page table up front) — the
+    paged analog of the dense append's vmap, donation-safe like every
+    ``.at[].set`` on a donated operand; written pages are private to
+    their slot by the sharing contract, idle slots write the trash
+    page.  At capacity the write clamps into the trash page / last
     row — the same bounded-damage semantics as the dense clamp, with
     the damage redirected off the live data entirely (slots at
     capacity may alias the trash page; they hold garbage by contract,
@@ -734,14 +753,20 @@ def _append_layer_paged(cache: PagedKVCache, layer: int, k_tok,
     pages = jnp.take_along_axis(cache.page_table, ordinal[:, None],
                                 axis=1)[:, 0]               # [slots]
     offs = jnp.minimum(pos - ordinal * ps, jnp.int32(ps - 1))
-    # advanced indices (pages, offs) with interior slices: the
-    # broadcast slot dim leads, giving [slots, kv_heads, head_dim] —
-    # exactly the token layout
-    new_k = cache.k.at[pages, layer, :, offs, :].set(
-        k_tok.astype(cache.k.dtype), mode="drop")
-    new_v = cache.v.at[pages, layer, :, offs, :].set(
-        v_tok.astype(cache.v.dtype), mode="drop")
-    return cache.replace(k=new_k, v=new_v)
+    # read-modify-write of each slot's CURRENT page of this layer
+    # ([slots, kv_heads, page_size, head_dim], ~1 MiB): a row scatter
+    # indexed on (page, layer, row-in-page) makes XLA relayout the
+    # whole pool around it on every step (see insert_tokens); indexing
+    # the two leading dims only does not
+    sid = jnp.arange(cache.slots, dtype=jnp.int32)
+
+    def write(pool, tok):
+        cur = pool[pages, layer]
+        cur = cur.at[sid, :, offs, :].set(tok.astype(pool.dtype))
+        return pool.at[pages, layer].set(cur, mode="drop")
+
+    return cache.replace(k=write(cache.k, k_tok),
+                         v=write(cache.v, v_tok))
 
 
 class PageAllocator:

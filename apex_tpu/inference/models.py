@@ -202,18 +202,22 @@ def _suffix_attend(cache, layer: int, row, q, k, v, start):
         return flash_attention(q, k, v, causal=True)
 
     def warm(q, k, v, pk, pv):
-        # pk/pv [pages, kvh, ps, d] -> the slot's virtual window
-        # [b, kvh, max_seq, d] in row order; unowned ordinals gather the
-        # trash page — finite garbage masked by start
+        # pk/pv: the WHOLE pool [pages, layers, kvh, ps, d] -> the
+        # slot's virtual window [b, kvh, max_seq, d] of this layer in
+        # row order; unowned ordinals gather the trash page — finite
+        # garbage masked by start
         def window(p):
-            w = jnp.take(p, row, axis=0)          # [mpps, kvh, ps, d]
+            w = p[row, layer]                     # [mpps, kvh, ps, d]
             return w.transpose(1, 0, 2, 3).reshape(
                 1, kvh, -1, d).astype(q.dtype)
         return prefix_window_attention(q, k, v, window(pk), window(pv),
                                        start)
 
-    return jax.lax.cond(start > 0, warm, cold, q, k, v,
-                        cache.k[:, layer], cache.v[:, layer])
+    # the pool goes into the cond whole and only the slot's own pages
+    # are gathered inside: a per-layer slice as the operand is
+    # materialized — one pool-sized temporary per layer, 9 GB of them
+    # for a 24 GiB pool over tp=4 (PERF.md "Bring-up, PR 21")
+    return jax.lax.cond(start > 0, warm, cold, q, k, v, cache.k, cache.v)
 
 
 def _slab_attend(cache, layer: int, q, lengths):

@@ -255,21 +255,23 @@ def attribute(ranks: Sequence[RankTrace], *,
         record["step_us"] = _r(head["window_us"] / steps)
         record["step_exposed_comm_us"] = _r(
             head["exposed_comm_us"] / steps)
+    from apex_tpu.chip_specs import match_spec
+    spec = match_spec(device_kind)
     if steps and steps > 0 and flops_per_step \
-            and head["compute_us"] > 0:
-        from apex_tpu.chip_specs import find_spec
-        peak = find_spec(device_kind).bf16_tflops * 1e12
-        # 6 decimals: a CPU dryrun measured against a TPU peak is
-        # legitimately ~1e-5 and must not round to a fabricated 0
+            and head["compute_us"] > 0 and spec is not None:
+        peak = spec.bf16_tflops * 1e12
         record["mfu"] = round(
             steps * flops_per_step / (head["compute_us"] * 1e-6) / peak,
             6)
         record["mfu_provenance"] = PROVENANCE_MEASURED
     else:
+        # a device kind outside the chip table has no peak to divide
+        # by: the MFU is absent, never priced against another chip's
         record["mfu_provenance"] = UNAVAILABLE_PREFIX + (
             "no-step-count" if not steps
             else "no-compiled-flops" if not flops_per_step
-            else "no-compute-time")
+            else "no-compute-time" if not head["compute_us"] > 0
+            else "device-kind-not-in-chip-specs")
     if model_exposed_comm_us is not None:
         record["model_exposed_comm_us"] = _r(model_exposed_comm_us)
         measured_per_step = record.get("step_exposed_comm_us")

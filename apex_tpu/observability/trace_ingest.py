@@ -284,8 +284,9 @@ def main(argv=None) -> int:
                    help="compiled FLOPs per step (xla_stats) for "
                         "measured MFU")
     p.add_argument("--chip", default=None,
-                   help="device kind for the chip-spec peak (default: "
-                        "the chip_specs default generation)")
+                   help="device_kind the trace was captured on, for the "
+                        "chip-spec peak (measured MFU is unavailable "
+                        "without it)")
     p.add_argument("--model-exposed-comm-us", type=float, default=None,
                    help="comm_model.step_time_estimate exposed_comm_us "
                         "prediction to compare against")

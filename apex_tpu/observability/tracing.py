@@ -93,33 +93,16 @@ def profile_dir_unusable(log_dir: str) -> Optional[str]:
 
 def _start_trace_device_only(log_dir: str) -> None:
     """``jax.profiler.start_trace`` with the Python-call tracer OFF
-    (ISSUE 14).  A bench capture window spans jit TRACING, whose
-    millions of python-call events exhaust the trace-viewer export's
-    event cap (~1e6) before a single XLA op event lands — the ingested
-    capture of the main leg then reads ``unavailable:no-op-events``.
-    The XLA op events (the ones attribution prices) come from the
-    HOST/runtime tracer, so ``python_tracer_level=0`` keeps everything
-    measured and drops only the python noise.  This jax's public
-    ``start_trace`` takes no options, so its body is replicated with
-    an options-carrying session; any internal-API mismatch falls back
-    to the public call — a python-heavy trace beats no trace."""
-    try:
-        from jax._src import profiler as _prof
-        from jax._src import xla_bridge as _xb
-        from jax._src.lib import xla_client as _xc
-        opts = _xc.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        with _prof._profile_state.lock:
-            if _prof._profile_state.profile_session is not None:
-                raise RuntimeError("profile already started")
-            _xb.get_backend()     # libtpu must init before the tracer
-            _prof._profile_state.profile_session = \
-                _xc.profiler.ProfilerSession(opts)
-            _prof._profile_state.create_perfetto_link = False
-            _prof._profile_state.create_perfetto_trace = False
-            _prof._profile_state.log_dir = str(log_dir)
-    except Exception:  # noqa: BLE001 — richer trace beats no trace
-        jax.profiler.start_trace(log_dir)
+    (ISSUE 14).  A capture window spans jit TRACING, whose millions of
+    python-call events exhaust the trace-viewer export's event cap
+    (~1e6) before a single XLA op event lands — the ingested capture
+    then reads ``unavailable:no-op-events``.  The XLA op events (the
+    ones attribution prices) come from the host/runtime tracer, so
+    ``python_tracer_level = 0`` keeps everything measured and drops
+    only the python noise."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
 
 
 def start_profile(log_dir: Optional[str] = None) -> bool:

@@ -22,10 +22,6 @@ never a fabricated zero.  The three provenance markers:
 * ``"unavailable:<reason>"`` — nothing compiled (trace/compile failure,
   no cost model): every numeric field is ``None``.
 
-The jax-version differences (list-vs-dict ``cost_analysis``, missing
-methods) are absorbed by :mod:`apex_tpu._jax_compat`'s
-``compiled_cost_analysis`` / ``compiled_memory_analysis`` helpers.
-
 CLI: ``python -m apex_tpu.observability.xla_stats [--execs a,b]
 [--out stats.json]`` dumps the ledger-executable stats the flight
 recorder consumes.
@@ -94,14 +90,43 @@ def _unavailable(reason: str) -> CompiledStats:
         provenance=PROVENANCE_UNAVAILABLE_PREFIX + reason)
 
 
+def _compiled_cost_analysis(compiled):
+    """``Compiled.cost_analysis()`` as one flat dict, or None.  A
+    missing method or a backend that raises (some PJRT plugins ship no
+    cost model) -> None — callers must treat None as "unavailable",
+    never as zero."""
+    fn = getattr(compiled, "cost_analysis", None)
+    if fn is None:
+        return None
+    try:
+        out = fn()
+    except Exception:  # noqa: BLE001 — unimplemented on this backend
+        return None
+    return dict(out) if out else None
+
+
+def _compiled_memory_analysis(compiled):
+    """``Compiled.memory_analysis()``: the backend's
+    ``CompiledMemoryStats`` (argument/output/alias/temp byte fields) or
+    None when the method is missing, raises, or returns nothing — the
+    degraded-backend case the caller must mark explicitly."""
+    fn = getattr(compiled, "memory_analysis", None)
+    if fn is None:
+        return None
+    try:
+        out = fn()
+    except Exception:  # noqa: BLE001 — unimplemented on this backend
+        return None
+    if out is None or not hasattr(out, "argument_size_in_bytes"):
+        return None
+    return out
+
+
 def stats_from_compiled(compiled) -> CompiledStats:
     """Extract :class:`CompiledStats` from an already-compiled
     ``jax.stages.Compiled`` (or anything exposing the same analysis
     methods)."""
-    from apex_tpu._jax_compat import (compiled_cost_analysis,
-                                      compiled_memory_analysis)
-
-    cost = compiled_cost_analysis(compiled)
+    cost = _compiled_cost_analysis(compiled)
     if cost is None or "flops" not in cost:
         return _unavailable("no-cost-analysis-on-this-backend")
     flops = int(cost["flops"])
@@ -110,7 +135,7 @@ def stats_from_compiled(compiled) -> CompiledStats:
     bytes_accessed = (int(cost["bytes accessed"])
                       if "bytes accessed" in cost else None)
 
-    mem = compiled_memory_analysis(compiled)
+    mem = _compiled_memory_analysis(compiled)
     if mem is None:
         return CompiledStats(provenance=PROVENANCE_COST_ONLY,
                              flops=flops, bytes_accessed=bytes_accessed)

@@ -105,24 +105,40 @@ def _tail_mask(i, n: int, x, fill):
     return jnp.where(idx < n, x, fill)
 
 
-# every kernel grid is parallel (Megacore splits it freely): the flag /
-# accumulator kernels write PER-BLOCK partials into a (grid,)-shaped SMEM
-# output (each step owns its own slot) that the wrapper reduces with one
-# tiny XLA max/sum — no SMEM state carried across grid steps, unlike the
-# earlier serialized ("arbitrary") variant that pinned the whole unscale
-# path to one core (parity: ``amp_C.multi_tensor_scale``'s chunked
-# launcher is likewise grid-parallel with a global flag buffer)
+# the elementwise kernels' grids are parallel (Megacore splits them
+# freely).  The flag / accumulator kernels write PER-BLOCK partials into
+# a (grid,)-shaped SMEM output (each step owns its own slot) that the
+# wrapper reduces with one tiny XLA max/sum — no scalar state is carried
+# across grid steps (parity: ``amp_C.multi_tensor_scale``'s chunked
+# launcher with a global flag buffer).  Their grid is "arbitrary": the
+# slots travel in shared 1024-slot output blocks (see :func:`_bspec`),
+# which consecutive steps must revisit in order.
 _PAR = pltpu.CompilerParams(dimension_semantics=("parallel",))
+_SEQ = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+
+#: slots per SMEM partials block.  A (1,) block per step, which jax 0.4
+#: accepted, no longer lowers: Mosaic requires a rank-1 block to be the
+#: whole array or a multiple of 128 32-bit words, and to match XLA's own
+#: tiling of the operand — T(1024) for a long fp32 vector (observed on
+#: "TPU v5 lite", PR 21: a 128-slot block fails layout verification)
+_SLOTS = 1024
 
 
 def _bspec():
-    """Per-grid-step (1,) SMEM output block: step i owns slot i.
+    """SMEM partials block: grid step ``i`` owns slot ``i % _SLOTS`` of
+    block ``i // _SLOTS``.  Only one 4 KiB block is staged in SMEM at
+    a time (the assembled array lives in HBM), so SMEM pressure is O(1)
+    in buffer size; SMEM is the right home for a scalar store (Mosaic
+    vector stores want lane-shaped VMEM tiles)."""
+    return pl.BlockSpec((_SLOTS,), lambda i: (i // _SLOTS,),
+                        memory_space=pltpu.SMEM)
 
-    The blocked index map means only ONE element is staged in SMEM per
-    grid step (the assembled ``(grid,)`` array lives in HBM), so SMEM
-    pressure is O(1) in buffer size; SMEM is the right home for a scalar
-    store (Mosaic vector stores want lane-shaped VMEM tiles)."""
-    return pl.BlockSpec((1,), lambda i: (i,), memory_space=pltpu.SMEM)
+
+def _bshape(grid: int):
+    """The partials array: ``grid`` slots rounded up to whole blocks;
+    wrappers reduce ``[:grid]`` (the tail slots are never written)."""
+    return jax.ShapeDtypeStruct((cdiv(grid, _SLOTS) * _SLOTS,),
+                                jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -133,8 +149,8 @@ def _scale_kernel(n, x_ref, hp_ref, o_ref, flag_ref):
     i = pl.program_id(0)
     x = x_ref[...].astype(jnp.float32)
     y = x * hp_ref[0]
-    flag_ref[0] = jnp.any(~jnp.isfinite(_tail_mask(i, n, y, 0.0))
-                          ).astype(jnp.float32)
+    flag_ref[i % _SLOTS] = jnp.any(~jnp.isfinite(_tail_mask(i, n, y, 0.0))
+                                   ).astype(jnp.float32)
     o_ref[...] = y.astype(o_ref.dtype)
 
 
@@ -156,12 +172,12 @@ def fused_scale(flat: jax.Array, scale, out_dtype=None):
         out_specs=[_vspec(), _bspec()],
         out_shape=[
             jax.ShapeDtypeStruct(flat.shape, out_dtype),
-            jax.ShapeDtypeStruct((_grid(flat),), jnp.float32),
+            _bshape(_grid(flat)),
         ],
-        compiler_params=_PAR,
+        compiler_params=_SEQ,
         interpret=interpret_mode(),
     )(flat, hp)
-    return out, jnp.max(flags)
+    return out, jnp.max(flags[:_grid(flat)])
 
 
 def _axpby_kernel(n, x_ref, y_ref, hp_ref, o_ref, flag_ref):
@@ -169,8 +185,8 @@ def _axpby_kernel(n, x_ref, y_ref, hp_ref, o_ref, flag_ref):
     x = x_ref[...].astype(jnp.float32)
     y = y_ref[...].astype(jnp.float32)
     o = hp_ref[0] * x + hp_ref[1] * y
-    flag_ref[0] = jnp.any(~jnp.isfinite(_tail_mask(i, n, o, 0.0))
-                          ).astype(jnp.float32)
+    flag_ref[i % _SLOTS] = jnp.any(~jnp.isfinite(_tail_mask(i, n, o, 0.0))
+                                   ).astype(jnp.float32)
     o_ref[...] = o.astype(o_ref.dtype)
 
 
@@ -191,12 +207,12 @@ def fused_axpby(a, x: jax.Array, b, y: jax.Array, out_dtype=None):
         out_specs=[_vspec(), _bspec()],
         out_shape=[
             jax.ShapeDtypeStruct(x.shape, out_dtype),
-            jax.ShapeDtypeStruct((_grid(x),), jnp.float32),
+            _bshape(_grid(x)),
         ],
-        compiler_params=_PAR,
+        compiler_params=_SEQ,
         interpret=interpret_mode(),
     )(x, y, hp)
-    return out, jnp.max(flags)
+    return out, jnp.max(flags[:_grid(x)])
 
 
 # ---------------------------------------------------------------------------
@@ -206,7 +222,7 @@ def fused_axpby(a, x: jax.Array, b, y: jax.Array, out_dtype=None):
 def _l2norm_kernel(n, x_ref, acc_ref):
     i = pl.program_id(0)
     x = _tail_mask(i, n, x_ref[...].astype(jnp.float32), 0.0)
-    acc_ref[0] = jnp.sum(x * x)
+    acc_ref[i % _SLOTS] = jnp.sum(x * x)
 
 
 def fused_l2norm(flat: jax.Array) -> jax.Array:
@@ -222,19 +238,19 @@ def fused_l2norm(flat: jax.Array) -> jax.Array:
         grid=(_grid(flat),),
         in_specs=[_vspec()],
         out_specs=_bspec(),
-        out_shape=jax.ShapeDtypeStruct((_grid(flat),), jnp.float32),
-        compiler_params=_PAR,
+        out_shape=_bshape(_grid(flat)),
+        compiler_params=_SEQ,
         interpret=interpret_mode(),
     )(flat)
-    return jnp.sqrt(jnp.sum(acc))
+    return jnp.sqrt(jnp.sum(acc[:_grid(flat)]))
 
 
 def _l2norm_scale_kernel(n, x_ref, hp_ref, o_ref, acc_ref, flag_ref):
     i = pl.program_id(0)
     x = x_ref[...].astype(jnp.float32) * hp_ref[0]
     xm = _tail_mask(i, n, x, 0.0)
-    acc_ref[0] = jnp.sum(xm * xm)
-    flag_ref[0] = jnp.any(~jnp.isfinite(xm)).astype(jnp.float32)
+    acc_ref[i % _SLOTS] = jnp.sum(xm * xm)
+    flag_ref[i % _SLOTS] = jnp.any(~jnp.isfinite(xm)).astype(jnp.float32)
     o_ref[...] = x.astype(o_ref.dtype)
 
 
@@ -258,13 +274,14 @@ def fused_l2norm_scale(flat: jax.Array, scale, out_dtype=None):
         out_specs=[_vspec(), _bspec(), _bspec()],
         out_shape=[
             jax.ShapeDtypeStruct(flat.shape, out_dtype),
-            jax.ShapeDtypeStruct((_grid(flat),), jnp.float32),
-            jax.ShapeDtypeStruct((_grid(flat),), jnp.float32),
+            _bshape(_grid(flat)),
+            _bshape(_grid(flat)),
         ],
-        compiler_params=_PAR,
+        compiler_params=_SEQ,
         interpret=interpret_mode(),
     )(flat, hp)
-    return out, jnp.sqrt(jnp.sum(acc)), jnp.max(flags)
+    g = _grid(flat)
+    return out, jnp.sqrt(jnp.sum(acc[:g])), jnp.max(flags[:g])
 
 
 # ---------------------------------------------------------------------------

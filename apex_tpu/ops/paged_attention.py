@@ -58,7 +58,9 @@ from apex_tpu.utils import interpret_mode
 
 __all__ = ["paged_decode_attention", "paged_xla_max_pages",
            "paged_slab_attention", "fused_block_decode", "decode_fusion",
-           "fusion_min_pages", "resolve_decode_fusion"]
+           "fusion_min_pages", "resolve_decode_fusion",
+           "fused_block_vmem_bytes", "fused_block_refusal",
+           "FUSED_BLOCK_VMEM_LIMIT"]
 
 #: pallas_audit registration (analysis hook only, no behavior change):
 #: both kernels run online-softmax in fp32 scratch (APX302) and mask
@@ -419,14 +421,81 @@ def fusion_min_pages(override=None) -> int:
     return _FUSION_MIN_PAGES
 
 
+#: VMEM one kernel may claim through ``vmem_limit_bytes``.  OBSERVED in the
+#: PR 21 bring-up on "TPU v5 lite" (jax 0.9.0, libtpu 0.0.34): Mosaic
+#: reports 134217728 B (128 MiB) of VMEM per core, and the fused block
+#: at hidden 2048 compiled and matched its twin with a 106.4 MiB limit
+#: — with its weights single-buffered; double-buffered they need 2x and
+#: cannot fit.  8 MiB under the physical size is left to the compiler.
+#: PERF.md, "Bring-up, PR 21".
+FUSED_BLOCK_VMEM_LIMIT = 120 * 1024 * 1024
+
+#: room left for Mosaic's own scratch and the kernel's live values (the
+#: [1, ffn] fp32 activations, the per-head score rows) on top of the
+#: buffers the BlockSpecs name
+_VMEM_HEADROOM = 8 * 1024 * 1024
+
+
+def fused_block_vmem_bytes(kind: str, *, hidden: int, ffn: int, heads: int,
+                           kv_heads: int, head_dim: int, page_size: int,
+                           itemsize: int, cache_itemsize: int = 2,
+                           fuse_mlp: bool = True,
+                           partial_out: bool = False) -> int:
+    """VMEM the fused block kernel's buffers occupy, from its BlockSpecs:
+    the layer's weights ONCE (constant index maps, single-buffered), the
+    per-slot activation rows and the k/v page blocks TWICE (pipelined),
+    and the fp32 scratch.  ``heads``/``kv_heads``/``ffn`` are the
+    per-rank values under tensor parallelism."""
+    gpt = kind == "gpt"
+    hd, kvd = heads * head_dim, kv_heads * head_dim
+    # a [1, n] row block still occupies a full sublane tile
+    row = lambda n, size: n * 32 if size < 4 else n * 8 * size  # noqa: E731
+    w = hidden * hd + 2 * hidden * kvd + hd * hidden
+    rows = [hidden] + ([hidden, hd, kvd, kvd] if gpt else [])
+    if gpt and not partial_out:
+        rows.append(hidden)                                   # bo
+    if fuse_mlp:
+        w += (2 if gpt else 3) * hidden * ffn
+        rows += [hidden] + ([hidden, ffn, hidden] if gpt else [])
+    resident = w * itemsize + sum(row(n, itemsize) for n in rows)
+    streamed = (2 * row(hidden, itemsize) + 2 * row(kvd, itemsize)
+                + (0 if gpt else 2 * row(head_dim, itemsize))
+                + 2 * kv_heads * page_size * head_dim * cache_itemsize)
+    lanes = lambda n: -(-n // 128) * 128                      # noqa: E731
+    scratch = 4 * (2 * heads * head_dim + 2 * max(kv_heads, 8) * head_dim
+                   + heads * lanes(page_size) + 2 * heads * 128)
+    return resident + 2 * streamed + scratch
+
+
+def fused_block_refusal(kind: str, **dims) -> Optional[str]:
+    """Why the fused block kernel cannot run at these dims — its buffers
+    plus headroom exceed the VMEM the compiler grants — or ``None`` when
+    it fits.  The string names the observed limit."""
+    need = fused_block_vmem_bytes(kind, **dims) + _VMEM_HEADROOM
+    if need <= FUSED_BLOCK_VMEM_LIMIT:
+        return None
+    return (f"fused-block decode at hidden {dims['hidden']} (ffn "
+            f"{dims['ffn']}, {dims['heads']} heads x {dims['head_dim']}) "
+            f"needs {need / 2**20:.1f} MiB of VMEM for one layer's "
+            f"resident weights, pages and scratch; the compiler grants a "
+            f"kernel at most {FUSED_BLOCK_VMEM_LIMIT / 2**20:.0f} MiB")
+
+
 def resolve_decode_fusion(mode=None, *, paged: bool,
                           max_pages: Optional[int] = None,
-                          min_pages: Optional[int] = None) -> bool:
+                          min_pages: Optional[int] = None,
+                          dims: Optional[dict] = None) -> bool:
     """Engine-side dispatch: does THIS engine run the fused-block
     decode kernel?  The fused kernel streams the slot's pages via the
     page table, so it rides the paged cache only — ``mode="1"`` on a
     dense engine is a configuration error, while ``"auto"`` quietly
-    resolves to the (only available) unfused path."""
+    resolves to the (only available) unfused path.
+
+    ``dims`` (``kind`` plus the :func:`fused_block_vmem_bytes` keywords)
+    lets the width be checked against the VMEM the compiler grants when
+    the engine is BUILT: ``"1"`` at a width that does not fit raises
+    with the limit in the message instead of failing inside Mosaic on
+    the first decode; ``"auto"`` resolves to the unfused path there."""
     mode = decode_fusion(mode)
     if mode == "0":
         return False
@@ -437,9 +506,16 @@ def resolve_decode_fusion(mode=None, *, paged: bool,
                 "the page table (APEX_TPU_DECODE_FUSION=1 needs a "
                 "paged engine); this engine runs the dense slot cache")
         return False
+    refusal = fused_block_refusal(**dims) if dims else None
     if mode == "1":
+        if refusal:
+            raise ValueError(
+                f"{refusal} — serve this width with "
+                f"{_DECODE_FUSION_ENV}=0 (the per-op decode) or shard "
+                f"the layer over more tensor-parallel ranks")
         return True
-    return int(max_pages or 0) >= fusion_min_pages(min_pages)
+    return (not refusal
+            and int(max_pages or 0) >= fusion_min_pages(min_pages))
 
 
 def _fused_block_kernel(kind, scale, kvh, group, ps, mpps, hidden, d,
@@ -501,14 +577,14 @@ def _fused_block_kernel(kind, scale, kvh, group, ps, mpps, hidden, d,
     def _project():
         # norm1 + the three projections run ONCE per slot; everything
         # they produce stays in VMEM scratch across the page loop
-        xv = x_ref[...].astype(f32)                      # [1, hidden]
+        xv = x_ref[0].astype(f32)                        # [1, hidden]
         h1 = norm(xv, ln1_w, ln1_b)
         qh = matmul(h1, wq, bq).reshape(h, d)
         kh = matmul(h1, wk, bk).reshape(kvh, d)
         vh = matmul(h1, wv, bv).reshape(kvh, d)
         if not gpt:
-            cos = cos_ref[...].astype(f32)               # [1, d]
-            sin = sin_ref[...].astype(f32)
+            cos = cos_ref[0].astype(f32)                 # [1, d]
+            sin = sin_ref[0].astype(f32)
 
             def rot(t):
                 t1, t2 = jnp.split(t, 2, axis=-1)
@@ -519,8 +595,8 @@ def _fused_block_kernel(kind, scale, kvh, group, ps, mpps, hidden, d,
         q_scr[...] = qh
         kn_scr[...] = kh
         vn_scr[...] = vh
-        kt_ref[...] = kh.reshape(1, kvh * d).astype(kt_ref.dtype)
-        vt_ref[...] = vh.reshape(1, kvh * d).astype(vt_ref.dtype)
+        kt_ref[0] = kh.reshape(1, kvh * d).astype(kt_ref.dtype)
+        vt_ref[0] = vh.reshape(1, kvh * d).astype(vt_ref.dtype)
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
@@ -578,9 +654,9 @@ def _fused_block_kernel(kind, scale, kvh, group, ps, mpps, hidden, d,
             # out-proj row product — no residual, no bias.  The caller
             # psums at the row boundary, adds ``bo`` once, and runs
             # norm2 + the col/row MLP outside the kernel.
-            o_ref[...] = attn.astype(o_ref.dtype)
+            o_ref[0] = attn.astype(o_ref.dtype)
             return
-        x2 = x_ref[...].astype(f32) + attn               # [1, hidden]
+        x2 = x_ref[0].astype(f32) + attn                 # [1, hidden]
         if fuse_mlp:
             h2 = norm(x2, ln2_w, ln2_b)
             if gpt:
@@ -592,7 +668,7 @@ def _fused_block_kernel(kind, scale, kvh, group, ps, mpps, hidden, d,
                 y = x2 + matmul(jax.nn.silu(g) * u, wd, None)
         else:
             y = x2
-        o_ref[...] = y.astype(o_ref.dtype)
+        o_ref[0] = y.astype(o_ref.dtype)
 
 
 def fused_block_decode(x, blk, k_pages, v_pages, page_table, lengths, *,
@@ -670,17 +746,22 @@ def fused_block_decode(x, blk, k_pages, v_pages, page_table, lengths, *,
     lengths = lengths.astype(jnp.int32)
 
     const = lambda s, p, pt, ln: (0, 0)                  # noqa: E731
-    slot = lambda s, p, pt, ln: (s, 0)                   # noqa: E731
+    # per-slot rows travel as [slots, 1, n] so the block's last two dims
+    # are the array's own: Mosaic rejects a (1, n) block of a
+    # [slots, n] array (1 is neither 8-divisible nor the full dim)
+    slot = lambda s, p, pt, ln: (s, 0, 0)                # noqa: E731
 
     def page_index(s, p, pt, ln):
         last = jnp.maximum((ln[s] + ps - 1) // ps - 1, 0)
         return (pt[s, jnp.minimum(p, last)], 0, 0, 0)
 
     def wspec(a):
-        return pl.BlockSpec(a.shape, const)
+        # constant index map: fetched once, so a second pipeline buffer
+        # would only double the layer's VMEM footprint
+        return pl.BlockSpec(a.shape, const, pipeline_mode=pl.Buffered(1))
 
-    operands = [x]
-    in_specs = [pl.BlockSpec((1, hidden), slot)]
+    operands = [x[:, None, :]]
+    in_specs = [pl.BlockSpec((1, 1, hidden), slot)]
 
     def add_w(*names):
         for n in names:
@@ -688,8 +769,8 @@ def fused_block_decode(x, blk, k_pages, v_pages, page_table, lengths, *,
             in_specs.append(wspec(blk[n]))
 
     if not gpt:
-        operands.extend([cos, sin])
-        in_specs.extend([pl.BlockSpec((1, d), slot)] * 2)
+        operands.extend([cos[:, None, :], sin[:, None, :]])
+        in_specs.extend([pl.BlockSpec((1, 1, d), slot)] * 2)
         add_w("ln1_w", "wq", "wk", "wv")
     else:
         add_w("ln1_w", "ln1_b", "wq", "bq", "wk", "bk", "wv", "bv")
@@ -707,9 +788,9 @@ def fused_block_decode(x, blk, k_pages, v_pages, page_table, lengths, *,
         grid=(slots, mpps),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, hidden), slot),
-            pl.BlockSpec((1, kvh * d), slot),
-            pl.BlockSpec((1, kvh * d), slot),
+            pl.BlockSpec((1, 1, hidden), slot),
+            pl.BlockSpec((1, 1, kvh * d), slot),
+            pl.BlockSpec((1, 1, kvh * d), slot),
         ],
         scratch_shapes=[
             pltpu.VMEM((h, d), jnp.float32),      # q (RoPE'd, unscaled)
@@ -724,16 +805,28 @@ def fused_block_decode(x, blk, k_pages, v_pages, page_table, lengths, *,
     kernel = functools.partial(_fused_block_kernel, kind, scale, kvh,
                                group, ps, mpps, hidden, d, eps, fuse_mlp,
                                partial_out)
+    # price the kernel against what it will actually be granted: without
+    # a limit Mosaic scopes it to 16 MiB, far under one layer's weights
+    vmem_limit = min(FUSED_BLOCK_VMEM_LIMIT, _VMEM_HEADROOM +
+                     fused_block_vmem_bytes(
+                         kind, hidden=hidden,
+                         ffn=blk["wu"].shape[1] if fuse_mlp else 0,
+                         heads=h, kv_heads=kvh, head_dim=d, page_size=ps,
+                         itemsize=blk["wq"].dtype.itemsize,
+                         cache_itemsize=k_pages.dtype.itemsize,
+                         fuse_mlp=fuse_mlp, partial_out=partial_out))
     y, kt, vt = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((slots, hidden), x.dtype),
-            jax.ShapeDtypeStruct((slots, kvh * d), x.dtype),
-            jax.ShapeDtypeStruct((slots, kvh * d), x.dtype),
+            jax.ShapeDtypeStruct((slots, 1, hidden), x.dtype),
+            jax.ShapeDtypeStruct((slots, 1, kvh * d), x.dtype),
+            jax.ShapeDtypeStruct((slots, 1, kvh * d), x.dtype),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
         interpret=interpret_mode(),
     )(page_table, lengths, *operands)
-    return y, kt.reshape(slots, kvh, d), vt.reshape(slots, kvh, d)
+    return (y[:, 0], kt.reshape(slots, kvh, d),
+            vt.reshape(slots, kvh, d))

@@ -1,6 +1,5 @@
 from .common import (
     interpret_mode,
-    on_tpu,
     round_up,
     pad_rows,
     cdiv,
@@ -10,7 +9,6 @@ from .prefetcher import DevicePrefetcher
 
 __all__ = [
     "interpret_mode",
-    "on_tpu",
     "round_up",
     "pad_rows",
     "cdiv",

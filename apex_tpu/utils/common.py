@@ -1,31 +1,31 @@
 """Shared helpers for apex_tpu.
 
-Pallas kernels compile natively on TPU and run in interpret mode everywhere
-else (CPU CI), mirroring the reference's "fused kernel vs eager fallback"
-dispatch (e.g. ``apex/normalization/fused_layer_norm.py :: FusedLayerNorm``
-falls back to ``F.layer_norm`` on CPU tensors).
+Pallas kernels compile natively on TPU and run in interpret mode on the
+CPU platform (the test suite), mirroring the reference's "fused kernel vs
+eager fallback" dispatch (e.g. ``apex/normalization/fused_layer_norm.py ::
+FusedLayerNorm`` falls back to ``F.layer_norm`` on CPU tensors).
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.flatten_util
 import jax.numpy as jnp
 
 
-@functools.cache
-def on_tpu() -> bool:
-    """True when the default JAX backend is a real TPU."""
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
-
-
 def interpret_mode() -> bool:
-    """Whether pallas_call should run in interpret mode (non-TPU backends)."""
-    return not on_tpu()
+    """Whether ``pallas_call`` runs in interpret mode: True on platform
+    ``cpu``, False on ``tpu``.  Any other platform — and a backend that
+    fails to come up, which ``jax.default_backend()`` raises for — is an
+    error, never a quiet switch to the interpreter: a kernel that
+    interprets on a machine with a chip hides the chip."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"apex_tpu Pallas kernels run compiled on 'tpu' and interpreted "
+        f"on 'cpu'; the default JAX backend is {platform!r}")
 
 
 def cdiv(a: int, b: int) -> int:

@@ -250,4 +250,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.compile_cache import \
+        enable_persistent_compile_cache
+    enable_persistent_compile_cache()
     main()

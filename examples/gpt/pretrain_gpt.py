@@ -88,7 +88,9 @@ def parse_args(argv=None):
                         "the per-step params materialize via "
                         "all-gather (numerics match the dense run)")
     p.add_argument("--platform", type=str, default=None,
-                   help="force a jax platform (e.g. cpu)")
+                   help="force a jax platform (e.g. cpu); same as the "
+                        "JAX_PLATFORMS environment variable, which is "
+                        "honoured")
     return p.parse_args(argv)
 
 
@@ -293,4 +295,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.compile_cache import \
+        enable_persistent_compile_cache
+    enable_persistent_compile_cache()
     main()

@@ -26,9 +26,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-import apex_tpu._jax_compat  # noqa: F401  (grafts jax.shard_map on old jax)
-
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
@@ -128,4 +125,7 @@ def _train(expert_parallel_size):
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.compile_cache import \
+        enable_persistent_compile_cache
+    enable_persistent_compile_cache()
     main()

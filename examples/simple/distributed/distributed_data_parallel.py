@@ -22,9 +22,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-import apex_tpu._jax_compat  # noqa: F401  (grafts jax.shard_map on old jax)
-
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -84,4 +81,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.compile_cache import \
+        enable_persistent_compile_cache
+    enable_persistent_compile_cache()
     main()

@@ -287,14 +287,9 @@ def test_xla_path_dropout_stream_matches_kernel():
 
 
 def test_auto_dispatch_predicate(monkeypatch):
-    """On TPU backends short seqs take the XLA path, long seqs and
-    explicit blocks take the kernel; non-TPU backends always kernel."""
+    """On the TPU short seqs take the XLA path, long seqs and explicit
+    blocks take the kernel; the CPU platform always takes the kernel."""
     import apex_tpu.ops.attention as A
-    import apex_tpu.utils.common as common
-    # on_tpu() is functools.cache'd: pre-warm it with the REAL backend
-    # so the monkeypatched default_backend below can't poison it for
-    # this test (interpret-mode selection) or later kernel tests
-    common.on_tpu()
     calls = {}
     real_xla, real_fwd = A._xla_attention, A._fwd
 
@@ -304,13 +299,17 @@ def test_auto_dispatch_predicate(monkeypatch):
 
     def spy_fwd(*a, **k):
         calls["kernel"] = True
-        return real_fwd(*a, **k)
+        # the dispatch under test saw "compiled"; the kernel itself must
+        # still interpret on this CPU host
+        with monkeypatch.context() as m:
+            m.setattr(A, "interpret_mode", lambda: True)
+            return real_fwd(*a, **k)
 
     monkeypatch.setattr(A, "_xla_attention", spy_xla)
     monkeypatch.setattr(A, "_fwd", spy_fwd)
     q, k, v = _qkv(21, 1, 2, 128, 128, 64)
 
-    monkeypatch.setattr(A.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(A, "interpret_mode", lambda: False)
     calls.clear()
     A.flash_attention(q, k, v)
     assert calls == {"xla": True}            # short seq on tpu -> XLA
@@ -319,10 +318,10 @@ def test_auto_dispatch_predicate(monkeypatch):
     A.flash_attention(q, k, v, block_q=128, block_k=128)
     assert calls == {"kernel": True}         # explicit blocks -> kernel
 
-    monkeypatch.setattr(A.jax, "default_backend", lambda: "cpu")
+    monkeypatch.setattr(A, "interpret_mode", lambda: True)
     calls.clear()
     A.flash_attention(q, k, v)
-    assert calls == {"kernel": True}         # non-tpu backend -> kernel
+    assert calls == {"kernel": True}         # cpu platform -> kernel
 
 
 def _xla_kernel_parity_case(b, h, sq, sk, d, seed, **kw):
@@ -378,7 +377,7 @@ def test_xla_kernel_rect_causal_parity(sq, sk):
 def test_xla_max_seq_override_env_and_kwarg(monkeypatch):
     """The kernel/XLA auto-dispatch crossover is tunable without a code
     edit: APEX_TPU_ATTN_XLA_MAX_SEQ env var, overridden in turn by the
-    per-call kwarg (VERDICT weak #8 — the 256 default is interpolated,
+    per-call kwarg (the 256 default is interpolated,
     not densely measured)."""
     from apex_tpu.ops.attention import (_XLA_PATH_MAX_SEQ,
                                         xla_path_max_seq)

@@ -113,37 +113,50 @@ def test_fused_llama_tracks_unfused_step_locked(kvh):
 
 
 def test_fused_layer_params_is_exact_reslicing():
-    """The fused layout must reproduce the model path's projections
-    EXACTLY (same dots over the same reduction order): q/k/v from the
-    deinterleaved planes equal the interleaved qkv's split, for both
-    weight conventions."""
+    """The fused layout is an EXACT re-slicing of the model tree's
+    weights: the deinterleaved q/k/v planes equal the interleaved
+    qkv's columns bit for bit, for both weight conventions, and the
+    projections through them match the model path's.  (The projections
+    were pinned bitwise under jax 0.4; a [5, h] x [h, n] dot and the
+    same columns of a [5, h] x [h, 3n] dot are different XLA:CPU
+    kernels, and jax 0.9's sum them in different orders — 3.6e-7 apart
+    — so the bitwise claim now sits on the weights, where it belongs.)"""
     cfg, params = _gpt(layers=1)
+    d = cfg.hidden_size // cfg.num_attention_heads
     p = params["params"]["layer_0"]["self_attention"]["query_key_value"]
     blk = inf_models.fused_layer_params("gpt", cfg, params)[0]
+    w = np.asarray(p["weight"]).T.reshape(
+        cfg.hidden_size, cfg.num_attention_heads, 3, d)
+    b = np.asarray(p["bias"]).reshape(cfg.num_attention_heads, 3, d)
     x = jax.random.normal(jax.random.PRNGKey(3), (5, cfg.hidden_size))
     qkv = (x @ p["weight"].T + p["bias"]).reshape(
-        5, cfg.num_attention_heads, 3 * 16)
-    q_ref, k_ref, v_ref = jnp.split(qkv, 3, axis=-1)
-    np.testing.assert_array_equal(
-        np.asarray(x @ blk["wq"] + blk["bq"]),
-        np.asarray(q_ref.reshape(5, -1)))
-    np.testing.assert_array_equal(
-        np.asarray(x @ blk["wk"] + blk["bk"]),
-        np.asarray(k_ref.reshape(5, -1)))
-    np.testing.assert_array_equal(
-        np.asarray(x @ blk["wv"] + blk["bv"]),
-        np.asarray(v_ref.reshape(5, -1)))
+        5, cfg.num_attention_heads, 3 * d)
+    for i, (name, ref) in enumerate(zip(
+            "qkv", jnp.split(qkv, 3, axis=-1))):
+        np.testing.assert_array_equal(
+            np.asarray(blk["w" + name]),
+            w[:, :, i, :].reshape(cfg.hidden_size, -1))
+        np.testing.assert_array_equal(
+            np.asarray(blk["b" + name]), b[:, i, :].reshape(1, -1))
+        np.testing.assert_allclose(
+            np.asarray(x @ blk["w" + name] + blk["b" + name]),
+            np.asarray(ref.reshape(5, -1)), rtol=1e-5, atol=1e-6)
 
     cfg2, params2 = _llama(2)
+    d2 = cfg2.hidden_size // cfg2.num_attention_heads
     att = params2["params"]["layer_0"]["attention"]
     blk2 = inf_models.fused_layer_params("llama", cfg2, params2)[0]
+    kvw = np.asarray(att["kv_proj"]["weight"]).T.reshape(
+        cfg2.hidden_size, 2, 2, d2)
     x2 = jax.random.normal(jax.random.PRNGKey(4), (5, cfg2.hidden_size))
-    kv = (x2 @ att["kv_proj"]["weight"].T).reshape(5, 2, 2 * 8)
-    k2, v2 = jnp.split(kv, 2, axis=-1)
-    np.testing.assert_array_equal(np.asarray(x2 @ blk2["wk"]),
-                                  np.asarray(k2.reshape(5, -1)))
-    np.testing.assert_array_equal(np.asarray(x2 @ blk2["wv"]),
-                                  np.asarray(v2.reshape(5, -1)))
+    kv = (x2 @ att["kv_proj"]["weight"].T).reshape(5, 2, 2 * d2)
+    for i, (name, ref) in enumerate(zip("kv", jnp.split(kv, 2, axis=-1))):
+        np.testing.assert_array_equal(
+            np.asarray(blk2["w" + name]),
+            kvw[:, :, i, :].reshape(cfg2.hidden_size, -1))
+        np.testing.assert_allclose(
+            np.asarray(x2 @ blk2["w" + name]),
+            np.asarray(ref.reshape(5, -1)), rtol=1e-5, atol=1e-6)
 
 
 def test_fused_decode_logits_close_to_unfused():
@@ -205,6 +218,39 @@ def test_decode_fusion_knob_resolution(monkeypatch):
     assert not resolve_decode_fusion("0", paged=True, max_pages=99)
     with pytest.raises(ValueError):
         resolve_decode_fusion("1", paged=False)
+
+
+def test_fused_block_width_is_priced_against_vmem_at_engine_build():
+    """ISSUE 21: a layer whose weights do not fit the VMEM the compiler
+    grants is refused when the engine is BUILT, with the limit in the
+    message — not inside Mosaic on the first decode.  GPT-3 1.3B's 2048
+    (16 x 128, ffn 8192, bf16) is the widest that fits on the v5e; the
+    same width fits again under tp=4, attention-only."""
+    from apex_tpu.ops.paged_attention import (FUSED_BLOCK_VMEM_LIMIT,
+                                              fused_block_refusal,
+                                              fused_block_vmem_bytes)
+
+    def dims(hidden, tp=1):
+        heads = hidden // 128 // tp
+        return dict(kind="gpt", hidden=hidden, ffn=4 * hidden // tp,
+                    heads=heads, kv_heads=heads, head_dim=128,
+                    page_size=64, itemsize=2, fuse_mlp=tp == 1,
+                    partial_out=tp > 1)
+
+    # resident weights dominate: 24 * hidden^2 bytes at bf16
+    assert 24 * 2048 ** 2 < fused_block_vmem_bytes(**dims(2048)) \
+        < 1.04 * 24 * 2048 ** 2
+    assert fused_block_refusal(**dims(2048)) is None
+    assert resolve_decode_fusion("1", paged=True, dims=dims(2048))
+    limit = f"{FUSED_BLOCK_VMEM_LIMIT / 2**20:.0f} MiB"
+    for hidden in (2560, 4096):
+        assert limit in fused_block_refusal(**dims(hidden))
+        with pytest.raises(ValueError, match=f"hidden {hidden}.*{limit}"):
+            resolve_decode_fusion("1", paged=True, dims=dims(hidden))
+        # auto resolves to the path that fits instead of raising
+        assert not resolve_decode_fusion("auto", paged=True, max_pages=64,
+                                         dims=dims(hidden))
+    assert resolve_decode_fusion("1", paged=True, dims=dims(4096, tp=4))
 
 
 def test_fusion_requires_paged_engine():

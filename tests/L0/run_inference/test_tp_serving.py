@@ -131,6 +131,45 @@ def test_gpt_tp2_parity_and_per_rank_hbm_fast():
     assert rank < 0.75 * full, (rank, full)
 
 
+def test_init_cache_builds_the_pool_sharded_never_whole(monkeypatch):
+    """ISSUE 21: a pool sized for tp chips must never exist on one.
+    ``init_cache`` at tp=2 returns k/v sharded over the kv-head dim, no
+    array of the full pool shape is committed to a single device, and
+    nothing of that shape is ever handed to ``device_put`` (the old
+    build-whole-then-reshard path)."""
+    from jax.sharding import PartitionSpec as P
+
+    cfg, params = _gpt(hidden=32, heads=2, layers=1, vocab=64,
+                       max_seq=64)
+    eng = InferenceEngine("gpt", cfg, params, slots=2, paged=True,
+                          page_size=16, num_pages=12,
+                          sampling=SamplingConfig(), tp=2)
+    full_shape = (12 + 1, 1, 2, 16, 16)
+    put_shapes = []
+    real_put = jax.device_put
+    monkeypatch.setattr(
+        jax, "device_put",
+        lambda x, *a, **k: (put_shapes.extend(
+            getattr(leaf, "shape", None)
+            for leaf in jax.tree_util.tree_leaves(x)),
+            real_put(x, *a, **k))[1])
+    before = {id(a) for a in jax.live_arrays()}
+    cache = eng.init_cache()
+    assert cache.k.shape == cache.v.shape == full_shape
+    for pool in (cache.k, cache.v):
+        assert pool.sharding.spec == P(None, None, "tensor", None, None)
+        assert len(pool.sharding.device_set) == 2
+        assert {s.data.shape for s in pool.addressable_shards} == \
+            {(13, 1, 1, 16, 16)}
+    for table in (cache.page_table, cache.lengths, cache.capacity):
+        assert table.sharding.is_fully_replicated
+    assert full_shape not in put_shapes
+    whole = [a for a in jax.live_arrays()
+             if id(a) not in before and a.shape == full_shape
+             and len(a.sharding.device_set) == 1]
+    assert not whole, "an unsharded pool was committed to one device"
+
+
 @pytest.mark.parametrize("tp", [2, 4])
 @pytest.mark.parametrize("fusion", ["0", "1"])
 def test_gpt_tp_matrix(tp, fusion):

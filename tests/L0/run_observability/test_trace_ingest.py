@@ -136,16 +136,24 @@ def test_attribute_category_times_and_exposed_comm():
 
 def test_attribute_steps_mfu_and_model_comparison():
     rec = attribute([_scenario_rank0()], steps=2, flops_per_step=1e9,
-                    device_kind="cpu-falls-to-default",
+                    device_kind="TPU v5 lite",
                     model_exposed_comm_us=10.0)
     assert rec["steps"] == 2
     assert rec["step_us"] == 65.0
     assert rec["step_exposed_comm_us"] == 8.5
-    # measured MFU = steps * flops / compute seconds / default-chip peak
-    from apex_tpu.chip_specs import default_spec
-    expect = 2e9 / (107e-6) / (default_spec().bf16_tflops * 1e12)
+    # measured MFU = steps * flops / compute seconds / the NAMED chip's peak
+    from apex_tpu.chip_specs import find_spec
+    expect = 2e9 / (107e-6) / (find_spec("TPU v5 lite").bf16_tflops * 1e12)
     assert rec["mfu"] == pytest.approx(expect, abs=1e-4)
     assert rec["mfu_provenance"] == "measured:trace"
+    # a device kind outside the chip table has no peak: MFU is absent
+    # with a marker, never priced against another chip's
+    for kind in ("cpu", None):
+        rec2 = attribute([_scenario_rank0()], steps=2, flops_per_step=1e9,
+                         device_kind=kind)
+        assert "mfu" not in rec2
+        assert rec2["mfu_provenance"] == \
+            "unavailable:device-kind-not-in-chip-specs"
     assert rec["model_exposed_comm_us"] == 10.0
     assert rec["exposed_comm_drift_ratio"] == pytest.approx(0.85)
 

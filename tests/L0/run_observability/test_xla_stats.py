@@ -129,24 +129,6 @@ def test_compile_failure_yields_marker_not_exception():
     assert stats.flops is None
 
 
-def test_list_and_dict_cost_analysis_both_normalize():
-    """Old jax returns cost_analysis() as [dict], modern jax as dict —
-    the _jax_compat helper must accept both spellings."""
-    from apex_tpu._jax_compat import compiled_cost_analysis
-
-    class _ListStyle:
-        def cost_analysis(self):
-            return [{"flops": 10.0, "bytes accessed": 20.0}]
-
-    class _DictStyle:
-        def cost_analysis(self):
-            return {"flops": 10.0, "bytes accessed": 20.0}
-
-    for style in (_ListStyle(), _DictStyle()):
-        out = compiled_cost_analysis(style)
-        assert out == {"flops": 10.0, "bytes accessed": 20.0}
-
-
 @pytest.mark.parametrize("exec_name", ["train_step_dense"])
 def test_ledger_stats_covers_registered_executable(exec_name):
     from apex_tpu.observability.xla_stats import ledger_stats

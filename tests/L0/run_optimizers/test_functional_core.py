@@ -121,8 +121,11 @@ def test_noop_flag_and_grad_scale_parity(name, make_cls, tx, hyper):
 def test_state_dict_roundtrip_through_init_update():
     """Functional slots ARE the class checkpoint format: pack a
     FlatState into a ``state_dict``, load it into a fresh class
-    optimizer, and both continuations stay bitwise identical — and the
-    reverse direction (class state_dict -> FlatState) too."""
+    optimizer, and both continuations agree to one fp32 ulp — and the
+    reverse direction (class state_dict -> FlatState) too.  (Pinned
+    bitwise under jax 0.4; the two continuations are two different
+    XLA programs — traced vs baked-in hyperparameters — and jax 0.9's
+    XLA:CPU contracts their multiply-adds differently, 3e-8 apart.)"""
     params = _params()
     tx = functional.fused_adam(lr=3e-3, weight_decay=0.05)
     hyper = dict(lr=3e-3, beta1=0.9, beta2=0.999, eps=1e-8,
@@ -142,8 +145,9 @@ def test_state_dict_roundtrip_through_init_update():
     g3 = _params(7)
     opt.step(g3)
     st = upd(st, _flat(g3), _traced(hyper))
-    np.testing.assert_array_equal(np.asarray(st.master),
-                                  np.asarray(opt.param_groups[0].master))
+    np.testing.assert_allclose(np.asarray(st.master),
+                               np.asarray(opt.param_groups[0].master),
+                               rtol=2e-7, atol=1e-7)
 
     # class -> functional
     sd = opt.state_dict()
@@ -156,8 +160,9 @@ def test_state_dict_roundtrip_through_init_update():
     g4 = _params(8)
     opt.step(g4)
     st2 = upd(st2, _flat(g4), _traced(hyper))
-    np.testing.assert_array_equal(np.asarray(st2.master),
-                                  np.asarray(opt.param_groups[0].master))
+    np.testing.assert_allclose(np.asarray(st2.master),
+                               np.asarray(opt.param_groups[0].master),
+                               rtol=2e-7, atol=1e-7)
 
 
 def test_update_is_donation_safe():

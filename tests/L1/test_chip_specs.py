@@ -28,10 +28,29 @@ def test_find_spec_matches_device_kind_spellings():
     assert chip_specs.find_spec("TPU v5e").key == "v5e"
     assert chip_specs.find_spec("TPU v5 lite").key == "v5lite"
     assert chip_specs.find_spec("TPU v4").key == "v4"
-    # unknown kinds fall back to the default generation
-    assert chip_specs.find_spec("Colossus MK1") is \
-        chip_specs.default_spec()
-    assert chip_specs.find_spec(None) is chip_specs.default_spec()
+    # a kind outside the table is an error, never the default chip
+    for kind in ("Colossus MK1", "cpu", None):
+        with pytest.raises(KeyError, match="not in apex_tpu.chip_specs"):
+            chip_specs.find_spec(kind)
+
+
+def test_local_spec_raises_on_unknown_live_device(monkeypatch):
+    """The live device of this host is the CPU platform — not a chip in
+    the table — so ``local_spec()`` raises; and it resolves a live
+    device that IS in the table."""
+    import jax
+
+    with pytest.raises(KeyError, match="'cpu'"):
+        chip_specs.local_spec()
+
+    class _Dev:
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
+    assert chip_specs.local_spec() is chip_specs.CHIP_SPECS["v5lite"]
+    _Dev.device_kind = "TPU v9 mega"
+    with pytest.raises(KeyError, match="TPU v9 mega"):
+        chip_specs.local_spec()
 
 
 def test_no_second_copy_of_the_numbers():
@@ -58,9 +77,11 @@ def test_no_second_copy_of_the_numbers():
 
 
 def test_bench_chip_spec_resolves_through_the_table():
+    """On this CPU host the --inner cpu legs price against the nominal
+    chip, asked for by name."""
     import bench
     tflops, hbm = bench._chip_spec()
-    spec = chip_specs.local_spec()
+    spec = chip_specs.default_spec()
     assert (tflops, hbm) == (spec.bf16_tflops, spec.hbm_gbps)
 
 

@@ -82,7 +82,7 @@ def test_timeout_entries_are_retried_and_not_clobbered(monkeypatch,
     monkeypatch.setattr(exp, "run_experiment", succeed)
     exp.main()
     assert json.loads(out.read_text())["moe"]["moe_dispatch_sweep"] == []
-    # every experiment clean -> the watcher's full-batch marker prints
+    # every experiment clean -> the full-batch marker prints
     assert "ALL_COMPLETE" in capsys.readouterr().out
 
 

@@ -42,7 +42,9 @@ def captured_leg(tmp_path, monkeypatch):
 
 
 def test_cpu_leg_stamps_measured_attribution(captured_leg):
-    extras = {"chip": "cpu", "compiled_flops": 2 * 128 ** 3 * 2,
+    # the trace is a CPU one; the chip is NAMED so the MFU arithmetic
+    # has a peak to divide by (a "cpu" kind yields no MFU — below)
+    extras = {"chip": "TPU v5 lite", "compiled_flops": 2 * 128 ** 3 * 2,
               "exposed_comm_model_us": 0.0}
     bench._stamp_measured_attribution(extras, captured_leg, steps=3)
     assert extras["measured_attribution_provenance"] == "measured:trace"
@@ -58,6 +60,11 @@ def test_cpu_leg_stamps_measured_attribution(captured_leg):
     assert "exposed_comm_drift_ratio" not in extras
     # measured MFU landed from compiled FLOPs / measured compute time
     assert 0 < extras.get("measured_mfu", 0) <= 1.0
+    # the live kind of this host is not in the chip table: no MFU stamp
+    cpu_extras = {"chip": "cpu", "compiled_flops": 2 * 128 ** 3 * 2}
+    bench._stamp_measured_attribution(cpu_extras, captured_leg, steps=3)
+    assert cpu_extras["measured_attribution_provenance"] == "measured:trace"
+    assert "measured_mfu" not in cpu_extras
 
     # acceptance arithmetic: the attributed category times + host gap
     # sum to the measured window within the documented tolerance

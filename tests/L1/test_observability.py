@@ -247,11 +247,15 @@ def test_instrumented_train_loop_zero_recompiles_and_parity():
                                rtol=1e-6)
 
 
-def test_instrumented_loop_arms_mfu_from_compiled_flops():
+def test_instrumented_loop_arms_mfu_from_compiled_flops(monkeypatch):
     """mfu_from_compiled=True (ISSUE 10): the gauge is priced from the
     COMPILED step's cost_analysis() FLOPs, and the one AOT compile at
     run start lands outside every step bracket — the recompile counter
     still pins 0."""
+    # this host's device kind ("cpu") is not in the chip table, so the
+    # gauge's peak is the nominal chip, asked for by name
+    from apex_tpu import chip_specs
+    monkeypatch.setattr(chip_specs, "local_spec", chip_specs.default_spec)
     params = _make_params()
     tx = functional.fused_adam(lr=1e-2)
     tel = TrainTelemetry(MetricsRegistry())

@@ -1,7 +1,7 @@
 """Two-process ``jax.distributed`` smoke test (slow lane).
 
 Executable evidence for the multi-process story MIGRATION.md documents
-(VERDICT missing #4): the recipe is one SPMD process per host plus
+: the recipe is one SPMD process per host plus
 ``jax.distributed.initialize(coordinator_address, num_processes,
 process_id)`` — this test actually runs it, as two OS processes on the
 CPU backend, and asserts the coordination service forms, the global
