@@ -70,6 +70,8 @@ the 1/tp-sharded weight-block envelope for ROADMAP item 1.
 from __future__ import annotations
 
 import importlib
+import linecache
+import re
 from dataclasses import dataclass
 from typing import Callable, Optional, Sequence
 
@@ -78,7 +80,8 @@ from apex_tpu.chip_specs import CHIP_SPECS, DEFAULT_CHIP, ChipSpec
 
 __all__ = [
     "BUDGET_NAME", "DOUBLE_BUFFER", "KernelOpSpec", "BlockRecord",
-    "KernelRecord", "kernel_specs", "extract_kernels",
+    "KernelRecord", "kernel_specs", "kernel_function_name",
+    "extract_kernels",
     "check_kernel_record", "audit_kernel_op", "run_kernel_audit",
     "compare_kernel_budget", "fused_block_envelope",
     "predict_fusion_max_hidden", "FUSION_SWEEP",
@@ -341,14 +344,26 @@ def _prod(shape) -> int:
     return out
 
 
+def kernel_function_name(eqn) -> str:
+    """The name of the kernel FUNCTION of a ``pallas_call`` equation —
+    what ``PALLAS_AUDIT`` and the budget ledger key on.
+    ``pallas_call(name=)`` (the kernel's stable name in profiler traces)
+    takes the function's place in the kernel jaxpr's debug info but
+    leaves the file and line of its ``def``: read the name there."""
+    info = eqn.params["jaxpr"].debug_info
+    if eqn.params.get("name") and info.func_filename and info.func_lineno:
+        m = re.match(r"\s*def\s+(\w+)", linecache.getline(
+            info.func_filename, info.func_lineno))
+        if m:
+            return m.group(1)
+    return info.func_name
+
+
 def _record_from_eqn(eqn) -> KernelRecord:
     import jax.extend.core as jex_core
 
     gm = eqn.params["grid_mapping"]
-    # the explicit pallas_call(name=) if any, else the kernel function's
-    # own name off the kernel jaxpr's debug info
-    kname = (eqn.params.get("name")
-             or eqn.params["jaxpr"].debug_info.func_name)
+    kname = kernel_function_name(eqn)
 
     npre = gm.num_index_operands
     prefetch = sum(_prod(sh.shape) * _itemsize(sh.dtype)

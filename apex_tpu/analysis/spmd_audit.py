@@ -87,6 +87,7 @@ from apex_tpu.analysis.comm_model import (COLLECTIVE_PRIMS, collective_axes,
                                           comm_report, jaxpr_dot_flops,
                                           peak_live_bytes)
 from apex_tpu.analysis.finding import Finding
+from apex_tpu.analysis.pallas_audit import kernel_function_name
 
 __all__ = ["ExecSpec", "exec_specs", "run_spmd_audit", "compare_budget",
            "ensure_devices", "CANONICAL_AXES", "DONATION_FLOOR_BYTES",
@@ -807,8 +808,7 @@ class _Uniformity:
         return [a | b for a, b in zip(carry, out)]
 
     def _pallas(self, eqn, vof) -> None:
-        label = str(eqn.params.get("name_and_src_info")
-                    or eqn.params.get("name") or "")
+        label = kernel_function_name(eqn)
         if not any(mark in label for mark in _UPDATE_KERNEL_MARKS):
             return
         if not self.spec.check_update_uniformity:

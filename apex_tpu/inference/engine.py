@@ -759,7 +759,9 @@ class InferenceEngine:
         REFERENCES host-side only after this returns, so a stub engine
         (protocol audit) can mirror the whole lifecycle without a
         device."""
-        return kv_cache.evict(cache, slot)
+        with obs.trace_annotation("apex_tpu.inference.evict_slot",
+                                  slot=int(slot)):
+            return kv_cache.evict(cache, slot)
 
     def page_host_bytes(self) -> int:
         """Host-DRAM bytes ONE page's k+v slabs occupy in the host

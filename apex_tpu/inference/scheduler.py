@@ -79,7 +79,7 @@ import numpy as np
 from apex_tpu.inference import kv_cache
 from apex_tpu.inference.prefix_cache import PrefixCache, prefix_cache_enabled
 from apex_tpu.inference.speculative import Drafter, NGramDrafter
-from apex_tpu.observability import ServeTelemetry
+from apex_tpu.observability import ServeTelemetry, trace_annotation
 from apex_tpu.observability.slo import SLOTracker
 
 __all__ = ["Request", "SlotScheduler", "generate",
@@ -314,39 +314,40 @@ class SlotScheduler:
                eos_id: Optional[int] = None, tenant: str = "default",
                priority: int = 0) -> int:
         """Queue one request; returns its uid (results key)."""
-        tel = self.telemetry
-        prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
-        if not prompt:
-            tel.request_rejected("empty_prompt", tenant=tenant)
-            raise ValueError("empty prompt")
-        if len(prompt) > self.engine.max_seq:
-            tel.request_rejected("prompt_over_max_seq", tenant=tenant)
-            raise ValueError(
-                f"prompt length {len(prompt)} exceeds engine max_seq "
-                f"{self.engine.max_seq}")
-        if self.alloc is not None:
-            # fail fast: a request no empty pool could ever cover would
-            # otherwise stall the queue mid-run after earlier requests
-            # already finished (and their results were built).  The
-            # check is conservative — cold-path pages — because hits
-            # cannot be known before the prefix cache is populated.
-            need = self.alloc.pages_needed(len(prompt)
-                                           + int(max_new_tokens))
-            if need > self.engine.num_pages:
-                tel.request_rejected("request_over_pool", tenant=tenant)
+        with trace_annotation("apex_tpu.scheduler.submit"):
+            tel = self.telemetry
+            prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
+            if not prompt:
+                tel.request_rejected("empty_prompt", tenant=tenant)
+                raise ValueError("empty prompt")
+            if len(prompt) > self.engine.max_seq:
+                tel.request_rejected("prompt_over_max_seq", tenant=tenant)
                 raise ValueError(
-                    f"request needs {need} pages of "
-                    f"{self.engine.page_size} (prompt {len(prompt)} + "
-                    f"budget {int(max_new_tokens)} tokens) but the "
-                    f"pool has only {self.engine.num_pages}; grow "
-                    f"num_pages or shrink the request")
-        uid = self._next_uid
-        self._next_uid += 1
-        self.queue.append(Request(uid, prompt, int(max_new_tokens),
-                                  eos_id, str(tenant), int(priority)))
-        tel.request_submitted(uid, len(prompt), int(max_new_tokens),
-                              queue_depth=len(self.queue))
-        return uid
+                    f"prompt length {len(prompt)} exceeds engine max_seq "
+                    f"{self.engine.max_seq}")
+            if self.alloc is not None:
+                # fail fast: a request no empty pool could ever cover would
+                # otherwise stall the queue mid-run after earlier requests
+                # already finished (and their results were built).  The
+                # check is conservative — cold-path pages — because hits
+                # cannot be known before the prefix cache is populated.
+                need = self.alloc.pages_needed(len(prompt)
+                                               + int(max_new_tokens))
+                if need > self.engine.num_pages:
+                    tel.request_rejected("request_over_pool", tenant=tenant)
+                    raise ValueError(
+                        f"request needs {need} pages of "
+                        f"{self.engine.page_size} (prompt {len(prompt)} + "
+                        f"budget {int(max_new_tokens)} tokens) but the "
+                        f"pool has only {self.engine.num_pages}; grow "
+                        f"num_pages or shrink the request")
+            uid = self._next_uid
+            self._next_uid += 1
+            self.queue.append(Request(uid, prompt, int(max_new_tokens),
+                                      eos_id, str(tenant), int(priority)))
+            tel.request_submitted(uid, len(prompt), int(max_new_tokens),
+                                  queue_depth=len(self.queue))
+            return uid
 
     def _offload_pages(self, page_ids):
         """Eviction-side device→host copy for the prefix cache's host
@@ -671,33 +672,38 @@ class SlotScheduler:
         start = st.prefilled
         end = (total if not self.prefill_chunk
                else min(total, start + self.prefill_chunk))
-        with tel.prefill_step(
-                prompt_len=end - start,
-                bucket_len=eng.bucket_for(end - start),
-                uid=st.uid, start_tok=start):
-            self.cache, tok, _ = eng.prefill(
-                self.cache, st.prompt[:end], slot, pages=st.pages,
-                prefill_from=start)
-            tok = int(np.asarray(tok))
-        st.prefilled = end
-        if st.chunked:
-            tel.prefill_chunked(st.uid, start, end - start)
-        if end < total:
-            return                     # more chunks to go
-        # final piece: the sampled token is the request's first
-        tel.first_token(st.uid)
-        st.generated.append(tok)
-        self._run_last[slot] = tok
-        if self.drafter is not None and eng.spec_k:
-            self.drafter.begin(slot, st.prompt, tok)
-        if self.prefix is not None:
-            ps = eng.page_size
-            new = self.prefix.insert(
-                st.prompt, st.pages[:-(-total // ps)])
-            if new:
-                self._pool_gauges()
-        if st.done():
-            self._retire(slot, REASON_LENGTH)
+        bucket = eng.bucket_for(end - start)
+        with trace_annotation("apex_tpu.scheduler.prefill", uid=st.uid,
+                              slot=slot, tokens=end - start, bucket=bucket):
+            with tel.prefill_step(
+                    prompt_len=end - start, bucket_len=bucket,
+                    uid=st.uid, start_tok=start):
+                self.cache, tok, _ = eng.prefill(
+                    self.cache, st.prompt[:end], slot, pages=st.pages,
+                    prefill_from=start)
+                # the one place of a prefill where the host waits
+                # for the device
+                with trace_annotation("apex_tpu.scheduler.token_read"):
+                    tok = int(np.asarray(tok))
+            st.prefilled = end
+            if st.chunked:
+                tel.prefill_chunked(st.uid, start, end - start)
+            if end < total:
+                return                     # more chunks to go
+            # final piece: the sampled token is the request's first
+            tel.first_token(st.uid)
+            st.generated.append(tok)
+            self._run_last[slot] = tok
+            if self.drafter is not None and eng.spec_k:
+                self.drafter.begin(slot, st.prompt, tok)
+            if self.prefix is not None:
+                ps = eng.page_size
+                new = self.prefix.insert(
+                    st.prompt, st.pages[:-(-total // ps)])
+                if new:
+                    self._pool_gauges()
+            if st.done():
+                self._retire(slot, REASON_LENGTH)
 
     def _admit_one(self) -> bool:
         eng, tel = self.engine, self.telemetry
@@ -762,28 +768,35 @@ class SlotScheduler:
         sees only the fixed-shape prefill/decode (+COW copy)
         executables; everything else here is host-side bookkeeping on
         ints."""
+        with trace_annotation("apex_tpu.scheduler.pass"):
+            self._pass()
+
+    def _pass(self) -> None:
         eng, tel = self.engine, self.telemetry
         slots = self._run_slots
-        # SLO load observation (ISSUE 13): one host-side sample per
-        # pass through the overload detector; while the advisory
-        # holds and shedding is armed, the worst-ranked queued
-        # request is rejected (at most one per pass — shedding
-        # relieves pressure, it does not empty the queue)
-        advisory = self.slo.observe_load(
-            queue_depth=len(self.queue),
-            backpressure_total=tel.backpressure_waits.total(),
-            free_pages=(self.alloc.free_pages
-                        if self.alloc is not None else None))
-        if advisory and self.shed_on_overload and self.queue:
-            self._shed_one()
-        # admit: fill free slots from the queue (priority/fairness
-        # ordered — a picked request the pool can't cover yet
-        # blocks this pass rather than being starved)
-        blocked = False
-        while self.queue and self._run_free:
-            if not self._admit_one():
-                blocked = True
-                break
+        with trace_annotation("apex_tpu.scheduler.admit",
+                              queue=len(self.queue),
+                              free_slots=len(self._run_free)):
+            # SLO load observation (ISSUE 13): one host-side sample per
+            # pass through the overload detector; while the advisory
+            # holds and shedding is armed, the worst-ranked queued
+            # request is rejected (at most one per pass — shedding
+            # relieves pressure, it does not empty the queue)
+            advisory = self.slo.observe_load(
+                queue_depth=len(self.queue),
+                backpressure_total=tel.backpressure_waits.total(),
+                free_pages=(self.alloc.free_pages
+                            if self.alloc is not None else None))
+            if advisory and self.shed_on_overload and self.queue:
+                self._shed_one()
+            # admit: fill free slots from the queue (priority/fairness
+            # ordered — a picked request the pool can't cover yet
+            # blocks this pass rather than being starved)
+            blocked = False
+            while self.queue and self._run_free:
+                if not self._admit_one():
+                    blocked = True
+                    break
         # advance prefills.  Chunking off: every pending admission
         # prefills now (the classic loop).  Chunking on: at most
         # max_chunks_per_pass chunks run BETWEEN decode steps, so a
@@ -830,7 +843,8 @@ class SlotScheduler:
         for slot, st in enumerate(slots):
             if st is not None and active[slot] \
                     and st.cache_len() >= st.capacity:
-                self._retire(slot, REASON_TRUNCATED)
+                with trace_annotation("apex_tpu.scheduler.retire"):
+                    self._retire(slot, REASON_TRUNCATED)
                 active[slot] = False
         if not active.any():
             return
@@ -849,13 +863,16 @@ class SlotScheduler:
             slab = np.zeros((eng.slots, k + 1), np.int32)
             slab[:, 0] = self._run_last
             slab[:, 1:] = self.drafter.draft_batch(active, k)
-            with tel.verify_step(n_active,
-                                 capacity=eng.slots) as vstep:
+            with trace_annotation("apex_tpu.scheduler.verify",
+                                  active=n_active), \
+                    tel.verify_step(n_active,
+                                    capacity=eng.slots) as vstep:
                 self.cache, toks, n_emit, truncated = eng.verify(
                     self.cache, slab, active)
-                toks = np.asarray(toks)
-                n_emit = np.asarray(n_emit)
-                truncated = np.asarray(truncated)
+                with trace_annotation("apex_tpu.scheduler.token_read"):
+                    toks = np.asarray(toks)
+                    n_emit = np.asarray(n_emit)
+                    truncated = np.asarray(truncated)
                 # per-token latency back-channel: the bracket's
                 # histogram sample divides by mean emitted/slot.
                 # Clamped the way the consumption loop below will
@@ -870,57 +887,62 @@ class SlotScheduler:
                         - len(slots[s].generated))
                     for s in range(eng.slots)
                     if slots[s] is not None and active[s]))
-            for slot, st in enumerate(slots):
-                if st is None or not active[slot]:
-                    continue
-                # the host capacity mirror clamps exactly like the
-                # device's advance_by did (same inputs, same min)
-                remaining = st.capacity - st.cache_len()
-                usable = int(min(int(n_emit[slot]), remaining))
-                emitted = []
-                reason = None
-                for t in toks[slot, :usable]:
-                    st.generated.append(int(t))
-                    emitted.append(int(t))
-                    if st.done():
-                        reason = REASON_LENGTH
-                        break
-                # emitted counts tokens that actually reached the
-                # request (capacity- AND budget-clamped), so
-                # spec_emitted == tokens_generated minus the
-                # prefill-sampled firsts — conservation-testable
-                tel.speculation(k, int(n_emit[slot]) - 1,
-                                len(emitted))
-                if emitted:
-                    self._run_last[slot] = emitted[-1]
-                    self.drafter.observe(slot, emitted)
-                if reason is not None:
-                    self._retire(slot, reason)
-                elif usable < int(n_emit[slot]) or truncated[slot]:
-                    # capacity cut the emitted stream short
-                    self._retire(slot, REASON_TRUNCATED)
+            with trace_annotation("apex_tpu.scheduler.retire"):
+                for slot, st in enumerate(slots):
+                    if st is None or not active[slot]:
+                        continue
+                    # the host capacity mirror clamps exactly like the
+                    # device's advance_by did (same inputs, same min)
+                    remaining = st.capacity - st.cache_len()
+                    usable = int(min(int(n_emit[slot]), remaining))
+                    emitted = []
+                    reason = None
+                    for t in toks[slot, :usable]:
+                        st.generated.append(int(t))
+                        emitted.append(int(t))
+                        if st.done():
+                            reason = REASON_LENGTH
+                            break
+                    # emitted counts tokens that actually reached the
+                    # request (capacity- AND budget-clamped), so
+                    # spec_emitted == tokens_generated minus the
+                    # prefill-sampled firsts — conservation-testable
+                    tel.speculation(k, int(n_emit[slot]) - 1,
+                                    len(emitted))
+                    if emitted:
+                        self._run_last[slot] = emitted[-1]
+                        self.drafter.observe(slot, emitted)
+                    if reason is not None:
+                        self._retire(slot, reason)
+                    elif usable < int(n_emit[slot]) or truncated[slot]:
+                        # capacity cut the emitted stream short
+                        self._retire(slot, REASON_TRUNCATED)
             return
         # the decode bracket closes after the token host-read the
         # loop performs anyway, so the histogram sample is the true
         # per-token latency (dispatch + sync), and its recompile
         # flag feeds serve_recompiles_total (pinned 0 by tests)
-        with tel.decode_step(n_active, capacity=eng.slots):
+        with trace_annotation("apex_tpu.scheduler.decode",
+                              active=n_active), \
+                tel.decode_step(n_active, capacity=eng.slots):
             self.cache, toks, _, truncated = eng.decode(
                 self.cache, self._run_last, active)
-            toks = np.asarray(toks)
-            truncated = np.asarray(truncated)
-        for slot, st in enumerate(slots):
-            if st is None or not active[slot]:
-                continue
-            if truncated[slot]:
-                # the host guard above should have retired this
-                # slot first; trust the device flag regardless
-                self._retire(slot, REASON_TRUNCATED)
-                continue
-            st.generated.append(int(toks[slot]))
-            self._run_last[slot] = toks[slot]
-            if st.done():
-                self._retire(slot, REASON_LENGTH)
+            with trace_annotation("apex_tpu.scheduler.token_read"):
+                toks = np.asarray(toks)
+                truncated = np.asarray(truncated)
+        with trace_annotation("apex_tpu.scheduler.retire"):
+            for slot, st in enumerate(slots):
+                if st is None or not active[slot]:
+                    continue
+                if truncated[slot]:
+                    # the host guard above should have retired this
+                    # slot first; trust the device flag regardless
+                    self._retire(slot, REASON_TRUNCATED)
+                    continue
+                st.generated.append(int(toks[slot]))
+                self._run_last[slot] = toks[slot]
+                if st.done():
+                    self._retire(slot, REASON_LENGTH)
 
     def run(self, cache=None) -> dict:
         """Drain the queue; returns ``{uid: generated token list}``.

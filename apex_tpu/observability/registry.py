@@ -222,6 +222,8 @@ class MetricsRegistry:
             raise KeyError(
                 f"event kind {kind!r} is not declared in "
                 f"apex_tpu.observability.schema.EVENT_FIELDS")
+        if not self._sinks:
+            return
         obj = {"ts": time.time(), "kind": kind, **fields}
         for sink in self._sinks:
             sink.event(obj)

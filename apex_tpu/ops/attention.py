@@ -338,6 +338,7 @@ def _fwd(q3, k3, v3, mask3, causal, scale, bq, bk, out_dtype=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret_mode(),
+        name="apex_flash_fwd",
     )(*operands)
     return out, lse[:, :, 0]
 
@@ -600,6 +601,7 @@ def _bwd_impl(q3, k3, v3, mask3, o3, lse, do3, causal, scale, bq, bk,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
             interpret=interpret_mode(),
+            name="apex_flash_bwd",
         )(*common)
         return dq, dk, dv
 
@@ -629,6 +631,7 @@ def _bwd_impl(q3, k3, v3, mask3, o3, lse, do3, causal, scale, bq, bk,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret_mode(),
+        name="apex_flash_bwd_dq",
     )(*common)
 
     dkv_kernel = functools.partial(
@@ -652,6 +655,7 @@ def _bwd_impl(q3, k3, v3, mask3, o3, lse, do3, causal, scale, bq, bk,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret_mode(),
+        name="apex_flash_bwd_dkv",
     )(*common)
     return dq, dk, dv
 

@@ -176,6 +176,7 @@ def fused_scale(flat: jax.Array, scale, out_dtype=None):
         ],
         compiler_params=_SEQ,
         interpret=interpret_mode(),
+        name="apex_amp_unscale",
     )(flat, hp)
     return out, jnp.max(flags[:_grid(flat)])
 
@@ -211,6 +212,7 @@ def fused_axpby(a, x: jax.Array, b, y: jax.Array, out_dtype=None):
         ],
         compiler_params=_SEQ,
         interpret=interpret_mode(),
+        name="apex_axpby",
     )(x, y, hp)
     return out, jnp.max(flags[:_grid(x)])
 
@@ -241,6 +243,7 @@ def fused_l2norm(flat: jax.Array) -> jax.Array:
         out_shape=_bshape(_grid(flat)),
         compiler_params=_SEQ,
         interpret=interpret_mode(),
+        name="apex_l2norm",
     )(flat)
     return jnp.sqrt(jnp.sum(acc[:_grid(flat)]))
 
@@ -279,6 +282,7 @@ def fused_l2norm_scale(flat: jax.Array, scale, out_dtype=None):
         ],
         compiler_params=_SEQ,
         interpret=interpret_mode(),
+        name="apex_l2norm_scale",
     )(flat, hp)
     g = _grid(flat)
     return out, jnp.sqrt(jnp.sum(acc[:g])), jnp.max(flags[:g])
@@ -361,6 +365,7 @@ def fused_adam_flat(p, g, m, v, *, lr, beta1, beta2, eps, weight_decay,
         input_output_aliases={0: 0, 2: 1, 3: 2},
         compiler_params=_PAR,
         interpret=interpret_mode(),
+        name="apex_adam_update",
     )(p2, g2, m2, v2, hp)
     return (po, mo, vo)
 
@@ -433,6 +438,7 @@ def fused_adagrad_flat(p, g, h, *, lr, eps, weight_decay, w_mode=False,
         input_output_aliases={0: 0, 2: 1},
         compiler_params=_PAR,
         interpret=interpret_mode(),
+        name="apex_adagrad_update",
     )(p2, g2, h2, hp)
     return po, ho
 
@@ -501,6 +507,7 @@ def fused_sgd_flat(p, g, buf, *, lr, momentum, dampening, weight_decay,
         input_output_aliases={0: 0, 2: 1},
         compiler_params=_PAR,
         interpret=interpret_mode(),
+        name="apex_sgd_update",
     )(p2, g2, b2, hp)
     return po, bo
 
@@ -569,5 +576,6 @@ def fused_lamb_phase1_flat(p, g, m, v, *, beta1, beta2, eps, weight_decay,
         input_output_aliases={2: 0, 3: 1},
         compiler_params=_PAR,
         interpret=interpret_mode(),
+        name="apex_lamb_stage1",
     )(p2, g2, m2, v2, hp)
     return (mo, vo, u)

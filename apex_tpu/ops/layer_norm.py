@@ -162,6 +162,7 @@ def _fwd_2d(x2, w, b, eps, rms):
         out_specs=pl.BlockSpec((br, hidden), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2p.shape, x2.dtype),
         interpret=interpret_mode(),
+        name="apex_layer_norm_fwd",
     )(x2p, w2, b2)
     return out[:orig]
 
@@ -192,6 +193,7 @@ def _bwd_2d(x2, w, dy2, eps, rms):
             jax.ShapeDtypeStruct((1, hidden), jnp.float32),
         ],
         interpret=interpret_mode(),
+        name="apex_layer_norm_bwd",
     )(x2p, w2, dy2p)
     return dx[:orig], dw.reshape(hidden), db.reshape(hidden)
 

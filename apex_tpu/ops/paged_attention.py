@@ -197,6 +197,7 @@ def _paged_kernel_call(q, k_pages, v_pages, page_table, lengths, scale):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret_mode(),
+        name="apex_paged_decode",
     )(page_table, lengths, q, k_pages, v_pages)
 
 
@@ -827,6 +828,7 @@ def fused_block_decode(x, blk, k_pages, v_pages, page_table, lengths, *,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=vmem_limit),
         interpret=interpret_mode(),
+        name="apex_fused_block_decode",
     )(page_table, lengths, *operands)
     return (y[:, 0], kt.reshape(slots, kvh, d),
             vt.reshape(slots, kvh, d))
