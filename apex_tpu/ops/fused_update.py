@@ -22,6 +22,12 @@ Design (TPU-first, not a translation):
   whole launch into a no-op) maps to a traced scalar in SMEM: the kernel
   computes the update and predicates the write with ``jnp.where`` — no host
   sync, jit-safe, exactly the semantics amp needs for skip-on-overflow.
+  Every state-writing kernel (Adam, Adagrad, SGD, LAMB stage 1) takes it:
+  the state buffers are aliased to the outputs, so a select spelled
+  OUTSIDE the kernel would read the old buffer after the kernel has
+  overwritten it and force XLA to copy each buffer first.  LAMB stage 1
+  predicates its two moments; its ``u`` output and the parameter apply
+  (stage 2, per-tensor trust ratios) stay with the optimizer.
 * Hyperparameters (lr, betas, bias corrections, the noop flag) travel in a
   single small fp32 vector placed in SMEM, so changing the learning rate does
   NOT recompile the kernel.
@@ -520,6 +526,7 @@ def _lamb1_kernel(p_ref, g_ref, m_ref, v_ref, hp_ref, mo_ref, vo_ref, u_ref):
     b1, b2, eps, wd = hp_ref[0], hp_ref[1], hp_ref[2], hp_ref[3]
     inv_bc1, inv_sqrt_bc2, gscale = hp_ref[4], hp_ref[5], hp_ref[6]
     beta3 = hp_ref[7]      # 1-b1 normally; 1.0 when grad_averaging=False
+    noop = hp_ref[8]
     p = p_ref[...].astype(jnp.float32)
     g = g_ref[...].astype(jnp.float32) * gscale
     m = m_ref[...].astype(jnp.float32)
@@ -527,19 +534,27 @@ def _lamb1_kernel(p_ref, g_ref, m_ref, v_ref, hp_ref, mo_ref, vo_ref, u_ref):
     m_new = b1 * m + beta3 * g
     v_new = b2 * v + (1.0 - b2) * g * g
     u = (m_new * inv_bc1) / (jnp.sqrt(v_new) * inv_sqrt_bc2 + eps) + wd * p
-    mo_ref[...] = m_new.astype(mo_ref.dtype)
-    vo_ref[...] = v_new.astype(vo_ref.dtype)
+    # u is written unpredicated: its value on a skipped step is never used
+    skip = noop > 0.0
+    mo_ref[...] = jnp.where(skip, m, m_new).astype(mo_ref.dtype)
+    vo_ref[...] = jnp.where(skip, v, v_new).astype(vo_ref.dtype)
     u_ref[...] = u.astype(u_ref.dtype)
 
 
 def fused_lamb_phase1_flat(p, g, m, v, *, beta1, beta2, eps, weight_decay,
                            step, bias_correction=True, grad_scale=1.0,
-                           grad_averaging=True):
+                           grad_averaging=True, noop_flag=0.0):
     """LAMB stage 1: moments + raw update direction ``u``.
 
     Parity: ``amp_C.multi_tensor_lamb_stage_1`` / the fused
     ``multi_tensor_lamb.cu``; stage 2 (per-tensor trust ratio apply) happens
     at the optimizer level where tensor boundaries are known.
+    ``noop_flag`` > 0 (overflow skip) returns ``m`` and ``v`` unchanged,
+    in place: the moment buffers are aliased to the outputs and the
+    predicate sits inside the kernel, so a caller must NOT select between
+    old and new moments afterwards (a late read of the old buffer costs a
+    whole-buffer copy of each).  ``u`` is not predicated — on a skipped
+    step it may hold inf/nan and the caller selects the parameter.
     Returns (m, v, u).
     """
     if bias_correction:
@@ -558,6 +573,7 @@ def fused_lamb_phase1_flat(p, g, m, v, *, beta1, beta2, eps, weight_decay,
         jnp.asarray(inv_sqrt_bc2, jnp.float32),
         jnp.asarray(grad_scale, jnp.float32),
         jnp.asarray((1.0 - beta1) if grad_averaging else 1.0, jnp.float32),
+        jnp.asarray(noop_flag, jnp.float32),
     ])
     p2, n = p, p.shape[0]
     g2 = g

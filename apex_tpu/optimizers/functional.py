@@ -507,7 +507,8 @@ class _LambTx:
         gnorm = jnp.sqrt(gsq)
         clip = jnp.where((mgn > 0) & (gnorm > mgn), mgn / (gnorm + 1e-6),
                          1.0)
-        m_new, v_new, u = fused_lamb_phase1_flat(
+        # the kernel predicates the moments on noop_flag in place
+        m, v, u = fused_lamb_phase1_flat(
             p, g32, m, v,
             beta1=_f32(self.beta1 if beta1 is None else beta1),
             beta2=_f32(self.beta2 if beta2 is None else beta2),
@@ -515,7 +516,8 @@ class _LambTx:
             weight_decay=_f32(self.weight_decay if weight_decay is None
                               else weight_decay),
             step=t, bias_correction=self.bias_correction,
-            grad_scale=clip, grad_averaging=self.grad_averaging)
+            grad_scale=clip, grad_averaging=self.grad_averaging,
+            noop_flag=_f32(noop_flag))
 
         if sharded:
             # EXACT per-tensor trust ratios across shards (reference:
@@ -554,11 +556,12 @@ class _LambTx:
             scale = _broadcast_leaf_scalars(ratio, sizes)
         p_new = p - _f32(self.lr if lr is None else lr) * scale * u
 
+        # the master keeps its select (one fusion with the apply): on an
+        # overflowed step u holds inf/nan and lr*0*inf would be nan
         skip = _f32(noop_flag) > 0
         return state.replace(
             master=jnp.where(skip, p, p_new), count=t,
-            slots={"exp_avg": jnp.where(skip, m, m_new),
-                   "exp_avg_sq": jnp.where(skip, v, v_new)})
+            slots={"exp_avg": m, "exp_avg_sq": v})
 
 
 @dataclasses.dataclass(frozen=True)

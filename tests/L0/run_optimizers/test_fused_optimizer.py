@@ -11,7 +11,8 @@ import pytest
 import torch
 
 from apex_tpu.ops.fused_update import (
-    adam_reference, fused_adam_flat, fused_axpby, fused_l2norm, fused_scale,
+    _BLOCK, adam_reference, fused_adam_flat, fused_axpby, fused_l2norm,
+    fused_lamb_phase1_flat, fused_scale,
 )
 from apex_tpu.optimizers import (
     FusedAdagrad, FusedAdam, FusedLAMB, FusedNovoGrad, FusedSGD,
@@ -91,6 +92,97 @@ class TestKernels:
             weight_decay=0.0, step=1, noop_flag=1.0)
         np.testing.assert_array_equal(np.asarray(po), np.asarray(p))
         np.testing.assert_array_equal(np.asarray(mo), np.asarray(m))
+
+
+_LAMB1_KW = dict(beta1=0.9, beta2=0.999, eps=1e-6, weight_decay=0.01)
+
+
+def _lamb1_inputs(n, poison=None):
+    rng = np.random.RandomState(0)
+    g = rng.randn(n).astype(np.float32)
+    if poison is not None:
+        g[[0, n // 2, n - 1]] = poison
+    return (jnp.asarray(rng.randn(n), jnp.float32), jnp.asarray(g),
+            jnp.asarray(rng.randn(n), jnp.float32),
+            jnp.asarray(rng.rand(n), jnp.float32))
+
+
+def _lamb1_reference(p, g, m, v, *, beta1, beta2, eps, weight_decay, step,
+                     grad_scale=1.0):
+    """Pure-jnp spelling of LAMB stage 1 (grad averaging, bias
+    correction): moments and the raw direction ``u``.  Every scalar is
+    fp32 before it is combined, as in the kernel: fp32 ``1 - 0.999`` is
+    4.7e-5 from 0.001 relatively, which ``m / sqrt(v)`` shows in ``u``
+    wherever ``v`` is small."""
+    b1, b2, t = jnp.float32(beta1), jnp.float32(beta2), jnp.float32(step)
+    bc1 = 1.0 - jnp.power(b1, t)
+    bc2 = 1.0 - jnp.power(b2, t)
+    g = g * grad_scale
+    m = b1 * m + (1.0 - b1) * g
+    v = b2 * v + (1.0 - b2) * g * g
+    u = (m / bc1) / (jnp.sqrt(v) / jnp.sqrt(bc2) + eps) + weight_decay * p
+    return m, v, u
+
+
+class TestLambStage1Kernel:
+    """``noop_flag`` inside ``apex_lamb_stage1``: the moments are
+    predicated where they are written, so no caller selects after it."""
+
+    #: one partial block; whole blocks plus a tail block
+    LENGTHS = (10_000, _BLOCK + 777)
+
+    @pytest.mark.parametrize("n", LENGTHS)
+    @pytest.mark.parametrize("poison", [None, np.inf, np.nan],
+                             ids=["finite", "inf", "nan"])
+    def test_noop_returns_moments_bit_identical(self, n, poison):
+        assert n % _BLOCK
+        p, g, m, v = _lamb1_inputs(n, poison)
+        mo, vo, u = jax.jit(lambda *a: fused_lamb_phase1_flat(
+            *a, noop_flag=1.0, step=3, **_LAMB1_KW))(p, g, m, v)
+        assert u.shape == (n,) and u.dtype == jnp.float32
+        np.testing.assert_array_equal(
+            np.asarray(mo).view(np.uint32), np.asarray(m).view(np.uint32))
+        np.testing.assert_array_equal(
+            np.asarray(vo).view(np.uint32), np.asarray(v).view(np.uint32))
+
+    @pytest.mark.parametrize("n", LENGTHS)
+    def test_clean_step_vs_jnp(self, n):
+        # the step count is traced, as in a train step: XLA's CPU
+        # constant folder takes 0.999**3 another way (1e-5 off in u)
+        p, g, m, v = _lamb1_inputs(n)
+        mo, vo, u = jax.jit(lambda p, g, m, v, t: fused_lamb_phase1_flat(
+            p, g, m, v, noop_flag=0.0, grad_scale=0.5, step=t,
+            **_LAMB1_KW))(p, g, m, v, jnp.float32(3))
+        mr, vr, ur = _lamb1_reference(p, g, m, v, grad_scale=0.5, step=3,
+                                      **_LAMB1_KW)
+        np.testing.assert_allclose(np.asarray(mo), np.asarray(mr), atol=1e-6)
+        np.testing.assert_allclose(np.asarray(vo), np.asarray(vr), atol=1e-6)
+        np.testing.assert_allclose(np.asarray(u), np.asarray(ur), atol=1e-6,
+                                   rtol=1e-6)
+
+    def test_default_noop_is_a_clean_step(self):
+        p, g, m, v = _lamb1_inputs(10_000)
+        got = fused_lamb_phase1_flat(p, g, m, v, step=3, **_LAMB1_KW)
+        want = fused_lamb_phase1_flat(p, g, m, v, noop_flag=0.0, step=3,
+                                      **_LAMB1_KW)
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_noop_flag_is_traced_not_compiled_in(self):
+        from apex_tpu import observability
+        p, g, m, v = _lamb1_inputs(10_000)
+        fn = jax.jit(lambda p, g, m, v, noop: fused_lamb_phase1_flat(
+            p, g, m, v, noop_flag=noop, step=3, **_LAMB1_KW))
+        clean = fn(p, g, m, v, jnp.float32(0.0))
+        before = observability.compile_count()
+        skipped = fn(p, g, m, v, jnp.float32(1.0))
+        again = fn(p, g, m, v, jnp.float32(0.0))
+        jax.block_until_ready((skipped, again))
+        assert observability.compile_count() == before
+        np.testing.assert_array_equal(np.asarray(skipped[0]), np.asarray(m))
+        np.testing.assert_array_equal(np.asarray(again[0]),
+                                      np.asarray(clean[0]))
+        assert not np.array_equal(np.asarray(clean[0]), np.asarray(m))
 
 
 def _torch_steps(torch_opt_cls, params, grads_seq, **kw):

@@ -19,6 +19,7 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 sys.path.insert(0, os.path.abspath(
     os.path.join(os.path.dirname(__file__), "..", "..")))
@@ -26,6 +27,7 @@ sys.path.insert(0, os.path.abspath(
 from apex_tpu import train_step
 from apex_tpu.amp.scaler import LossScaler
 from apex_tpu.analysis.jaxpr_audit import FORBIDDEN_PRIMS
+from apex_tpu.ops.fused_update import _BLOCK
 from apex_tpu.optimizers import FusedAdam, functional
 from apex_tpu.utils import tree_ravel
 
@@ -191,12 +193,53 @@ def test_train_loop_learns_and_matches_stepwise():
     assert jax.tree.structure(out) == jax.tree.structure(params)
 
 
-def test_overflow_step_skips_in_program_and_backs_off_scale():
+def _flat_selects(jaxpr, n):
+    """``select_n`` equations whose output is a whole flat buffer of
+    length ``n`` (``jnp.where`` sits inside a ``jit`` equation, so the
+    sub-jaxprs are walked)."""
+    return [e for e in _iter_eqns(jaxpr)
+            if e.primitive.name == "select_n"
+            and e.outvars[0].aval.shape == (n,)]
+
+
+def test_lamb_step_selects_only_the_master():
+    """LAMB's skip-on-overflow of the moments lives inside
+    ``apex_lamb_stage1``: the step holds ONE flat-length select, the
+    master's.  A select of a moment after the kernel reads the buffer
+    the kernel has overwritten in place, which costs the select pass
+    AND a whole-buffer copy of each moment (PR 28: 20 ms of a 142 ms
+    BERT-large step)."""
+    params = _make_params()
+    n = int(tree_ravel(params)[0].size)
+    assert n % _BLOCK      # the kernel's tail block is on the path
+    tx = functional.fused_lamb(lr=1e-2)
+    state = train_step.init_train_state(tx, params, loss_scale="dynamic")
+    step = train_step.make_train_step(_loss_fn, tx)
+    jaxpr = jax.make_jaxpr(step)(state, _batch())
+    assert len(_flat_selects(jaxpr, n)) == 1
+
+    # detector positive control: the old spelling — the moments selected
+    # outside the kernel — reads three
+    def old_style(state, batch):
+        new, loss = step(state, batch)
+        skip = loss > 0
+        slots = {k: jnp.where(skip, state.opt.slots[k], v)
+                 for k, v in new.opt.slots.items()}
+        return new.replace(opt=new.opt.replace(slots=slots)), loss
+
+    old_jaxpr = jax.make_jaxpr(old_style)(state, _batch())
+    assert len(_flat_selects(old_jaxpr, n)) == 3
+
+
+@pytest.mark.parametrize("make_tx", [functional.fused_adam,
+                                     functional.fused_lamb],
+                         ids=["adam", "lamb"])
+def test_overflow_step_skips_in_program_and_backs_off_scale(make_tx):
     """A non-finite grad must be caught by the fused unscale flag and
     skipped by the update kernel's noop predicate — all in-program —
     with the dynamic scale halved afterwards."""
     params = _make_params()
-    tx = functional.fused_adam(lr=1e-2)
+    tx = make_tx(lr=1e-2)
 
     def loss_fn(params, batch):
         # batch["poison"] = 0 -> clean loss; huge -> inf grads
@@ -210,11 +253,20 @@ def test_overflow_step_skips_in_program_and_backs_off_scale():
 
     state, _ = step(state, clean)
     master_before = np.asarray(state.opt.master)
+    slots_before = {k: np.asarray(v) for k, v in state.opt.slots.items()}
+    assert sorted(slots_before) == ["exp_avg", "exp_avg_sq"]
+    assert all(np.any(v) for v in slots_before.values())
     scale_before = float(state.scaler.loss_scale)
+    count_before = float(state.opt.count)
     state, _ = step(state, poisoned)
     np.testing.assert_array_equal(np.asarray(state.opt.master),
                                   master_before)       # update skipped
+    for k, v in slots_before.items():                  # moments too, bitwise
+        np.testing.assert_array_equal(
+            np.asarray(state.opt.slots[k]).view(np.uint32),
+            v.view(np.uint32), err_msg=k)
     assert float(state.scaler.loss_scale) == scale_before * 0.5
+    assert float(state.opt.count) == count_before + 1  # as it always has
     # and the loop recovers on the next clean batch
     state, _ = step(state, clean)
     assert not np.array_equal(np.asarray(state.opt.master), master_before)

@@ -74,13 +74,14 @@ def _iter_eqns(jaxpr):
                     yield from _iter_eqns(sub)
 
 
-def _zero_setup(loss_scale=None, placed=False):
+def _zero_setup(loss_scale=None, placed=False, make_tx=functional.fused_adam,
+                loss_fn=_loss_fn):
     params = _make_params()
-    tx = functional.fused_adam(lr=1e-2)
+    tx = make_tx(lr=1e-2)
     mesh = Mesh(np.array(jax.devices()[:DP]), ("data",))
     state, specs = train_step.init_zero_train_state(
         tx, params, "data", DP, loss_scale=loss_scale)
-    step = train_step.make_train_step(_loss_fn, tx, zero=True)
+    step = train_step.make_train_step(loss_fn, tx, zero=True)
     sharded = functools.partial(jax.shard_map, check_vma=False)(
         step, mesh=mesh, in_specs=(specs, P()), out_specs=(specs, P()))
     if placed:
@@ -127,6 +128,45 @@ def test_zero_spmd_audit_clean_and_ledger():
         and e.outvars[0].aval.size >= n_params
         and len(e.invars) >= n_leaves // 2]
     assert not reravel, "zero step rebuilt flat grads by concatenation"
+
+
+def test_zero_lamb_selects_only_the_master_and_skips_bitwise():
+    """ZeRO twin of ``test_lamb_step_selects_only_the_master``: each
+    rank's LAMB update holds ONE shard-length select (the master's; the
+    moments are predicated inside ``apex_lamb_stage1``), and an
+    overflowed step leaves master and both moments bit-identical on
+    every rank with the scale halved."""
+    def loss_fn(p, batch):
+        return _loss_fn(p, batch) + jnp.sum(p["w0"]) * batch["poison"]
+
+    _, _, state, sharded = _zero_setup(
+        loss_scale="dynamic", make_tx=functional.fused_lamb,
+        loss_fn=loss_fn)
+    clean = dict(_batch(), poison=jnp.float32(0.0))
+    poisoned = dict(_batch(), poison=jnp.float32(1e38))
+    shard_len = state.opt.master.shape[0] // DP
+    selects = [e for e in _iter_eqns(jax.make_jaxpr(sharded)(state, clean))
+               if e.primitive.name == "select_n"
+               and e.outvars[0].aval.shape == (shard_len,)]
+    assert len(selects) == 1, selects
+
+    step = jax.jit(sharded)
+    state, _ = step(state, clean)
+    before = {"master": np.asarray(state.opt.master),
+              **{k: np.asarray(v) for k, v in state.opt.slots.items()}}
+    assert sorted(before) == ["exp_avg", "exp_avg_sq", "master"]
+    assert all(np.any(v) for v in before.values())
+    scale_before = float(state.scaler.loss_scale)
+    state, _ = step(state, poisoned)
+    after = {"master": state.opt.master, **state.opt.slots}
+    for k, v in before.items():
+        np.testing.assert_array_equal(
+            np.asarray(after[k]).view(np.uint32), v.view(np.uint32),
+            err_msg=k)
+    assert float(state.scaler.loss_scale) == scale_before * 0.5
+    state, _ = step(state, clean)       # and the loop recovers
+    assert not np.array_equal(np.asarray(state.opt.master),
+                              before["master"])
 
 
 def test_zero_step_compiles_one_donated_executable():
