@@ -144,7 +144,7 @@ def cmd_sweep(args, cell, devices):
 
 
 def cmd_trace(args, cell, devices):
-    driver = H.load_driver(cell.mix["driver"])
+    driver = H.load_driver(cell)
     profiler = H.ProfilerWindow(cell.root)
     out = driver.run(cell=cell, devices=devices, seed=ints(args.seeds)[0],
                      seconds=args.seconds, profiler=profiler,

@@ -23,69 +23,23 @@ import numpy as np
 
 from .. import harness as H
 from .. import traffic, weights
-from ..references import gpt_lm
-from ..references.transformer import LAYER_KEYS
-from .train import LAYER_LEAVES, dig, free_device
-
-_TOP_LEAVES = {
-    ("embedding", "word_embeddings", "weight"): "wte",
-    ("embedding", "position_embeddings"): "wpe",
-    ("final_layernorm", "weight"): "lnf_g",
-    ("final_layernorm", "bias"): "lnf_b",
-}
-
-
-def reference_weights(params, n_layers: int) -> dict:
-    p = params["params"]
-    f32 = lambda x: jnp.asarray(x, jnp.float32)         # noqa: E731
-    out = {ref: f32(dig(p, prog)) for prog, ref in _TOP_LEAVES.items()}
-    out["layers"] = {
-        ref: jnp.stack([f32(dig(p[f"layer_{i}"], prog))
-                        for i in range(n_layers)])
-        for prog, ref in LAYER_LEAVES.items()}
-    assert set(out["layers"]) == set(LAYER_KEYS)
-    return out
-
-
-def model_of(cfg):
-    from apex_tpu.transformer import parallel_state
-    from apex_tpu.transformer.testing import GPTConfig, gpt_model_provider
-
-    parallel_state.destroy_model_parallel()
-    parallel_state.initialize_model_parallel(1)
-    gcfg = GPTConfig(
-        vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
-        num_layers=cfg["num_hidden_layers"],
-        num_attention_heads=cfg["num_attention_heads"],
-        max_seq_length=cfg["max_position_embeddings"], hidden_dropout=0.0,
-        attention_dropout=0.0, params_dtype=jnp.bfloat16)
-    model = gpt_model_provider(gcfg)
-    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
-                            jnp.zeros((1, 8), jnp.int32))
-    return gcfg, shapes
+from .train import free_device
 
 
 def build(cell, seed: int):
-    """The program's engine and scheduler over the benchmark's weights."""
-    from apex_tpu.inference import (InferenceEngine, SamplingConfig,
-                                    SlotScheduler)
+    """The program's engine and scheduler over the benchmark's weights.
+    What is particular to the model kind is the configuration's binding."""
+    from apex_tpu.inference import SlotScheduler
 
     cfg, mix = cell.config, cell.mix
     if not traffic.greedy_sampling(mix):
         raise H.Refused("only greedy mixes can be checked against the "
                         "reference; mix greedy requests in")
-    gcfg, shapes = model_of(cfg)
-    # every leaf in the type it is served in (the engine would round the
-    # float32 LayerNorm leaves itself; the reference must see what is served)
-    shapes = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16), shapes)
+    binding = H.load_binding(cell)
+    binding.check_supported(cfg)         # before any weight is made
+    model_cfg, shapes = binding.model_of(cfg)
     params = weights.make(shapes, seed)
-    engine = InferenceEngine(
-        "gpt", gcfg, params, slots=mix["slots"],
-        max_seq=cfg["max_position_embeddings"],
-        page_size=mix["page_size"], num_pages=mix["pool_pages"],
-        dtype=jnp.bfloat16, sampling=SamplingConfig(),
-        seed=seed & 0x7FFFFFFF)
+    engine = binding.engine(cfg, model_cfg, mix, params, seed)
     del params
     return engine, SlotScheduler(engine), shapes
 
@@ -195,8 +149,8 @@ def measure(cell, sched, requests, seconds: float, profiler=None) -> dict:
         now = time.perf_counter()
         if now < close:
             if backlog:
-                while nxt < len(requests) \
-                        and len(sched.queue) < mix["arrivals"]["depth"]:
+                # ``requests`` laps (traffic.Backlog): it never runs dry
+                while len(sched.queue) < mix["arrivals"]["depth"]:
                     loop.submit(requests[nxt], t0)
                     nxt += 1
             else:
@@ -255,8 +209,8 @@ def sample_sequences(cell, seed: int, requests, by_uid, served) -> list:
     sample = _sample(finished, by_uid,
                      cell.config["correct"]["sample_requests"],
                      traffic.rng_for(seed, stream=3))
-    by_index = {r.index: r for r in requests}
-    return [(by_index[by_uid[u]["index"]].prompt, np.asarray(served[u]))
+    # a request's index is its place in ``requests``, whatever its lap
+    return [(requests[by_uid[u]["index"]].prompt, np.asarray(served[u]))
             for u in sample]
 
 
@@ -320,17 +274,16 @@ def served_token_gap(cell, shapes, seed: int, seqs, *, quant=None) -> dict:
     cfg = cell.config
     if not seqs:
         return {"widest": float("inf"), "tokens": 0}
-    w = reference_weights(weights.make(shapes, seed),
-                          cfg["num_hidden_layers"])
+    binding = H.load_binding(cell)
+    w = binding.reference_weights(cfg, weights.make(shapes, seed))
     pad = cfg["correct"]["reference_pad_to"]
-    heads = cfg["num_attention_heads"]
+    # the rows judged, one shape for every sequence: the mix's longest answer
+    rows = max(cell.mix["new_tokens"]["max"], max(len(o) for _, o in seqs))
 
     @jax.jit
-    def gaps(ref_logits, judged, first, count):
-        pos = first + jnp.arange(judged.shape[0])
-        rows = ref_logits[jnp.clip(pos, 0, ref_logits.shape[0] - 1)]
-        best = jnp.max(rows, axis=-1)
-        got = jnp.take_along_axis(rows, judged[:, None], axis=-1)[:, 0]
+    def gaps(ref_rows, judged, count):
+        best = jnp.max(ref_rows, axis=-1)
+        got = jnp.take_along_axis(ref_rows, judged[:, None], axis=-1)[:, 0]
         live = jnp.arange(judged.shape[0]) < count
         return jnp.max(jnp.where(live, best - got, 0.0))
 
@@ -342,17 +295,17 @@ def served_token_gap(cell, shapes, seed: int, seqs, *, quant=None) -> dict:
                             f"longer than reference_pad_to {pad}")
         padded = np.zeros((pad,), np.int32)
         padded[:len(full)] = full
-        ref = gpt_lm.logits(w, jnp.asarray(padded), heads=heads)
         first = len(prompt) - 1
-        judged = np.zeros((pad,), np.int32)
+        ref = binding.reference_logits(cfg, w, padded, first, rows)
+        judged = np.zeros((rows,), np.int32)
         if quant is None:
             judged[:len(out)] = out
         else:
-            low = gpt_lm.logits(w, jnp.asarray(padded), heads=heads,
-                                quant=quant)
+            low = binding.reference_logits(cfg, w, padded, first, rows,
+                                           quant=quant)
             judged[:len(out)] = np.asarray(
-                jnp.argmax(low, axis=-1))[first:first + len(out)]
+                jnp.argmax(low, axis=-1))[:len(out)]
         widest = max(widest, float(gaps(ref, jnp.asarray(judged),
-                                        first, len(out))))
+                                        len(out))))
         tokens += len(out)
     return {"widest": widest, "tokens": tokens}

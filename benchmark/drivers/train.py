@@ -1,5 +1,11 @@
 """Driver of the training cells: the program's own jitted train step, driven
-from the host one step and one sync at a time, batches made in the loop.
+from the host as a training job drives it: batches made in the loop, the
+mix's ``ahead_seconds`` of steps dispatched ahead of the one whose loss is
+waited for, each loss read that late.  So the chip stays fed while the host
+stands still, and a stall of the shared host weighs on the rate only where
+it outlasts what was dispatched.  When the window's time is up nothing more
+is sent, all that was sent is waited for, and the clock is read after that
+wait: every step sent counts, over all of that time.
 
 Set-up builds ONE object — the compiled step with its state — drives it
 through its first ``CHECK_STEPS`` steps by the window's own call and feed
@@ -10,6 +16,7 @@ steps from the same weights and batches.
 """
 from __future__ import annotations
 
+import collections
 import gc
 import importlib.util
 import time
@@ -216,41 +223,86 @@ def run(*, cell, devices, seed, seconds, profiler, t_process) -> dict:
     H.note(t_process, "state built")
     n_params = int(sum(state.opt.sizes))
 
-    def one_step(state):
+    def send(state):
+        """One step made and dispatched; nothing is waited for."""
         with H.span("make_batch"):
             batch = feed.next()
         with H.span("step"):
             state, loss = step(state, batch)
-        with H.span("sync"):
-            jax.block_until_ready((state, loss))
         return state, loss, batch
 
+    def one_step(state):
+        """The first steps: the window's own call and feed, read at once."""
+        t = time.perf_counter()
+        state, loss, batch = send(state)
+        with H.span("sync"):
+            jax.block_until_ready((state, loss))
+        warm_s.append(time.perf_counter() - t)
+        return state, loss, batch
+
+    warm_s = []
     state, got, first_batches = first_steps(
         cell, seed, state, shapes, one_step,
         lambda what: H.note(t_process, what))
     state, loss, _ = one_step(state)        # one more, warm and unrecorded
+    # the depth in steps, from the quickest of the steps that set-up drove
+    # after the one that compiled
+    step_s = min(warm_s[1:])
+    ahead = steps_ahead(mix["ahead_seconds"], step_s)
+    H.note(t_process, f"a step takes {step_s * 1e3:.1f} ms: "
+                      f"up to {ahead} steps dispatched ahead")
 
     # -- the window ---------------------------------------------------------
     trace_s = mix["trace_seconds"]
     compiles0 = compile_count()
     starts, ends = [], []
-    tracing = False
+    flying = collections.deque()            # losses sent and not yet read
+
+    def wait_until(left: int):
+        """Read the oldest losses until ``left`` are in flight; each stamp
+        is the host's first sight of a finished step."""
+        nonlocal loss
+        while len(flying) > left:
+            loss = flying.popleft()
+            with H.span("sync"):
+                jax.block_until_ready(loss)
+            ends.append(time.perf_counter())
+
+    tracing, slowest_send = False, 0.0
+    # a traced run stops sending early enough for the wait that closes its
+    # untraced part, so that the traced tail still ends with the window
+    t_end = seconds
+    t_trace = None if profiler is None else max(
+        0.0, seconds - trace_s - mix["ahead_seconds"])
     setup_s = time.perf_counter() - t_process
     t0 = time.perf_counter()
     while True:
         now = time.perf_counter()
-        if now - t0 >= seconds:
-            break
-        if profiler is not None and not tracing \
-                and now - t0 >= seconds - trace_s:
+        if t_trace is not None and not tracing and now - t0 >= t_trace:
+            # the untraced part closes as a window does: all that was sent
+            # is waited for; the traced tail then runs shallow for
+            # ``trace_seconds``, so that the trace holds whole steps only
+            wait_until(0)
+            ahead = steps_ahead(mix["trace_ahead_seconds"], step_s)
             profiler.start()
             tracing = True
             now = time.perf_counter()
+            t_end = now - t0 + trace_s
+        if now - t0 >= t_end:
+            break
         starts.append(now)
-        state, loss, _ = one_step(state)
-        ends.append(time.perf_counter())
+        state, sent_loss, _ = send(state)
+        slowest_send = max(slowest_send, time.perf_counter() - now)
+        flying.append(sent_loss)
+        wait_until(ahead)
+    t_close = time.perf_counter()
+    wait_until(0)                           # nothing more is sent
+    jax.block_until_ready(state)
     if tracing:
         profiler.stop()
+    H.note(t_process, f"window closed: {len(starts)} steps sent, the "
+                      f"slowest send {slowest_send * 1e3:.1f} ms, the last "
+                      f"wait {ends[-1] - t_close:.2f} s")
     compiles = compile_count() - compiles0
     last_loss = float(loss)
     peak = devices.memory_peak_bytes()
@@ -276,6 +328,12 @@ def run(*, cell, devices, seed, seconds, profiler, t_process) -> dict:
     return {"facts": facts, "setup_s": setup_s, "memory_peak_bytes": peak,
             "correct": all(c["value"] <= c["limit"] for c in checks),
             "attempted": len(starts), "failed": 0, "checks": checks}
+
+
+def steps_ahead(seconds: float, step_s: float) -> int:
+    """How many steps make up ``seconds`` of work for the chip: at least
+    one, so that the host always makes the next batch while a step runs."""
+    return max(1, int(round(seconds / step_s)))
 
 
 def free_device(platform: str) -> None:

@@ -52,7 +52,20 @@ def _reports(metric: dict, cell: str) -> bool:
     return cell in metric.get("workloads", [cell])
 
 
+def _adopt(root: Path) -> None:
+    """Let this process's ``benchmark`` packages find the modules that a
+    checkout other than ours has added (a toy checkout rehearsed in
+    process; the one command's checkout is its own)."""
+    for folder in (root / "benchmark").iterdir():
+        if (folder / "__init__.py").is_file():
+            pkg = importlib.import_module(f"benchmark.{folder.name}")
+            if str(folder) not in pkg.__path__:
+                pkg.__path__.append(str(folder))
+
+
 def load_cell(name: str, root: Path) -> Cell:
+    if root != ROOT:
+        _adopt(root)
     index = _load(root / "BENCHMARK.json")
     entry = next((w for w in index["workloads"] if w["name"] == name), None)
     if entry is None:
@@ -186,8 +199,33 @@ def read_metric(name: str, run: Run):
     return mod.read(run)
 
 
-def load_driver(kind: str):
-    return importlib.import_module(f"benchmark.drivers.{kind}")
+def _load_named(package: str, name: str, root: Path):
+    """``benchmark/<package>/<name>.py`` of the checkout ``root``, imported
+    as ``benchmark.<package>.<name>``.  A name with no file is ``Refused``
+    with the names that have one: nothing stands in for it."""
+    folder = root / "benchmark" / package
+    if not (folder / f"{name}.py").is_file():
+        have = sorted(f.stem for f in folder.glob("*.py")
+                      if f.stem != "__init__")
+        raise Refused(f"no benchmark/{package}/{name}.py in {root}; "
+                      f"there is {', '.join(have) or 'none'}")
+    return importlib.import_module(f"benchmark.{package}.{name}")
+
+
+def load_driver(cell: Cell):
+    """The driver the cell's mix names: ``drivers/<driver>.py :: run``."""
+    return _load_named("drivers", cell.mix["driver"], cell.root)
+
+
+def load_binding(cell: Cell):
+    """The model binding the cell's configuration names under ``binding``
+    (``bindings/<name>.py``).  There is no default: a configuration that
+    names none is ``Refused``."""
+    name = cell.config.get("binding")
+    if not name:
+        raise Refused(f"the configuration of {cell.name} names no "
+                      f"\"binding\" (a file under benchmark/bindings/)")
+    return _load_named("bindings", name, cell.root)
 
 
 def check_line(checks: list) -> str:

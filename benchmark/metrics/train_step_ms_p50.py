@@ -1,4 +1,4 @@
-"""Median host wall of one step: batch, dispatch and sync."""
+"""Median time from one step's end to the next's, as the host sees them end."""
 from benchmark import reduce
 
 

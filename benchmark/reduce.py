@@ -32,10 +32,13 @@ def train_tokens_per_s(facts):
 
 
 def train_step_ms_p50(facts):
-    starts, ends = _untraced_steps(facts)
-    if not starts:
+    """Median time between the host's sight of one finished step and of the
+    next: with steps dispatched ahead, the pace at which the chip ends
+    them."""
+    _, ends = _untraced_steps(facts)
+    if len(ends) < 2:
         return None
-    return float(np.median(np.subtract(ends, starts))) * 1e3
+    return float(np.median(np.diff(ends))) * 1e3
 
 
 def train_flops_per_token(run) -> float:

@@ -50,7 +50,7 @@ def run_cell(args, t_process: float, root=H.ROOT) -> dict:
     if devices.platform == "tpu":
         H.enable_compile_cache(root)
     H.CompileBook()                  # its counts ride on every note
-    driver = H.load_driver(cell.mix["driver"])
+    driver = H.load_driver(cell)
     profiler = H.ProfilerWindow(root) if args.trace else None
     out = driver.run(cell=cell, devices=devices, seed=args.seed,
                      seconds=args.seconds, profiler=profiler,

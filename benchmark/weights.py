@@ -3,8 +3,9 @@
 The benchmark makes the weights — not the program's ``init`` — so that the
 plain reference can be handed the very same numbers without taking anything
 the program has made.  Every matrix, embedding and bias is normal(0, 0.02);
-a LayerNorm scale (a rank-1 leaf named ``weight`` under a ``*layernorm``) is
-1 + normal(0, 0.02), so every leaf has a non-zero norm and gradient path.
+a norm's scale (a rank-1 leaf named ``weight`` under a ``*norm``: LayerNorm
+or RMSNorm) is 1 + normal(0, 0.02), so every leaf has a non-zero norm and
+gradient path.
 """
 from __future__ import annotations
 
@@ -22,9 +23,10 @@ def seed_key(seed: int):
     return jax.random.fold_in(key, (seed >> 31) & 0x7FFFFFFF)
 
 
-def _is_norm_scale(path) -> bool:
+def _is_norm_scale(path, leaf) -> bool:
     names = [str(getattr(p, "key", p)) for p in path]
-    return names[-1] == "weight" and "layernorm" in names[-2]
+    return (names[-1] == "weight" and leaf.ndim == 1
+            and names[-2].endswith("norm"))
 
 
 def make(shapes, seed: int):
@@ -37,7 +39,7 @@ def make(shapes, seed: int):
         for i, (path, leaf) in enumerate(flat):
             x = STD * jax.random.normal(jax.random.fold_in(key, i),
                                         leaf.shape, jnp.float32)
-            if _is_norm_scale(path):
+            if _is_norm_scale(path, leaf):
                 x = 1.0 + x
             out.append(x.astype(leaf.dtype))
         return jax.tree_util.tree_unflatten(treedef, out)

@@ -1,6 +1,7 @@
-"""Driven by data: cells, configurations, mixes and a per-layer metric
-added as NEW FILES (plus entries in ``BENCHMARK.json``) run end to end
-through the one command, with no existing file edited."""
+"""Driven by data: cells, configurations, mixes, a per-layer metric and a
+second model kind (its binding, reference and driver) added as NEW FILES
+(plus entries in ``BENCHMARK.json``) run end to end through the one command,
+with no existing file edited."""
 import hashlib
 import json
 import os
@@ -28,7 +29,15 @@ def test_toy_cells_are_only_new_files(toy_root):
         "benchmark/traffic/toy-train.json",
         "benchmark/traffic/toy-chat.json",
         "benchmark/traffic/toy-backlog.json",
-        "benchmark/metrics/toy_passes.py"}
+        "benchmark/metrics/toy_passes.py",
+        # the second model kind
+        "benchmark/configs/toy-llama.json",
+        "benchmark/bindings/llama.py", "benchmark/references/llama_lm.py",
+        "benchmark/drivers/toy_serve.py",
+        "benchmark/traffic/toy-llama-backlog.json"}
+    index = json.loads((toy_root / "BENCHMARK.json").read_text())
+    llama = next(c for c in index["configs"] if c["name"] == "toy-llama")
+    assert llama["reduced"] == ["num_hidden_layers"]
 
 
 def _run(root, *argv):
@@ -38,8 +47,9 @@ def _run(root, *argv):
         capture_output=True, text=True, timeout=300)
 
 
-def test_the_one_command_runs_an_added_cell(toy_root):
-    p = _run(toy_root, "--workload", "toy.backlog", "--seed",
+@pytest.mark.parametrize("cell", ["toy.backlog", "toy.llama"])
+def test_the_one_command_runs_an_added_cell(toy_root, cell):
+    p = _run(toy_root, "--workload", cell, "--seed",
              str(2 ** 31 + 77), "--seconds", "1.5", "--trace", "1",
              "--rehearse")
     assert p.returncode == 0, p.stderr[-2000:]
@@ -49,7 +59,8 @@ def test_the_one_command_runs_an_added_cell(toy_root):
         last)
     # the reader added as a file was found by its name alone
     assert last["metrics"]["toy_passes"]["value"] > 0
-    assert p.stderr.strip().splitlines()[-1].startswith("checks: ")
+    assert p.stderr.strip().splitlines()[-1].startswith(
+        "checks: served_token_gap=")
 
 
 def test_the_command_refuses_a_cpu_without_the_rehearsal_flag(toy_root):

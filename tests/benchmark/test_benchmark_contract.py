@@ -13,6 +13,10 @@ SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 CELLS = [w["name"] for w in INDEX["workloads"]]
 E2E = {m["name"]: m for m in INDEX["end_to_end"]}
 METRICS = INDEX["end_to_end"] + INDEX["per_layer"]
+#: keys ``reduced`` may never name: widths, and only widths (depth is
+#: listed under its published name, ``num_hidden_layers``)
+WIDTHS = re.compile(r"(hidden_size|intermediate|_dim$|_rank$|head_dim|"
+                    r"_width$|sliding_window|experts_per_tok)")
 
 
 def test_top_level_keys_and_size():
@@ -41,11 +45,51 @@ def test_configuration_entry(cfg):
     assert any(cfg["file"].startswith(p + "/") for p in INDEX["paths"])
     doc = json.loads((REPO / cfg["file"]).read_text())
     assert doc["reduced"] == cfg["reduced"] and len(cfg["reduced"]) <= 16
-    widths = re.compile(r"(hidden|intermediate|_dim$|_rank$|head_dim)")
-    assert not any(widths.search(k) for k in cfg["reduced"])
+    assert not any(WIDTHS.search(k) for k in cfg["reduced"])
     assert all(1 <= len(cfg[k]) <= 200 and "\n" not in cfg[k]
                for k in ("source", "why"))
     assert any(w["config"] == cfg["name"] for w in INDEX["workloads"])
+    if "binding" in doc:       # a serving configuration names its model's
+        binding = (REPO / "benchmark" / "bindings"
+                   / f"{doc['binding']}.py").read_text()
+        assert all(f"\ndef {f}(" in binding for f in (
+            "check_supported", "model_of", "engine", "reference_weights",
+            "reference_logits"))
+
+
+#: every top-level key of the published ``config.json`` of a mixture-of-
+#: experts model with window layers (poolside/Laguna-XS.2), widths first
+LAGUNA_WIDTHS = ["hidden_size", "intermediate_size", "head_dim",
+                 "moe_intermediate_size", "shared_expert_intermediate_size",
+                 "sliding_window", "num_experts_per_tok"]
+LAGUNA_OTHERS = ["model_type", "vocab_size", "num_hidden_layers",
+                 "num_attention_heads", "num_key_value_heads",
+                 "max_position_embeddings", "attention_bias",
+                 "rms_norm_eps", "num_experts", "tie_word_embeddings",
+                 "gating", "rope_parameters", "layer_types",
+                 "moe_apply_router_weight_on_input",
+                 "partial_rotary_factor", "mlp_layer_types",
+                 "moe_routed_scaling_factor",
+                 "num_attention_heads_per_layer"]
+
+
+def test_reduced_may_list_depth_and_never_a_width():
+    """Both directions: each width key of both configurations and of that
+    ``config.json`` is refused, each depth key is taken."""
+    ours = [set(json.loads((REPO / c["file"]).read_text()))
+            for c in INDEX["configs"]]
+    for keys in ours:
+        assert {"hidden_size", "intermediate_size", "num_hidden_layers",
+                "max_position_embeddings"} <= keys
+    refused = {k for keys in ours + [set(LAGUNA_WIDTHS + LAGUNA_OTHERS)]
+               for k in keys if WIDTHS.search(k)}
+    assert refused == set(LAGUNA_WIDTHS)
+    for depth in ("num_hidden_layers", "num_layers", "layer_types",
+                  "mlp_layer_types", "num_attention_heads_per_layer"):
+        assert not WIDTHS.search(depth)
+    for width in ("kv_lora_rank", "qk_rope_head_dim", "expert_width",
+                  "ffn_hidden_size", "d_state_dim"):
+        assert WIDTHS.search(width)
 
 
 @pytest.mark.parametrize("cell", INDEX["workloads"], ids=lambda w: w["name"])
@@ -55,7 +99,9 @@ def test_cell_entry(cell):
     assert cell["chips"] in (1, 4) and 1 <= len(cell["why"]) <= 200
     assert cell["config"] in {c["name"] for c in INDEX["configs"]}
     mix = REPO / "benchmark" / "traffic" / f"{cell['traffic']}.json"
-    assert json.loads(mix.read_text())["driver"] in ("train", "serve")
+    driver = REPO / "benchmark" / "drivers" / (
+        json.loads(mix.read_text())["driver"] + ".py")
+    assert "\ndef run(" in driver.read_text()
     mine = [m for m in INDEX["end_to_end"]
             if cell["name"] in m.get("workloads", CELLS)]
     assert "setup_s" in {m["name"] for m in mine} and len(mine) >= 2

@@ -1,6 +1,8 @@
 """The serving driver end to end on the CPU at a toy size, both loop kinds;
 a token altered where it is produced comes out NOT correct, and so does the
 control."""
+import time
+
 import numpy as np
 import pytest
 
@@ -31,6 +33,47 @@ def test_backlog_rehearsal_keeps_the_queue_deep(run_toy):
     r = run_toy("toy.backlog", seconds=1.5)
     assert r["correct"] and r["failed"] == 0 and r["attempted"] > 8
     assert "serve_ttft_p95_ms" not in r["metrics"]
+
+
+def test_a_lapped_backlog_never_runs_dry_inside_the_window(toy_root,
+                                                           monkeypatch):
+    """A lap of 5 requests under a server that finishes many more: the
+    queue stays ``depth`` deep until the window closes."""
+    cell = harness.load_cell("toy.backlog", toy_root)
+    cell.mix["arrivals"] = dict(cell.mix["arrivals"],
+                                requests_per_s_bound=2.5)
+    seconds, depth = 2.0, cell.mix["arrivals"]["depth"]
+    requests = traffic.serve_requests(cell.mix, 5, seconds,
+                                      cell.config["token_ids"])
+    assert requests.lap == len(requests) == 5
+    engine, sched, _ = D.build(cell, 5)
+    D.warm_up(sched, cell, traffic.rng_for(5, stream=2))
+    queued, one_pass = [], D.Loop.one_pass
+
+    def watched(self):
+        queued.append((time.perf_counter(), len(self.sched.queue)))
+        one_pass(self)
+    monkeypatch.setattr(D.Loop, "one_pass", watched)
+    out = D.measure(cell, sched, requests, seconds)
+    lo, hi = out["facts"]["window"]
+    inside = [n for t, n in queued if t < hi]
+    assert len(inside) > 5 and set(inside) == {depth}
+    by_uid = out["by_uid"]
+    indices = sorted(r["index"] for r in by_uid.values())
+    assert len(indices) > 3 * requests.lap        # every lap's are attempted
+    assert indices == list(range(len(indices)))   # unique, none skipped
+    assert all(r["reason"] == "length" for r in by_uid.values())
+    # the sample is found by index across laps: the prompt that was sent
+    seqs = D.sample_sequences(cell, 5, requests, by_uid, out["served"])
+    sent = {(r["prompt_len"], r["new_tokens"]) for r in by_uid.values()}
+    assert len(seqs) == cell.config["correct"]["sample_requests"]
+    assert all((len(p), len(t)) in sent for p, t in seqs)
+    late = max(by_uid, key=lambda u: by_uid[u]["index"])
+    assert len(requests[by_uid[late]["index"]].prompt) \
+        == by_uid[late]["prompt_len"]
+    limit = cell.config["correct"]["limits"]["served_token_gap"]
+    _, shapes = harness.load_binding(cell).model_of(cell.config)
+    assert D.served_token_gap(cell, shapes, 5, seqs)["widest"] <= limit
 
 
 def test_measure_stamps_every_token_of_every_request(toy_root):
@@ -80,7 +123,8 @@ def test_control_in_the_precision_below_is_not_correct(toy_root):
     """The token the fp8 reference puts first lies further below the fp32
     reference's best than the limit allows."""
     cell = harness.load_cell("toy.chat", toy_root)
-    _, shapes = D.model_of(cell.config)
+    binding = harness.load_binding(cell)
+    _, shapes = binding.model_of(cell.config)
     rng = np.random.RandomState(0)
     seqs = [(rng.randint(0, 128, size=40).astype(np.int32),
              rng.randint(0, 128, size=60).astype(np.int32))
@@ -92,8 +136,7 @@ def test_control_in_the_precision_below_is_not_correct(toy_root):
     import jax.numpy as jnp
     from benchmark import weights
     from benchmark.references import gpt_lm
-    w = D.reference_weights(weights.make(shapes, 7),
-                            cell.config["num_hidden_layers"])
+    w = binding.reference_weights(cell.config, weights.make(shapes, 7))
     prompt = seqs[0][0]
     first = int(jnp.argmax(gpt_lm.logits(
         w, jnp.asarray(prompt), heads=4)[len(prompt) - 1]))

@@ -2,6 +2,7 @@
 correct, a measurement without the chip is refused, the control and each
 planted fault come out NOT correct."""
 import json
+import time
 
 import numpy as np
 import pytest
@@ -22,6 +23,71 @@ def test_rehearsal_is_correct_and_prints_counts_only(run_toy):
     assert names[:3] == ["loss1_rel", "loss2_rel", "loss3_rel"]
     assert list(r)[-1] == "checks"
     json.dumps(r)
+
+
+class _Profiler:
+    """Stands in for ``harness.ProfilerWindow``: what the driver calls."""
+    started = stopped = None
+
+    def start(self):
+        self.started = time.perf_counter()
+
+    def stop(self):
+        self.stopped = time.perf_counter()
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_window_sends_ahead_and_closes_after_all_it_sent(
+        toy_root, monkeypatch, trace):
+    """Steps are dispatched ahead of the loss that is waited for, as deep
+    as the mix says; when the time is up nothing more is sent, all that was
+    sent is waited for and the clock read after that wait, so every step
+    sent counts, over all of that time.  A traced run closes its untraced
+    part the same way before the profiler comes on, and the trace holds
+    whole steps only."""
+    cell = harness.load_cell("toy.train", toy_root)
+    depths, real = [], D.steps_ahead
+    monkeypatch.setattr(D, "steps_ahead", lambda s, step_s: depths.append(
+        real(s, step_s)) or depths[-1])
+    profiler = _Profiler() if trace else None
+    out = D.run(cell=cell, devices=harness.find_devices(cell.chips, True),
+                seed=4, seconds=1.5, profiler=profiler,
+                t_process=time.perf_counter())
+    starts, ends = out["facts"]["step_starts"], out["facts"]["step_ends"]
+    n = len(starts)
+    assert out["correct"] and out["attempted"] == n == len(ends) > 3
+    assert ends == sorted(ends) and ends[-1] >= starts[-1]
+    mix = cell.mix
+    assert depths[0] >= 2 and mix["ahead_seconds"] > mix[
+        "trace_ahead_seconds"]
+    k = n - out["facts"]["traced_steps"]        # the untraced steps
+    if trace:
+        assert 0 < k < n and len(depths) == 2 and 1 <= depths[1] < depths[0]
+        # all that was sent had been waited for when the profiler came on,
+        # and again when it went off
+        assert ends[k - 1] <= profiler.started <= starts[k]
+        assert ends[-1] <= profiler.stopped
+        # the traced tail lasts the mix's ``trace_seconds``, wait and all,
+        # and the untraced part stopped sending in time for its own wait
+        assert n - k > depths[1]
+        assert profiler.stopped - profiler.started >= mix["trace_seconds"]
+        assert starts[k - 1] - starts[0] <= 1.5 - mix["trace_seconds"]
+    else:
+        assert k == n and len(depths) == 1
+    for lo, hi, d in ((0, k, depths[0]), (k, n, depths[-1])):
+        for i in range(lo, hi):
+            # a step is seen to end once ``d`` later ones are sent, and
+            # before the one after them goes out
+            if i + d < hi:
+                assert ends[i] >= starts[i + d]
+            if i + d + 1 < hi:
+                assert ends[i] <= starts[i + d + 1]
+
+
+def test_steps_ahead_is_the_mix_seconds_in_whole_steps():
+    assert D.steps_ahead(6.0, 0.125) == 48
+    assert D.steps_ahead(0.25, 0.125) == 2
+    assert D.steps_ahead(0.25, 3.0) == 1        # never none
 
 
 def test_measurement_without_the_chip_is_refused(run_toy, capsys):

@@ -1,7 +1,9 @@
 """A temporary checkout with toy cells ADDED AS FILES: the benchmark's own
-files copied unchanged, the program linked in, the fixtures' configuration,
-mixes and metric reader dropped beside them, and entries appended to the
-copy's ``BENCHMARK.json``.  No file that exists is edited."""
+files copied unchanged, the program linked in, the fixtures' configurations,
+mixes, metric reader and — for a second model kind — binding, reference and
+driver dropped beside them, and entries appended to the copy's
+``BENCHMARK.json``.  No file that exists is edited: this is the path a
+``model_config`` PR walks."""
 from __future__ import annotations
 
 import json
@@ -18,7 +20,10 @@ TOY_CELLS = [
      "chips": 1, "why": "fixture"},
     {"name": "toy.backlog", "config": "toy-gpt", "traffic": "toy-backlog",
      "chips": 1, "why": "fixture"},
+    {"name": "toy.llama", "config": "toy-llama",
+     "traffic": "toy-llama-backlog", "chips": 1, "why": "fixture"},
 ]
+KINDS = ("configs", "traffic", "metrics", "bindings", "references", "drivers")
 
 
 def make(tmp: Path) -> Path:
@@ -27,19 +32,20 @@ def make(tmp: Path) -> Path:
                     ignore=shutil.ignore_patterns("__pycache__"))
     for linked in ("apex_tpu", "examples"):
         (root / linked).symlink_to(REPO / linked)
-    for kind in ("configs", "traffic", "metrics"):
+    for kind in KINDS:
         for f in (FIXTURES / kind).iterdir():
             target = root / "benchmark" / kind / f.name
             assert not target.exists(), f"{target} would be overwritten"
             shutil.copy(f, target)
     index = json.loads((REPO / "BENCHMARK.json").read_text())
-    for name in ("toy-bert", "toy-gpt"):
+    for name in ("toy-bert", "toy-gpt", "toy-llama"):
+        file = f"benchmark/configs/{name}.json"
         index["configs"].append({
-            "name": name, "source": "fixture",
-            "file": f"benchmark/configs/{name}.json", "reduced": [],
+            "name": name, "source": "fixture", "file": file,
+            "reduced": json.loads((root / file).read_text())["reduced"],
             "why": "fixture"})
     index["workloads"].extend(TOY_CELLS)
-    serve = ["toy.chat", "toy.backlog"]
+    serve = ["toy.chat", "toy.backlog", "toy.llama"]
     for m in index["end_to_end"]:
         if "workloads" not in m:
             continue
