@@ -149,6 +149,34 @@ def make_prefill_fn(kind: str, cfg, sampling: SamplingConfig,
                                sampling)
         return cache, tok, last
 
+    def prefill_laguna_fn(cache, params, tokens, slot, length, row,
+                          prefill_from, key, step):
+        # two pools (ISSUE 30): the full layers' k/v go to the slot's
+        # pages, the window layers' last positions to its rings.  There
+        # is no suffix mode — prefill_from is held to 0 by the engine —
+        # and the sampled token carries the step's counters as its tail
+        # (models.LAGUNA_STATS), read in the one transfer the scheduler
+        # already makes
+        with obs.named_scope("apex_prefill_forward"):
+            logits, ks, vs, wks, wvs, stats = models.prefill_forward(
+                kind, cfg, params, tokens[None], length)
+        with obs.named_scope("apex_prefill_cache_insert"):
+            cache = kv_cache.insert_tokens(cache, slot, ks, vs, length,
+                                           row, prefill_from)
+            if wks is not None:
+                cache = kv_cache.insert_window(cache, slot, wks, wvs,
+                                               length)
+        with obs.named_scope("apex_prefill_sample"):
+            last = logits[0].astype(jnp.float32)            # [vocab]
+            tok = sample_token(last, jax.random.fold_in(key, step),
+                               sampling)
+            tok = jnp.concatenate([
+                tok.astype(jnp.int32)[None],
+                models.laguna_stats_tail(stats, cache)])
+        return cache, tok, last
+
+    if kind == "laguna":
+        return prefill_laguna_fn
     return prefill_paged_fn if paged else prefill_fn
 
 
@@ -184,7 +212,23 @@ def make_decode_fn(kind: str, cfg, sampling: SamplingConfig,
             cache, truncated = kv_cache.advance(cache, active)
         return cache, toks, logits, truncated
 
-    return decode_fn
+    def decode_laguna_fn(cache, params, tokens, active, key, step):
+        with obs.named_scope("apex_decode_forward"):
+            logits, cache, stats = models.decode_forward(
+                kind, cfg, params, cache, tokens, active=active)
+        with obs.named_scope("apex_decode_sample"):
+            logits = logits.astype(jnp.float32)
+            toks = sample_token(logits, jax.random.fold_in(key, step),
+                                sampling)
+        with obs.named_scope("apex_decode_advance"):
+            cache, truncated = kv_cache.advance(cache, active)
+            # [slots] tokens + the counters' tail: one read for both
+            toks = jnp.concatenate([
+                toks.astype(jnp.int32),
+                models.laguna_stats_tail(stats, cache)])
+        return cache, toks, logits, truncated
+
+    return decode_laguna_fn if kind == "laguna" else decode_fn
 
 
 def make_verify_fn(kind: str, cfg, sampling: SamplingConfig, k: int,
@@ -284,8 +328,16 @@ class PendingSwapOut:
 
 
 class InferenceEngine:
-    """Serving engine over a standalone GPT/LLaMA/BERT — single-chip by
-    default, tensor-parallel over a ``tp``-wide mesh on request.
+    """Serving engine over a standalone GPT/LLaMA/Laguna/BERT —
+    single-chip by default, tensor-parallel over a ``tp``-wide mesh on
+    request (``gpt``/``llama``).
+
+    The ``laguna`` kind (ISSUE 30: expert FFN, window + full layers, a
+    head count per layer) serves from the paged cache only, on one chip,
+    greedy or sampled; tp > 1, speculative verify, the host KV tier,
+    prefix sharing and the fused block kernel are refused for it at
+    construction, each with its reason.  Its prefill and decode append
+    ``stats_tail`` int32 counters to the tokens they return.
 
     Static shape contract: ``slots`` concurrent sequences, each with a
     ``max_seq``-deep cache line, decode always batched over every slot.
@@ -318,10 +370,17 @@ class InferenceEngine:
                  tp: Optional[int] = None,
                  host_tier_bytes: Optional[int] = None,
                  swap_batch_pages: Optional[int] = None):
-        if kind not in ("gpt", "llama", "bert"):
+        if kind not in ("gpt", "llama", "laguna", "bert"):
             raise ValueError(f"unknown model kind {kind!r}")
         if kind != "bert":
             models.check_supported(kind, cfg)
+        #: int32 counters a step appends to the tokens it returns
+        #: (models.LAGUNA_STATS); 0 for kinds without an expert FFN
+        self.stats_tail = len(models.LAGUNA_STATS) if kind == "laguna" \
+            else 0
+        #: can a cached prefix's pages be mapped into another slot, or
+        #: a prefill resume mid-prompt?  Not over window rings
+        self.supports_prefix_sharing = kind != "laguna"
         self.kind, self.cfg = kind, cfg
         self.slots = int(slots)
         self.max_seq = min(int(max_seq or cfg.max_seq_length),
@@ -400,6 +459,8 @@ class InferenceEngine:
                     "tensor-parallel serving shards the PAGED kv pool "
                     "over kv heads — pass page_size=/num_pages= (the "
                     "dense slot cache does not shard)")
+        if kind == "laguna":
+            self._refuse_unbuilt_for_laguna(decode_fusion, spec_k)
         if dtype is not None:
             from apex_tpu.optimizers.functional import _cast_floating
             params = _cast_floating(params, dtype)
@@ -448,7 +509,7 @@ class InferenceEngine:
             # layout is a one-time device-side re-copy of the layer
             # weights (prefill keeps the original tree) — HBM for
             # decode latency, documented beside the knob.
-            self.decode_fused = resolve_decode_fusion(
+            self.decode_fused = kind != "laguna" and resolve_decode_fusion(
                 decode_fusion, paged=self.paged,
                 max_pages=self.max_pages_per_slot,
                 min_pages=fusion_min_pages,
@@ -479,6 +540,7 @@ class InferenceEngine:
             # speculative decoding (ISSUE 15): ONE verify executable
             # per (k, engine) — the slab width is static
             self.spec_k = int(spec_k if spec_k is not None
+                              else 0 if kind == "laguna"
                               else default_spec_k())
             if self.spec_k:
                 self._verify_raw = self._tp_wrap(
@@ -516,6 +578,33 @@ class InferenceEngine:
                     in_specs=(cs, P(), sb, sb), out_specs=cs)
                 self._swap_in = jax.jit(self._swap_in_raw,
                                         donate_argnums=(0,))
+
+    def _refuse_unbuilt_for_laguna(self, decode_fusion, spec_k) -> None:
+        """What ISSUE 30 did not build for the kind is refused here,
+        with its reason — never run wrong."""
+        if not self.paged:
+            raise ValueError(
+                "the 'laguna' kind serves from the paged cache only "
+                "(its full layers page, its window layers ring): pass "
+                "page_size=/num_pages=")
+        if self.tp > 1:
+            models.tp_dims("laguna", self.cfg, self.tp)     # raises why
+        if spec_k:
+            raise ValueError(
+                "speculative verify is not built for the 'laguna' kind "
+                "(a rejected slab would have to roll its window rings "
+                "back)")
+        if self.host_tier_bytes:
+            raise ValueError(
+                "the host KV tier is not built for the 'laguna' kind "
+                "(it swaps prefix pages, and a prefix over window rings "
+                "cannot be shared)")
+        if decode_fusion is not None \
+                and resolve_fusion_mode(decode_fusion) == "1":
+            raise ValueError(
+                "fused_block_decode is not built for the 'laguna' kind "
+                "(the kernel has one head count, a dense FFN and no "
+                "window)")
 
     def _fused_block_dims(self) -> dict:
         """The per-rank layer geometry the fused block kernel would run
@@ -606,12 +695,13 @@ class InferenceEngine:
             # kv_heads_pool / tp heads of every page
             def build():
                 return kv_cache.init_paged_cache(
-                    self.num_pages, d["layers"],
+                    self.num_pages, d["pool_layers"],
                     self.tp_dims["kv_heads_pool"],
                     self.page_size, d["head_dim"], slots=self.slots,
                     max_pages_per_slot=self.max_pages_per_slot,
                     dtype=self.cache_dtype,
-                    attn_max_pages=self.paged_attn_max_pages)
+                    attn_max_pages=self.paged_attn_max_pages,
+                    window_layers=d["window_layers"], window=d["window"])
 
             if self.tp == 1:
                 return build()
@@ -647,10 +737,16 @@ class InferenceEngine:
         d = self.dims
         itemsize = jnp.dtype(self.cache_dtype).itemsize
         kvh = self.tp_dims["kv_heads_pool"] // self.tp   # per-rank heads
-        per_tok = 2 * d["layers"] * kvh * d["head_dim"] * itemsize
+        per_layer_tok = 2 * kvh * d["head_dim"] * itemsize
         if self.paged:
-            return (self.num_pages + 1) * self.page_size * per_tok
-        return self.slots * self.max_seq * per_tok
+            # the pool's layers, + the window layers' rings: fixed rows a
+            # slot whatever the context (none without such layers)
+            ring = kv_cache.ring_rows(d["window"], self.page_size)
+            return ((self.num_pages + 1) * self.page_size
+                    * d["pool_layers"] * per_layer_tok
+                    + self.slots * ring * d["window_layers"]
+                    * per_layer_tok)
+        return self.slots * self.max_seq * d["layers"] * per_layer_tok
 
     # -- generative path -----------------------------------------------------
     def _next_step(self):
@@ -698,6 +794,11 @@ class InferenceEngine:
                 "prefill_from needs the paged cache (prefix sharing is "
                 "a page-table edit); this engine runs the dense slot "
                 "cache")
+        if start and not self.supports_prefix_sharing:
+            raise ValueError(
+                f"prefill_from={start}: the {self.kind!r} kind prefills "
+                f"a prompt whole (the positions its window rings would "
+                f"need are not in the pages a prefix shares)")
         suffix = tokens[start:]
         bucket = self.bucket_for(suffix.shape[0])
         padded = np.zeros((bucket,), np.int32)
@@ -774,7 +875,7 @@ class InferenceEngine:
                              "slot cache")
         d = self.dims
         itemsize = jnp.dtype(self.cache_dtype).itemsize
-        return (2 * d["layers"] * self.tp_dims["kv_heads_pool"]
+        return (2 * d["pool_layers"] * self.tp_dims["kv_heads_pool"]
                 * self.page_size * d["head_dim"] * itemsize)
 
     def swap_out_pages(self, cache, page_ids, defer: bool = False):

@@ -46,6 +46,17 @@ Shared design positions:
   length (and, paged, its capacity); the stale k/v rows are dead
   weight masked out by the length.  No data movement on the retire
   path — the host allocator reclaims the page IDs.
+* **Two kinds of state in one manager (ISSUE 30).**  A model that mixes
+  full-attention layers with sliding-window layers keeps them in TWO
+  pools under the one :class:`PagedKVCache`: ``k``/``v`` hold the FULL
+  layers only (pages from the :class:`PageAllocator`, growing with the
+  context), ``wk``/``wv`` the WINDOW layers — per slot a fixed ring of
+  ``ceil(window / page_size) + 1`` pages in which position ``t`` lives
+  at ring row ``t % ring``: no allocator traffic, no growth with the
+  context, nothing to free at eviction (a stale row is masked by its
+  position).  Lengths, capacity and the page table are shared.  Models
+  without window layers leave ``wk``/``wv`` ``None`` and keep the one
+  pool, op for op.
 * **The trash page.**  The paged pool carries ONE sacrificial page at
   index ``pages - 1`` that the allocator never hands out; page-table
   entries beyond a slot's reservation point there, so the statically
@@ -65,6 +76,8 @@ import numpy as np
 from apex_tpu.transformer.parallel_state import TENSOR_AXIS
 
 __all__ = ["KVCache", "init_cache", "PagedKVCache", "init_paged_cache",
+           "insert_window", "append_window", "window_pages_live",
+           "ring_rows",
            "PageAllocator", "HostPageStore", "default_page_size",
            "default_swap_batch_pages", "insert_tokens", "cow_page",
            "extract_pages", "restore_pages", "append_slab",
@@ -393,6 +406,17 @@ class PagedKVCache:
     capacity: jax.Array    # [slots] int32: page_size * owned pages
     attn_max_pages: Optional[int] = flax.struct.field(
         pytree_node=False, default=None)
+    # the window layers' rings (ISSUE 30), None without window layers:
+    # [window_layers, slots, kv_heads, ring, head_dim] (layer-major: a
+    # decode step rewrites one layer at a time), ring a whole number of
+    # pages; position t of a slot sits at ring row t % ring
+    wk: Optional[jax.Array] = None
+    wv: Optional[jax.Array] = None
+
+    @property
+    def ring(self) -> int:
+        """Positions one slot's window ring holds (0 without one)."""
+        return 0 if self.wk is None else self.wk.shape[3]
 
     @property
     def pages(self) -> int:
@@ -442,22 +466,42 @@ class PagedKVCache:
 def init_paged_cache(pages: int, layers: int, kv_heads: int,
                      page_size: int, head_dim: int, *, slots: int,
                      max_pages_per_slot: int, dtype=jnp.bfloat16,
-                     attn_max_pages: Optional[int] = None) -> PagedKVCache:
+                     attn_max_pages: Optional[int] = None,
+                     window_layers: int = 0,
+                     window: int = 0) -> PagedKVCache:
     """Allocate an empty pool: ``pages`` allocatable pages (+1 trash
     page appended), every page-table entry pointing at the trash page,
-    every slot empty."""
+    every slot empty.  ``layers`` counts the layers the POOL holds;
+    ``window_layers`` sliding-window layers of ``window`` positions get
+    the second pool of per-slot rings instead (module docstring)."""
     if pages < 1 or page_size < 1 or max_pages_per_slot < 1:
         raise ValueError(
             f"pages ({pages}), page_size ({page_size}) and "
             f"max_pages_per_slot ({max_pages_per_slot}) must be >= 1")
     shape = (pages + 1, layers, kv_heads, page_size, head_dim)
+    rings = {}
+    if window_layers:
+        if window < 1:
+            raise ValueError(f"window layers need window >= 1, got "
+                             f"{window}")
+        ring = ring_rows(window, page_size)
+        wshape = (window_layers, slots, kv_heads, ring, head_dim)
+        rings = dict(wk=jnp.zeros(wshape, dtype),
+                     wv=jnp.zeros(wshape, dtype))
     return PagedKVCache(
         k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
         page_table=jnp.full((slots, max_pages_per_slot), pages,
                             jnp.int32),
         lengths=jnp.zeros((slots,), jnp.int32),
         capacity=jnp.zeros((slots,), jnp.int32),
-        attn_max_pages=attn_max_pages)
+        attn_max_pages=attn_max_pages, **rings)
+
+
+def ring_rows(window: int, page_size: int) -> int:
+    """Positions one slot's window ring holds: ``ceil(window / page_size)
+    + 1`` whole pages — the window, and the page a prefill or the newest
+    token is still filling."""
+    return (-(-window // page_size) + 1) * page_size
 
 
 def paged_cache_partition_specs(attn_max_pages: Optional[int] = None,
@@ -638,6 +682,77 @@ def insert_tokens(cache: PagedKVCache, slot, k, v, length, row,
             cache.lengths, jnp.asarray(length, jnp.int32)[None], (slot,)),
         capacity=jax.lax.dynamic_update_slice(
             cache.capacity, (owned * ps)[None], (slot,)))
+
+
+def insert_window(cache: PagedKVCache, slot, k, v,
+                  length) -> PagedKVCache:
+    """Prefill write of the WINDOW layers (ISSUE 30): of a prompt's k/v
+    ``[window_layers, kv_heads, s, head_dim]`` only the last ``ring``
+    positions below ``length`` are kept, each at ring row ``t % ring``.
+
+    Ring row ``r`` receives position ``(length - 1) - ((length - 1 - r)
+    mod ring)`` — the newest one congruent to ``r``; where that is
+    negative (a prompt shorter than the ring) the row keeps a clamped
+    gather that every reader masks by position.  One gather along the
+    sequence and one whole-ring write: the slot's ring is rewritten, so
+    nothing of its previous occupant survives an admission."""
+    ring, s = cache.ring, k.shape[2]
+    if not ring or k.shape != v.shape \
+            or k.shape[:2] != (cache.wk.shape[0], cache.wk.shape[2]) \
+            or k.shape[3] != cache.head_dim:
+        raise ValueError(
+            f"window k/v must be [window_layers, kv_heads, s, head_dim]"
+            f" of a cache with rings; got k {tuple(k.shape)} v "
+            f"{tuple(v.shape)}, rings "
+            f"{None if cache.wk is None else tuple(cache.wk.shape)}")
+    last = jnp.asarray(length, jnp.int32) - 1
+    held = last - jnp.mod(last - jnp.arange(ring, dtype=jnp.int32), ring)
+    src = jnp.clip(held, 0, s - 1)
+    slot = jnp.asarray(slot, jnp.int32)
+    zero = jnp.int32(0)
+
+    def write(rings, x):
+        rows = jnp.take(x, src, axis=2).astype(rings.dtype)
+        return jax.lax.dynamic_update_slice(
+            rings, rows[:, None], (zero, slot, zero, zero, zero))
+
+    return cache.replace(wk=write(cache.wk, k), wv=write(cache.wv, v))
+
+
+def append_window(cache: PagedKVCache, layer: int, k_tok,
+                  v_tok) -> PagedKVCache:
+    """Decode write for ONE window layer (``layer`` counts window layers
+    only): slot ``i``'s token row lands at ring row ``lengths[i] %
+    ring`` — where the position ``ring`` back, by now outside every
+    window, used to be.  The dense cache's one-row-per-slot update."""
+    if k_tok.shape != (cache.slots, cache.kv_heads, cache.head_dim):
+        raise ValueError(
+            f"token k/v must be [slots={cache.slots}, "
+            f"kv_heads={cache.kv_heads}, head_dim={cache.head_dim}], "
+            f"got {tuple(k_tok.shape)}")
+    rows = jnp.mod(cache.lengths, cache.ring)
+
+    def write(buf, tok, row):
+        return jax.lax.dynamic_update_slice(
+            buf, tok[:, None, :].astype(buf.dtype),
+            (jnp.int32(0), row, jnp.int32(0)))
+
+    upd = jax.vmap(write)
+    return cache.replace(
+        wk=cache.wk.at[layer].set(upd(cache.wk[layer], k_tok, rows)),
+        wv=cache.wv.at[layer].set(upd(cache.wv[layer], v_tok, rows)))
+
+
+def window_pages_live(cache: PagedKVCache):
+    """Ring pages that hold a position some live slot can still attend
+    (int32 scalar, on the device): per admitted slot ``min(ceil(length
+    / page_size), ring pages)``.  Never above ``slots * ring pages``."""
+    if not cache.ring:
+        return jnp.int32(0)
+    ps = cache.page_size
+    pages = jnp.minimum(-(-cache.lengths // ps), cache.ring // ps)
+    return jnp.sum(jnp.where(cache.capacity > 0, pages, 0)).astype(
+        jnp.int32)
 
 
 def cow_page(cache: PagedKVCache, src, dst) -> PagedKVCache:

@@ -14,8 +14,17 @@ bit-for-bit on the same weights and the parity tests in
 
 Single-chip serving (tp = 1): the TP layers all collapse to plain
 matmuls at world size 1, which is what these forwards implement.
-Unsupported training-only configs (scan_layers, MoE FFN, sequence/
-context parallelism) fail loudly at engine construction.
+Unsupported training-only configs (scan_layers, the capacity-slot MoE
+FFN of ``transformer/moe/MoELayer``, sequence/context parallelism) fail
+loudly at engine construction.
+
+The ``laguna`` kind (ISSUE 30) is built from the per-layer pieces of
+``transformer/testing/standalone_laguna.py`` rather than mirrored by
+hand: a head count per layer, window layers (``flash_attention(window=)``
+in prefill, a per-slot ring in decode) beside full layers in the paged
+pool, and an expert FFN that drops no token
+(``transformer/moe/dropless.py``) inside both executables.  Its steps
+return their expert counters (:data:`LAGUNA_STATS`) beside the logits.
 
 Multi-chip serving (ISSUE 17): every forward takes a static ``tp`` and,
 at ``tp > 1``, runs as the per-rank body of a ``shard_map`` over the
@@ -45,6 +54,7 @@ from apex_tpu.ops.attention import (
     decode_attention,
     flash_attention,
     prefix_window_attention,
+    ring_decode_attention,
     slab_decode_attention,
 )
 from apex_tpu.ops.paged_attention import (
@@ -55,10 +65,13 @@ from apex_tpu.ops.paged_attention import (
 from apex_tpu.transformer.functional.fused_rope import (
     fused_apply_rotary_pos_emb_cached,
 )
+from apex_tpu.transformer.moe.dropless import fold_stats
 from apex_tpu.transformer.parallel_state import TENSOR_AXIS
+from apex_tpu.transformer.testing import standalone_laguna as laguna
 from apex_tpu.transformer.testing.standalone_llama import _rope_cos_sin
 
 __all__ = ["model_dims", "tp_dims", "check_supported", "prefill_forward",
+           "LAGUNA_STATS", "laguna_stats_tail",
            "decode_forward", "verify_forward", "fused_layer_params",
            "expand_kv_for_tp", "param_partition_specs",
            "fused_partition_specs"]
@@ -66,12 +79,28 @@ __all__ = ["model_dims", "tp_dims", "check_supported", "prefill_forward",
 
 def model_dims(kind: str, cfg) -> dict:
     """Static cache geometry for a model config: layers / kv_heads /
-    head_dim (+ query heads)."""
+    head_dim (+ query heads).
+
+    ``laguna`` (ISSUE 30) has no ONE head count: ``heads``,
+    ``layer_types`` and ``ffn_types`` are per-layer tuples, and
+    ``pool_layers`` / ``window_layers`` say how many layers the paged
+    pool and the window rings hold (``kv_cache`` module docstring);
+    ``window`` is the sliding window in positions."""
+    if kind == "laguna":
+        return {"layers": cfg.num_layers,
+                "heads": tuple(cfg.heads_per_layer),
+                "layer_types": tuple(cfg.layer_types),
+                "ffn_types": tuple(cfg.mlp_types),
+                "kv_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
+                "pool_layers": len(cfg.full_layers),
+                "window_layers": len(cfg.window_layers),
+                "window": cfg.sliding_window}
     head_dim = cfg.hidden_size // cfg.num_attention_heads
     kv_heads = (cfg.kv_heads if kind == "llama"
                 else cfg.num_attention_heads)
     return {"layers": cfg.num_layers, "heads": cfg.num_attention_heads,
-            "kv_heads": kv_heads, "head_dim": head_dim}
+            "kv_heads": kv_heads, "head_dim": head_dim,
+            "pool_layers": cfg.num_layers, "window_layers": 0, "window": 0}
 
 
 def tp_dims(kind: str, cfg, tp: int) -> dict:
@@ -88,6 +117,11 @@ def tp_dims(kind: str, cfg, tp: int) -> dict:
     if tp <= 1:
         return dict(d, heads_local=heads, kv_heads_local=kvh,
                     kv_heads_pool=kvh, rep=1)
+    if kind == "laguna":
+        raise ValueError(
+            "tp > 1 is not built for the 'laguna' kind: its expert "
+            "stacks, per-layer head counts and window rings have no "
+            "partition specs yet (serve it on one chip)")
     if heads % tp:
         raise ValueError(
             f"tp={tp} does not divide num_attention_heads={heads}")
@@ -105,9 +139,15 @@ def tp_dims(kind: str, cfg, tp: int) -> dict:
 
 
 def check_supported(kind: str, cfg) -> None:
-    if kind not in ("gpt", "llama"):
+    if kind not in ("gpt", "llama", "laguna"):
         raise ValueError(f"unknown generative model kind {kind!r} "
-                         "(expected 'gpt' or 'llama')")
+                         "(expected 'gpt', 'llama' or 'laguna')")
+    if kind == "laguna":
+        if not isinstance(cfg, laguna.LagunaConfig):
+            raise TypeError(
+                f"the 'laguna' kind takes a LagunaConfig, got "
+                f"{type(cfg).__name__}")
+        return          # its expert FFN IS built (dropless, ISSUE 30)
     for flag in ("sequence_parallel", "context_parallel", "scan_layers"):
         if getattr(cfg, flag, False):
             raise ValueError(
@@ -822,6 +862,90 @@ def _llama_verify(cfg, params, cache, tokens, tp=1):
 
 
 # --------------------------------------------------------------------------
+# Laguna (standalone_laguna's per-layer pieces; ISSUE 30): a head count
+# per layer, window layers in per-slot rings beside full layers in the
+# paged pool, an expert FFN that drops no token
+# --------------------------------------------------------------------------
+
+#: what a laguna step reports beside its tokens, in this order — the
+#: int32 tail of the token read (``InferenceEngine.stats_tail`` long)
+LAGUNA_STATS = ("moe_assignments", "moe_experts_hit",
+                "moe_expert_load_max", "window_pages_live")
+
+
+def laguna_stats_tail(acc, cache):
+    """The step's counters as ``int32[4]`` in ``LAGUNA_STATS`` order."""
+    zero = jnp.int32(0)
+    acc = acc or {"assignments": zero, "experts_hit": zero,
+                  "load_max": zero}
+    return jnp.stack([acc["assignments"], acc["experts_hit"],
+                      acc["load_max"], kv_cache.window_pages_live(cache)
+                      ]).astype(jnp.int32)
+
+
+def _laguna_prefill(cfg, params, tokens, length=None):
+    """``tokens [1, s]`` -> ``(logits, ks, vs, wks, wvs, stats)``: the
+    full layers' k/v ``[full_layers, kvh, s, d]`` for the paged pool, the
+    window layers' for the rings.  Rows at or past ``length`` are bucket
+    padding: they are routed to no expert."""
+    p = _params_subtree(params)
+    valid = None if length is None else (
+        jnp.arange(tokens.shape[1], dtype=jnp.int32) < length)
+    x, kv, stats = laguna.forward_hidden(cfg, p, tokens, valid=valid)
+    x = x.transpose(1, 0, 2)                                # [s, 1, h]
+    logits = _linear(p["lm_head"],
+                     x if length is None else _last_row(x, length))
+
+    def stack(layers, which):
+        return jnp.stack([kv[i][which][0] for i in layers]) \
+            if layers else None
+
+    return (logits, stack(cfg.full_layers, 0), stack(cfg.full_layers, 1),
+            stack(cfg.window_layers, 0), stack(cfg.window_layers, 1), stats)
+
+
+def _laguna_decode(cfg, params, cache, tokens, active=None):
+    """One token per slot against the two pools -> ``(logits, cache,
+    stats)``.  ``active [slots]`` marks the slots that carry a request:
+    the others are routed to no expert (they would otherwise read
+    experts nobody asked for)."""
+    p = _params_subtree(params)
+    positions = cache.lengths                               # [slots]
+    live = positions + 1
+    x = jnp.take(p["embed_tokens"]["weight"], tokens, axis=0)
+    rope = {t: tuple(c[:, None, :]
+                     for c in laguna.rope_cos_sin(cfg, t, positions))
+            for t in set(cfg.layer_types)}
+    full_of = {i: n for n, i in enumerate(cfg.full_layers)}
+    ring_of = {i: n for n, i in enumerate(cfg.window_layers)}
+    stats = None
+    for i in range(cfg.num_layers):
+        lp, kind_i = p[f"layer_{i}"], cfg.layer_types[i]
+        h1 = rms_norm(x, lp["input_norm"]["weight"], eps=cfg.rms_eps)
+        q, k_tok, v_tok, g = laguna.attn_project(cfg, i, lp, h1,
+                                                 *rope[kind_i])
+        if kind_i == laguna.FULL:
+            n = full_of[i]
+            cache = kv_cache.append_layer(cache, n, k_tok, v_tok)
+            ctx = paged_decode_attention(q, cache.k, cache.v,
+                                         cache.page_table, live, layer=n)
+        else:
+            n = ring_of[i]
+            cache = kv_cache.append_window(cache, n, k_tok, v_tok)
+            ctx = ring_decode_attention(
+                q, cache.wk[n], cache.wv[n], positions,
+                window=cfg.sliding_window)
+        x = x + laguna.attn_output(lp, ctx, g)
+        h2 = rms_norm(x, lp["post_attention_norm"]["weight"],
+                      eps=cfg.rms_eps)
+        y, st = laguna.ffn(cfg, i, lp, h2, valid=active)
+        stats = fold_stats(stats, st)
+        x = x + y
+    x = rms_norm(x, p["final_norm"]["weight"], eps=cfg.rms_eps)
+    return _linear(p["lm_head"], x), cache, stats
+
+
+# --------------------------------------------------------------------------
 # dispatch
 # --------------------------------------------------------------------------
 
@@ -850,6 +974,11 @@ def prefill_forward(kind: str, cfg, params, tokens, length=None, *,
     if tokens.ndim != 2 or tokens.shape[0] != 1:
         raise ValueError(
             f"prefill takes one prompt [1, s], got {tuple(tokens.shape)}")
+    if kind == "laguna":
+        # -> (logits, ks, vs, wks, wvs, stats): the pool's layers, the
+        # rings' layers, the expert counters.  No suffix mode: a window
+        # ring cannot be shared or resumed (the engine refuses both)
+        return _laguna_prefill(cfg, params, tokens, length)
     fn = _gpt_prefill if kind == "gpt" else _llama_prefill
     if cache is None:
         return fn(cfg, params, tokens, length, tp=tp)
@@ -861,7 +990,7 @@ def prefill_forward(kind: str, cfg, params, tokens, length=None, *,
 
 
 def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
-                   tp=1):
+                   tp=1, active=None):
     """One-token step for every slot: ``tokens [slots]`` ->
     ``(logits [slots, v], cache)`` with the new k/v appended at each
     slot's position.  Lengths do not advance here (the engine advances
@@ -874,7 +1003,12 @@ def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
     fused_block_decode`) instead of the per-op XLA sequence — same
     embed/head, same pool append, same signature, tolerance-level
     numerics (the in-kernel residual chain stays fp32 where the
-    unfused path rounds to bf16 at each sublayer)."""
+    unfused path rounds to bf16 at each sublayer).
+
+    ``laguna`` returns a third value, the step's expert counters, and
+    takes ``active`` (the slots that carry a request)."""
+    if kind == "laguna":
+        return _laguna_decode(cfg, params, cache, tokens, active=active)
     fn = _gpt_decode if kind == "gpt" else _llama_decode
     return fn(cfg, params, cache, tokens, fused=fused, tp=tp)
 
@@ -890,5 +1024,9 @@ def verify_forward(kind: str, cfg, params, cache, tokens, tp=1):
     if tokens.ndim != 2:
         raise ValueError(
             f"verify takes a [slots, S] slab, got {tuple(tokens.shape)}")
+    if kind == "laguna":
+        raise ValueError(
+            "speculative verify is not built for the 'laguna' kind (a "
+            "rejected slab would have to roll its window rings back)")
     fn = _gpt_verify if kind == "gpt" else _llama_verify
     return fn(cfg, params, cache, tokens, tp=tp)

@@ -236,6 +236,17 @@ class SlotScheduler:
                           else ServeTelemetry())
         use_prefix = (prefix_cache if prefix_cache is not None
                       else prefix_cache_enabled())
+        if not getattr(engine, "supports_prefix_sharing", True):
+            # a kind with window layers (ISSUE 30): a ring cannot be
+            # shared or resumed.  Asked for by name it is refused; the
+            # environment's default is simply not applied
+            if prefix_cache:
+                raise ValueError(
+                    f"prefix sharing is not built for the "
+                    f"{engine.kind!r} kind: its window layers keep a "
+                    f"ring per slot, which a shared prefix's pages do "
+                    f"not hold")
+            use_prefix = False
         # host-DRAM page tier (ISSUE 18): armed when the engine carries
         # a byte budget AND prefix caching is on — the tier is the
         # prefix cache's second level, nothing else swaps.  The store
@@ -262,6 +273,12 @@ class SlotScheduler:
             raise ValueError(
                 "chunked prefill rides the paged cache's prefill_from "
                 "path; this engine runs the dense slot cache")
+        if self.prefill_chunk \
+                and not getattr(engine, "supports_prefix_sharing", True):
+            raise ValueError(
+                f"chunked prefill is not built for the {engine.kind!r} "
+                f"kind (a later chunk would have to attend the earlier "
+                f"chunks' window rings)")
         if self.prefill_chunk and engine.paged \
                 and self.prefill_chunk % engine.page_size:
             raise ValueError(
@@ -663,6 +680,17 @@ class SlotScheduler:
             self.drafter.retire(slot)
         self.telemetry.request_finished(st.uid, reason, len(gen))
 
+    def _peel_stats(self, toks, phase: str):
+        """Tokens as read from the device, less the counters a kind
+        with an expert FFN appends to them (``engine.stats_tail`` int32
+        values — ISSUE 30: they ride the token read the pass makes
+        anyway).  The counters go to the telemetry."""
+        tail = getattr(self.engine, "stats_tail", 0)
+        if not tail:
+            return toks
+        self.telemetry.expert_pass(phase, *(int(v) for v in toks[-tail:]))
+        return toks[:-tail]
+
     def _prefill_piece(self, slot: int) -> None:
         """Advance one slot's prefill by one chunk (or the whole
         uncached tail when chunking is off / the tail fits)."""
@@ -684,7 +712,9 @@ class SlotScheduler:
                 # the one place of a prefill where the host waits
                 # for the device
                 with trace_annotation("apex_tpu.scheduler.token_read"):
-                    tok = int(np.asarray(tok))
+                    tok = np.asarray(tok).reshape(-1)
+                tok = self._peel_stats(tok, "prefill")
+                tok = int(tok[0])
             st.prefilled = end
             if st.chunked:
                 tel.prefill_chunked(st.uid, start, end - start)
@@ -930,6 +960,7 @@ class SlotScheduler:
             with trace_annotation("apex_tpu.scheduler.token_read"):
                 toks = np.asarray(toks)
                 truncated = np.asarray(truncated)
+            toks = self._peel_stats(toks, "decode")
         with trace_annotation("apex_tpu.scheduler.retire"):
             for slot, st in enumerate(slots):
                 if st is None or not active[slot]:

@@ -174,6 +174,28 @@ METRIC_SPECS: Dict[str, MetricSpec] = {s.name: s for s in [
     MetricSpec("serve_prefix_host_hits_total", "counter",
                "admissions whose matched prefix was (partly) host-"
                "resident and was served by swap-in uploads"),
+    # -- expert FFN + window rings (ISSUE 30): counted on the device by
+    #    the step itself and read with its tokens, per phase (a prefill
+    #    routes a prompt, a decode step one token a slot).
+    MetricSpec("serve_moe_passes_total", "counter",
+               "steps that ran an expert FFN", labels=("phase",)),
+    MetricSpec("serve_moe_assignments_total", "counter",
+               "(token, expert) assignments routed, summed over the "
+               "expert layers (tokens x experts_per_token x layers)",
+               labels=("phase",)),
+    MetricSpec("serve_moe_experts_hit_total", "counter",
+               "experts that received at least one token, summed over "
+               "the expert layers of every step", labels=("phase",)),
+    MetricSpec("serve_moe_expert_load_max_total", "counter",
+               "the busiest expert's tokens in a step (max over its "
+               "expert layers), summed over steps — over assignments "
+               "per expert it is the straggler ratio",
+               labels=("phase",)),
+    MetricSpec("serve_window_pages_live", "gauge",
+               "window-ring pages holding a position a live slot can "
+               "still attend (never above slots x ring pages)"),
+    MetricSpec("serve_window_pages_live_peak", "gauge",
+               "the largest serve_window_pages_live any step reported"),
     # -- speculative decoding (ISSUE 15): the verify step's accept/
     #    reject accounting.  Drafted counts what the verify executable
     #    SCORED (k per active slot per round, padding drafts

@@ -35,7 +35,19 @@ from apex_tpu.observability.spans import RequestTracer
 from apex_tpu.observability.timers import StepTimer
 
 __all__ = ["ServeTelemetry", "FleetTelemetry", "SPEC_METRIC_FAMILIES",
-           "TIER_METRIC_FAMILIES", "FLEET_METRIC_FAMILIES"]
+           "TIER_METRIC_FAMILIES", "FLEET_METRIC_FAMILIES",
+           "EXPERT_METRIC_FAMILIES"]
+
+#: the ISSUE 30 expert-FFN / window-ring families (same schema-guard
+#: contract as SPEC/TIER_METRIC_FAMILIES)
+EXPERT_METRIC_FAMILIES = (
+    "serve_moe_passes_total",
+    "serve_moe_assignments_total",
+    "serve_moe_experts_hit_total",
+    "serve_moe_expert_load_max_total",
+    "serve_window_pages_live",
+    "serve_window_pages_live_peak",
+)
 
 #: the ISSUE 15 speculation families (schema-guard tested: every name
 #: here must be pinned in ``.telemetry_schema.json`` — the
@@ -231,6 +243,14 @@ class ServeTelemetry:
         self.host_tier_bytes = d("serve_host_tier_bytes")
         self.host_tier_evictions = d("serve_host_tier_evictions_total")
         self.prefix_host_hits = d("serve_prefix_host_hits_total")
+        # expert FFN + window rings (ISSUE 30): the step counts them on
+        # the device; the scheduler hands them over with the tokens
+        self.moe_passes = d("serve_moe_passes_total")
+        self.moe_assignments = d("serve_moe_assignments_total")
+        self.moe_experts_hit = d("serve_moe_experts_hit_total")
+        self.moe_expert_load_max = d("serve_moe_expert_load_max_total")
+        self.window_pages_live = d("serve_window_pages_live")
+        self.window_pages_live_peak = d("serve_window_pages_live_peak")
         # request tracing (ISSUE 13): spans ride the SAME host
         # boundaries the methods below already occupy — arming the
         # tracer (trace= or APEX_TPU_TRACE) adds zero device work
@@ -501,6 +521,17 @@ class ServeTelemetry:
 
     def backpressured(self) -> None:
         self.backpressure_waits.inc()
+
+    def expert_pass(self, phase: str, assignments: int, experts_hit: int,
+                    load_max: int, window_pages: int) -> None:
+        """One step's device-side counters (``models.LAGUNA_STATS``
+        order), ``phase`` ``"prefill"`` or ``"decode"``."""
+        self.moe_passes.inc(phase=phase)
+        self.moe_assignments.inc(assignments, phase=phase)
+        self.moe_experts_hit.inc(experts_hit, phase=phase)
+        self.moe_expert_load_max.inc(load_max, phase=phase)
+        self.window_pages_live.set(window_pages)
+        self.window_pages_live_peak.set_max(window_pages)
 
     def request_finished(self, uid: int, reason: str,
                          n_tokens: int) -> None:

@@ -61,7 +61,8 @@ from jax.experimental.pallas import tpu as pltpu
 from apex_tpu.utils import cdiv, interpret_mode
 
 __all__ = ["flash_attention", "mha_reference", "decode_attention",
-           "prefix_window_attention", "slab_decode_attention"]
+           "ring_decode_attention", "prefix_window_attention",
+           "slab_decode_attention"]
 
 #: pallas_audit registration (analysis hook only, no behavior change):
 #: every attention kernel carries online-softmax (m/l/acc) or wgrad
@@ -208,7 +209,7 @@ def _valid_mask(s, valid, qi, ki, bq, bk):
 
 
 def _fwd_kernel(causal, off, scale, bq, bk, nk, masked, valid, rate,
-                *refs):
+                *refs, window=None):
     q_ref, k_ref, v_ref = refs[:3]
     i = 3
     mask_ref = refs[i] if masked else None
@@ -228,6 +229,10 @@ def _fwd_kernel(causal, off, scale, bq, bk, nk, masked, valid, rate,
 
     # causal: whole block above the diagonal contributes nothing — skip
     run = True if not causal else (ki * bk <= qi * bq + bq - 1 + off)
+    if window is not None:
+        # ... and a block wholly BEHIND the window of its every row
+        # (its last column at or before the first row's first key)
+        run = run & (ki * bk + bk - 1 > qi * bq + off - window)
 
     @pl.when(run)
     def _body():
@@ -245,7 +250,10 @@ def _fwd_kernel(causal, off, scale, bq, bk, nk, masked, valid, rate,
         if causal:
             rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(rows + off >= cols, s, _NEG_INF)
+            keep = rows + off >= cols
+            if window is not None:    # row i sees keys (i - window, i]
+                keep = keep & (rows + off - cols < window)
+            s = jnp.where(keep, s, _NEG_INF)
         if masked:
             s = jnp.where(mask_ref[0], _NEG_INF, s)
         s = _valid_mask(s, valid, qi, ki, bq, bk)
@@ -294,17 +302,27 @@ def _fwd_kernel(causal, off, scale, bq, bk, nk, masked, valid, rate,
 
 
 def _fwd(q3, k3, v3, mask3, causal, scale, bq, bk, out_dtype=None,
-         causal_off=None, valid=None, rate=0.0, seed3=None):
+         causal_off=None, valid=None, rate=0.0, seed3=None, window=None):
     bh, sq, d = q3.shape
     out_dtype = out_dtype or q3.dtype
     sk = k3.shape[1]
     off = (sk - sq) if causal_off is None else causal_off
     nq, nk = cdiv(sq, bq), cdiv(sk, bk)
     masked = mask3 is not None
+
+    def kv_index(b, i, j):
+        if window is None:
+            return (b, j, 0)
+        # hold the block index inside the band of q block i: a skipped
+        # block then repeats its neighbour's index and is not fetched
+        lo = jnp.maximum(i * bq + off - window + 1, 0) // bk
+        hi = jnp.minimum((i * bq + bq - 1 + off) // bk, nk - 1)
+        return (b, jnp.clip(j, lo, jnp.maximum(hi, lo)), 0)
+
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
+        pl.BlockSpec((1, bk, d), kv_index),
+        pl.BlockSpec((1, bk, d), kv_index),
     ]
     operands = [q3, k3, v3]
     if masked:
@@ -317,7 +335,7 @@ def _fwd(q3, k3, v3, mask3, causal, scale, bq, bk, out_dtype=None,
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         operands.append(seed3)
     kernel = functools.partial(_fwd_kernel, causal, off, scale, bq, bk, nk,
-                               masked, valid, rate)
+                               masked, valid, rate, window=window)
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, nq, nk),
@@ -748,7 +766,8 @@ def xla_path_max_seq(override=None) -> int:
     return _XLA_PATH_MAX_SEQ
 
 
-def _xla_attention(q, k, v, *, causal, scale, mask, rate, seed):
+def _xla_attention(q, k, v, *, causal, scale, mask, rate, seed,
+                   window=None):
     """Short-sequence attention as plain XLA ops — same semantics as the
     kernels (True-=-masked boolean mask, fully-masked rows emit zeros,
     the identical coordinate-hash probability dropout), but lowered to
@@ -765,7 +784,10 @@ def _xla_attention(q, k, v, *, causal, scale, mask, rate, seed):
     if causal:
         rows = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
         cols = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
-        s = jnp.where(cols <= rows + (sk - sq), s, _NEG_INF)
+        keep = cols <= rows + (sk - sq)
+        if window is not None:
+            keep = keep & (rows + (sk - sq) - cols < window)
+        s = jnp.where(keep, s, _NEG_INF)
     if mask is not None:
         s = jnp.where(mask, _NEG_INF, s)
     m = jnp.max(s, axis=-1, keepdims=True)
@@ -791,7 +813,8 @@ def flash_attention(q, k, v, *, causal: bool = False, mask=None,
                     dropout_rate: float = 0.0,
                     dropout_seed=None,
                     use_kernel: Optional[bool] = None,
-                    xla_max_seq: Optional[int] = None):
+                    xla_max_seq: Optional[int] = None,
+                    window: Optional[int] = None):
     """Fused blockwise attention, ``[b, h, s, d]`` layout.
 
     Drop-in fused path for the reference's ``fmhalib`` /
@@ -821,10 +844,21 @@ def flash_attention(q, k, v, *, causal: bool = False, mask=None,
     the mask; the backward regenerates it from the same seed, so
     activation-recompute training stays bit-identical.  ``rate`` itself
     is static: rate=0 compiles the exact pre-dropout kernels.
+
+    ``window`` (static, needs ``causal``; ISSUE 30): sliding-window
+    attention — query ``i`` sees key ``j`` iff ``i - window < j <= i``
+    (the Hugging Face ``sliding_window`` convention).  Key blocks wholly
+    behind the window are neither fetched nor computed, the partial one
+    is masked.  ``None`` compiles exactly the kernel it always did.
+    Forward only: the backward kernels know no window and refuse.
     """
     b, h, sq, d = q.shape
     sk = k.shape[2]
     scale = (d ** -0.5) if sm_scale is None else sm_scale
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"window={window!r} needs causal=True and window >= 1 (a "
+            f"sliding window is a band below the causal diagonal)")
     if not 0.0 <= dropout_rate < 1.0:
         raise ValueError(f"dropout_rate must be in [0, 1), got "
                          f"{dropout_rate}")
@@ -855,7 +889,7 @@ def flash_attention(q, k, v, *, causal: bool = False, mask=None,
     if not use_kernel:
         return _xla_attention(q, k, v, causal=causal, scale=scale,
                               mask=mask, rate=dropout_rate,
-                              seed=dropout_seed)
+                              seed=dropout_seed, window=window)
     seed3 = None
     if dropout_rate:
         seed3 = _seed_operand(dropout_seed)
@@ -867,6 +901,11 @@ def flash_attention(q, k, v, *, causal: bool = False, mask=None,
     # to the conservative 512 so previously-compiling calls keep
     # compiling.  _plan_block shrinks further for short sequences.
     default_block = 1024 if (d <= 128 and mask is None) else 512
+    if window is not None:
+        # a band `window` wide under 1024-wide blocks is mostly masked
+        # work: blocks no wider than the window (but lane-wide)
+        default_block = min(default_block, max(
+            _LANES, cdiv(window, _LANES) * _LANES))
     bq, sq_pad = _plan_block(sq, block_q or default_block)
     bk, sk_pad = _plan_block(sk, block_k or default_block)
     padded = (sq_pad != sq) or (sk_pad != sk)
@@ -904,16 +943,20 @@ def flash_attention(q, k, v, *, causal: bool = False, mask=None,
     def run(q3, k3, v3, mask3, seed3):
         out, _ = _fwd(q3, k3, v3, mask3, causal, scale, bq, bk,
                       causal_off=causal_off, valid=valid,
-                      rate=dropout_rate, seed3=seed3)
+                      rate=dropout_rate, seed3=seed3, window=window)
         return out
 
     def run_fwd(q3, k3, v3, mask3, seed3):
         out, lse = _fwd(q3, k3, v3, mask3, causal, scale, bq, bk,
                         causal_off=causal_off, valid=valid,
-                        rate=dropout_rate, seed3=seed3)
+                        rate=dropout_rate, seed3=seed3, window=window)
         return out, (q3, k3, v3, mask3, seed3, out, lse)
 
     def run_bwd(res, do3):
+        if window is not None:
+            raise NotImplementedError(
+                "flash_attention(window=) is forward-only: the backward "
+                "kernels recompute scores without the window mask")
         q3, k3, v3, mask3, seed3, out, lse = res
         dq, dk, dv = _bwd_impl(q3, k3, v3, mask3, out, lse, do3,
                                causal, scale, bq, bk,
@@ -1052,6 +1095,51 @@ def decode_attention(q, k, v, lengths, *, sm_scale: Optional[float] = None,
         preferred_element_type=jnp.float32)             # [b, kvh, group, d]
     out = out.reshape(b, h, 1, d).astype(q.dtype)
     return out[:, :, 0] if squeezed else out
+
+
+def ring_decode_attention(q, k, v, positions, *, window: int,
+                          sm_scale: Optional[float] = None):
+    """Single-token attention of a SLIDING-WINDOW layer against per-slot
+    ring buffers (ISSUE 30): position ``t`` of a slot lives at ring
+    index ``t % ring``, so the ring holds the last ``ring`` positions
+    whatever the context.
+
+    * ``q``: ``[b, h, d]`` — the token at ``positions[b]``, whose own
+      k/v row is already in the ring;
+    * ``k``/``v``: ``[b, kv_heads, ring, d]`` with ``ring >= window``;
+    * ``positions``: ``[b]`` int32.
+
+    Ring index ``r`` holds position ``p - ((p - r) mod ring)`` — the
+    newest one congruent to ``r`` that is not ahead of ``p``; it is
+    attended iff that position exists (``>= 0``) and lies inside the
+    window (``> p - window``).  Softmax does not care about the order of
+    its keys, so the ring is never unrolled.  Plain XLA: a matvec over
+    ``ring`` keys, the grouped-query einsum chain of
+    :func:`decode_attention`."""
+    b, h, d = q.shape
+    kvh, ring = k.shape[1], k.shape[2]
+    if k.shape != v.shape or k.shape[0] != b or k.shape[3] != d \
+            or h % kvh or ring < window:
+        raise ValueError(
+            f"k/v must be [b={b}, kv_heads | {h}, ring >= {window}, "
+            f"{d}] and equal-shaped; got k {tuple(k.shape)} v "
+            f"{tuple(v.shape)}")
+    scale = (d ** -0.5) if sm_scale is None else sm_scale
+    p = positions.astype(jnp.int32)[:, None]                  # [b, 1]
+    held = p - jnp.mod(p - jnp.arange(ring, dtype=jnp.int32)[None], ring)
+    live = ((held >= 0) & (held > p - window))[:, None, None, :]
+    qg = q.reshape(b, kvh, h // kvh, d)
+    s = jax.lax.dot_general(
+        qg, k, (((3,), (3,)), ((0, 1), (0, 1))),
+        preferred_element_type=jnp.float32) * scale   # [b, kvh, g, ring]
+    s = jnp.where(live, s, _NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    pr = jnp.exp(s - m)
+    pr = pr / jnp.sum(pr, axis=-1, keepdims=True)
+    out = jax.lax.dot_general(
+        pr.astype(v.dtype), v, (((3,), (2,)), ((0, 1), (0, 1))),
+        preferred_element_type=jnp.float32)           # [b, kvh, g, d]
+    return out.reshape(b, h, d).astype(q.dtype)
 
 
 def slab_decode_attention(q, win_k, win_v, lengths,

@@ -109,7 +109,9 @@ def paged_xla_max_pages(override=None) -> int:
 
 def _paged_kernel(scale, kvh, group, ps, mpps,
                   pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  s_scr, m_scr, l_scr, acc_scr):
+                  s_scr, m_scr, l_scr, acc_scr, pooled=False):
+    if pooled:      # blocks of the whole pool: [1, 1, kvh, ps, d]
+        k_ref, v_ref = k_ref.at[0], v_ref.at[0]
     sid = pl.program_id(0)
     p = pl.program_id(1)
     h = kvh * group
@@ -160,26 +162,31 @@ def _paged_kernel(scale, kvh, group, ps, mpps,
                     ).astype(o_ref.dtype)
 
 
-def _paged_kernel_call(q, k_pages, v_pages, page_table, lengths, scale):
+def _paged_kernel_call(q, k_pages, v_pages, page_table, lengths, scale,
+                       layer=None):
     slots, h, d = q.shape
-    _, kvh, ps, _ = k_pages.shape
+    kvh, ps = k_pages.shape[-3], k_pages.shape[-2]
     mpps = page_table.shape[1]
     group = h // kvh
+    pooled = layer is not None
 
     def page_index(s, p, pt, ln):
         # clamp dead trailing pages to the slot's last live page: an
         # unchanged block index lets Pallas skip the (useless) refetch,
         # and pl.when skips its compute entirely
         last = jnp.maximum((ln[s] + ps - 1) // ps - 1, 0)
-        return (pt[s, jnp.minimum(p, last)], 0, 0, 0)
+        page = pt[s, jnp.minimum(p, last)]
+        return (page, layer, 0, 0, 0) if pooled else (page, 0, 0, 0)
+
+    page_block = (1, 1, kvh, ps, d) if pooled else (1, kvh, ps, d)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(slots, mpps),
         in_specs=[
             pl.BlockSpec((1, h, d), lambda s, p, pt, ln: (s, 0, 0)),
-            pl.BlockSpec((1, kvh, ps, d), page_index),
-            pl.BlockSpec((1, kvh, ps, d), page_index),
+            pl.BlockSpec(page_block, page_index),
+            pl.BlockSpec(page_block, page_index),
         ],
         out_specs=pl.BlockSpec((1, h, d), lambda s, p, pt, ln: (s, 0, 0)),
         scratch_shapes=[
@@ -189,7 +196,8 @@ def _paged_kernel_call(q, k_pages, v_pages, page_table, lengths, scale):
             pltpu.VMEM((h, d), jnp.float32),      # fp32 output accum
         ],
     )
-    kernel = functools.partial(_paged_kernel, scale, kvh, group, ps, mpps)
+    kernel = functools.partial(_paged_kernel, scale, kvh, group, ps, mpps,
+                               pooled=pooled)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -208,7 +216,8 @@ def _paged_kernel_call(q, k_pages, v_pages, page_table, lengths, scale):
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                            sm_scale: Optional[float] = None,
                            use_kernel: Optional[bool] = None,
-                           xla_max_pages: Optional[int] = None):
+                           xla_max_pages: Optional[int] = None,
+                           layer: Optional[int] = None):
     """Single-token attention against a paged KV pool.
 
     * ``q``: ``[slots, h, 1, d]`` (or ``[slots, h, d]``) — the current
@@ -231,6 +240,12 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     (numerically identical to the dense cache's decode); above it the
     Pallas kernel streams the live pages via the page table with no
     materialized gather.
+
+    ``layer`` (static; ISSUE 30): ``k_pages``/``v_pages`` are then the
+    WHOLE pool ``[pages, layers, kv_heads, page_size, d]`` and the
+    kernel's blocks index the layer themselves — a layer's slice handed
+    to a custom call is first copied out of the pool, once a step.
+    Kernel path only.
     """
     squeezed = q.ndim == 3
     if squeezed:
@@ -240,6 +255,19 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         raise ValueError(
             f"paged_decode_attention is the q_len == 1 path, got q_len "
             f"{q_len}; use flash_attention for prefill")
+    if layer is not None:
+        if k_pages.shape != v_pages.shape or k_pages.ndim != 5 \
+                or k_pages.shape[4] != d or h % k_pages.shape[2] \
+                or not 0 <= layer < k_pages.shape[1]:
+            raise ValueError(
+                f"with layer={layer} k/v must be the pool [pages, "
+                f"layers, kv_heads | {h}, page_size, {d}]; got k "
+                f"{tuple(k_pages.shape)} v {tuple(v_pages.shape)}")
+        scale = (d ** -0.5) if sm_scale is None else sm_scale
+        out = _paged_kernel_call(
+            q[:, :, 0, :], k_pages, v_pages, page_table.astype(jnp.int32),
+            lengths.astype(jnp.int32), scale, layer=layer)
+        return out if squeezed else out[:, :, None, :]
     if k_pages.shape != v_pages.shape or k_pages.ndim != 4 \
             or k_pages.shape[3] != d:
         raise ValueError(

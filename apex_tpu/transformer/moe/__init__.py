@@ -17,6 +17,11 @@ where they expect them:
   the canonical TPU MoE formulation) around an ``all_to_all`` over the
   ``expert`` mesh axis.
 
+* :func:`~apex_tpu.transformer.moe.dropless.dropless_moe_ffn` — the
+  formulation serving needs (ISSUE 30): no capacity, no dropped token,
+  SwiGLU experts + a shared expert, grouped products
+  (``jax.lax.ragged_dot``) over the experts that received tokens.
+
 Everything is differentiable through plain jnp ops + ``lax.all_to_all``
 (whose transpose is the inverse resharding), so no custom VJPs are
 needed; ep=1 degrades to a single-host MoE with zero collectives.
@@ -24,8 +29,11 @@ needed; ep=1 degrades to a single-host MoE with zero collectives.
 from apex_tpu.transformer.moe.router import (TopKRouter,
                                              load_balancing_loss, sinkhorn)
 from apex_tpu.transformer.moe.experts import GroupedMLP
+from apex_tpu.transformer.moe.dropless import (dropless_moe_ffn,
+                                               route_top_k)
 from apex_tpu.transformer.moe.layer import (MoELayer, reduce_moe_grads,
                                             resolve_dispatch_mode)
 
 __all__ = ["TopKRouter", "GroupedMLP", "MoELayer", "load_balancing_loss",
-           "reduce_moe_grads", "resolve_dispatch_mode", "sinkhorn"]
+           "reduce_moe_grads", "resolve_dispatch_mode", "sinkhorn",
+           "dropless_moe_ffn", "route_top_k"]

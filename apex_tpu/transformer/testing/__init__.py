@@ -11,6 +11,8 @@ from .commons import IdentityLayer, initialize_distributed, set_random_seed
 from .standalone_gpt import GPTConfig, GPTModel, gpt_model_provider
 from .standalone_bert import BertConfig, BertModel, bert_model_provider
 from .standalone_llama import LlamaConfig, LlamaModel, llama_model_provider
+from .standalone_laguna import (LagunaConfig, LagunaModel,
+                                laguna_model_provider)
 from .batch_sampler import (
     MegatronPretrainingSampler,
     MegatronPretrainingRandomSampler,
@@ -29,6 +31,9 @@ __all__ = [
     "LlamaConfig",
     "LlamaModel",
     "llama_model_provider",
+    "LagunaConfig",
+    "LagunaModel",
+    "laguna_model_provider",
     "MegatronPretrainingSampler",
     "MegatronPretrainingRandomSampler",
 ]

@@ -1,0 +1,237 @@
+"""The ``laguna`` kind (ISSUE 30) against the benchmark's plain reference,
+``benchmark/references/laguna_lm.py`` — the same file the chip runs judge
+the served tokens with.  Tiny sizes, seeded float32 weights.
+
+Tolerance: both sides compute in float32 on the CPU (the Pallas kernels in
+interpret mode, the reference at ``Precision.HIGHEST``); what differs is the
+order of accumulation (blockwise online softmax, grouped products over
+sorted rows, RMSNorm through the fused kernel) — a few 1e-6 of the largest
+logit a layer.  ``TOL`` = 2e-4 of the largest reference logit holds that with
+room; one window position more or less, or an unrotated channel, moves the
+logits by percents (checked below), so the tolerance has teeth.
+"""
+import math
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = Path(__file__).resolve().parents[3]
+if str(REPO) not in sys.path:
+    sys.path.insert(0, str(REPO))
+
+from apex_tpu.inference import InferenceEngine, SamplingConfig  # noqa: E402
+from apex_tpu.inference import models  # noqa: E402
+from apex_tpu.transformer.testing import standalone_laguna as SL  # noqa: E402
+from benchmark.bindings import moe_laguna as binding  # noqa: E402
+from benchmark.references import laguna_lm  # noqa: E402
+
+TOL = 2e-4
+PAD = laguna_lm.ROW_BLOCK
+
+#: a configuration file in the published keys, at toy sizes: heads per
+#: layer that differ (4 / 6 over 2 KV heads), window 8 under page 4 (a ring
+#: of 12 rows), a dense layer then expert layers, full / sliding / sliding /
+#: full
+TINY = {
+    "vocab_size": 96, "hidden_size": 32, "intermediate_size": 64,
+    "num_hidden_layers": 4, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "head_dim": 16,
+    "max_position_embeddings": 128, "attention_bias": False,
+    "rms_norm_eps": 1e-6, "num_experts": 8, "num_experts_per_tok": 2,
+    "moe_intermediate_size": 16, "shared_expert_intermediate_size": 16,
+    "tie_word_embeddings": False, "gating": True, "sliding_window": 8,
+    "rope_parameters": {
+        "full_attention": {
+            "rope_theta": 500000, "rope_type": "yarn", "factor": 4,
+            "original_max_position_embeddings": 32, "beta_slow": 1,
+            "beta_fast": 4, "attention_factor": 1.1386294361119891,
+            "partial_rotary_factor": 0.5},
+        "sliding_attention": {"rope_type": "default", "rope_theta": 10000,
+                              "partial_rotary_factor": 1}},
+    "layer_types": ["full_attention", "sliding_attention",
+                    "sliding_attention", "full_attention"],
+    "moe_apply_router_weight_on_input": False,
+    "mlp_layer_types": ["dense", "sparse", "sparse", "sparse"],
+    "moe_routed_scaling_factor": 2.5,
+    "num_attention_heads_per_layer": [4, 6, 6, 4],
+}
+
+
+def seeded(shapes, seed, std=0.2):
+    """float32 weights large enough that positions and the window decide
+    tokens (at 0.02 attention is all but uniform); norm gains 1 + noise."""
+    leaves, treedef = jax.tree_util.tree_flatten(shapes)
+    key = jax.random.PRNGKey(seed)
+    out = []
+    for n, leaf in enumerate(leaves):
+        x = std * jax.random.normal(jax.random.fold_in(key, n), leaf.shape,
+                                    jnp.float32)
+        out.append(1.0 + 0.1 * x if leaf.ndim == 1 else x)
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    import dataclasses
+    lcfg, shapes = binding.model_of(TINY)
+    lcfg = dataclasses.replace(lcfg, params_dtype=jnp.float32)
+    params = seeded(shapes, 5)
+    return lcfg, params, binding.reference_weights(TINY, params)
+
+
+def reference(w, tokens, spec=None):
+    """The reference's logits of every real position of ``tokens``."""
+    padded = np.zeros((PAD,), np.int32)
+    padded[:len(tokens)] = tokens
+    return np.asarray(laguna_lm.logits(
+        w, jnp.asarray(padded), 0, len(tokens),
+        spec=spec or laguna_lm.spec_from_config(TINY)))
+
+
+def test_full_forward_and_prefill_match_the_reference(tiny):
+    lcfg, params, w = tiny
+    tokens = np.random.RandomState(1).randint(0, 96, size=40)
+    want = reference(w, tokens)
+    scale = np.abs(want).max()
+    model = SL.laguna_model_provider(lcfg)
+    got = np.asarray(jax.jit(model.apply)(params,
+                                            jnp.asarray(tokens[None])))[0]
+    assert np.abs(got - want).max() < TOL * scale
+    pre = models.prefill_forward("laguna", lcfg, params,
+                                 jnp.asarray(tokens[None], jnp.int32))
+    assert np.abs(np.asarray(pre[0])[:, 0] - want).max() < TOL * scale
+    # the pool's layers and the rings' layers, by layer type
+    assert pre[1].shape == (2, 2, 40, 16) and pre[3].shape == (2, 2, 40, 16)
+
+
+def test_the_tolerance_has_teeth(tiny):
+    """One window position more, or plain frequencies where YaRN's belong,
+    moves the reference by far more than ``TOL``."""
+    _, _, w = tiny
+    tokens = np.random.RandomState(2).randint(0, 96, size=40)
+    spec = laguna_lm.spec_from_config(TINY)
+    want = reference(w, tokens)
+    scale = np.abs(want).max()
+    wider = reference(w, tokens, spec._replace(window=spec.window + 1))
+    plain = reference(w, tokens, spec._replace(yarn_factor=1.0))
+    assert np.abs(wider - want).max() > 50 * TOL * scale
+    assert np.abs(plain - want).max() > 50 * TOL * scale
+
+
+def test_prefill_then_decode_through_both_pools(tiny):
+    """Three slots at unequal lengths — one prompt shorter than the window,
+    one longer than window and ring — then 48 decode steps: the ring of 12
+    rows wraps four times.  Every step's logits against the reference's
+    full forward over prompt + generated tokens."""
+    lcfg, params, w = tiny
+    eng = InferenceEngine("laguna", lcfg, params, slots=3, max_seq=128,
+                          page_size=4, num_pages=90,
+                          cache_dtype=jnp.float32,
+                          sampling=SamplingConfig())
+    assert eng.stats_tail == 4 and not eng.supports_prefix_sharing
+    alloc = eng.new_allocator()
+    cache = eng.init_cache()
+    # two pools in one cache: 2 full layers paged, 2 window layers ringed
+    assert cache.k.shape == (91, 2, 2, 4, 16)
+    assert cache.wk.shape == (2, 3, 2, 12, 16) and cache.ring == 12
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(0, 96, size=n) for n in (5, 31, 14)]
+    steps = 48
+    seqs, last = [], np.zeros((3,), np.int32)
+    for slot, p in enumerate(prompts):
+        pages = alloc.acquire(alloc.pages_needed(len(p) + steps + 1))
+        cache, tok, logits = eng.prefill(cache, p, slot, pages=pages)
+        tok = np.asarray(tok)
+        assert tok.shape == (1 + 4,)              # the token, the counters
+        want = reference(w, p)[-1]
+        assert np.abs(np.asarray(logits) - want).max() \
+            < TOL * np.abs(want).max()
+        seqs.append(list(p) + [int(tok[0])])
+        last[slot] = tok[0]
+    for _ in range(steps):
+        cache, toks, logits, truncated = eng.decode(cache, last)
+        toks = np.asarray(toks)
+        assert toks.shape == (3 + 4,) and not np.asarray(truncated).any()
+        for slot in range(3):
+            seqs[slot].append(int(toks[slot]))
+        last = toks[:3].copy()
+        step_logits = np.asarray(logits)
+    # the last step's logits of every slot, and each slot's whole greedy
+    # stream, against one reference pass per slot
+    for slot, p in enumerate(prompts):
+        seq = np.asarray(seqs[slot][:-1])
+        want = reference(w, seq)
+        scale = np.abs(want).max()
+        assert np.abs(step_logits[slot] - want[-1]).max() < TOL * scale
+        greedy = want[len(p) - 1:].argmax(-1)
+        assert list(greedy) == seqs[slot][len(p):]
+    # the counters rode the token read: assignments = live tokens x top_k
+    # x expert layers; window pages never above slots x ring pages
+    tail = toks[3:]
+    assert tail[0] == 3 * 2 * 3 and 1 <= tail[1] <= 3 * 8
+    assert tail[2] <= 3 and tail[3] == 3 * 3
+
+
+def test_yarn_table_against_hand_computed_values():
+    """Published parameters: base 5e5 over 64 rotated channels, factor 64,
+    original 4096, beta_fast 64, beta_slow 1.  ``c(r) = 64 ln(4096 / (2 pi
+    r)) / (2 ln 5e5)``: c(64) = 5.66 -> low 5; c(1) = 15.80 -> high 16."""
+    rope = SL.YarnRope()
+    inv = SL.yarn_inv_freq(rope)
+    assert len(inv) == 32
+    c64 = 64 * math.log(4096 / (2 * math.pi * 64)) / (2 * math.log(5e5))
+    c1 = 64 * math.log(4096 / (2 * math.pi)) / (2 * math.log(5e5))
+    assert math.floor(c64) == 5 and math.ceil(c1) == 16
+    for i in (0, 5):                      # at or under low: plain RoPE
+        assert inv[i] == pytest.approx(5e5 ** (-2 * i / 64), rel=1e-12)
+    for i in (16, 31):                    # at or over high: divided by 64
+        assert inv[i] == pytest.approx(5e5 ** (-2 * i / 64) / 64, rel=1e-12)
+    # half way up the ramp, by hand: i = 10 -> ramp 5/11
+    extrap = 5e5 ** (-20 / 64)
+    assert inv[10] == pytest.approx(
+        extrap / 64 * (5 / 11) + extrap * (6 / 11), rel=1e-12)
+    # the reference file computes the same table from the published keys
+    import json
+    cfg = json.loads((REPO / "benchmark" / "configs"
+                      / "laguna-xs.2-serve.json").read_text())
+    spec = laguna_lm.spec_from_config(cfg)
+    assert laguna_lm.yarn_inv_freq(spec) == pytest.approx(inv, rel=1e-12)
+    assert spec.attention_factor == 1.4158883083359672
+    # cos / sin carry the attention factor; sliding layers rotate the head
+    lcfg = binding._program_config(cfg)
+    cos, _ = SL.rope_cos_sin(lcfg, SL.FULL, jnp.zeros((1,), jnp.int32))
+    assert cos.shape == (1, 64)
+    assert float(cos[0, 0]) == pytest.approx(1.4158883083359672, rel=1e-6)
+    cos, _ = SL.rope_cos_sin(lcfg, SL.SLIDING, jnp.arange(2))
+    assert cos.shape == (2, 128) and float(cos[0, 0]) == 1.0
+
+
+def test_what_is_not_built_for_the_kind_is_refused_with_its_reason(tiny):
+    from apex_tpu.inference import SlotScheduler
+    lcfg, params, _ = tiny
+    kw = dict(slots=2, max_seq=64, page_size=4, num_pages=40)
+    for bad, why in (
+            (dict(tp=2), "tp > 1 is not built"),
+            (dict(spec_k=2), "speculative verify is not built"),
+            (dict(host_tier_bytes=1 << 20), "host KV tier is not built"),
+            (dict(decode_fusion="1"), "fused_block_decode is not built")):
+        with pytest.raises(ValueError, match=why):
+            InferenceEngine("laguna", lcfg, params, **kw, **bad)
+    with pytest.raises(ValueError, match="paged cache only"):
+        InferenceEngine("laguna", lcfg, params, slots=2, max_seq=64)
+    eng = InferenceEngine("laguna", lcfg, params, **kw)
+    with pytest.raises(ValueError, match="prefix sharing is not built"):
+        SlotScheduler(eng, prefix_cache=True)
+    with pytest.raises(ValueError, match="chunked prefill is not built"):
+        SlotScheduler(eng, prefill_chunk=8)
+    assert SlotScheduler(eng).prefix is None
+    with pytest.raises(ValueError, match="prefills a prompt whole"):
+        eng.prefill(eng.init_cache(), list(range(9)), 0,
+                    pages=[0, 1, 2], prefill_from=4)
+    with pytest.raises(ValueError, match="speculative verify is not built"):
+        models.verify_forward("laguna", lcfg, params, None,
+                              jnp.zeros((2, 3), jnp.int32))
