@@ -322,16 +322,6 @@ KNOBS: Dict[str, EnvKnob] = {k.name: k for k in [
                "fleet bench captures as fleet_policy",
         read_by="apex_tpu/fleet/router.py"),
     EnvKnob(
-        name="APEX_TPU_PAGED_XLA_MAX_PAGES",
-        default="64",
-        effect="paged_decode_attention gathers slot windows through "
-               "the XLA einsum chain at or below this many pages per "
-               "slot and streams pages with the Pallas kernel above "
-               "it (PROVISIONAL crossover, stamped into paged infer "
-               "bench captures); per-call override: "
-               "paged_decode_attention(xla_max_pages=...)",
-        read_by="apex_tpu/ops/paged_attention.py"),
-    EnvKnob(
         name="APEX_TPU_PROTOCOL_SCOPE",
         default="0",
         effect="comma-separated scope names `apex-tpu-analyze "

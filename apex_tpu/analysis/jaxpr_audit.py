@@ -194,9 +194,8 @@ def _builders():
         would be 4 x 256 = 1024 cached tokens dense, but the pool holds
         only 20 pages x 16 = 320 (mean_seq << max_seq sizing) — the
         geometry the APX215 peak-live comparison test measures the
-        paged win on.  attn_max_pages=0 pins the Pallas kernel path so
-        the registered executable is the one with NO materialized
-        gather window."""
+        paged win on: decode reads the pool through the page table
+        with NO materialized gather window."""
         import flax  # noqa: F401 — optional dep; ImportError skips
         from apex_tpu.inference import kv_cache
         from apex_tpu.inference.sampling import SamplingConfig
@@ -217,7 +216,6 @@ def _builders():
                 20, cfg.num_layers, cfg.num_attention_heads, 16,
                 64 // cfg.num_attention_heads, slots=4,
                 max_pages_per_slot=16))
-        cache = cache.replace(attn_max_pages=0)
         key = s((2,), jnp.uint32)
         return cfg, SamplingConfig(), params, cache, key
 

@@ -175,8 +175,8 @@ class KernelOpSpec:
 
 def _builders():
     """Lazy fixtures (importing this module stays jax-free).  Every
-    fixture pins the PALLAS path explicitly (``xla_max_seq=0`` /
-    ``xla_max_pages=0``) — the auditor prices kernels, not the XLA
+    fixture with an XLA twin pins the PALLAS path explicitly
+    (``xla_max_seq=0``) — the auditor prices kernels, not the XLA
     twins the crossover knobs would otherwise dispatch these tiny
     shapes to.  Norm/attention ops trace fwd+bwd via ``jax.vjp`` so
     the backward kernels (the wgrad accumulators) are covered."""
@@ -223,10 +223,9 @@ def _builders():
 
     def paged_decode_attention():
         from apex_tpu.ops import paged_decode_attention as op
-        pages = s((9, 4, 16, 64), bf16)
-        return (lambda q, kp, vp, pt, n: op(q, kp, vp, pt, n,
-                                            xla_max_pages=0),
-                (s((2, 4, 64), bf16), pages, pages,
+        pool = s((9, 2, 4, 16, 64), bf16)
+        return (lambda q, kp, vp, pt, n: op(q, kp, vp, pt, n, layer=1),
+                (s((2, 4, 64), bf16), pool, pool,
                  s((2, 4), jnp.int32), s((2,), jnp.int32)))
 
     def fused_block_decode():

@@ -19,7 +19,9 @@ Workload split (the flash-attention/Megatron serving shape):
 Cache layouts (ISSUE 6): the dense slot cache provisions ``max_seq``
 per slot; ``page_size=``/``num_pages=`` switch to the ragged paged
 pool — k/v in fixed-size pages threaded through a traced per-slot page
-table (``paged_decode_attention`` per layer), the host-side
+table (``paged_decode_attention`` per layer: the ``apex_paged_decode``
+kernel reading the live pages straight from the whole pool, whatever
+the kind or window), the host-side
 ``PageAllocator`` handing out reservations.  Same two executables,
 same donation discipline; only the memory model (and the scheduler's
 admission unit — pages, not slots) changes.
@@ -364,7 +366,6 @@ class InferenceEngine:
                  seed: int = 0, paged: bool = False,
                  page_size: Optional[int] = None,
                  num_pages: Optional[int] = None,
-                 paged_attn_max_pages: Optional[int] = None,
                  decode_fusion=None, fusion_min_pages=None,
                  spec_k: Optional[int] = None,
                  tp: Optional[int] = None,
@@ -416,7 +417,6 @@ class InferenceEngine:
             if self.num_pages < 1:
                 raise ValueError(
                     f"num_pages must be >= 1, got {self.num_pages}")
-            self.paged_attn_max_pages = paged_attn_max_pages
             # host-DRAM page tier (ISSUE 18): explicit kwargs win, else
             # the registered env knobs; 0 bytes = tier off (eviction
             # discards, the pre-tier behavior)
@@ -441,7 +441,6 @@ class InferenceEngine:
                     "this engine runs the dense slot cache")
             self.page_size = self.num_pages = None
             self.max_pages_per_slot = None
-            self.paged_attn_max_pages = None
             self.host_tier_bytes = 0
             self.swap_batch_pages = None
         # tensor-parallel serving width (ISSUE 17): explicit kwarg wins,
@@ -677,8 +676,7 @@ class InferenceEngine:
         # page table / lengths / capacity replicated, k/v pool sharded
         # over the kv-head dim — the host-side allocator, prefix cache,
         # COW, and eviction logic never see the shard boundary
-        self._cache_specs = kv_cache.paged_cache_partition_specs(
-            attn_max_pages=self.paged_attn_max_pages)
+        self._cache_specs = kv_cache.paged_cache_partition_specs()
         self._key = jax.device_put(
             self._key, NamedSharding(mesh, PartitionSpec()))
 
@@ -700,7 +698,6 @@ class InferenceEngine:
                     self.page_size, d["head_dim"], slots=self.slots,
                     max_pages_per_slot=self.max_pages_per_slot,
                     dtype=self.cache_dtype,
-                    attn_max_pages=self.paged_attn_max_pages,
                     window_layers=d["window_layers"], window=d["window"])
 
             if self.tp == 1:

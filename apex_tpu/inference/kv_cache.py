@@ -384,10 +384,9 @@ class PagedKVCache:
     :func:`advance` enforces (the dense cache's ``max_seq``, made
     per-slot).
 
-    ``attn_max_pages`` is STATIC aux data (not a leaf): the engine's
-    kernel/XLA crossover override for
-    :func:`~apex_tpu.ops.paged_attention.paged_decode_attention`
-    (None = the env/default dispatch).
+    A decode step hands ``k``/``v`` WHOLE to
+    :func:`~apex_tpu.ops.paged_attention.paged_decode_attention`, which
+    reads the slot's live pages of one layer straight from the pool.
 
     Tensor-parallel serving (ISSUE 17) shards ONLY the ``k``/``v``
     pool, over the kv-head dim (``kv_heads/tp`` heads per rank — see
@@ -404,8 +403,6 @@ class PagedKVCache:
     page_table: jax.Array  # [slots, max_pages_per_slot] int32
     lengths: jax.Array     # [slots] int32: live tokens per slot
     capacity: jax.Array    # [slots] int32: page_size * owned pages
-    attn_max_pages: Optional[int] = flax.struct.field(
-        pytree_node=False, default=None)
     # the window layers' rings (ISSUE 30), None without window layers:
     # [window_layers, slots, kv_heads, ring, head_dim] (layer-major: a
     # decode step rewrites one layer at a time), ring a whole number of
@@ -466,7 +463,6 @@ class PagedKVCache:
 def init_paged_cache(pages: int, layers: int, kv_heads: int,
                      page_size: int, head_dim: int, *, slots: int,
                      max_pages_per_slot: int, dtype=jnp.bfloat16,
-                     attn_max_pages: Optional[int] = None,
                      window_layers: int = 0,
                      window: int = 0) -> PagedKVCache:
     """Allocate an empty pool: ``pages`` allocatable pages (+1 trash
@@ -493,8 +489,7 @@ def init_paged_cache(pages: int, layers: int, kv_heads: int,
         page_table=jnp.full((slots, max_pages_per_slot), pages,
                             jnp.int32),
         lengths=jnp.zeros((slots,), jnp.int32),
-        capacity=jnp.zeros((slots,), jnp.int32),
-        attn_max_pages=attn_max_pages, **rings)
+        capacity=jnp.zeros((slots,), jnp.int32), **rings)
 
 
 def ring_rows(window: int, page_size: int) -> int:
@@ -504,8 +499,7 @@ def ring_rows(window: int, page_size: int) -> int:
     return (-(-window // page_size) + 1) * page_size
 
 
-def paged_cache_partition_specs(attn_max_pages: Optional[int] = None,
-                                axis: str = TENSOR_AXIS) -> PagedKVCache:
+def paged_cache_partition_specs(axis: str = TENSOR_AXIS) -> PagedKVCache:
     """The pool's ``PartitionSpec`` tree for tensor-parallel serving:
     ``k``/``v`` ``[pages+1, layers, kv_heads/tp, page_size, head_dim]``
     sharded over the kv-head dim, page table / lengths / capacity
@@ -513,12 +507,11 @@ def paged_cache_partition_specs(attn_max_pages: Optional[int] = None,
     paged-attention layout argument), and page IDs mean the same thing
     on every rank.  Doubles as the engine's ``shard_map`` in/out spec
     for the cache operand and as the ``NamedSharding`` source for the
-    one-time ``device_put``; ``attn_max_pages`` must match the cache it
-    will describe (aux data participates in pytree equality)."""
+    one-time ``device_put``."""
     from jax.sharding import PartitionSpec as P
     kv = P(None, None, axis, None, None)
     return PagedKVCache(k=kv, v=kv, page_table=P(), lengths=P(),
-                        capacity=P(), attn_max_pages=attn_max_pages)
+                        capacity=P())
 
 
 def page_row(page_ids: Sequence[int], max_pages_per_slot: int,

@@ -280,13 +280,12 @@ def _cache_attend(cache, layer: int, q, live):
     """Single-token attention against ONE layer of whichever cache
     layout the engine runs: the dense slot window
     (:func:`~apex_tpu.ops.attention.decode_attention`) or the paged
-    pool threaded through the slot page table
-    (:func:`~apex_tpu.ops.paged_attention.paged_decode_attention`).
+    pool, handed to the kernel WHOLE and threaded through the slot page
+    table (:func:`~apex_tpu.ops.paged_attention.paged_decode_attention`).
     Both score the pre-broadcast per-kv-head cache (GQA/MQA grouped)."""
     if isinstance(cache, kv_cache.PagedKVCache):
-        return paged_decode_attention(
-            q, cache.k[:, layer], cache.v[:, layer], cache.page_table,
-            live, xla_max_pages=cache.attn_max_pages)
+        return paged_decode_attention(q, cache.k, cache.v,
+                                      cache.page_table, live, layer=layer)
     return decode_attention(q, cache.k[:, layer], cache.v[:, layer], live)
 
 

@@ -6,40 +6,33 @@ query per sequence slot scores the slot's live tokens, but the tokens
 live in fixed-size PAGES of a shared pool rather than a contiguous
 per-slot ``max_seq`` window —
 
-    k_pages, v_pages : [pages, kv_heads, page_size, head_dim]
-    page_table       : [slots, max_pages_per_slot]  int32
-    lengths          : [slots]                      int32
+    k_pool, v_pool : [pages, layers, kv_heads, page_size, head_dim]
+    page_table     : [slots, max_pages_per_slot]  int32
+    lengths        : [slots]                      int32
 
 virtual position ``t`` of a slot resolves to physical page
 ``page_table[slot, t // page_size]``, row ``t % page_size``.
 
-Two implementations behind one crossover knob, mirroring the dense
-kernel/XLA machinery in ``attention.py``:
-
-* **Pallas kernel** (long virtual windows): grid ``(slots, pages)``
-  with the page table and lengths as SCALAR-PREFETCH operands — the
-  k/v BlockSpec index map reads ``page_table[slot, page]`` so Pallas
-  DMAs exactly that slot's live pages from HBM, page by page, with its
-  standard double buffering; nothing resembling the gathered
-  ``[slots, max_seq]`` window ever materializes.  Online softmax (fp32
-  running max/normalizer/accumulator in VMEM scratch, base-2 log
-  domain like the flash kernels) carries across the page loop; dead
-  pages are skipped (``pl.when``) and their DMA is deduplicated by
-  clamping the index map to the slot's last live page (Pallas skips
-  refetching an unchanged block index).  Dead rows inside the last
-  live page mask to ``_NEG_INF``.
-
-* **XLA gather fallback** (short windows): gather the slot's pages
-  into the dense ``[slots, kv_heads, max_seq, d]`` window and reuse
-  ``decode_attention``'s grouped-query einsum chain — at small
-  ``max_pages_per_slot`` the gather transient is cheap and XLA's fused
-  matvec wins for the same reason the dense crossover exists.  The
-  gathered window equals the dense cache's view position for position,
-  so this path is numerically IDENTICAL to the dense XLA decode path.
+ONE implementation, for every kind and window size: the Pallas kernel
+``apex_paged_decode`` on the WHOLE pool.  Grid ``(slots, pages)`` with
+the page table, lengths and layer as SCALAR-PREFETCH operands — the k/v
+BlockSpec index map reads ``(page_table[slot, page], layer)`` so Pallas
+DMAs exactly that slot's live pages of that layer from HBM, page by
+page, with its standard double buffering; neither a per-layer slice of
+the pool nor the gathered ``[slots, max_seq]`` window ever
+materializes.  Online softmax (fp32 running max/normalizer/accumulator
+in VMEM scratch, base-2 log domain like the flash kernels) carries
+across the page loop; dead pages are skipped (``pl.when``) and their
+DMA is deduplicated by clamping the index map to the slot's last live
+page (Pallas skips refetching an unchanged block index).  Dead rows
+inside the last live page mask to ``_NEG_INF``.
 
 GQA/MQA: ``kv_heads`` divides the query heads; the kernel loops kv
 heads (static, small) scoring each head's ``group`` query rows against
 the once-per-kv-head page — no broadcast materialized anywhere.
+
+The speculative verify slab (:func:`paged_slab_attention`) still
+gathers the slot windows and scores them with the dense XLA chain.
 """
 from __future__ import annotations
 
@@ -52,13 +45,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex_tpu.ops.attention import (_LOG2E, _NEG_INF, decode_attention,
+from apex_tpu.ops.attention import (_LOG2E, _NEG_INF,
                                     slab_decode_attention)
 from apex_tpu.utils import interpret_mode
 
-__all__ = ["paged_decode_attention", "paged_xla_max_pages",
-           "paged_slab_attention", "fused_block_decode", "decode_fusion",
-           "fusion_min_pages", "resolve_decode_fusion",
+__all__ = ["paged_decode_attention", "paged_slab_attention",
+           "fused_block_decode", "decode_fusion", "fusion_min_pages",
+           "resolve_decode_fusion",
            "fused_block_vmem_bytes", "fused_block_refusal",
            "FUSED_BLOCK_VMEM_LIMIT"]
 
@@ -72,46 +65,16 @@ PALLAS_AUDIT = {
     "_fused_block_kernel": {"reduction": True, "masked_tail": True},
 }
 
-#: paged kernel/XLA crossover, in PAGES per slot (the paged analog of
-#: ``_DECODE_XLA_MAX_SEQ``; ~4096 tokens at the default page size 64).
-#: Below it the XLA gather fallback materializes the slot windows —
-#: fine while they are small; above it the Pallas kernel streams pages
-#: straight from the pool.  PROVISIONAL like the dense decode crossover
-#: was at introduction: override per-run with the environment variable
-#: ``APEX_TPU_PAGED_XLA_MAX_PAGES`` or per-call with ``xla_max_pages=``
-#: (0 forces the kernel path); bench infer captures stamp the
-#: effective value so on-chip sweeps can refine it without a code edit.
-_PAGED_XLA_MAX_PAGES = 64
-
-_PAGED_XLA_MAX_PAGES_ENV = "APEX_TPU_PAGED_XLA_MAX_PAGES"
-
-
-def paged_xla_max_pages(override=None) -> int:
-    """Effective paged-decode crossover: explicit kwarg override >
-    ``APEX_TPU_PAGED_XLA_MAX_PAGES`` env var > the provisional
-    default."""
-    if override is not None:
-        return int(override)
-    env = os.environ.get(_PAGED_XLA_MAX_PAGES_ENV)
-    if env:
-        try:
-            return int(env)
-        except ValueError as e:
-            raise ValueError(
-                f"{_PAGED_XLA_MAX_PAGES_ENV} must be an int, got "
-                f"{env!r}") from e
-    return _PAGED_XLA_MAX_PAGES
-
 
 # --------------------------------------------------------------------------
 # Pallas kernel: grid (slots, pages), page table as scalar prefetch
 # --------------------------------------------------------------------------
 
 def _paged_kernel(scale, kvh, group, ps, mpps,
-                  pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  s_scr, m_scr, l_scr, acc_scr, pooled=False):
-    if pooled:      # blocks of the whole pool: [1, 1, kvh, ps, d]
-        k_ref, v_ref = k_ref.at[0], v_ref.at[0]
+                  pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
+                  s_scr, m_scr, l_scr, acc_scr):
+    # blocks of the whole pool: [1, 1, kvh, ps, d]
+    k_ref, v_ref = k_ref.at[0], v_ref.at[0]
     sid = pl.program_id(0)
     p = pl.program_id(1)
     h = kvh * group
@@ -162,33 +125,38 @@ def _paged_kernel(scale, kvh, group, ps, mpps,
                     ).astype(o_ref.dtype)
 
 
-def _paged_kernel_call(q, k_pages, v_pages, page_table, lengths, scale,
-                       layer=None):
+@functools.partial(jax.jit, static_argnames=("scale",))
+def _paged_kernel_call(q, k_pool, v_pool, page_table, lengths, layer, *,
+                       scale):
+    # ``layer`` is a TRACED int32 [1], the third scalar-prefetch operand:
+    # every layer of a decode step is then the same jitted call, traced
+    # and lowered to Mosaic ONCE (a static layer in the index map makes
+    # each layer a kernel of its own: 24 lowerings, seconds of every
+    # process's start, compile cache or not)
     slots, h, d = q.shape
-    kvh, ps = k_pages.shape[-3], k_pages.shape[-2]
+    kvh, ps = k_pool.shape[2], k_pool.shape[3]
     mpps = page_table.shape[1]
     group = h // kvh
-    pooled = layer is not None
 
-    def page_index(s, p, pt, ln):
+    def page_index(s, p, pt, ln, ly):
         # clamp dead trailing pages to the slot's last live page: an
         # unchanged block index lets Pallas skip the (useless) refetch,
         # and pl.when skips its compute entirely
         last = jnp.maximum((ln[s] + ps - 1) // ps - 1, 0)
-        page = pt[s, jnp.minimum(p, last)]
-        return (page, layer, 0, 0, 0) if pooled else (page, 0, 0, 0)
+        return (pt[s, jnp.minimum(p, last)], ly[0], 0, 0, 0)
 
-    page_block = (1, 1, kvh, ps, d) if pooled else (1, kvh, ps, d)
+    page_block = (1, 1, kvh, ps, d)
+    slot_block = pl.BlockSpec((1, h, d), lambda s, p, pt, ln, ly: (s, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(slots, mpps),
         in_specs=[
-            pl.BlockSpec((1, h, d), lambda s, p, pt, ln: (s, 0, 0)),
+            slot_block,
             pl.BlockSpec(page_block, page_index),
             pl.BlockSpec(page_block, page_index),
         ],
-        out_specs=pl.BlockSpec((1, h, d), lambda s, p, pt, ln: (s, 0, 0)),
+        out_specs=slot_block,
         scratch_shapes=[
             pltpu.VMEM((h, ps), jnp.float32),     # score block
             pltpu.VMEM((h, 1), jnp.float32),      # running max (base 2)
@@ -196,8 +164,7 @@ def _paged_kernel_call(q, k_pages, v_pages, page_table, lengths, scale,
             pltpu.VMEM((h, d), jnp.float32),      # fp32 output accum
         ],
     )
-    kernel = functools.partial(_paged_kernel, scale, kvh, group, ps, mpps,
-                               pooled=pooled)
+    kernel = functools.partial(_paged_kernel, scale, kvh, group, ps, mpps)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -206,24 +173,25 @@ def _paged_kernel_call(q, k_pages, v_pages, page_table, lengths, scale,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret_mode(),
         name="apex_paged_decode",
-    )(page_table, lengths, q, k_pages, v_pages)
+    )(page_table, lengths, layer, q, k_pool, v_pool)
 
 
 # --------------------------------------------------------------------------
 # public entry
 # --------------------------------------------------------------------------
 
-def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
-                           sm_scale: Optional[float] = None,
-                           use_kernel: Optional[bool] = None,
-                           xla_max_pages: Optional[int] = None,
-                           layer: Optional[int] = None):
-    """Single-token attention against a paged KV pool.
+def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
+                           layer: int, sm_scale: Optional[float] = None):
+    """Single-token attention against ONE layer of a paged KV pool.
 
     * ``q``: ``[slots, h, 1, d]`` (or ``[slots, h, d]``) — the current
       token's query heads per slot.
-    * ``k_pages``/``v_pages``: ``[pages, kv_heads, page_size, d]`` —
-      ONE layer's slice of the pool, ``kv_heads`` dividing ``h``.
+    * ``k_pool``/``v_pool``: the WHOLE pool ``[pages, layers, kv_heads,
+      page_size, d]``, ``kv_heads`` dividing ``h``.  ``layer`` (a
+      python int) picks the layer INSIDE the kernel's block specs: a
+      layer's slice handed to a custom call would first be copied out
+      of the pool, once a step.  It reaches the kernel as a scalar-
+      prefetch operand, so all layers of a step share one trace.
     * ``page_table``: ``[slots, max_pages_per_slot]`` int32 — physical
       page backing each ``page_size`` stretch of the slot's virtual
       window; dead entries may hold any valid page index (they are
@@ -232,20 +200,10 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     * ``lengths``: ``[slots]`` int32 — live tokens per slot; a slot
       with length 0 emits zeros.
 
-    ``use_kernel=None`` auto-dispatches on ``max_pages_per_slot``: at
-    or under the crossover (``xla_max_pages`` kwarg >
-    ``APEX_TPU_PAGED_XLA_MAX_PAGES`` env var > the provisional default
-    ``_PAGED_XLA_MAX_PAGES``) the pages are gathered into dense slot
-    windows and scored by ``decode_attention``'s XLA einsum chain
-    (numerically identical to the dense cache's decode); above it the
-    Pallas kernel streams the live pages via the page table with no
-    materialized gather.
-
-    ``layer`` (static; ISSUE 30): ``k_pages``/``v_pages`` are then the
-    WHOLE pool ``[pages, layers, kv_heads, page_size, d]`` and the
-    kernel's blocks index the layer themselves — a layer's slice handed
-    to a custom call is first copied out of the pool, once a step.
-    Kernel path only.
+    Always the Pallas kernel (interpret mode off-TPU), whatever the
+    window: it streams the live pages via the page table with no
+    materialized gather, scoring bf16 operands with fp32 accumulation
+    and an fp32 online softmax.
     """
     squeezed = q.ndim == 3
     if squeezed:
@@ -255,26 +213,16 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         raise ValueError(
             f"paged_decode_attention is the q_len == 1 path, got q_len "
             f"{q_len}; use flash_attention for prefill")
-    if layer is not None:
-        if k_pages.shape != v_pages.shape or k_pages.ndim != 5 \
-                or k_pages.shape[4] != d or h % k_pages.shape[2] \
-                or not 0 <= layer < k_pages.shape[1]:
-            raise ValueError(
-                f"with layer={layer} k/v must be the pool [pages, "
-                f"layers, kv_heads | {h}, page_size, {d}]; got k "
-                f"{tuple(k_pages.shape)} v {tuple(v_pages.shape)}")
-        scale = (d ** -0.5) if sm_scale is None else sm_scale
-        out = _paged_kernel_call(
-            q[:, :, 0, :], k_pages, v_pages, page_table.astype(jnp.int32),
-            lengths.astype(jnp.int32), scale, layer=layer)
-        return out if squeezed else out[:, :, None, :]
-    if k_pages.shape != v_pages.shape or k_pages.ndim != 4 \
-            or k_pages.shape[3] != d:
+    if k_pool.shape != v_pool.shape or k_pool.ndim != 5 \
+            or k_pool.shape[4] != d:
         raise ValueError(
-            f"k/v pages must be [pages, kv_heads, page_size, {d}] and "
-            f"equal-shaped; got k {tuple(k_pages.shape)} v "
-            f"{tuple(v_pages.shape)}")
-    kvh = k_pages.shape[1]
+            f"k/v must be the whole pool [pages, layers, kv_heads, "
+            f"page_size, {d}] and equal-shaped; got k "
+            f"{tuple(k_pool.shape)} v {tuple(v_pool.shape)}")
+    layers, kvh = k_pool.shape[1], k_pool.shape[2]
+    if not 0 <= layer < layers:
+        raise ValueError(
+            f"layer {layer} is outside the pool's {layers} layers")
     if kvh == 0 or h % kvh:
         raise ValueError(
             f"kv_heads ({kvh}) must divide query heads ({h})")
@@ -285,28 +233,11 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     if lengths.shape != (slots,):
         raise ValueError(
             f"lengths must be [{slots}], got {tuple(lengths.shape)}")
-    mpps = page_table.shape[1]
-    ps = k_pages.shape[2]
     scale = (d ** -0.5) if sm_scale is None else sm_scale
-    page_table = page_table.astype(jnp.int32)
-    lengths = lengths.astype(jnp.int32)
-
-    if use_kernel is None:
-        use_kernel = mpps > paged_xla_max_pages(xla_max_pages)
-
-    if not use_kernel:
-        # gather the virtual windows and reuse the dense XLA chain —
-        # [slots, mpps, kvh, ps, d] -> [slots, kvh, mpps*ps, d]
-        def window(pages):
-            g = jnp.take(pages, page_table, axis=0)
-            return jnp.moveaxis(g, 2, 1).reshape(slots, kvh, mpps * ps, d)
-
-        out = decode_attention(q, window(k_pages), window(v_pages),
-                               lengths, sm_scale=scale, use_kernel=False)
-        return out[:, :, 0] if squeezed else out
-
-    out = _paged_kernel_call(q[:, :, 0, :], k_pages, v_pages, page_table,
-                             lengths, scale)
+    out = _paged_kernel_call(
+        q[:, :, 0, :], k_pool, v_pool, page_table.astype(jnp.int32),
+        lengths.astype(jnp.int32), jnp.full((1,), layer, jnp.int32),
+        scale=float(scale))
     return out if squeezed else out[:, :, None, :]
 
 
@@ -321,8 +252,9 @@ def paged_slab_attention(q, k_pages, v_pages, page_table, lengths, *,
     positions ``[lengths, lengths + S)``) score the slot's virtual
     window, causally within the slab.
 
-    The q_len = S sibling of :func:`paged_decode_attention`'s XLA
-    gather path: the slot's pages gather into the dense
+    The q_len = S sibling of :func:`paged_decode_attention`, as an XLA
+    gather: the slot's pages (ONE layer's ``[pages, kv_heads,
+    page_size, d]`` slice of the pool) gather into the dense
     ``[slots, kv_heads, max_seq, d]`` window (position for position the
     dense cache's view) and
     :func:`~apex_tpu.ops.attention.slab_decode_attention` scores it —

@@ -853,7 +853,6 @@ def _microbench_infer(rtt: float, on_tpu: bool):
     from apex_tpu.inference.kv_cache import default_page_size, page_row
     from apex_tpu.inference.sampling import SamplingConfig
     from apex_tpu.ops.attention import decode_xla_max_seq
-    from apex_tpu.ops.paged_attention import paged_xla_max_pages
     from apex_tpu.transformer import parallel_state
     from apex_tpu.transformer.testing import GPTConfig, gpt_model_provider
 
@@ -994,7 +993,6 @@ def _microbench_infer(rtt: float, on_tpu: bool):
     if paged:
         out["infer_page_size"] = page_size
         out["infer_pages"] = engine.num_pages
-        out["infer_paged_xla_max_pages"] = paged_xla_max_pages()
 
     # serve-path telemetry stamp (ISSUE 8): a short wave through the
     # REAL continuous-batching scheduler over a private registry — the

@@ -636,26 +636,37 @@ def case_paged(k, seed):
     import jax.numpy as jnp
     import numpy as np
 
+    from apex_tpu.ops.attention import decode_attention
     from apex_tpu.ops.paged_attention import paged_decode_attention
     slots, h, ps, d, mpps, lengths = k["paged"]
     n_pages = slots * mpps
+    layers, layer = 2, 1
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = _normal(keys[0], (slots, h, d), "bfloat16")
-    pk = _normal(keys[1], (n_pages + 1, h, ps, d), "bfloat16")
-    pv = _normal(keys[2], (n_pages + 1, h, ps, d), "bfloat16")
+    pk = _normal(keys[1], (n_pages + 1, layers, h, ps, d), "bfloat16")
+    pv = _normal(keys[2], (n_pages + 1, layers, h, ps, d), "bfloat16")
     # a scrambled, non-contiguous page assignment
     pt = jnp.asarray(np.random.RandomState(seed).permutation(n_pages)
                      .reshape(slots, mpps), jnp.int32)
     ln = jnp.asarray(lengths, jnp.int32)
     got = jax.jit(lambda *a: paged_decode_attention(
-        *a, xla_max_pages=0))(q, pk, pv, pt, ln)
-    want = reference(lambda *a: paged_decode_attention(
-        *a, use_kernel=False), q, pk, pv, pt, ln)
+        *a, layer=layer))(q, pk, pv, pt, ln)
+
+    def gathered(q, pk, pv, pt, ln):
+        # the slots' windows gathered dense, scored by the XLA chain
+        def window(pool):
+            g = jnp.take(pool[:, layer], pt, axis=0)
+            return jnp.moveaxis(g, 2, 1).reshape(slots, h, mpps * ps, d)
+        return decode_attention(q[:, :, None, :], window(pk), window(pv),
+                                ln, use_kernel=False)[:, :, 0]
+
+    want = reference(gathered, q, pk, pv, pt, ln)
     return [check(f"paged_decode_attention kernel MHA {h} x {d}, page "
-                  f"{ps}, {mpps} pages/slot, lengths {lengths}",
+                  f"{ps}, {mpps} pages/slot, layer {layer} of {layers}, "
+                  f"lengths {lengths}",
                   {"out": rel_err(got, want)}, 2e-2,
-                  "bf16 p into the MXU; the XLA gather twin at highest "
-                  "precision")]
+                  "bf16 p into the MXU; the gathered windows through the "
+                  "dense XLA decode at highest precision")]
 
 
 def case_fused_block(k, seed):

@@ -5,8 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from apex_tpu.ops.attention import (flash_attention, mha_reference,
-                                    ring_decode_attention)
+from apex_tpu.ops.attention import (decode_attention, flash_attention,
+                                    mha_reference, ring_decode_attention)
 from apex_tpu.ops.paged_attention import paged_decode_attention
 
 
@@ -98,8 +98,9 @@ def test_ring_decode_reads_exactly_the_window(positions):
 
 def test_paged_kernel_indexes_a_layer_of_the_whole_pool():
     """``layer=`` hands the kernel the pool and lets its blocks pick the
-    layer: the same numbers as the layer's slice, with a query group of 6
-    (48 heads over 8 KV heads at the published sizes)."""
+    layer: the numbers of the dense decode over that layer's gathered
+    windows, with a query group of 6 (48 heads over 8 KV heads at the
+    published sizes)."""
     pages, layers, kvh, ps, d, slots, group = 9, 2, 2, 8, 16, 3, 6
     rng = np.random.RandomState(0)
     pool_k = jnp.asarray(rng.randn(pages, layers, kvh, ps, d), jnp.float32)
@@ -107,12 +108,19 @@ def test_paged_kernel_indexes_a_layer_of_the_whole_pool():
     table = jnp.asarray([[0, 1, 2], [3, 4, 8], [5, 8, 8]], jnp.int32)
     lengths = jnp.asarray([20, 11, 3], jnp.int32)
     q = jnp.asarray(rng.randn(slots, kvh * group, d), jnp.float32)
+
+    def window(pool, layer):
+        g = jnp.take(pool[:, layer], table, axis=0)    # [slots, 3, kvh, ps, d]
+        return jnp.moveaxis(g, 2, 1).reshape(slots, kvh, 3 * ps, d)
+
     for layer in range(layers):
         got = paged_decode_attention(q, pool_k, pool_v, table, lengths,
                                      layer=layer)
-        want = paged_decode_attention(q, pool_k[:, layer], pool_v[:, layer],
-                                      table, lengths, use_kernel=True)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    with pytest.raises(ValueError, match="must be the pool"):
+        want = decode_attention(q, window(pool_k, layer),
+                                window(pool_v, layer), lengths,
+                                use_kernel=False)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+    with pytest.raises(ValueError, match="whole pool"):
         paged_decode_attention(q, pool_k[:, 0], pool_v[:, 0], table,
                                lengths, layer=0)

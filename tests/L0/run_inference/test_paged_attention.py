@@ -1,33 +1,33 @@
-"""Ragged paged decode attention vs the dense decode path: the XLA
-gather fallback must be numerically identical to the dense cache's
-decode, the Pallas kernel must match within fp tolerance on ragged
-batches (straggler + shorts) across MHA/GQA/MQA, and the crossover
-knob must dispatch like the dense machinery it mirrors."""
+"""Ragged paged decode attention vs the dense decode path: the one
+``apex_paged_decode`` kernel, reading a layer of the WHOLE pool through
+the page table, must match the dense cache's decode within fp tolerance
+on ragged batches (straggler + shorts) across MHA/GQA/MQA, for every
+layer of a multi-layer pool, in fp32 and bf16."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from apex_tpu.ops.attention import decode_attention
-from apex_tpu.ops.paged_attention import (
-    _PAGED_XLA_MAX_PAGES,
-    paged_decode_attention,
-    paged_xla_max_pages,
-)
+from apex_tpu.ops.paged_attention import paged_decode_attention
+
+LAYERS = 3
 
 
-def _paged_twin(slots, h, kvh, ps, mpps, lengths, d=16, seed=0):
-    """(q, dense k/v, paged pool k/v + scrambled page table, lengths):
-    the SAME cache contents laid out both ways, with dead pool pages
-    holding garbage so masking bugs can't hide."""
+def _paged_twin(slots, h, kvh, ps, mpps, lengths, d=16, seed=0,
+                layers=LAYERS):
+    """(q, dense k/v per layer, the whole paged pool k/v + scrambled
+    page table, lengths): the SAME cache contents laid out both ways,
+    every layer different, with dead pool pages holding garbage so
+    masking bugs can't hide."""
     rng = np.random.RandomState(seed)
     max_seq = ps * mpps
     n_pages = slots * mpps
     q = rng.randn(slots, h, d).astype(np.float32)
-    k = rng.randn(slots, kvh, max_seq, d).astype(np.float32)
-    v = rng.randn(slots, kvh, max_seq, d).astype(np.float32)
-    pool_k = rng.randn(n_pages + 1, kvh, ps, d).astype(np.float32)
-    pool_v = rng.randn(n_pages + 1, kvh, ps, d).astype(np.float32)
+    k = rng.randn(layers, slots, kvh, max_seq, d).astype(np.float32)
+    v = rng.randn(layers, slots, kvh, max_seq, d).astype(np.float32)
+    pool_k = rng.randn(n_pages + 1, layers, kvh, ps, d).astype(np.float32)
+    pool_v = rng.randn(n_pages + 1, layers, kvh, ps, d).astype(np.float32)
     perm = rng.permutation(n_pages)       # non-contiguous assignment
     pt = np.empty((slots, mpps), np.int32)
     i = 0
@@ -36,8 +36,8 @@ def _paged_twin(slots, h, kvh, ps, mpps, lengths, d=16, seed=0):
             pid = perm[i]
             i += 1
             pt[s, j] = pid
-            pool_k[pid] = k[s, :, j * ps:(j + 1) * ps, :]
-            pool_v[pid] = v[s, :, j * ps:(j + 1) * ps, :]
+            pool_k[pid] = k[:, s, :, j * ps:(j + 1) * ps, :]
+            pool_v[pid] = v[:, s, :, j * ps:(j + 1) * ps, :]
     return (jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
             jnp.asarray(pool_k), jnp.asarray(pool_v), jnp.asarray(pt),
             jnp.asarray(lengths, jnp.int32))
@@ -45,95 +45,123 @@ def _paged_twin(slots, h, kvh, ps, mpps, lengths, d=16, seed=0):
 
 RAGGED = [32, 0, 1, 7, 8, 9]              # straggler + shorts around ps
 
+#: dtype -> tolerance of the kernel against the dense XLA decode
+TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 
-@pytest.mark.parametrize("h,kvh", [(4, 4), (8, 2), (4, 1)])  # MHA/GQA/MQA
-def test_xla_path_is_bitwise_the_dense_decode(h, kvh):
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("layer", [0, LAYERS - 1])
+@pytest.mark.parametrize("h,kvh", [(4, 4), (8, 2), (4, 1)],
+                         ids=["mha", "gqa", "mqa"])
+def test_kernel_matches_dense_on_ragged_batch(h, kvh, layer, dtype):
     q, k, v, pk, pv, pt, ln = _paged_twin(6, h, kvh, 8, 4, RAGGED)
-    dense = decode_attention(q, k, v, ln, use_kernel=False)
-    paged = paged_decode_attention(q, pk, pv, pt, ln, use_kernel=False)
-    # the gathered window IS the dense window: identical ops, identical
-    # bits — the paged memory model changes storage, not math
-    np.testing.assert_array_equal(np.asarray(paged), np.asarray(dense))
-
-
-@pytest.mark.parametrize("h,kvh", [(4, 4), (8, 2), (4, 1)])
-def test_kernel_matches_dense_on_ragged_batch(h, kvh):
-    q, k, v, pk, pv, pt, ln = _paged_twin(6, h, kvh, 8, 4, RAGGED)
-    dense = decode_attention(q, k, v, ln, use_kernel=False)
-    kern = paged_decode_attention(q, pk, pv, pt, ln, use_kernel=True)
-    np.testing.assert_allclose(np.asarray(kern), np.asarray(dense),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_kernel_bf16_matches_dense_bf16():
-    q, k, v, pk, pv, pt, ln = _paged_twin(4, 8, 2, 8, 3, [24, 5, 0, 13])
-    bf = jnp.bfloat16
-    dense = decode_attention(q.astype(bf), k.astype(bf), v.astype(bf),
-                             ln, use_kernel=False)
-    kern = paged_decode_attention(q.astype(bf), pk.astype(bf),
-                                  pv.astype(bf), pt, ln, use_kernel=True)
+    q, k, v, pk, pv = (t.astype(dtype) for t in (q, k, v, pk, pv))
+    dense = decode_attention(q, k[layer], v[layer], ln, use_kernel=False)
+    kern = paged_decode_attention(q, pk, pv, pt, ln, layer=layer)
+    assert kern.dtype == dtype and kern.shape == q.shape
     np.testing.assert_allclose(
         np.asarray(kern, np.float32), np.asarray(dense, np.float32),
-        rtol=2e-2, atol=2e-2)
+        rtol=TOL[dtype], atol=TOL[dtype])
 
 
-def test_zero_length_slots_emit_zeros_finite():
-    q, k, v, pk, pv, pt, ln = _paged_twin(3, 4, 2, 4, 3, [0, 5, 0])
-    for use_kernel in (False, True):
-        out = np.asarray(paged_decode_attention(q, pk, pv, pt, ln,
-                                                use_kernel=use_kernel))
-        assert np.all(out[0] == 0) and np.all(out[2] == 0)
-        assert np.all(np.isfinite(out))
+def test_layers_of_one_pool_differ():
+    """``layer`` really indexes the pool: two layers of one pool give
+    two answers (a block spec stuck on layer 0 would pass a one-layer
+    comparison)."""
+    q, k, v, pk, pv, pt, ln = _paged_twin(3, 4, 2, 4, 3, [5, 3, 1])
+    outs = [np.asarray(paged_decode_attention(q, pk, pv, pt, ln, layer=i))
+            for i in range(LAYERS)]
+    for i in range(1, LAYERS):
+        assert np.abs(outs[i] - outs[0]).max() > 1e-2
+
+
+@pytest.mark.parametrize("h,kvh", [(4, 4), (4, 2), (4, 1)],
+                         ids=["mha", "gqa", "mqa"])
+def test_zero_length_slots_emit_zeros_finite(h, kvh):
+    q, k, v, pk, pv, pt, ln = _paged_twin(3, h, kvh, 4, 3, [0, 5, 0])
+    out = np.asarray(paged_decode_attention(q, pk, pv, pt, ln, layer=1))
+    assert np.all(out[0] == 0) and np.all(out[2] == 0)
+    assert np.any(out[1] != 0)
+    assert np.all(np.isfinite(out))
+
+
+def test_dead_table_entries_may_hold_any_page():
+    """Entries of the page table beyond a slot's live pages are never
+    read into the result, whatever valid page they name."""
+    q, k, v, pk, pv, pt, ln = _paged_twin(3, 4, 2, 4, 3, [5, 3, 12])
+    want = np.asarray(paged_decode_attention(q, pk, pv, pt, ln, layer=0))
+    live = (np.asarray(ln)[:, None] + 3) // 4 > np.arange(3)[None, :]
+    trash = pk.shape[0] - 1
+    got = np.asarray(paged_decode_attention(
+        q, pk, pv, jnp.where(live, pt, trash), ln, layer=0))
+    np.testing.assert_array_equal(got, want)
 
 
 def test_four_dim_q_round_trips():
     q, k, v, pk, pv, pt, ln = _paged_twin(3, 4, 2, 4, 3, [5, 3, 1])
-    out3 = paged_decode_attention(q, pk, pv, pt, ln)
-    out4 = paged_decode_attention(q[:, :, None, :], pk, pv, pt, ln)
+    out3 = paged_decode_attention(q, pk, pv, pt, ln, layer=2)
+    out4 = paged_decode_attention(q[:, :, None, :], pk, pv, pt, ln,
+                                  layer=2)
+    assert out3.shape == (3, 4, 16)
     assert out4.shape == (3, 4, 1, 16)
     np.testing.assert_array_equal(np.asarray(out4[:, :, 0]),
                                   np.asarray(out3))
 
 
-def test_crossover_knob(monkeypatch):
-    assert paged_xla_max_pages() == _PAGED_XLA_MAX_PAGES
-    assert paged_xla_max_pages(8) == 8                 # kwarg wins
-    monkeypatch.setenv("APEX_TPU_PAGED_XLA_MAX_PAGES", "3")
-    assert paged_xla_max_pages() == 3
-    assert paged_xla_max_pages(7) == 7
-    monkeypatch.setenv("APEX_TPU_PAGED_XLA_MAX_PAGES", "bogus")
-    with pytest.raises(ValueError, match="must be an int"):
-        paged_xla_max_pages()
+def test_sm_scale_is_applied():
+    q, k, v, pk, pv, pt, ln = _paged_twin(3, 4, 2, 4, 3, [5, 3, 9])
+    dense = decode_attention(q, k[1], v[1], ln, sm_scale=0.5,
+                             use_kernel=False)
+    kern = paged_decode_attention(q, pk, pv, pt, ln, layer=1,
+                                  sm_scale=0.5)
+    np.testing.assert_allclose(np.asarray(kern), np.asarray(dense),
+                               rtol=2e-5, atol=2e-5)
 
 
-def test_auto_dispatch_selects_kernel_above_crossover(monkeypatch):
-    """The traced program contains a pallas_call exactly when the page
-    count exceeds the effective crossover — the knob really steers."""
+def test_every_call_is_the_one_kernel():
+    """One path: the traced program is the ``apex_paged_decode``
+    pallas_call and holds no gather, whatever the window."""
+    for mpps in (1, 3, 64):
+        q, k, v, pk, pv, pt, ln = _paged_twin(2, 4, 2, 4, mpps, [3, 1])
+        jaxpr = str(jax.make_jaxpr(
+            lambda *a: paged_decode_attention(*a, layer=0))(
+                q, pk, pv, pt, ln))
+        assert jaxpr.count("pallas_call") == 1
+        assert "apex_paged_decode" in jaxpr
+        assert "gather" not in jaxpr
+
+
+def _bad_pool(case):
     q, k, v, pk, pv, pt, ln = _paged_twin(3, 4, 2, 4, 3, [5, 3, 1])
+    return {
+        "q_len": (jnp.zeros((3, 4, 2, 16)), pk, pv, pt, ln, 0,
+                  "q_len == 1"),
+        "k_v_differ": (q, pk, pv[:, :, :, :2], pt, ln, 0, "equal-shaped"),
+        "one_layer_slice": (q, pk[:, 0], pv[:, 0], pt, ln, 0,
+                            "whole pool"),
+        "head_dim": (q, pk[..., :8], pv[..., :8], pt, ln, 0,
+                     "whole pool"),
+        "layer_high": (q, pk, pv, pt, ln, LAYERS, "outside the pool"),
+        "layer_negative": (q, pk, pv, pt, ln, -1, "outside the pool"),
+        "kv_heads": (q, jnp.zeros((5, 2, 3, 4, 16)),
+                     jnp.zeros((5, 2, 3, 4, 16)), pt, ln, 0,
+                     "must divide"),
+        "page_table": (q, pk, pv, pt[:2], ln, 0, "page_table"),
+        "lengths": (q, pk, pv, pt, ln[:2], 0, "lengths"),
+    }[case]
 
-    def has_pallas(xla_max_pages):
-        jaxpr = jax.make_jaxpr(
-            lambda *a: paged_decode_attention(
-                *a, xla_max_pages=xla_max_pages))(q, pk, pv, pt, ln)
-        return "pallas_call" in str(jaxpr)
 
-    assert not has_pallas(3)          # mpps == 3 <= 3: XLA gather path
-    assert has_pallas(2)              # mpps > 2: kernel path
-    assert has_pallas(0)              # 0 forces the kernel
-    monkeypatch.setenv("APEX_TPU_PAGED_XLA_MAX_PAGES", "0")
-    assert has_pallas(None)           # env steers the auto dispatch
+@pytest.mark.parametrize("case", [
+    "q_len", "k_v_differ", "one_layer_slice", "head_dim", "layer_high",
+    "layer_negative", "kv_heads", "page_table", "lengths"])
+def test_validates_shapes(case):
+    q, pk, pv, pt, ln, layer, match = _bad_pool(case)
+    with pytest.raises(ValueError, match=match):
+        paged_decode_attention(q, pk, pv, pt, ln, layer=layer)
 
 
-def test_validates_shapes():
+def test_layer_is_required():
     q, k, v, pk, pv, pt, ln = _paged_twin(3, 4, 2, 4, 3, [5, 3, 1])
-    with pytest.raises(ValueError, match="q_len == 1"):
-        paged_decode_attention(jnp.zeros((3, 4, 2, 16)), pk, pv, pt, ln)
-    with pytest.raises(ValueError, match="equal-shaped"):
-        paged_decode_attention(q, pk, pv[:, :, :2], pt, ln)
-    with pytest.raises(ValueError, match="must divide"):
-        bad = jnp.zeros((5, 3, 4, 16))      # 3 kv heads !| 4 q heads
-        paged_decode_attention(q, bad, bad, pt, ln)
-    with pytest.raises(ValueError, match="page_table"):
-        paged_decode_attention(q, pk, pv, pt[:2], ln)
-    with pytest.raises(ValueError, match="lengths"):
-        paged_decode_attention(q, pk, pv, pt, ln[:2])
+    with pytest.raises(TypeError):
+        paged_decode_attention(q, pk, pv, pt, ln)

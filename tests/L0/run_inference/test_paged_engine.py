@@ -83,14 +83,16 @@ def test_paged_generate_equals_dense_generate():
 
 
 def test_paged_kernel_path_engine_matches_dense():
-    """paged_attn_max_pages=0 pins the Pallas kernel inside the decode
-    executable; greedy streams still match the dense engine."""
-    cfg, model, params = _tiny_gpt(max_seq=64)
+    """Every paged decode executable holds the Pallas kernel (one page
+    a slot here, the shortest window there is); greedy streams still
+    match the dense engine."""
+    cfg, model, params = _tiny_gpt(max_seq=16)
     rng = np.random.RandomState(5)
     prompts = [list(rng.randint(0, 64, size=n)) for n in (6, 3)]
-    dense = InferenceEngine("gpt", cfg, params, slots=2, max_seq=64)
-    kern = InferenceEngine("gpt", cfg, params, slots=2, max_seq=64,
-                           page_size=16, paged_attn_max_pages=0)
+    dense = InferenceEngine("gpt", cfg, params, slots=2, max_seq=16)
+    kern = InferenceEngine("gpt", cfg, params, slots=2, max_seq=16,
+                           page_size=16)
+    assert kern.max_pages_per_slot == 1
     assert dense.generate(prompts, max_new_tokens=5) == \
         kern.generate(prompts, max_new_tokens=5)
 
@@ -254,3 +256,118 @@ def test_paged_decode_is_one_executable_across_admits_and_retires():
     finally:
         for attr, listeners in saved.items():
             getattr(_mon, attr)[:] = listeners
+
+
+# -- one way to hand pages to the kernel (ISSUE 31) --------------------------
+
+def _eqns(jaxpr):
+    """Every equation reachable from ``jaxpr``, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+PAGED_LAYERS = 2
+
+
+def _tiny_kind(kind):
+    """``(cfg, params)`` of a tiny model of ``kind`` with
+    ``PAGED_LAYERS`` layers that attend through the paged pool."""
+    if kind == "gpt":
+        cfg, _, params = _tiny_gpt(max_seq=64, layers=PAGED_LAYERS)
+        return cfg, params
+    if kind == "llama":
+        cfg = LlamaConfig(vocab_size=32, hidden_size=16, num_layers=2,
+                          num_attention_heads=4, num_kv_heads=2,
+                          max_seq_length=64)
+        model = llama_model_provider(cfg)
+    else:
+        from apex_tpu.transformer.testing import (LagunaConfig,
+                                                  laguna_model_provider)
+        cfg = LagunaConfig(vocab_size=64, hidden_size=32, head_dim=8,
+                           heads_per_layer=(2, 4, 2),
+                           layer_types=("full", "sliding", "full"),
+                           mlp_types=("dense", "sparse", "sparse"),
+                           ffn_hidden_size=32, moe_ffn_hidden_size=16,
+                           shared_ffn_hidden_size=16, num_experts=4,
+                           max_seq_length=64)
+        model = laguna_model_provider(cfg)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))
+    return cfg, params
+
+
+@pytest.mark.parametrize("kind", ["gpt", "llama", "laguna"])
+def test_decode_attends_through_the_kernel_on_the_whole_pool(kind):
+    """The decode step of every kind: exactly one ``apex_paged_decode``
+    call a paged (full) layer, each handed the WHOLE pool, and no gather
+    of a slot's window out of it — the only gather from the pool is the
+    append's read of each slot's one current page."""
+    from apex_tpu.inference.engine import make_decode_fn
+    from apex_tpu.inference.sampling import SamplingConfig
+
+    cfg, params = _tiny_kind(kind)
+    slots = 3
+    eng = InferenceEngine(kind, cfg, params, slots=slots, max_seq=64,
+                          page_size=8, sampling=SamplingConfig())
+    assert eng.max_pages_per_slot == 8
+    cache = jax.eval_shape(eng.init_cache)
+    pool = cache.k.shape
+    assert pool[1] == PAGED_LAYERS
+    fn = make_decode_fn(kind, cfg, SamplingConfig())
+    jaxpr = jax.make_jaxpr(fn)(
+        cache, eng.params, jnp.zeros((slots,), jnp.int32),
+        jnp.ones((slots,), bool), jax.random.PRNGKey(0), jnp.int32(0))
+    eqns = list(_eqns(jaxpr.jaxpr))
+    kernels = [e for e in eqns if e.primitive.name == "pallas_call"
+               and e.params["name"] == "apex_paged_decode"]
+    assert len(kernels) == PAGED_LAYERS
+    for e in kernels:             # page table, lengths, layer, q, k, v
+        assert [v.aval.shape for v in e.invars[-2:]] == [pool, pool]
+    one_page_a_slot = slots * int(np.prod(pool[2:]))
+    from_pool = [e for e in eqns if e.primitive.name == "gather"
+                 and e.invars[0].aval.shape == pool]
+    assert len(from_pool) == 2 * PAGED_LAYERS          # k and v appends
+    assert all(e.outvars[0].aval.size == one_page_a_slot for e in from_pool)
+    # and no layer's slice of the pool is taken anywhere
+    assert not [e for e in eqns
+                if e.primitive.name in ("slice", "dynamic_slice", "squeeze")
+                and e.invars[0].aval.shape == pool]
+
+
+@pytest.mark.parametrize("cache_dtype,tol", [(jnp.float32, 1e-4),
+                                             (jnp.bfloat16, 2e-2)],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("kind", ["gpt", "llama"])
+def test_paged_decode_logits_match_dense_within_the_kernels_tolerance(
+        kind, cache_dtype, tol):
+    """Same prompt, same step: the paged engine's decode logits against
+    the dense engine's.  Not bit-identical (the kernel's online softmax
+    accumulates page by page, and feeds the cache's own dtype to the
+    products where the dense XLA chain up-casts to fp32), but within the
+    kernel's tolerance for the cache's dtype — and the greedy token is
+    the same."""
+    cfg, params = _tiny_kind(kind)
+    dense = InferenceEngine(kind, cfg, params, slots=2, max_seq=64,
+                            cache_dtype=cache_dtype)
+    paged = InferenceEngine(kind, cfg, params, slots=2, max_seq=64,
+                            page_size=8, cache_dtype=cache_dtype)
+    prompt = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5]          # 2 pages of 8
+    dc, d_tok, _ = dense.prefill(dense.init_cache(), prompt, 0)
+    alloc = paged.new_allocator()
+    pc, p_tok, _ = paged.prefill(paged.init_cache(), prompt, 0,
+                                 pages=alloc.acquire(3))
+    assert int(d_tok) == int(p_tok)
+    last = np.array([int(d_tok), 0], np.int32)
+    active = np.array([True, False])
+    for _ in range(2):
+        dc, d_toks, d_logits, _ = dense.decode(dc, last, active)
+        pc, p_toks, p_logits, _ = paged.decode(pc, last, active)
+        np.testing.assert_allclose(np.asarray(p_logits[0]),
+                                   np.asarray(d_logits[0]),
+                                   rtol=tol, atol=tol)
+        assert int(d_toks[0]) == int(p_toks[0])
+        last = np.array([int(d_toks[0]), 0], np.int32)

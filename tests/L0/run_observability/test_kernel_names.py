@@ -175,3 +175,62 @@ def test_v5e_compiles_a_custom_call_under_the_kernels_name(
     # %jvp_apex_layer_norm_fwd_.1), never after the jitted function
     assert any(re.match(rf"\s*(ROOT )?%\w*{name}[\w.]* = ", line)
                for line in calls), [c[:120] for c in calls]
+
+
+def test_v5e_gpt_decode_step_reads_the_pool_in_place(one_chip, monkeypatch):
+    """A paged ``gpt`` decode step compiled for the chip (ISSUE 31): one
+    ``apex_paged_decode`` custom call a layer, each reading the WHOLE pool
+    where ``append_layer`` wrote it — no copy, slice or any other op whose
+    result is pool-sized besides the appends' in-place updates.  (Interpret
+    mode cannot show this: there the kernel is a loop that carries the
+    pool.)"""
+    import apex_tpu.ops.attention as at
+    import apex_tpu.ops.paged_attention as pa
+    from apex_tpu.inference import kv_cache
+    from apex_tpu.inference.engine import make_decode_fn
+    from apex_tpu.inference.sampling import SamplingConfig
+    from apex_tpu.transformer import parallel_state
+    from apex_tpu.transformer.testing import GPTConfig, gpt_model_provider
+
+    for mod in (at, ln, pa):
+        monkeypatch.setattr(mod, "interpret_mode", lambda: False)
+    if not parallel_state.model_parallel_is_initialized():
+        parallel_state.initialize_model_parallel(1)
+    # a pool of 0.4 GB: one small enough is prefetched to on-chip memory
+    layers, heads, d, ps, slots, pages = 3, 2, 128, 64, 8, 4096
+    cfg = GPTConfig(vocab_size=256, hidden_size=heads * d, num_layers=layers,
+                    num_attention_heads=heads, max_seq_length=512,
+                    hidden_dropout=0.0, attention_dropout=0.0,
+                    params_dtype=BF16)
+    on_chip = lambda x: _s(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        gpt_model_provider(cfg).init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 8), jnp.int32)))
+    cache = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: kv_cache.init_paged_cache(
+            pages, layers, heads, ps, d, slots=slots,
+            max_pages_per_slot=512 // ps)))
+    step = jax.jit(make_decode_fn("gpt", cfg, SamplingConfig()),
+                   donate_argnums=(0,))
+    hlo = step.lower(
+        cache, params, _s((slots,), jnp.int32, sharding=one_chip),
+        _s((slots,), bool, sharding=one_chip),
+        _s((2,), jnp.uint32, sharding=one_chip),
+        _s((), jnp.int32, sharding=one_chip)).compile().as_text()
+    pool = f"bf16[{pages + 1},{layers},{heads},{ps},{d}]"
+    made = {}                       # op -> count, of pool-sized results
+    kernels = 0
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\(", line)
+        if not m:
+            continue
+        name, result, op = m.groups()
+        if op == "custom-call" and name.startswith("apex_paged_decode"):
+            kernels += 1
+            assert line.count(pool) == 2, line[:300]     # k and v, whole
+        if result.startswith(pool) and op != "parameter":
+            made[op] = made.get(op, 0) + 1
+    assert kernels == layers
+    # the appends: one in-place scatter of k and of v a layer, nothing else
+    assert set(made) <= {"fusion", "scatter"}, made
+    assert sum(made.values()) <= 4 * layers, made

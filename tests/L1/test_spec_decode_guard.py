@@ -11,10 +11,11 @@ machine-checked, not claimed.
    the verify step as REGISTERED, audited executables (the only
    legitimate way the closed set grows), and the jaxpr auditor pins
    the fused-block kernel op itself.
-3. The XLA-fallback decode path (fusion off) is the bitwise-unchanged
-   per-op lowering: a fusion-off engine's decode step produces
-   bit-identical logits and cache to the direct models/kv_cache
-   composition the paged parity suite has pinned since ISSUE 6.
+3. The per-op decode path (fusion off) serves what the DENSE slot
+   cache serves: the same greedy tokens step for step, its logits
+   within the ``apex_paged_decode`` kernel's tolerance (since ISSUE 31
+   every paged decode attends through that kernel; the XLA gather
+   whose logits were bit-identical to the dense cache's is gone).
 """
 import json
 import os
@@ -106,13 +107,13 @@ def test_ledger_carries_fused_and_verify_executables():
             "inference_verify_paged"} <= names
 
 
-def test_fusion_off_decode_is_bitwise_the_xla_fallback():
-    """The acceptance criterion's bitwise half: an engine built with
-    fusion OFF (the default) serves the XLA gather-fallback decode —
-    bit-identical logits, step for step, to the DENSE slot cache on
-    mirrored state (the ISSUE 6 parity property, re-pinned through
-    the fusion-capable engine so the knob cannot silently perturb the
-    fallback lowering)."""
+def test_fusion_off_decode_serves_the_dense_caches_tokens():
+    """The acceptance criterion's parity half: an engine built with
+    fusion OFF (the default) serves the per-op decode — the same greedy
+    token, step for step, as the DENSE slot cache on mirrored state,
+    logits within the paged kernel's bf16 tolerance (the ISSUE 6 parity
+    property, re-pinned through the fusion-capable engine so the knob
+    cannot silently perturb the per-op lowering)."""
     eng, cfg, params = _engine()           # decode_fusion default "0"
     assert not eng.decode_fused
     dense = InferenceEngine("gpt", cfg, params, slots=2, max_seq=64)
@@ -130,6 +131,7 @@ def test_fusion_off_decode_is_bitwise_the_xla_fallback():
     for _ in range(3):
         cache_p, toks_p, lp, _ = eng.decode(cache_p, toks_p)
         cache_d, toks_d, ld, _ = dense.decode(cache_d, toks_d)
-        np.testing.assert_array_equal(np.asarray(lp), np.asarray(ld))
+        np.testing.assert_allclose(np.asarray(lp), np.asarray(ld),
+                                   rtol=2e-2, atol=2e-2)
         np.testing.assert_array_equal(np.asarray(toks_p),
                                       np.asarray(toks_d))

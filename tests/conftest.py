@@ -66,7 +66,6 @@ SLOW = {
     "tests/L0/run_inference/test_fused_block.py::test_fused_llama_tracks_unfused_step_locked[mqa]",
     "tests/L0/run_inference/test_host_tier.py::test_hit_after_eviction_swaps_in_instead_of_recompute[2]",
     "tests/L0/run_inference/test_kv_cache.py::test_append_writes_at_each_slots_own_length",
-    "tests/L0/run_inference/test_paged_attention.py::test_kernel_bf16_matches_dense_bf16",
     "tests/L0/run_inference/test_paged_engine.py::test_admission_by_pages_beats_equal_hbm_slot_cache",
     "tests/L0/run_inference/test_paged_engine.py::test_llama_gqa_one_layer_paged_greedy_fast",
     "tests/L0/run_inference/test_paged_engine.py::test_paged_decode_is_one_executable_across_admits_and_retires",
