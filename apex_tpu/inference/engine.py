@@ -119,15 +119,19 @@ def make_prefill_fn(kind: str, cfg, sampling: SamplingConfig,
     prefills, prefix-cache hits, and chunked-prefill chunks alike —
     sharing changes page-table rows, never device programs."""
 
+    rec = models.KINDS[kind]
+    # static facts of the kind: a kind without them traces none of it
+    shares = "prefix_sharing" not in rec.refuses
+    rings = bool(rec.dims(cfg)["window_layers"])
+
     def prefill_fn(cache, params, tokens, slot, length, key, step):
         # named_scope = metadata-only xprof regions (no prims added, so
         # the jaxpr/SPMD audits of these exact builders are unchanged)
         with obs.named_scope("apex_prefill_forward"):
             # length threads into the forward so the lm head projects
             # ONLY the last real position, not every bucket-padded row
-            logits, ks, vs = models.prefill_forward(kind, cfg, params,
-                                                    tokens[None], length,
-                                                    tp=tp)
+            logits, ks, vs = models.prefill_forward(
+                kind, cfg, params, tokens[None], length, tp=tp)[:3]
         with obs.named_scope("apex_prefill_cache_insert"):
             cache = kv_cache.insert(cache, slot, ks, vs, length)
         with obs.named_scope("apex_prefill_sample"):
@@ -138,47 +142,33 @@ def make_prefill_fn(kind: str, cfg, sampling: SamplingConfig,
 
     def prefill_paged_fn(cache, params, tokens, slot, length, row,
                          prefill_from, key, step):
-        with obs.named_scope("apex_prefill_forward"):
-            logits, ks, vs = models.prefill_forward(
-                kind, cfg, params, tokens[None], length, cache=cache,
-                row=row, prefill_from=prefill_from, tp=tp)
-        with obs.named_scope("apex_prefill_cache_insert"):
-            cache = kv_cache.insert_tokens(cache, slot, ks, vs, length,
-                                           row, prefill_from)
-        with obs.named_scope("apex_prefill_sample"):
-            last = logits[0].astype(jnp.float32)            # [vocab]
-            tok = sample_token(last, jax.random.fold_in(key, step),
-                               sampling)
-        return cache, tok, last
-
-    def prefill_laguna_fn(cache, params, tokens, slot, length, row,
-                          prefill_from, key, step):
-        # two pools (ISSUE 30): the full layers' k/v go to the slot's
-        # pages, the window layers' last positions to its rings.  There
-        # is no suffix mode — prefill_from is held to 0 by the engine —
-        # and the sampled token carries the step's counters as its tail
-        # (models.LAGUNA_STATS), read in the one transfer the scheduler
+        # the pool layers' k/v go to the slot's pages; a kind with window
+        # layers (ISSUE 30) also writes their last positions to its
+        # rings, and prefills every prompt whole (prefill_from is held
+        # to 0 by the engine); a kind with stats returns them as the
+        # sampled token's tail, read in the one transfer the scheduler
         # already makes
+        suffix = dict(cache=cache, row=row,
+                      prefill_from=prefill_from) if shares else {}
         with obs.named_scope("apex_prefill_forward"):
             logits, ks, vs, wks, wvs, stats = models.prefill_forward(
-                kind, cfg, params, tokens[None], length)
+                kind, cfg, params, tokens[None], length, tp=tp, **suffix)
         with obs.named_scope("apex_prefill_cache_insert"):
             cache = kv_cache.insert_tokens(cache, slot, ks, vs, length,
                                            row, prefill_from)
-            if wks is not None:
+            if rings:
                 cache = kv_cache.insert_window(cache, slot, wks, wvs,
                                                length)
         with obs.named_scope("apex_prefill_sample"):
             last = logits[0].astype(jnp.float32)            # [vocab]
             tok = sample_token(last, jax.random.fold_in(key, step),
                                sampling)
-            tok = jnp.concatenate([
-                tok.astype(jnp.int32)[None],
-                models.laguna_stats_tail(stats, cache)])
+            if rec.stats:
+                tok = jnp.concatenate([
+                    tok.astype(jnp.int32)[None],
+                    models.stats_tail(stats, cache)])
         return cache, tok, last
 
-    if kind == "laguna":
-        return prefill_laguna_fn
     return prefill_paged_fn if paged else prefill_fn
 
 
@@ -199,38 +189,28 @@ def make_decode_fn(kind: str, cfg, sampling: SamplingConfig,
     construction by ``APEX_TPU_DECODE_FUSION``; fusion off keeps the
     original per-op lowering bitwise."""
 
+    rec = models.KINDS[kind]
+
     def decode_fn(cache, params, tokens, active, key, step):
         tree, fused_layers = params if fused else (params, None)
         with obs.named_scope("apex_decode_forward"):
-            logits, cache = models.decode_forward(kind, cfg, tree,
-                                                  cache, tokens,
-                                                  fused=fused_layers,
-                                                  tp=tp)
-        with obs.named_scope("apex_decode_sample"):
-            logits = logits.astype(jnp.float32)
-            toks = sample_token(logits, jax.random.fold_in(key, step),
-                                sampling)
-        with obs.named_scope("apex_decode_advance"):
-            cache, truncated = kv_cache.advance(cache, active)
-        return cache, toks, logits, truncated
-
-    def decode_laguna_fn(cache, params, tokens, active, key, step):
-        with obs.named_scope("apex_decode_forward"):
             logits, cache, stats = models.decode_forward(
-                kind, cfg, params, cache, tokens, active=active)
+                kind, cfg, tree, cache, tokens, fused=fused_layers, tp=tp,
+                active=active)
         with obs.named_scope("apex_decode_sample"):
             logits = logits.astype(jnp.float32)
             toks = sample_token(logits, jax.random.fold_in(key, step),
                                 sampling)
         with obs.named_scope("apex_decode_advance"):
             cache, truncated = kv_cache.advance(cache, active)
-            # [slots] tokens + the counters' tail: one read for both
-            toks = jnp.concatenate([
-                toks.astype(jnp.int32),
-                models.laguna_stats_tail(stats, cache)])
+            if rec.stats:
+                # [slots] tokens + the counters' tail: one read for both
+                toks = jnp.concatenate([
+                    toks.astype(jnp.int32),
+                    models.stats_tail(stats, cache)])
         return cache, toks, logits, truncated
 
-    return decode_laguna_fn if kind == "laguna" else decode_fn
+    return decode_fn
 
 
 def make_verify_fn(kind: str, cfg, sampling: SamplingConfig, k: int,
@@ -334,12 +314,14 @@ class InferenceEngine:
     single-chip by default, tensor-parallel over a ``tp``-wide mesh on
     request (``gpt``/``llama``).
 
-    The ``laguna`` kind (ISSUE 30: expert FFN, window + full layers, a
-    head count per layer) serves from the paged cache only, on one chip,
-    greedy or sampled; tp > 1, speculative verify, the host KV tier,
-    prefix sharing and the fused block kernel are refused for it at
-    construction, each with its reason.  Its prefill and decode append
-    ``stats_tail`` int32 counters to the tokens they return.
+    What a generative kind is, the engine asks its record
+    (``models.KINDS``), never its name: what the record ``refuses`` is
+    refused at construction with the record's reason — for ``laguna``
+    (ISSUE 30: expert FFN, window + full layers, a head count per layer)
+    the dense cache, tp > 1, speculative verify, the host KV tier, the
+    fused block kernel, and prefix sharing at the first prefill that asks
+    — and a kind with ``stats`` appends ``stats_tail`` int32 counters to
+    the tokens its prefill and decode return.
 
     Static shape contract: ``slots`` concurrent sequences, each with a
     ``max_seq``-deep cache line, decode always batched over every slot.
@@ -371,17 +353,19 @@ class InferenceEngine:
                  tp: Optional[int] = None,
                  host_tier_bytes: Optional[int] = None,
                  swap_batch_pages: Optional[int] = None):
-        if kind not in ("gpt", "llama", "laguna", "bert"):
-            raise ValueError(f"unknown model kind {kind!r}")
+        # a generative kind is its record (models.KINDS); "bert", the
+        # encode-only path, has none
+        rec = None
         if kind != "bert":
-            models.check_supported(kind, cfg)
+            models.check_supported(kind, cfg)   # an unknown kind raises
+            rec = models.KINDS[kind]
+        self._refuses = rec.refuses if rec else {}
         #: int32 counters a step appends to the tokens it returns
-        #: (models.LAGUNA_STATS); 0 for kinds without an expert FFN
-        self.stats_tail = len(models.LAGUNA_STATS) if kind == "laguna" \
-            else 0
+        #: (the record's ``stats``); 0 for kinds without an expert FFN
+        self.stats_tail = len(rec.stats) if rec else 0
         #: can a cached prefix's pages be mapped into another slot, or
         #: a prefill resume mid-prompt?  Not over window rings
-        self.supports_prefix_sharing = kind != "laguna"
+        self.supports_prefix_sharing = "prefix_sharing" not in self._refuses
         self.kind, self.cfg = kind, cfg
         self.slots = int(slots)
         self.max_seq = min(int(max_seq or cfg.max_seq_length),
@@ -458,8 +442,17 @@ class InferenceEngine:
                     "tensor-parallel serving shards the PAGED kv pool "
                     "over kv heads — pass page_size=/num_pages= (the "
                     "dense slot cache does not shard)")
-        if kind == "laguna":
-            self._refuse_unbuilt_for_laguna(decode_fusion, spec_k)
+        # what is not built for the kind is refused here, with the
+        # record's reason — never run wrong
+        fusion_on = (decode_fusion is not None
+                     and resolve_fusion_mode(decode_fusion) == "1")
+        asked = {"dense": not self.paged, "tp": self.tp > 1,
+                 "verify": bool(spec_k),
+                 "host_tier": bool(self.host_tier_bytes),
+                 "fused": fusion_on}
+        for feature, why in self._refuses.items():
+            if asked.get(feature):
+                raise ValueError(why)
         if dtype is not None:
             from apex_tpu.optimizers.functional import _cast_floating
             params = _cast_floating(params, dtype)
@@ -478,8 +471,7 @@ class InferenceEngine:
             # "off", "false", and "auto" — which can only resolve
             # unfused on a cache-less engine) passes; only an explicit
             # fusion-ON request is a configuration error here
-            if spec_k or (decode_fusion is not None
-                          and resolve_fusion_mode(decode_fusion) == "1"):
+            if spec_k or fusion_on:
                 raise ValueError("speculative decoding / fused-block "
                                  "decode are generative-path features; "
                                  "BERT is the encode-only path")
@@ -508,7 +500,8 @@ class InferenceEngine:
             # layout is a one-time device-side re-copy of the layer
             # weights (prefill keeps the original tree) — HBM for
             # decode latency, documented beside the knob.
-            self.decode_fused = kind != "laguna" and resolve_decode_fusion(
+            self.decode_fused = rec.fused is not None \
+                and resolve_decode_fusion(
                 decode_fusion, paged=self.paged,
                 max_pages=self.max_pages_per_slot,
                 min_pages=fusion_min_pages,
@@ -539,7 +532,7 @@ class InferenceEngine:
             # speculative decoding (ISSUE 15): ONE verify executable
             # per (k, engine) — the slab width is static
             self.spec_k = int(spec_k if spec_k is not None
-                              else 0 if kind == "laguna"
+                              else 0 if "verify" in self._refuses
                               else default_spec_k())
             if self.spec_k:
                 self._verify_raw = self._tp_wrap(
@@ -577,33 +570,6 @@ class InferenceEngine:
                     in_specs=(cs, P(), sb, sb), out_specs=cs)
                 self._swap_in = jax.jit(self._swap_in_raw,
                                         donate_argnums=(0,))
-
-    def _refuse_unbuilt_for_laguna(self, decode_fusion, spec_k) -> None:
-        """What ISSUE 30 did not build for the kind is refused here,
-        with its reason — never run wrong."""
-        if not self.paged:
-            raise ValueError(
-                "the 'laguna' kind serves from the paged cache only "
-                "(its full layers page, its window layers ring): pass "
-                "page_size=/num_pages=")
-        if self.tp > 1:
-            models.tp_dims("laguna", self.cfg, self.tp)     # raises why
-        if spec_k:
-            raise ValueError(
-                "speculative verify is not built for the 'laguna' kind "
-                "(a rejected slab would have to roll its window rings "
-                "back)")
-        if self.host_tier_bytes:
-            raise ValueError(
-                "the host KV tier is not built for the 'laguna' kind "
-                "(it swaps prefix pages, and a prefix over window rings "
-                "cannot be shared)")
-        if decode_fusion is not None \
-                and resolve_fusion_mode(decode_fusion) == "1":
-            raise ValueError(
-                "fused_block_decode is not built for the 'laguna' kind "
-                "(the kernel has one head count, a dense FFN and no "
-                "window)")
 
     def _fused_block_dims(self) -> dict:
         """The per-rank layer geometry the fused block kernel would run
@@ -792,10 +758,8 @@ class InferenceEngine:
                 "a page-table edit); this engine runs the dense slot "
                 "cache")
         if start and not self.supports_prefix_sharing:
-            raise ValueError(
-                f"prefill_from={start}: the {self.kind!r} kind prefills "
-                f"a prompt whole (the positions its window rings would "
-                f"need are not in the pages a prefix shares)")
+            raise ValueError(f"prefill_from={start}: "
+                             f"{self._refuses['prefix_sharing']}")
         suffix = tokens[start:]
         bucket = self.bucket_for(suffix.shape[0])
         padded = np.zeros((bucket,), np.int32)

@@ -1,49 +1,54 @@
-"""Pure prefill/decode forwards over the standalone model param trees.
+"""Prefill / decode / verify forwards over the standalone model param
+trees: ONE record per model kind, ONE layer loop per mode.
 
-The training models (``transformer/testing/standalone_{gpt,llama}``) are
-flax modules built for the training shapes; inference needs the same
-math split into a *prefill* (full prompt, causal flash attention,
-emitting every layer's k/v for the cache) and a *decode* (one token per
-slot against the cache).  These functions consume the EXACT param pytree
-``model.init`` produces — no re-keying, no conversion step — and mirror
-the modules' op sequence call for call (same fused LayerNorm/RMSNorm
-kernels, same flash attention, same RoPE convention, same qkv
-reshape/split layout), so prefill logits reproduce ``model.apply``
-bit-for-bit on the same weights and the parity tests in
-``tests/L0/run_inference`` can pin decode against the full forward.
+The training models (``transformer/testing/standalone_*``) are flax
+modules built for the training shapes; inference needs the same math
+split into a *prefill* (full prompt, causal flash attention, emitting
+every layer's k/v for the cache), a *decode* (one token per slot against
+the cache) and a speculative *verify* (a drafted slab per slot).  The
+forwards consume the EXACT param pytree ``model.init`` produces — no
+re-keying, no conversion step.
 
-Single-chip serving (tp = 1): the TP layers all collapse to plain
-matmuls at world size 1, which is what these forwards implement.
+**A kind is a record** (:class:`Kind`, three entries in :data:`KINDS`):
+its geometry (``dims`` / ``check``), its per-layer pieces (``embed``,
+``rope``, ``norm``, ``project``, ``attn_out``, ``ffn``, ``head``), its
+fused-block layout or ``None``, the names of the counters its steps
+report (``stats``) and what is not built for it, feature by feature,
+with the reason (``refuses``).  ``gpt`` and ``llama`` pieces are the
+flax modules' own op sequence (same fused LayerNorm/RMSNorm kernels,
+same RoPE convention, same qkv reshape/split layout), which is what
+lets ``tests/L0/run_inference/test_engine_parity.py`` pin prefill +
+decode against ``model.apply``; ``laguna``'s pieces ARE
+``standalone_laguna``'s (``attn_project`` / ``attn_output`` / ``ffn`` /
+``rope_cos_sin``), held to the benchmark's plain reference by
+``test_laguna_parity.py``.
+
+**A mode is a loop** (:func:`prefill_forward`, :func:`decode_forward`,
+:func:`verify_forward`): each owns its activation layout, where k/v go
+(collected for the insert; appended to the pool or a ring; appended as a
+slab), which attention reads them, and — in decode — the fused-block and
+``tp > 1`` branches.  Whether a layer lives in the paged pool or in a
+per-slot window ring is read from the record's ``layer_types``, never
+from the kind's name; the engine (``engine.py``) likewise asks the
+record, so adding a kind is adding a record.
+
 Unsupported training-only configs (scan_layers, the capacity-slot MoE
 FFN of ``transformer/moe/MoELayer``, sequence/context parallelism) fail
-loudly at engine construction.
-
-The ``laguna`` kind (ISSUE 30) is built from the per-layer pieces of
-``transformer/testing/standalone_laguna.py`` rather than mirrored by
-hand: a head count per layer, window layers (``flash_attention(window=)``
-in prefill, a per-slot ring in decode) beside full layers in the paged
-pool, and an expert FFN that drops no token
-(``transformer/moe/dropless.py``) inside both executables.  Its steps
-return their expert counters (:data:`LAGUNA_STATS`) beside the logits.
+loudly at engine construction (``Kind.check``).
 
 Multi-chip serving (ISSUE 17): every forward takes a static ``tp`` and,
 at ``tp > 1``, runs as the per-rank body of a ``shard_map`` over the
-``parallel_state`` tensor axis — the same column/row partitioning the
-training ``transformer/tensor_parallel`` layers implement.  qkv / gate /
-up projections are column-sharded over heads/ffn (no comm), out-proj and
-down-proj are row-sharded with ONE psum each at the row boundary
-(:func:`_row_linear` — the ``RowParallelLinear`` reduce, bias added
-once AFTER the reduction), and the embedding / LM head are
-vocab-sharded: the lookup is the ``VocabParallelEmbedding``
-mask-clip-take-zero-psum (the PR 9 vocab-parallel xent target-pick
-algebra), the head a local vocab-shard matmul whose tiled ``all_gather``
-reassembles the full logits rank-major — original vocab order — so
-sampling stays replica-uniform off one folded key.  GQA/MQA kv heads
-replicate below tp (:func:`expand_kv_for_tp`): each kv head's packed
-columns repeat ``tp/kvh`` times head-major, so the plain column shard
-hands every rank exactly the kv head its query group reads.
+``parallel_state`` tensor axis — the column/row partitioning of the
+training ``transformer/tensor_parallel`` layers: qkv / gate / up
+column-sharded over heads/ffn (no comm), out-proj and down-proj
+row-sharded with ONE psum each (:func:`_row_linear`), embedding and LM
+head vocab-sharded (:func:`_vocab_embed`, :func:`_gather_logits`), GQA /
+MQA kv heads replicated below tp (:func:`expand_kv_for_tp`).
 """
 from __future__ import annotations
+
+import dataclasses
+from typing import Callable, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -70,93 +75,20 @@ from apex_tpu.transformer.parallel_state import TENSOR_AXIS
 from apex_tpu.transformer.testing import standalone_laguna as laguna
 from apex_tpu.transformer.testing.standalone_llama import _rope_cos_sin
 
-__all__ = ["model_dims", "tp_dims", "check_supported", "prefill_forward",
-           "LAGUNA_STATS", "laguna_stats_tail",
-           "decode_forward", "verify_forward", "fused_layer_params",
-           "expand_kv_for_tp", "param_partition_specs",
-           "fused_partition_specs"]
+__all__ = ["Kind", "KINDS", "model_dims", "tp_dims", "check_supported",
+           "prefill_forward", "LAGUNA_STATS", "stats_tail",
+           "laguna_stats_tail", "decode_forward", "verify_forward",
+           "fused_layer_params", "expand_kv_for_tp",
+           "param_partition_specs", "fused_partition_specs"]
+
+#: a layer of this type keeps every position, in the paged pool; any
+#: other type keeps its last ``window`` positions in a per-slot ring
+FULL = laguna.FULL
 
 
-def model_dims(kind: str, cfg) -> dict:
-    """Static cache geometry for a model config: layers / kv_heads /
-    head_dim (+ query heads).
-
-    ``laguna`` (ISSUE 30) has no ONE head count: ``heads``,
-    ``layer_types`` and ``ffn_types`` are per-layer tuples, and
-    ``pool_layers`` / ``window_layers`` say how many layers the paged
-    pool and the window rings hold (``kv_cache`` module docstring);
-    ``window`` is the sliding window in positions."""
-    if kind == "laguna":
-        return {"layers": cfg.num_layers,
-                "heads": tuple(cfg.heads_per_layer),
-                "layer_types": tuple(cfg.layer_types),
-                "ffn_types": tuple(cfg.mlp_types),
-                "kv_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
-                "pool_layers": len(cfg.full_layers),
-                "window_layers": len(cfg.window_layers),
-                "window": cfg.sliding_window}
-    head_dim = cfg.hidden_size // cfg.num_attention_heads
-    kv_heads = (cfg.kv_heads if kind == "llama"
-                else cfg.num_attention_heads)
-    return {"layers": cfg.num_layers, "heads": cfg.num_attention_heads,
-            "kv_heads": kv_heads, "head_dim": head_dim,
-            "pool_layers": cfg.num_layers, "window_layers": 0, "window": 0}
-
-
-def tp_dims(kind: str, cfg, tp: int) -> dict:
-    """Per-rank geometry under tensor-parallel serving, validated.
-
-    ``heads_local`` / ``kv_heads_local`` are what each rank's forwards
-    compute with; ``kv_heads_pool`` is the GLOBAL kv-head count of the
-    sharded paged pool (``kvh * rep`` — GQA/MQA heads replicate below
-    tp, each kv head repeated ``rep = tp/kvh`` times head-major so the
-    plain shard over the pool's kv-head dim hands every rank the kv
-    head its query group reads)."""
-    d = model_dims(kind, cfg)
-    heads, kvh = d["heads"], d["kv_heads"]
-    if tp <= 1:
-        return dict(d, heads_local=heads, kv_heads_local=kvh,
-                    kv_heads_pool=kvh, rep=1)
-    if kind == "laguna":
-        raise ValueError(
-            "tp > 1 is not built for the 'laguna' kind: its expert "
-            "stacks, per-layer head counts and window rings have no "
-            "partition specs yet (serve it on one chip)")
-    if heads % tp:
-        raise ValueError(
-            f"tp={tp} does not divide num_attention_heads={heads}")
-    if kvh % tp == 0:
-        rep = 1
-    elif tp % kvh == 0:
-        rep = tp // kvh
-    else:
-        raise ValueError(
-            f"tp={tp} vs kv_heads={kvh}: need tp | kv_heads (shard) or "
-            f"kv_heads | tp (replicate below tp)")
-    return dict(d, heads_local=heads // tp,
-                kv_heads_local=max(kvh // tp, 1),
-                kv_heads_pool=kvh * rep, rep=rep)
-
-
-def check_supported(kind: str, cfg) -> None:
-    if kind not in ("gpt", "llama", "laguna"):
-        raise ValueError(f"unknown generative model kind {kind!r} "
-                         "(expected 'gpt', 'llama' or 'laguna')")
-    if kind == "laguna":
-        if not isinstance(cfg, laguna.LagunaConfig):
-            raise TypeError(
-                f"the 'laguna' kind takes a LagunaConfig, got "
-                f"{type(cfg).__name__}")
-        return          # its expert FFN IS built (dropless, ISSUE 30)
-    for flag in ("sequence_parallel", "context_parallel", "scan_layers"):
-        if getattr(cfg, flag, False):
-            raise ValueError(
-                f"inference forwards run tp=1 unrolled; cfg.{flag} is a "
-                "training-topology knob — export the weights into a "
-                "plain config instead")
-    if getattr(cfg, "num_moe_experts", None):
-        raise ValueError("MoE FFN decode is not implemented yet")
-
+# --------------------------------------------------------------------------
+# what every kind's pieces are made of
+# --------------------------------------------------------------------------
 
 def _params_subtree(params):
     """Accept ``model.init``'s ``{"params": ...}`` or the bare tree."""
@@ -216,77 +148,9 @@ def _gather_logits(local, tp):
                               axis=local.ndim - 1, tiled=True)
 
 
-def _suffix_attend(cache, layer: int, row, q, k, v, start):
-    """Prefill attention for a (possibly mid-prompt) token slab: cold
-    (``start == 0``) it is EXACTLY the causal flash path the original
-    prefill ran — bitwise, so cold prefills and the dense-parity tests
-    are untouched; warm (``start > 0``, a prefix-cache hit or a later
-    chunk of a chunked prefill) each row additionally attends to the
-    already-cached prefix, gathered from the slot's KV pages through
-    ``row`` (:func:`~apex_tpu.ops.attention.prefix_window_attention`).
-
-    ``q``: ``[b, h, s, d]``; ``k``/``v``: pre-broadcast
-    ``[b, kv_heads, s, d]``.  One ``lax.cond`` keeps both paths inside
-    the ONE compiled prefill executable per bucket — the runtime
-    executes only the taken branch, so cold prefills never pay the
-    window gather."""
-    b, h, s, d = q.shape
-    kvh = k.shape[1]
-    group = h // kvh
-
-    def cold(q, k, v, pk, pv):
-        if group > 1:                   # GQA: share kv across the group
-            k, v = (jnp.broadcast_to(
-                t[:, :, None], (b, kvh, group, s, d)
-            ).reshape(b, h, s, d) for t in (k, v))
-        return flash_attention(q, k, v, causal=True)
-
-    def warm(q, k, v, pk, pv):
-        # pk/pv: the WHOLE pool [pages, layers, kvh, ps, d] -> the
-        # slot's virtual window [b, kvh, max_seq, d] of this layer in
-        # row order; unowned ordinals gather the trash page — finite
-        # garbage masked by start
-        def window(p):
-            w = p[row, layer]                     # [mpps, kvh, ps, d]
-            return w.transpose(1, 0, 2, 3).reshape(
-                1, kvh, -1, d).astype(q.dtype)
-        return prefix_window_attention(q, k, v, window(pk), window(pv),
-                                       start)
-
-    # the pool goes into the cond whole and only the slot's own pages
-    # are gathered inside: a per-layer slice as the operand is
-    # materialized — one pool-sized temporary per layer, 9 GB of them
-    # for a 24 GiB pool over tp=4 (PERF.md "Bring-up, PR 21")
-    return jax.lax.cond(start > 0, warm, cold, q, k, v, cache.k, cache.v)
-
-
-def _slab_attend(cache, layer: int, q, lengths):
-    """Verify-slab attention against ONE layer of whichever cache
-    layout the engine runs: the dense slot window scored directly
-    (:func:`~apex_tpu.ops.attention.slab_decode_attention`) or the
-    paged pool gathered through the slot page table
-    (:func:`~apex_tpu.ops.paged_attention.paged_slab_attention`).
-    ``lengths`` is the live count BEFORE the slab was appended (the
-    causal offset)."""
-    if isinstance(cache, kv_cache.PagedKVCache):
-        return paged_slab_attention(q, cache.k[:, layer],
-                                    cache.v[:, layer], cache.page_table,
-                                    lengths)
-    return slab_decode_attention(q, cache.k[:, layer], cache.v[:, layer],
-                                 lengths)
-
-
-def _cache_attend(cache, layer: int, q, live):
-    """Single-token attention against ONE layer of whichever cache
-    layout the engine runs: the dense slot window
-    (:func:`~apex_tpu.ops.attention.decode_attention`) or the paged
-    pool, handed to the kernel WHOLE and threaded through the slot page
-    table (:func:`~apex_tpu.ops.paged_attention.paged_decode_attention`).
-    Both score the pre-broadcast per-kv-head cache (GQA/MQA grouped)."""
-    if isinstance(cache, kv_cache.PagedKVCache):
-        return paged_decode_attention(q, cache.k, cache.v,
-                                      cache.page_table, live, layer=layer)
-    return decode_attention(q, cache.k[:, layer], cache.v[:, layer], live)
+def _merge_heads(ctx):
+    """``[..., heads, head_dim]`` -> ``[..., heads * head_dim]``."""
+    return ctx.reshape(*ctx.shape[:-2], -1)
 
 
 def _fused_bias(p, width):
@@ -297,74 +161,422 @@ def _fused_bias(p, width):
     return jnp.zeros((1, width), p["weight"].dtype)
 
 
+# --------------------------------------------------------------------------
+# GPT (standalone_gpt's op sequence)
+# --------------------------------------------------------------------------
+
+def _gpt_dims(cfg) -> dict:
+    return {"layers": cfg.num_layers, "heads": cfg.num_attention_heads,
+            "kv_heads": cfg.num_attention_heads,
+            "head_dim": cfg.hidden_size // cfg.num_attention_heads,
+            "layer_types": (FULL,) * cfg.num_layers,
+            "pool_layers": cfg.num_layers, "window_layers": 0, "window": 0}
+
+
+def _check_dense(cfg) -> None:
+    for flag in ("sequence_parallel", "context_parallel", "scan_layers"):
+        if getattr(cfg, flag, False):
+            raise ValueError(
+                f"inference forwards run tp=1 unrolled; cfg.{flag} is a "
+                "training-topology knob — export the weights into a "
+                "plain config instead")
+    if getattr(cfg, "num_moe_experts", None):
+        raise ValueError("MoE FFN decode is not implemented yet")
+
+
+def _gpt_embed(p, tokens, positions, tp):
+    emb = p["embedding"]
+    h = _vocab_embed(emb["word_embeddings"]["weight"], tokens, tp)
+    tab = emb["position_embeddings"]
+    if positions is None:               # a cold prefill: rows 0 .. s
+        return h + tab[None, :tokens.shape[1], :]
+    return h + jnp.take(tab, positions, axis=0)
+
+
+def _gpt_norm(cfg, p, which, x):
+    n = p[which + "_layernorm"]
+    return layer_norm(x, n["weight"], n["bias"])
+
+
+def _gpt_project(cfg, dims, i, lp, h, rope):
+    """qkv projection + the model's reshape/split layout: q/k/v with a
+    trailing ``[..., heads, head_dim]``."""
+    qkv = _linear(lp["self_attention"]["query_key_value"], h)
+    qkv = qkv.reshape(*h.shape[:-1], dims["heads_local"],
+                      3 * dims["head_dim"])
+    return (*jnp.split(qkv, 3, axis=-1), None)
+
+
+def _gpt_attn_out(lp, ctx, extra, tp):
+    return _row_linear(lp["self_attention"]["dense"], _merge_heads(ctx), tp)
+
+
+def _gpt_ffn(cfg, i, lp, h, valid, tp):
+    return _row_linear(lp["mlp"]["dense_4h_to_h"],
+                       jax.nn.gelu(_linear(lp["mlp"]["dense_h_to_4h"],
+                                           h)), tp), None
+
+
+def _gpt_head(p, h, tp):                                    # tied head
+    return jnp.einsum("...h,vh->...v", h,
+                      p["embedding"]["word_embeddings"]["weight"])
+
+
+def _gpt_fused_layout(cfg, dims, lp):
+    """The interleaved ``query_key_value`` columns (per head: ``[q(d),
+    k(d), v(d)]``) deinterleaved into ``wq``/``wk``/``wv``."""
+    heads, d, hidden = dims["heads"], dims["head_dim"], cfg.hidden_size
+    att = lp["self_attention"]
+    w = jnp.transpose(att["query_key_value"]["weight"])
+    w = w.reshape(hidden, heads, 3, d)
+    b = _fused_bias(att["query_key_value"],
+                    3 * heads * d).reshape(heads, 3, d)
+    return {
+        "ln1_w": lp["input_layernorm"]["weight"].reshape(1, hidden),
+        "ln1_b": lp["input_layernorm"]["bias"].reshape(1, hidden),
+        "wq": w[:, :, 0, :].reshape(hidden, heads * d),
+        "bq": b[:, 0, :].reshape(1, heads * d),
+        "wk": w[:, :, 1, :].reshape(hidden, heads * d),
+        "bk": b[:, 1, :].reshape(1, heads * d),
+        "wv": w[:, :, 2, :].reshape(hidden, heads * d),
+        "bv": b[:, 2, :].reshape(1, heads * d),
+        "wo": jnp.transpose(att["dense"]["weight"]),
+        "bo": _fused_bias(att["dense"], hidden),
+        "ln2_w": lp["post_attention_layernorm"]["weight"].reshape(
+            1, hidden),
+        "ln2_b": lp["post_attention_layernorm"]["bias"].reshape(
+            1, hidden),
+        "wu": jnp.transpose(lp["mlp"]["dense_h_to_4h"]["weight"]),
+        "bu": _fused_bias(lp["mlp"]["dense_h_to_4h"], cfg.ffn),
+        "wd": jnp.transpose(lp["mlp"]["dense_4h_to_h"]["weight"]),
+        "bd": _fused_bias(lp["mlp"]["dense_4h_to_h"], hidden),
+    }
+
+
+def _gpt_fused_tail(cfg, blk, x, part):
+    x2 = x + jax.lax.psum(part, TENSOR_AXIS) + blk["bo"]
+    h2 = layer_norm(x2, blk["ln2_w"].reshape(-1), blk["ln2_b"].reshape(-1))
+    u = jax.nn.gelu(jnp.matmul(h2, blk["wu"]) + blk["bu"])
+    y = jax.lax.psum(jnp.matmul(u, blk["wd"]), TENSOR_AXIS)
+    return x2 + (y + blk["bd"])
+
+
+# --------------------------------------------------------------------------
+# LLaMA (standalone_llama's op sequence; GQA/MQA cached once per kv head);
+# its embedding, RMSNorm and untied head are laguna's too
+# --------------------------------------------------------------------------
+
+def _llama_dims(cfg) -> dict:
+    return dict(_gpt_dims(cfg), kv_heads=cfg.kv_heads)
+
+
+def _token_embed(p, tokens, positions, tp):
+    return _vocab_embed(p["embed_tokens"]["weight"], tokens, tp)
+
+
+def _llama_rope(cfg, dims, positions, n):
+    """The model's ``_rope_cos_sin`` values as flat ``[n, head_dim]``
+    tables, indexed at ``positions`` where there are any."""
+    d = dims["head_dim"]
+    cos, sin = (t.reshape(n, d)
+                for t in _rope_cos_sin(n, d, cfg.rope_theta))
+    if positions is not None:
+        cos = jnp.take(cos, positions, axis=0)
+        sin = jnp.take(sin, positions, axis=0)
+    return {FULL: (cos, sin)}
+
+
+def _rms_norm(cfg, p, which, x):
+    return rms_norm(x, p[which + "_norm"]["weight"], eps=cfg.rms_eps)
+
+
+def _llama_project(cfg, dims, i, lp, h, rope):
+    d = dims["head_dim"]
+    q = _linear(lp["attention"]["q_proj"], h)
+    kv = _linear(lp["attention"]["kv_proj"], h)
+    q = q.reshape(*h.shape[:-1], dims["heads_local"], d)
+    k, v = jnp.split(kv.reshape(*h.shape[:-1], dims["kv_heads_local"],
+                                2 * d), 2, axis=-1)
+    q = fused_apply_rotary_pos_emb_cached(q, *rope)
+    k = fused_apply_rotary_pos_emb_cached(k, *rope)
+    return q, k, v, None
+
+
+def _llama_attn_out(lp, ctx, extra, tp):
+    return _row_linear(lp["attention"]["o_proj"], _merge_heads(ctx), tp)
+
+
+def _llama_ffn(cfg, i, lp, h, valid, tp):
+    gate = _linear(lp["mlp"]["gate_proj"], h)
+    up = _linear(lp["mlp"]["up_proj"], h)
+    return _row_linear(lp["mlp"]["down_proj"],
+                       jax.nn.silu(gate) * up, tp), None
+
+
+def _untied_head(p, h, tp):
+    return _linear(p["lm_head"], h)
+
+
+def _llama_fused_layout(cfg, dims, lp):
+    """The packed ``kv_proj`` split into ``wk``/``wv`` planes."""
+    d, hidden = dims["head_dim"], cfg.hidden_size
+    att = lp["attention"]
+    kvw = jnp.transpose(att["kv_proj"]["weight"])
+    # kv-head count from the WEIGHT, not the config: a kv-expanded tree
+    # (expand_kv_for_tp) carries kvh*rep heads
+    kvh_w = kvw.shape[1] // (2 * d)
+    kvw = kvw.reshape(hidden, kvh_w, 2, d)
+    return {
+        "ln1_w": lp["input_norm"]["weight"].reshape(1, hidden),
+        "wq": jnp.transpose(att["q_proj"]["weight"]),
+        "wk": kvw[:, :, 0, :].reshape(hidden, kvh_w * d),
+        "wv": kvw[:, :, 1, :].reshape(hidden, kvh_w * d),
+        "wo": jnp.transpose(att["o_proj"]["weight"]),
+        "ln2_w": lp["post_attention_norm"]["weight"].reshape(1, hidden),
+        "wg": jnp.transpose(lp["mlp"]["gate_proj"]["weight"]),
+        "wu": jnp.transpose(lp["mlp"]["up_proj"]["weight"]),
+        "wd": jnp.transpose(lp["mlp"]["down_proj"]["weight"]),
+    }
+
+
+def _llama_fused_tail(cfg, blk, x, part):
+    x2 = x + jax.lax.psum(part, TENSOR_AXIS)
+    h2 = rms_norm(x2, blk["ln2_w"].reshape(-1), eps=cfg.rms_eps)
+    u = jax.nn.silu(jnp.matmul(h2, blk["wg"])) * jnp.matmul(h2, blk["wu"])
+    return x2 + jax.lax.psum(jnp.matmul(u, blk["wd"]), TENSOR_AXIS)
+
+
+# --------------------------------------------------------------------------
+# Laguna (standalone_laguna's per-layer pieces; ISSUE 30): a head count
+# per layer, window layers beside full ones, an expert FFN that drops no
+# token
+# --------------------------------------------------------------------------
+
+#: what a laguna step reports beside its tokens, in this order — the
+#: int32 tail of the token read (``InferenceEngine.stats_tail`` long)
+LAGUNA_STATS = ("moe_assignments", "moe_experts_hit",
+                "moe_expert_load_max", "window_pages_live")
+
+
+def _laguna_dims(cfg) -> dict:
+    return {"layers": cfg.num_layers,
+            "heads": tuple(cfg.heads_per_layer),
+            "layer_types": tuple(cfg.layer_types),
+            "ffn_types": tuple(cfg.mlp_types),
+            "kv_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
+            "pool_layers": len(cfg.full_layers),
+            "window_layers": len(cfg.window_layers),
+            "window": cfg.sliding_window}
+
+
+def _laguna_check(cfg) -> None:
+    if not isinstance(cfg, laguna.LagunaConfig):
+        raise TypeError(
+            f"the 'laguna' kind takes a LagunaConfig, got "
+            f"{type(cfg).__name__}")
+    # its expert FFN IS built (dropless, ISSUE 30)
+
+
+def _laguna_rope(cfg, dims, positions, n):
+    """One table per layer type: YaRN over part of the head for the full
+    layers, plain over the whole head for the sliding ones."""
+    if positions is None:
+        positions = jnp.arange(n, dtype=jnp.int32)
+    return {t: laguna.rope_cos_sin(cfg, t, positions)
+            for t in dict.fromkeys(cfg.layer_types)}
+
+
+def _laguna_project(cfg, dims, i, lp, h, rope):
+    return laguna.attn_project(cfg, i, lp, h, *rope)
+
+
+def _laguna_attn_out(lp, ctx, gate, tp):
+    return laguna.attn_output(lp, ctx, gate)
+
+
+def _laguna_ffn(cfg, i, lp, h, valid, tp):
+    """``valid`` marks the rows that carry a token: the others are routed
+    to no expert (they would otherwise read experts nobody asked for)."""
+    y, stats = laguna.ffn(cfg, i, lp, h.reshape(-1, h.shape[-1]),
+                          valid=valid)
+    return y.reshape(h.shape), stats
+
+
+# --------------------------------------------------------------------------
+# the record
+# --------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class Fused:
+    """A kind's half of the fused-block decode path (ISSUE 15)."""
+    #: ``(cfg, dims, lp) -> blk``: one layer's weights, matmul-ready
+    layout: Callable
+    #: ``(cfg, blk, x, part) -> h``: finish the block OUTSIDE the kernel
+    #: under tp — psum the rank-partial attention output at the row
+    #: boundary, add the out-proj bias once, then norm2 + the column /
+    #: row-parallel MLP with its own row-boundary psum (the same two
+    #: psums a layer the unfused sharded path pays)
+    tail: Callable
+    #: ``cfg -> float``: the norms' epsilon
+    eps: Callable
+
+
+@dataclasses.dataclass(frozen=True)
+class Kind:
+    """What the three layer loops and the engine ask of a model kind:
+    pure functions of ``(cfg, p | lp, ...)`` and static facts.  ``p`` is
+    the param tree, ``lp`` one layer's subtree, ``dims`` what
+    :func:`tp_dims` returns, ``tp`` the static tensor-parallel width."""
+    #: ``cfg -> dict``: layers / heads / kv_heads / head_dim, the layers'
+    #: types (``FULL`` = paged pool, else window ring), how many layers
+    #: the pool and the rings hold, the window in positions
+    dims: Callable
+    #: ``cfg -> None``: raise for a config the forwards do not serve
+    check: Callable
+    #: ``(p, tokens, positions, tp) -> h [*tokens.shape, hidden]``;
+    #: ``positions`` is None for a cold prefill (rows 0 .. s)
+    embed: Callable
+    #: ``(cfg, dims, positions, n) -> {layer type: (cos, sin)}`` of shape
+    #: ``[*positions.shape, rot]`` (``[n, rot]``, the first ``n``
+    #: positions, where ``positions`` is None); ``{}`` without RoPE
+    rope: Callable
+    #: ``(cfg, p | lp, which, x) -> x``; ``which`` is ``"input"``,
+    #: ``"post_attention"`` or ``"final"``
+    norm: Callable
+    #: ``(cfg, dims, i, lp, h, rope) -> q [..., heads, d], k, v [...,
+    #: kv_heads, d], extra`` — roped where the kind ropes; ``extra`` is
+    #: whatever ``attn_out`` wants back (laguna's gate)
+    project: Callable
+    #: ``(lp, ctx [..., heads, d], extra, tp) -> [..., hidden]``
+    attn_out: Callable
+    #: ``(cfg, i, lp, h, valid, tp) -> (y, stats | None)``
+    ffn: Callable
+    #: ``(p, h, tp) -> logits`` over this rank's vocab shard
+    head: Callable
+    #: the fused-block layout, or None where the kernel is not built
+    fused: Optional[Fused] = None
+    #: names of the int32 counters a step appends to its tokens
+    stats: Tuple[str, ...] = ()
+    #: feature -> why it is not built for the kind; the features are
+    #: ``dense`` (the slot cache), ``tp``, ``verify``, ``host_tier``,
+    #: ``fused`` — refused at engine construction — and
+    #: ``prefix_sharing`` (a prefill that resumes mid-prompt)
+    refuses: Mapping[str, str] = dataclasses.field(default_factory=dict)
+
+
+KINDS = {
+    "gpt": Kind(
+        dims=_gpt_dims, check=_check_dense, embed=_gpt_embed,
+        rope=lambda cfg, dims, positions, n: {}, norm=_gpt_norm,
+        project=_gpt_project, attn_out=_gpt_attn_out, ffn=_gpt_ffn,
+        head=_gpt_head,
+        fused=Fused(_gpt_fused_layout, _gpt_fused_tail, lambda cfg: 1e-5)),
+    "llama": Kind(
+        dims=_llama_dims, check=_check_dense, embed=_token_embed,
+        rope=_llama_rope, norm=_rms_norm, project=_llama_project,
+        attn_out=_llama_attn_out, ffn=_llama_ffn, head=_untied_head,
+        fused=Fused(_llama_fused_layout, _llama_fused_tail,
+                    lambda cfg: cfg.rms_eps)),
+    "laguna": Kind(
+        dims=_laguna_dims, check=_laguna_check, embed=_token_embed,
+        rope=_laguna_rope, norm=_rms_norm, project=_laguna_project,
+        attn_out=_laguna_attn_out, ffn=_laguna_ffn, head=_untied_head,
+        stats=LAGUNA_STATS,
+        refuses={
+            "dense": "the 'laguna' kind serves from the paged cache only "
+                     "(its full layers page, its window layers ring): "
+                     "pass page_size=/num_pages=",
+            "tp": "tp > 1 is not built for the 'laguna' kind: its expert "
+                  "stacks, per-layer head counts and window rings have no "
+                  "partition specs yet (serve it on one chip)",
+            "verify": "speculative verify is not built for the 'laguna' "
+                      "kind (a rejected slab would have to roll its "
+                      "window rings back)",
+            "host_tier": "the host KV tier is not built for the 'laguna' "
+                         "kind (it swaps prefix pages, and a prefix over "
+                         "window rings cannot be shared)",
+            "fused": "fused_block_decode is not built for the 'laguna' "
+                     "kind (the kernel has one head count, a dense FFN "
+                     "and no window)",
+            "prefix_sharing": "the 'laguna' kind prefills a prompt whole "
+                              "(the positions its window rings would "
+                              "need are not in the pages a prefix "
+                              "shares)",
+        }),
+}
+
+
+def _kind(kind: str) -> Kind:
+    if kind not in KINDS:
+        raise ValueError(f"unknown generative model kind {kind!r} "
+                         "(expected 'gpt', 'llama' or 'laguna')")
+    return KINDS[kind]
+
+
+def model_dims(kind: str, cfg) -> dict:
+    """Static cache geometry for a model config: layers / kv_heads /
+    head_dim (+ query heads), ``layer_types`` per layer, and how many
+    layers the paged pool (``pool_layers``) and the window rings
+    (``window_layers``, ``window`` positions each) hold (``kv_cache``
+    module docstring).  ``laguna`` (ISSUE 30) has no ONE head count:
+    its ``heads`` is a per-layer tuple."""
+    return _kind(kind).dims(cfg)
+
+
+def tp_dims(kind: str, cfg, tp: int) -> dict:
+    """Per-rank geometry under tensor-parallel serving, validated.
+
+    ``heads_local`` / ``kv_heads_local`` are what each rank's forwards
+    compute with; ``kv_heads_pool`` is the GLOBAL kv-head count of the
+    sharded paged pool (``kvh * rep`` — GQA/MQA heads replicate below
+    tp, each kv head repeated ``rep = tp/kvh`` times head-major so the
+    plain shard over the pool's kv-head dim hands every rank the kv
+    head its query group reads)."""
+    d = model_dims(kind, cfg)
+    heads, kvh = d["heads"], d["kv_heads"]
+    if tp <= 1:
+        return dict(d, heads_local=heads, kv_heads_local=kvh,
+                    kv_heads_pool=kvh, rep=1)
+    refuses = KINDS[kind].refuses
+    if "tp" in refuses:
+        raise ValueError(refuses["tp"])
+    if heads % tp:
+        raise ValueError(
+            f"tp={tp} does not divide num_attention_heads={heads}")
+    if kvh % tp == 0:
+        rep = 1
+    elif tp % kvh == 0:
+        rep = tp // kvh
+    else:
+        raise ValueError(
+            f"tp={tp} vs kv_heads={kvh}: need tp | kv_heads (shard) or "
+            f"kv_heads | tp (replicate below tp)")
+    return dict(d, heads_local=heads // tp,
+                kv_heads_local=max(kvh // tp, 1),
+                kv_heads_pool=kvh * rep, rep=rep)
+
+
+def check_supported(kind: str, cfg) -> None:
+    _kind(kind).check(cfg)
+
+
 def fused_layer_params(kind: str, cfg, params):
     """The per-layer weights re-laid-out for the fused-block decode
     kernel (ISSUE 15): matmul-ready ``[in, out]`` arrays with q/k/v
-    split into head-major planes, built ONCE at engine construction so
-    no transpose/gather ever runs inside the decode step.
+    split into head-major planes (``Fused.layout``), built ONCE at
+    engine construction so no transpose/gather ever runs inside the
+    decode step.
 
-    GPT's interleaved ``query_key_value`` columns (per head:
-    ``[q(d), k(d), v(d)]``) deinterleave into ``wq``/``wk``/``wv``;
-    LLaMA's packed ``kv_proj`` splits the same way.  The layout is a
-    one-time device-side copy of the layer weights — the engine then
-    holds BOTH layouts (prefill keeps the original tree), a deliberate
-    HBM-for-latency trade the README documents next to the knob.
-    """
-    p = _params_subtree(params)
-    dims = model_dims(kind, cfg)
-    heads, kvh, d = dims["heads"], dims["kv_heads"], dims["head_dim"]
-    hidden = cfg.hidden_size
-    out = []
-    for i in range(cfg.num_layers):
-        lp = p[f"layer_{i}"]
-        if kind == "gpt":
-            att = lp["self_attention"]
-            w = jnp.transpose(att["query_key_value"]["weight"])
-            w = w.reshape(hidden, heads, 3, d)
-            b = _fused_bias(att["query_key_value"],
-                            3 * heads * d).reshape(heads, 3, d)
-            blk = {
-                "ln1_w": lp["input_layernorm"]["weight"].reshape(
-                    1, hidden),
-                "ln1_b": lp["input_layernorm"]["bias"].reshape(1, hidden),
-                "wq": w[:, :, 0, :].reshape(hidden, heads * d),
-                "bq": b[:, 0, :].reshape(1, heads * d),
-                "wk": w[:, :, 1, :].reshape(hidden, heads * d),
-                "bk": b[:, 1, :].reshape(1, heads * d),
-                "wv": w[:, :, 2, :].reshape(hidden, heads * d),
-                "bv": b[:, 2, :].reshape(1, heads * d),
-                "wo": jnp.transpose(att["dense"]["weight"]),
-                "bo": _fused_bias(att["dense"], hidden),
-                "ln2_w": lp["post_attention_layernorm"][
-                    "weight"].reshape(1, hidden),
-                "ln2_b": lp["post_attention_layernorm"][
-                    "bias"].reshape(1, hidden),
-                "wu": jnp.transpose(lp["mlp"]["dense_h_to_4h"]["weight"]),
-                "bu": _fused_bias(lp["mlp"]["dense_h_to_4h"], cfg.ffn),
-                "wd": jnp.transpose(lp["mlp"]["dense_4h_to_h"]["weight"]),
-                "bd": _fused_bias(lp["mlp"]["dense_4h_to_h"], hidden),
-            }
-        else:
-            att = lp["attention"]
-            kvw = jnp.transpose(att["kv_proj"]["weight"])
-            # kv-head count from the WEIGHT, not the config: a
-            # kv-expanded tree (expand_kv_for_tp) carries kvh*rep heads
-            kvh_w = kvw.shape[1] // (2 * d)
-            kvw = kvw.reshape(hidden, kvh_w, 2, d)
-            blk = {
-                "ln1_w": lp["input_norm"]["weight"].reshape(1, hidden),
-                "wq": jnp.transpose(att["q_proj"]["weight"]),
-                "wk": kvw[:, :, 0, :].reshape(hidden, kvh_w * d),
-                "wv": kvw[:, :, 1, :].reshape(hidden, kvh_w * d),
-                "wo": jnp.transpose(att["o_proj"]["weight"]),
-                "ln2_w": lp["post_attention_norm"]["weight"].reshape(
-                    1, hidden),
-                "wg": jnp.transpose(lp["mlp"]["gate_proj"]["weight"]),
-                "wu": jnp.transpose(lp["mlp"]["up_proj"]["weight"]),
-                "wd": jnp.transpose(lp["mlp"]["down_proj"]["weight"]),
-            }
-        out.append(blk)
-    return out
+    The layout is a one-time device-side copy of the layer weights —
+    the engine then holds BOTH layouts (prefill keeps the original
+    tree), a deliberate HBM-for-latency trade the README documents next
+    to the knob."""
+    rec = _kind(kind)
+    if rec.fused is None:
+        raise ValueError(rec.refuses["fused"])
+    p, dims = _params_subtree(params), rec.dims(cfg)
+    return [rec.fused.layout(cfg, dims, p[f"layer_{i}"])
+            for i in range(cfg.num_layers)]
 
 
 # --------------------------------------------------------------------------
@@ -478,46 +690,29 @@ def fused_partition_specs(fused_layers, tp: int):
     return [one(b) for b in fused_layers]
 
 
-def _fused_block_tail_tp(kind: str, blk, x, part, eps):
-    """Finish one fused block OUTSIDE the kernel under tp: psum the
-    rank-partial attention output at the row boundary (the out-proj
-    psum the ISSUE moves out of the kernel), add the out-proj bias
-    once, then norm2 + the column/row-parallel MLP with its own
-    row-boundary psum — the same two-psums-per-layer the unfused
-    sharded path pays."""
-    attn = jax.lax.psum(part, TENSOR_AXIS)
-    if kind == "gpt":
-        x2 = x + attn + blk["bo"]
-        h2 = layer_norm(x2, blk["ln2_w"].reshape(-1),
-                        blk["ln2_b"].reshape(-1))
-        u = jax.nn.gelu(jnp.matmul(h2, blk["wu"]) + blk["bu"])
-        y = jax.lax.psum(jnp.matmul(u, blk["wd"]), TENSOR_AXIS)
-        y = y + blk["bd"]
-    else:
-        x2 = x + attn
-        h2 = rms_norm(x2, blk["ln2_w"].reshape(-1), eps=eps)
-        u = jax.nn.silu(jnp.matmul(h2, blk["wg"])) * jnp.matmul(
-            h2, blk["wu"])
-        y = jax.lax.psum(jnp.matmul(u, blk["wd"]), TENSOR_AXIS)
-    return x2 + y
-
-
 # --------------------------------------------------------------------------
-# GPT (standalone_gpt mirror)
+# what the loops share
 # --------------------------------------------------------------------------
 
-def _gpt_attn_proj(lp, h, heads, head_dim):
-    """qkv projection + the model's reshape/split layout: returns
-    q/k/v with a trailing ``[..., heads, head_dim]``."""
-    qkv = _linear(lp["self_attention"]["query_key_value"], h)
-    qkv = qkv.reshape(*h.shape[:-1], heads, 3 * head_dim)
-    return jnp.split(qkv, 3, axis=-1)
+def _cache_layers(layer_types):
+    """Per layer ``(pooled, n)``: does the paged pool keep it (its type
+    is ``FULL``) or a window ring, and which of the pool's — or the
+    rings' — layers it is."""
+    out, pool, rings = [], 0, 0
+    for t in layer_types:
+        if t == FULL:
+            out.append((True, pool))
+            pool += 1
+        else:
+            out.append((False, rings))
+            rings += 1
+    return out
 
 
-def _gpt_mlp(lp, h, tp=1):
-    return _row_linear(lp["mlp"]["dense_4h_to_h"],
-                       jax.nn.gelu(_linear(lp["mlp"]["dense_h_to_4h"],
-                                           h)), tp)
+def _expand_kv(t, heads: int):
+    """``[b, kvh, s, d]`` -> ``[b, heads, s, d]`` (GQA: share kv across
+    the group); the array itself where every head has its own."""
+    return t if heads == t.shape[1] else laguna.expand_kv(t, heads)
 
 
 def _last_row(h, length):
@@ -530,350 +725,78 @@ def _last_row(h, length):
                                         keepdims=False)       # [b, hid]
 
 
-def _gpt_prefill(cfg, params, tokens, length=None, cache=None, row=None,
-                 start=None, tp=1):
-    p = _params_subtree(params)
-    b, s = tokens.shape
-    dims = model_dims("gpt", cfg)
-    heads, head_dim = dims["heads"] // tp, dims["head_dim"]
-    suffix = cache is not None          # static: suffix-prefill variant
+def _suffix_attend(cache, layer: int, row, q, k, v, start):
+    """Prefill attention for a (possibly mid-prompt) token slab: cold
+    (``start == 0``) it is EXACTLY the causal flash path the original
+    prefill ran — bitwise, so cold prefills and the dense-parity tests
+    are untouched; warm (``start > 0``, a prefix-cache hit or a later
+    chunk of a chunked prefill) each row additionally attends to the
+    already-cached prefix, gathered from the slot's KV pages through
+    ``row`` (:func:`~apex_tpu.ops.attention.prefix_window_attention`).
 
-    emb_w = p["embedding"]["word_embeddings"]["weight"]
-    h = _vocab_embed(emb_w, tokens, tp)                     # [b, s, h]
-    pos_tab = p["embedding"]["position_embeddings"]
-    if suffix:
-        # rows sit at absolute positions start + i (clamped: dead
-        # bucket-padding rows past the table stay in range)
-        positions = jnp.minimum(
-            jnp.asarray(start, jnp.int32)
-            + jnp.arange(s, dtype=jnp.int32),
-            jnp.int32(pos_tab.shape[0] - 1))
-        h = h + jnp.take(pos_tab, positions, axis=0)[None]
-    else:
-        h = h + pos_tab[None, :s, :]
-    h = h.transpose(1, 0, 2)                                # [s, b, h]
+    ``q``: ``[b, h, s, d]``; ``k``/``v``: pre-broadcast
+    ``[b, kv_heads, s, d]``.  One ``lax.cond`` keeps both paths inside
+    the ONE compiled prefill executable per bucket — the runtime
+    executes only the taken branch, so cold prefills never pay the
+    window gather."""
+    h, (_, kvh, _, d) = q.shape[1], k.shape
 
-    ks, vs = [], []
-    for i in range(cfg.num_layers):
-        lp = p[f"layer_{i}"]
-        x = h
-        h1 = layer_norm(x, lp["input_layernorm"]["weight"],
-                        lp["input_layernorm"]["bias"])
-        q, k, v = _gpt_attn_proj(lp, h1, heads, head_dim)   # [s, b, n, d]
-        q, k, v = (t.transpose(1, 2, 0, 3) for t in (q, k, v))
-        ks.append(k[0])                                     # [n, s, d]
-        vs.append(v[0])
-        if suffix:
-            ctx = _suffix_attend(cache, i, row, q, k, v, start)
-        else:
-            ctx = flash_attention(q, k, v, causal=True)
-        ctx = ctx.transpose(2, 0, 1, 3).reshape(s, b, -1)
-        x = x + _row_linear(lp["self_attention"]["dense"], ctx, tp)
-        h2 = layer_norm(x, lp["post_attention_layernorm"]["weight"],
-                        lp["post_attention_layernorm"]["bias"])
-        h = x + _gpt_mlp(lp, h2, tp)
+    def cold(q, k, v, pk, pv):
+        return flash_attention(q, _expand_kv(k, h), _expand_kv(v, h),
+                               causal=True)
 
-    h = layer_norm(h, p["final_layernorm"]["weight"],
-                   p["final_layernorm"]["bias"])
-    if length is not None:
-        last = length - start if suffix else length   # local slab index
-        logits = jnp.einsum("bh,vh->bv", _last_row(h, last), emb_w)
-    else:
-        logits = jnp.einsum("sbh,vh->sbv", h, emb_w)        # tied head
-    return _gather_logits(logits, tp), jnp.stack(ks), jnp.stack(vs)
+    def warm(q, k, v, pk, pv):
+        # pk/pv: the WHOLE pool [pages, layers, kvh, ps, d] -> the
+        # slot's virtual window [b, kvh, max_seq, d] of this layer in
+        # row order; unowned ordinals gather the trash page — finite
+        # garbage masked by start
+        def window(p):
+            w = p[row, layer]                     # [mpps, kvh, ps, d]
+            return w.transpose(1, 0, 2, 3).reshape(
+                1, kvh, -1, d).astype(q.dtype)
+        return prefix_window_attention(q, k, v, window(pk), window(pv),
+                                       start)
+
+    # the pool goes into the cond whole and only the slot's own pages
+    # are gathered inside: a per-layer slice as the operand is
+    # materialized — one pool-sized temporary per layer, 9 GB of them
+    # for a 24 GiB pool over tp=4 (PERF.md "Bring-up, PR 21")
+    return jax.lax.cond(start > 0, warm, cold, q, k, v, cache.k, cache.v)
 
 
-def _gpt_decode(cfg, params, cache, tokens, fused=None, tp=1):
-    p = _params_subtree(params)
-    dims = model_dims("gpt", cfg)
-    heads, head_dim = dims["heads"] // tp, dims["head_dim"]
-    positions = cache.lengths                               # [slots]
-
-    emb_w = p["embedding"]["word_embeddings"]["weight"]
-    h = _vocab_embed(emb_w, tokens, tp)                     # [slots, h]
-    h = h + jnp.take(p["embedding"]["position_embeddings"],
-                     positions, axis=0)
-
-    live = positions + 1                    # incl. the token written now
-    for i in range(cfg.num_layers):
-        if fused is not None:
-            if tp > 1:
-                # sharded fused block (ISSUE 17): the kernel runs on
-                # the 1/tp weight shard and emits the RANK-PARTIAL
-                # out-proj product (no residual, no bias) — the row
-                # psum + bias + norm2 + col/row MLP finish outside
-                part, k_tok, v_tok = fused_block_decode(
-                    h, fused[i], cache.k[:, i], cache.v[:, i],
-                    cache.page_table, positions, kind="gpt", eps=1e-5,
-                    fuse_mlp=False, partial_out=True)
-                cache = kv_cache.append_layer(cache, i, k_tok, v_tok)
-                h = _fused_block_tail_tp("gpt", fused[i], h, part, 1e-5)
-                continue
-            # ISSUE 15: the whole block in ONE kernel (norm1 -> qkv ->
-            # paged attention incl. this token -> out proj -> norm2 ->
-            # MLP); only the pool append leaves the per-op path
-            h, k_tok, v_tok = fused_block_decode(
-                h, fused[i], cache.k[:, i], cache.v[:, i],
-                cache.page_table, positions, kind="gpt", eps=1e-5)
-            cache = kv_cache.append_layer(cache, i, k_tok, v_tok)
-            continue
-        lp = p[f"layer_{i}"]
-        x = h
-        h1 = layer_norm(x, lp["input_layernorm"]["weight"],
-                        lp["input_layernorm"]["bias"])
-        q, k_tok, v_tok = _gpt_attn_proj(lp, h1, heads, head_dim)
-        cache = kv_cache.append_layer(cache, i, k_tok, v_tok)
-        ctx = _cache_attend(cache, i, q, live)
-        x = x + _row_linear(lp["self_attention"]["dense"],
-                            ctx.reshape(ctx.shape[0], -1), tp)
-        h2 = layer_norm(x, lp["post_attention_layernorm"]["weight"],
-                        lp["post_attention_layernorm"]["bias"])
-        h = x + _gpt_mlp(lp, h2, tp)
-
-    h = layer_norm(h, p["final_layernorm"]["weight"],
-                   p["final_layernorm"]["bias"])
-    logits = jnp.einsum("bh,vh->bv", h, emb_w)
-    return _gather_logits(logits, tp), cache
+def _slab_attend(cache, layer: int, q, lengths):
+    """Verify-slab attention against ONE layer of whichever cache
+    layout the engine runs: the dense slot window scored directly
+    (:func:`~apex_tpu.ops.attention.slab_decode_attention`) or the
+    paged pool gathered through the slot page table
+    (:func:`~apex_tpu.ops.paged_attention.paged_slab_attention`).
+    ``lengths`` is the live count BEFORE the slab was appended (the
+    causal offset)."""
+    if isinstance(cache, kv_cache.PagedKVCache):
+        return paged_slab_attention(q, cache.k[:, layer],
+                                    cache.v[:, layer], cache.page_table,
+                                    lengths)
+    return slab_decode_attention(q, cache.k[:, layer], cache.v[:, layer],
+                                 lengths)
 
 
-def _gpt_verify(cfg, params, cache, tokens, tp=1):
-    """Speculative verify (ISSUE 15): score an ``S``-token drafted slab
-    per slot in ONE batched step — logits at EVERY slab position, the
-    slab's k/v appended at ``[lengths, lengths + S)``.  Lengths do not
-    advance here; the verify step advances by the accepted count
-    (:func:`kv_cache.advance_by`) so rejection is a pure length
-    rollback."""
-    p = _params_subtree(params)
-    dims = model_dims("gpt", cfg)
-    heads, head_dim = dims["heads"] // tp, dims["head_dim"]
-    slots, s = tokens.shape
-    base = cache.lengths                                    # [slots]
-    pos = base[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
-
-    emb_w = p["embedding"]["word_embeddings"]["weight"]
-    pos_tab = p["embedding"]["position_embeddings"]
-    h = _vocab_embed(emb_w, tokens, tp)                     # [b, S, hid]
-    h = h + jnp.take(pos_tab,
-                     jnp.minimum(pos, jnp.int32(pos_tab.shape[0] - 1)),
-                     axis=0)
-
-    for i in range(cfg.num_layers):
-        lp = p[f"layer_{i}"]
-        x = h
-        h1 = layer_norm(x, lp["input_layernorm"]["weight"],
-                        lp["input_layernorm"]["bias"])
-        q, k, v = _gpt_attn_proj(lp, h1, heads, head_dim)   # [b,S,n,d]
-        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        cache = kv_cache.append_slab(cache, i, k, v)
-        ctx = _slab_attend(cache, i, q, base)               # [b,h,S,d]
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(slots, s, -1)
-        x = x + _row_linear(lp["self_attention"]["dense"], ctx, tp)
-        h2 = layer_norm(x, lp["post_attention_layernorm"]["weight"],
-                        lp["post_attention_layernorm"]["bias"])
-        h = x + _gpt_mlp(lp, h2, tp)
-
-    h = layer_norm(h, p["final_layernorm"]["weight"],
-                   p["final_layernorm"]["bias"])
-    logits = jnp.einsum("bsh,vh->bsv", h, emb_w)
-    return _gather_logits(logits, tp), cache
+def _cache_attend(cache, layer: int, q, live):
+    """Single-token attention against ONE layer of whichever cache
+    layout the engine runs: the dense slot window
+    (:func:`~apex_tpu.ops.attention.decode_attention`) or the paged
+    pool, handed to the kernel WHOLE and threaded through the slot page
+    table (:func:`~apex_tpu.ops.paged_attention.paged_decode_attention`).
+    Both score the pre-broadcast per-kv-head cache (GQA/MQA grouped)."""
+    if isinstance(cache, kv_cache.PagedKVCache):
+        return paged_decode_attention(q, cache.k, cache.v,
+                                      cache.page_table, live, layer=layer)
+    return decode_attention(q, cache.k[:, layer], cache.v[:, layer], live)
 
 
-# --------------------------------------------------------------------------
-# LLaMA (standalone_llama mirror; GQA/MQA cached once per kv head)
-# --------------------------------------------------------------------------
-
-def _llama_rope_table(cfg, head_dim, max_seq):
-    """Flat ``[max_seq, head_dim]`` cos/sin tables (the model's
-    ``_rope_cos_sin`` values, position-indexable for decode)."""
-    cos, sin = _rope_cos_sin(max_seq, head_dim, cfg.rope_theta)
-    return cos.reshape(max_seq, head_dim), sin.reshape(max_seq, head_dim)
-
-
-def _llama_proj(lp, h, cfg, heads, kv_heads, head_dim):
-    q = _linear(lp["attention"]["q_proj"], h)
-    kv = _linear(lp["attention"]["kv_proj"], h)
-    q = q.reshape(*h.shape[:-1], heads, head_dim)
-    k, v = jnp.split(kv.reshape(*h.shape[:-1], kv_heads, 2 * head_dim),
-                     2, axis=-1)
-    return q, k, v
-
-
-def _llama_mlp(lp, h, tp=1):
-    gate = _linear(lp["mlp"]["gate_proj"], h)
-    up = _linear(lp["mlp"]["up_proj"], h)
-    return _row_linear(lp["mlp"]["down_proj"],
-                       jax.nn.silu(gate) * up, tp)
-
-
-def _llama_prefill(cfg, params, tokens, length=None, cache=None,
-                   row=None, start=None, tp=1):
-    p = _params_subtree(params)
-    b, s = tokens.shape
-    dims = tp_dims("llama", cfg, tp)
-    heads, kv_heads = dims["heads_local"], dims["kv_heads_local"]
-    head_dim, group = dims["head_dim"], (dims["heads_local"]
-                                         // dims["kv_heads_local"])
-    suffix = cache is not None          # static: suffix-prefill variant
-
-    h = _vocab_embed(p["embed_tokens"]["weight"], tokens, tp)
-    h = h.transpose(1, 0, 2)                                # [s, b, h]
-    if suffix:
-        # RoPE at the slab's absolute positions start + i (clamped for
-        # dead bucket-padding rows), indexed from the full-window table
-        cos_t, sin_t = _rope_cos_sin(cache.max_seq, head_dim,
-                                     cfg.rope_theta)  # [max_seq, 1, 1, d]
-        positions = jnp.minimum(
-            jnp.asarray(start, jnp.int32)
-            + jnp.arange(s, dtype=jnp.int32),
-            jnp.int32(cache.max_seq - 1))
-        cos = jnp.take(cos_t, positions, axis=0)            # [s, 1, 1, d]
-        sin = jnp.take(sin_t, positions, axis=0)
-    else:
-        cos, sin = _rope_cos_sin(s, head_dim, cfg.rope_theta)
-
-    ks, vs = [], []
-    for i in range(cfg.num_layers):
-        lp = p[f"layer_{i}"]
-        x = h
-        h1 = rms_norm(x, lp["input_norm"]["weight"], eps=cfg.rms_eps)
-        q, k, v = _llama_proj(lp, h1, cfg, heads, kv_heads, head_dim)
-        q = fused_apply_rotary_pos_emb_cached(q, cos, sin)
-        k = fused_apply_rotary_pos_emb_cached(k, cos, sin)
-        # cache the PRE-broadcast kv (once per kv head)
-        ks.append(k.transpose(1, 2, 0, 3)[0])               # [kv, s, d]
-        vs.append(v.transpose(1, 2, 0, 3)[0])
-        if suffix:
-            qb, kb, vb = (t.transpose(1, 2, 0, 3) for t in (q, k, v))
-            ctx = _suffix_attend(cache, i, row, qb, kb, vb, start)
-        else:
-            if group > 1:               # GQA: share kv across the group
-                k, v = (jnp.broadcast_to(
-                    t[:, :, :, None, :],
-                    (s, b, kv_heads, group, head_dim)
-                ).reshape(s, b, heads, head_dim) for t in (k, v))
-            q, k, v = (t.transpose(1, 2, 0, 3) for t in (q, k, v))
-            ctx = flash_attention(q, k, v, causal=True)
-        ctx = ctx.transpose(2, 0, 1, 3).reshape(s, b, -1)
-        x = x + _row_linear(lp["attention"]["o_proj"], ctx, tp)
-        h1 = rms_norm(x, lp["post_attention_norm"]["weight"],
-                      eps=cfg.rms_eps)
-        h = x + _llama_mlp(lp, h1, tp)
-
-    h = rms_norm(h, p["final_norm"]["weight"], eps=cfg.rms_eps)
-    if length is not None:
-        last = length - start if suffix else length   # local slab index
-        logits = _linear(p["lm_head"], _last_row(h, last))    # [b, v]
-    else:
-        logits = _linear(p["lm_head"], h)                     # [s, b, v]
-    return _gather_logits(logits, tp), jnp.stack(ks), jnp.stack(vs)
-
-
-def _llama_decode(cfg, params, cache, tokens, fused=None, tp=1):
-    p = _params_subtree(params)
-    dims = tp_dims("llama", cfg, tp)
-    heads, kv_heads = dims["heads_local"], dims["kv_heads_local"]
-    head_dim = dims["head_dim"]
-    positions = cache.lengths
-
-    h = _vocab_embed(p["embed_tokens"]["weight"], tokens, tp)
-    cos_t, sin_t = _llama_rope_table(cfg, head_dim, cache.max_seq)
-    cos2 = jnp.take(cos_t, positions, axis=0)               # [slots, d]
-    sin2 = jnp.take(sin_t, positions, axis=0)
-    cos, sin = cos2[:, None, :], sin2[:, None, :]           # [slots, 1, d]
-
-    live = positions + 1
-    for i in range(cfg.num_layers):
-        if fused is not None:
-            if tp > 1:
-                part, k_tok, v_tok = fused_block_decode(
-                    h, fused[i], cache.k[:, i], cache.v[:, i],
-                    cache.page_table, positions, kind="llama",
-                    eps=cfg.rms_eps, cos=cos2, sin=sin2,
-                    fuse_mlp=False, partial_out=True)
-                cache = kv_cache.append_layer(cache, i, k_tok, v_tok)
-                h = _fused_block_tail_tp("llama", fused[i], h, part,
-                                         cfg.rms_eps)
-                continue
-            h, k_tok, v_tok = fused_block_decode(
-                h, fused[i], cache.k[:, i], cache.v[:, i],
-                cache.page_table, positions, kind="llama",
-                eps=cfg.rms_eps, cos=cos2, sin=sin2)
-            cache = kv_cache.append_layer(cache, i, k_tok, v_tok)
-            continue
-        lp = p[f"layer_{i}"]
-        x = h
-        h1 = rms_norm(x, lp["input_norm"]["weight"], eps=cfg.rms_eps)
-        q, k_tok, v_tok = _llama_proj(lp, h1, cfg, heads, kv_heads,
-                                      head_dim)
-        q = fused_apply_rotary_pos_emb_cached(q, cos, sin)
-        k_tok = fused_apply_rotary_pos_emb_cached(k_tok, cos, sin)
-        cache = kv_cache.append_layer(cache, i, k_tok, v_tok)
-        # grouped-query scoring straight off the per-kv-head cache/pool
-        ctx = _cache_attend(cache, i, q, live)
-        x = x + _row_linear(lp["attention"]["o_proj"],
-                            ctx.reshape(ctx.shape[0], -1), tp)
-        h1 = rms_norm(x, lp["post_attention_norm"]["weight"],
-                      eps=cfg.rms_eps)
-        h = x + _llama_mlp(lp, h1, tp)
-
-    h = rms_norm(h, p["final_norm"]["weight"], eps=cfg.rms_eps)
-    logits = _linear(p["lm_head"], h)                       # [slots, v]
-    return _gather_logits(logits, tp), cache
-
-
-def _llama_verify(cfg, params, cache, tokens, tp=1):
-    """LLaMA twin of :func:`_gpt_verify`: RoPE at each slab row's
-    absolute position, GQA/MQA slab scoring straight off the
-    per-kv-head cache/pool."""
-    p = _params_subtree(params)
-    dims = tp_dims("llama", cfg, tp)
-    heads, kv_heads = dims["heads_local"], dims["kv_heads_local"]
-    head_dim = dims["head_dim"]
-    slots, s = tokens.shape
-    base = cache.lengths
-    pos = base[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
-    pos = jnp.minimum(pos, jnp.int32(cache.max_seq - 1))
-
-    h = _vocab_embed(p["embed_tokens"]["weight"], tokens, tp)
-    cos_t, sin_t = _llama_rope_table(cfg, head_dim, cache.max_seq)
-    cos = jnp.take(cos_t, pos, axis=0)[:, :, None, :]     # [b, S, 1, d]
-    sin = jnp.take(sin_t, pos, axis=0)[:, :, None, :]
-
-    for i in range(cfg.num_layers):
-        lp = p[f"layer_{i}"]
-        x = h
-        h1 = rms_norm(x, lp["input_norm"]["weight"], eps=cfg.rms_eps)
-        q, k, v = _llama_proj(lp, h1, cfg, heads, kv_heads, head_dim)
-        q = fused_apply_rotary_pos_emb_cached(q, cos, sin)
-        k = fused_apply_rotary_pos_emb_cached(k, cos, sin)
-        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        cache = kv_cache.append_slab(cache, i, k, v)
-        ctx = _slab_attend(cache, i, q, base)               # [b,h,S,d]
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(slots, s, -1)
-        x = x + _row_linear(lp["attention"]["o_proj"], ctx, tp)
-        h1 = rms_norm(x, lp["post_attention_norm"]["weight"],
-                      eps=cfg.rms_eps)
-        h = x + _llama_mlp(lp, h1, tp)
-
-    h = rms_norm(h, p["final_norm"]["weight"], eps=cfg.rms_eps)
-    logits = _linear(p["lm_head"], h)                     # [b, S, v]
-    return _gather_logits(logits, tp), cache
-
-
-# --------------------------------------------------------------------------
-# Laguna (standalone_laguna's per-layer pieces; ISSUE 30): a head count
-# per layer, window layers in per-slot rings beside full layers in the
-# paged pool, an expert FFN that drops no token
-# --------------------------------------------------------------------------
-
-#: what a laguna step reports beside its tokens, in this order — the
-#: int32 tail of the token read (``InferenceEngine.stats_tail`` long)
-LAGUNA_STATS = ("moe_assignments", "moe_experts_hit",
-                "moe_expert_load_max", "window_pages_live")
-
-
-def laguna_stats_tail(acc, cache):
-    """The step's counters as ``int32[4]`` in ``LAGUNA_STATS`` order."""
+def stats_tail(acc, cache):
+    """A step's counters as ``int32[4]`` in ``LAGUNA_STATS`` order: the
+    expert counters the loop folded over its layers (``acc``), and the
+    ring pages live in ``cache`` once the step has updated it."""
     zero = jnp.int32(0)
     acc = acc or {"assignments": zero, "experts_hit": zero,
                   "load_max": zero}
@@ -882,81 +805,27 @@ def laguna_stats_tail(acc, cache):
                       ]).astype(jnp.int32)
 
 
-def _laguna_prefill(cfg, params, tokens, length=None):
-    """``tokens [1, s]`` -> ``(logits, ks, vs, wks, wvs, stats)``: the
-    full layers' k/v ``[full_layers, kvh, s, d]`` for the paged pool, the
-    window layers' for the rings.  Rows at or past ``length`` are bucket
-    padding: they are routed to no expert."""
-    p = _params_subtree(params)
-    valid = None if length is None else (
-        jnp.arange(tokens.shape[1], dtype=jnp.int32) < length)
-    x, kv, stats = laguna.forward_hidden(cfg, p, tokens, valid=valid)
-    x = x.transpose(1, 0, 2)                                # [s, 1, h]
-    logits = _linear(p["lm_head"],
-                     x if length is None else _last_row(x, length))
-
-    def stack(layers, which):
-        return jnp.stack([kv[i][which][0] for i in layers]) \
-            if layers else None
-
-    return (logits, stack(cfg.full_layers, 0), stack(cfg.full_layers, 1),
-            stack(cfg.window_layers, 0), stack(cfg.window_layers, 1), stats)
-
-
-def _laguna_decode(cfg, params, cache, tokens, active=None):
-    """One token per slot against the two pools -> ``(logits, cache,
-    stats)``.  ``active [slots]`` marks the slots that carry a request:
-    the others are routed to no expert (they would otherwise read
-    experts nobody asked for)."""
-    p = _params_subtree(params)
-    positions = cache.lengths                               # [slots]
-    live = positions + 1
-    x = jnp.take(p["embed_tokens"]["weight"], tokens, axis=0)
-    rope = {t: tuple(c[:, None, :]
-                     for c in laguna.rope_cos_sin(cfg, t, positions))
-            for t in set(cfg.layer_types)}
-    full_of = {i: n for n, i in enumerate(cfg.full_layers)}
-    ring_of = {i: n for n, i in enumerate(cfg.window_layers)}
-    stats = None
-    for i in range(cfg.num_layers):
-        lp, kind_i = p[f"layer_{i}"], cfg.layer_types[i]
-        h1 = rms_norm(x, lp["input_norm"]["weight"], eps=cfg.rms_eps)
-        q, k_tok, v_tok, g = laguna.attn_project(cfg, i, lp, h1,
-                                                 *rope[kind_i])
-        if kind_i == laguna.FULL:
-            n = full_of[i]
-            cache = kv_cache.append_layer(cache, n, k_tok, v_tok)
-            ctx = paged_decode_attention(q, cache.k, cache.v,
-                                         cache.page_table, live, layer=n)
-        else:
-            n = ring_of[i]
-            cache = kv_cache.append_window(cache, n, k_tok, v_tok)
-            ctx = ring_decode_attention(
-                q, cache.wk[n], cache.wv[n], positions,
-                window=cfg.sliding_window)
-        x = x + laguna.attn_output(lp, ctx, g)
-        h2 = rms_norm(x, lp["post_attention_norm"]["weight"],
-                      eps=cfg.rms_eps)
-        y, st = laguna.ffn(cfg, i, lp, h2, valid=active)
-        stats = fold_stats(stats, st)
-        x = x + y
-    x = rms_norm(x, p["final_norm"]["weight"], eps=cfg.rms_eps)
-    return _linear(p["lm_head"], x), cache, stats
+laguna_stats_tail = stats_tail          # the name ISSUE 30 gave it
 
 
 # --------------------------------------------------------------------------
-# dispatch
+# the three layer loops
 # --------------------------------------------------------------------------
 
 def prefill_forward(kind: str, cfg, params, tokens, length=None, *,
                     cache=None, row=None, prefill_from=None, tp=1):
-    """Full-prompt forward: ``tokens [1, s]`` -> ``(logits, k_stack,
-    v_stack)`` with k/v ``[layers, kv_heads, s, head_dim]`` ready for
-    :func:`kv_cache.insert`.
+    """Full-prompt forward: ``tokens [1, s]`` -> ``(logits, ks, vs, wks,
+    wvs, stats)``.  ``ks`` / ``vs`` ``[pool_layers, kv_heads, s,
+    head_dim]`` are the pool layers' k/v, ready for
+    :func:`kv_cache.insert` / ``insert_tokens``; ``wks`` / ``wvs`` the
+    window layers', for ``insert_window`` (None for a kind without
+    them); ``stats`` the expert counters folded over the layers (None
+    without an expert FFN).
 
     With ``length`` (the real prompt length inside a bucket-padded
     ``s``, traced OK) the lm head runs on ONLY the last real position —
-    ``logits [1, v]``; without it every position is projected
+    ``logits [1, v]`` — and the rows at or past it, bucket padding, are
+    routed to no expert; without it every position is projected
     (``logits [s, 1, v]``, the full-forward shape parity tests pin).
 
     Suffix mode (ISSUE 12 — paged engines only): with ``cache`` (the
@@ -969,63 +838,170 @@ def prefill_forward(kind: str, cfg, params, tokens, length=None, *,
     and ``length`` is the TOTAL live length (prefix + real suffix).
     ``prefill_from == 0`` reproduces the cold path bitwise — one
     compiled executable per bucket serves cold prefills, prefix-cache
-    hits, and chunked-prefill continuation chunks alike."""
+    hits, and chunked-prefill continuation chunks alike.  Refused for a
+    kind whose record refuses ``prefix_sharing`` (a window ring cannot
+    be shared or resumed)."""
     if tokens.ndim != 2 or tokens.shape[0] != 1:
         raise ValueError(
             f"prefill takes one prompt [1, s], got {tuple(tokens.shape)}")
-    if kind == "laguna":
-        # -> (logits, ks, vs, wks, wvs, stats): the pool's layers, the
-        # rings' layers, the expert counters.  No suffix mode: a window
-        # ring cannot be shared or resumed (the engine refuses both)
-        return _laguna_prefill(cfg, params, tokens, length)
-    fn = _gpt_prefill if kind == "gpt" else _llama_prefill
-    if cache is None:
-        return fn(cfg, params, tokens, length, tp=tp)
-    if row is None or prefill_from is None or length is None:
+    rec, p = _kind(kind), _params_subtree(params)
+    suffix = cache is not None          # static: suffix-prefill variant
+    if suffix and "prefix_sharing" in rec.refuses:
+        raise ValueError(rec.refuses["prefix_sharing"])
+    if suffix and (row is None or prefill_from is None or length is None):
         raise ValueError(
             "suffix prefill needs cache, row, prefill_from AND length")
-    return fn(cfg, params, tokens, length, cache=cache, row=row,
-              start=prefill_from, tp=tp)
+    dims = tp_dims(kind, cfg, tp)
+    s = tokens.shape[1]
+    positions = None
+    if suffix:
+        # rows sit at absolute positions prefill_from + i (clamped: dead
+        # bucket-padding rows past the cache's window stay in range)
+        positions = jnp.minimum(
+            jnp.asarray(prefill_from, jnp.int32)
+            + jnp.arange(s, dtype=jnp.int32),
+            jnp.int32(cache.max_seq - 1))
+    h = rec.embed(p, tokens, positions, tp).transpose(1, 0, 2)  # [s, b, h]
+    rope = {t: tuple(c[:, None, None, :] for c in cs)       # [s, 1, 1, r]
+            for t, cs in rec.rope(cfg, dims, positions,
+                                  cache.max_seq if suffix else s).items()}
+    # the rows that carry a token, for a kind whose FFN routes (it is the
+    # one that reports stats): bucket padding goes to no expert
+    valid = None if length is None or not rec.stats else (
+        jnp.arange(s, dtype=jnp.int32) < length)
+
+    ks, vs, wks, wvs, stats = [], [], [], [], None
+    for i, (pooled, n) in enumerate(_cache_layers(dims["layer_types"])):
+        lp = p[f"layer_{i}"]
+        q, k, v, extra = rec.project(
+            cfg, dims, i, lp, rec.norm(cfg, lp, "input", h),
+            rope.get(dims["layer_types"][i]))               # [s, b, n, d]
+        q, k, v = (t.transpose(1, 2, 0, 3) for t in (q, k, v))
+        # cache the PRE-broadcast kv (once per kv head)
+        (ks if pooled else wks).append(k[0])                # [kv, s, d]
+        (vs if pooled else wvs).append(v[0])
+        if suffix:
+            ctx = _suffix_attend(cache, n, row, q, k, v, prefill_from)
+        else:
+            heads = q.shape[1]
+            ctx = flash_attention(
+                q, _expand_kv(k, heads), _expand_kv(v, heads), causal=True,
+                window=None if pooled else dims["window"])
+        x = h + rec.attn_out(lp, ctx.transpose(2, 0, 1, 3), extra, tp)
+        y, st = rec.ffn(cfg, i, lp, rec.norm(cfg, lp, "post_attention", x),
+                        valid, tp)
+        stats = fold_stats(stats, st)
+        h = x + y
+
+    h = rec.norm(cfg, p, "final", h)
+    if length is not None:      # the slab's own index of the last real row
+        h = _last_row(h, length - prefill_from if suffix else length)
+    logits = _gather_logits(rec.head(p, h, tp), tp)
+    return (logits, *(jnp.stack(x) if x else None
+                      for x in (ks, vs, wks, wvs)), stats)
 
 
 def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
                    tp=1, active=None):
     """One-token step for every slot: ``tokens [slots]`` ->
-    ``(logits [slots, v], cache)`` with the new k/v appended at each
-    slot's position.  Lengths do not advance here (the engine advances
-    active slots once per step).
+    ``(logits [slots, v], cache, stats)`` with the new k/v appended at
+    each slot's position — a pool layer's through the page table, a
+    window layer's at its ring row.  Lengths do not advance here (the
+    engine advances active slots once per step).  ``stats`` are the
+    expert counters folded over the layers (None without an expert
+    FFN); ``active [slots]`` marks the slots that carry a request, the
+    only ones such an FFN routes.
 
     ``fused`` (ISSUE 15) is the per-layer fused weight layout from
     :func:`fused_layer_params`: when present (paged engines under
     ``APEX_TPU_DECODE_FUSION``), every transformer block runs as ONE
     Pallas kernel (:func:`~apex_tpu.ops.paged_attention.
-    fused_block_decode`) instead of the per-op XLA sequence — same
-    embed/head, same pool append, same signature, tolerance-level
-    numerics (the in-kernel residual chain stays fp32 where the
-    unfused path rounds to bf16 at each sublayer).
+    fused_block_decode`: norm1 -> qkv -> paged attention incl. this
+    token -> out proj -> norm2 -> MLP; only the pool append leaves it)
+    instead of the per-op XLA sequence — same embed/head, same pool
+    append, same signature, tolerance-level numerics (the in-kernel
+    residual chain stays fp32 where the unfused path rounds to bf16 at
+    each sublayer).  Under ``tp > 1`` (ISSUE 17) the kernel runs on the
+    1/tp weight shard and emits the RANK-PARTIAL out-proj product (no
+    residual, no bias); ``Fused.tail`` finishes the block outside."""
+    rec, p = _kind(kind), _params_subtree(params)
+    dims = tp_dims(kind, cfg, tp)
+    positions = cache.lengths                               # [slots]
+    h = rec.embed(p, tokens, positions, tp)                 # [slots, hid]
+    flat = rec.rope(cfg, dims, positions, cache.max_seq)    # [slots, r]
+    rope = {t: tuple(c[:, None, :] for c in cs) for t, cs in flat.items()}
+    live = positions + 1                    # incl. the token written now
+    cos, sin = flat.get(FULL, (None, None))     # the kernel's: unshaped
+    stats = None
+    for i, (pooled, n) in enumerate(_cache_layers(dims["layer_types"])):
+        if fused is not None:
+            out, k_tok, v_tok = fused_block_decode(
+                h, fused[i], cache.k[:, i], cache.v[:, i],
+                cache.page_table, positions, kind=kind,
+                eps=rec.fused.eps(cfg), cos=cos, sin=sin,
+                **({"fuse_mlp": False, "partial_out": True}
+                   if tp > 1 else {}))
+            cache = kv_cache.append_layer(cache, i, k_tok, v_tok)
+            h = rec.fused.tail(cfg, fused[i], h, out) if tp > 1 else out
+            continue
+        lp = p[f"layer_{i}"]
+        q, k_tok, v_tok, extra = rec.project(
+            cfg, dims, i, lp, rec.norm(cfg, lp, "input", h),
+            rope.get(dims["layer_types"][i]))
+        if pooled:
+            cache = kv_cache.append_layer(cache, n, k_tok, v_tok)
+            # grouped-query scoring straight off the per-kv-head pool
+            ctx = _cache_attend(cache, n, q, live)
+        else:
+            cache = kv_cache.append_window(cache, n, k_tok, v_tok)
+            ctx = ring_decode_attention(q, cache.wk[n], cache.wv[n],
+                                        positions, window=dims["window"])
+        x = h + rec.attn_out(lp, ctx, extra, tp)
+        y, st = rec.ffn(cfg, i, lp, rec.norm(cfg, lp, "post_attention", x),
+                        active, tp)
+        stats = fold_stats(stats, st)
+        h = x + y
 
-    ``laguna`` returns a third value, the step's expert counters, and
-    takes ``active`` (the slots that carry a request)."""
-    if kind == "laguna":
-        return _laguna_decode(cfg, params, cache, tokens, active=active)
-    fn = _gpt_decode if kind == "gpt" else _llama_decode
-    return fn(cfg, params, cache, tokens, fused=fused, tp=tp)
+    h = rec.norm(cfg, p, "final", h)
+    return _gather_logits(rec.head(p, h, tp), tp), cache, stats
 
 
 def verify_forward(kind: str, cfg, params, cache, tokens, tp=1):
     """Speculative-verify step (ISSUE 15): ``tokens [slots, S]`` (the
     last confirmed token followed by ``S - 1`` drafts, per slot) ->
-    ``(logits [slots, S, v], cache)`` with the slab's k/v appended at
-    positions ``[lengths, lengths + S)``.  Lengths do NOT advance —
-    the verify fn advances by the accepted count, which IS the
-    page-table/length rollback (rejected rows go dead-by-mask; pages
-    were already reserved, so rejection releases nothing)."""
+    ``(logits [slots, S, v], cache)`` — logits at EVERY slab position,
+    RoPE at each row's absolute position, the slab's k/v appended at
+    positions ``[lengths, lengths + S)``.  Lengths do NOT advance — the
+    verify fn advances by the accepted count
+    (:func:`kv_cache.advance_by`), which IS the page-table/length
+    rollback (rejected rows go dead-by-mask; pages were already
+    reserved, so rejection releases nothing).  Pool layers only: a kind
+    with window rings refuses ``verify``."""
     if tokens.ndim != 2:
         raise ValueError(
             f"verify takes a [slots, S] slab, got {tuple(tokens.shape)}")
-    if kind == "laguna":
-        raise ValueError(
-            "speculative verify is not built for the 'laguna' kind (a "
-            "rejected slab would have to roll its window rings back)")
-    fn = _gpt_verify if kind == "gpt" else _llama_verify
-    return fn(cfg, params, cache, tokens, tp=tp)
+    rec, p = _kind(kind), _params_subtree(params)
+    if "verify" in rec.refuses:
+        raise ValueError(rec.refuses["verify"])
+    dims = tp_dims(kind, cfg, tp)
+    s = tokens.shape[1]
+    base = cache.lengths                                    # [slots]
+    pos = jnp.minimum(base[:, None] + jnp.arange(s, dtype=jnp.int32)[None],
+                      jnp.int32(cache.max_seq - 1))
+    h = rec.embed(p, tokens, pos, tp)                       # [b, S, hid]
+    rope = {t: tuple(c[:, :, None, :] for c in cs)          # [b, S, 1, r]
+            for t, cs in rec.rope(cfg, dims, pos, cache.max_seq).items()}
+    for i in range(cfg.num_layers):
+        lp = p[f"layer_{i}"]
+        q, k, v, extra = rec.project(
+            cfg, dims, i, lp, rec.norm(cfg, lp, "input", h),
+            rope.get(FULL))                                 # [b, S, n, d]
+        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+        cache = kv_cache.append_slab(cache, i, k, v)
+        ctx = _slab_attend(cache, i, q, base)               # [b, h, S, d]
+        x = h + rec.attn_out(lp, ctx.transpose(0, 2, 1, 3), extra, tp)
+        h = x + rec.ffn(cfg, i, lp, rec.norm(cfg, lp, "post_attention", x),
+                        None, tp)[0]
+
+    h = rec.norm(cfg, p, "final", h)
+    return _gather_logits(rec.head(p, h, tp), tp), cache

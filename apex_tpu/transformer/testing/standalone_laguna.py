@@ -20,8 +20,9 @@ adds to the LLaMA recipe of :mod:`standalone_llama`:
 
 RMSNorm with a learned scale, no bias anywhere, untied head.  The module
 is single-chip (no TP layers): its ``init`` tree is a nested dict that
-the serving forwards in ``inference/models.py`` consume as is, built
-from the SAME per-layer pieces below, so the two cannot drift.
+the serving loops in ``inference/models.py`` consume as is, and the
+``laguna`` record there IS the per-layer pieces below (``attn_project``,
+``attn_output``, ``ffn``, ``rope_cos_sin``), so the two cannot drift.
 """
 from __future__ import annotations
 
@@ -38,8 +39,7 @@ from apex_tpu.ops.attention import flash_attention
 from apex_tpu.transformer.functional.fused_rope import (
     fused_apply_rotary_pos_emb_cached,
 )
-from apex_tpu.transformer.moe.dropless import (dropless_moe_ffn,
-                                               fold_stats, swiglu)
+from apex_tpu.transformer.moe.dropless import dropless_moe_ffn, swiglu
 
 __all__ = ["LagunaConfig", "YarnRope", "LagunaModel",
            "laguna_model_provider", "laguna_param_shapes",
@@ -233,25 +233,20 @@ def expand_kv(t, heads: int):
                             ).reshape(b, heads, s, d)
 
 
-def forward_hidden(cfg: LagunaConfig, p, tokens, valid=None):
-    """The causal stack over ``tokens [b, s]`` -> ``(x, kv, stats)``: the
-    final-normed stream ``[b, s, hidden]``, each layer's roped ``(k, v)``
-    ``[b, kvh, s, d]`` (what a cache keeps) and the expert counters folded
-    over the expert layers.  ``valid [s]`` marks real positions: padding
-    is routed to no expert (``b`` must then be 1)."""
+def forward_hidden(cfg: LagunaConfig, p, tokens):
+    """The causal stack over ``tokens [b, s]`` -> the final-normed stream
+    ``[b, s, hidden]``."""
     b, s = tokens.shape
     x = jnp.take(p["embed_tokens"]["weight"], tokens, axis=0)
     pos = jnp.arange(s, dtype=jnp.int32)
     rope = {t: tuple(c[None, :, None, :] for c in rope_cos_sin(cfg, t, pos))
-            for t in set(cfg.layer_types)}
-    kv, stats = [], None
+            for t in dict.fromkeys(cfg.layer_types)}
     for i in range(cfg.num_layers):
         lp = p[f"layer_{i}"]
         h1 = rms_norm(x, lp["input_norm"]["weight"], eps=cfg.rms_eps)
         q, k, v, g = attn_project(cfg, i, lp, h1, *rope[cfg.layer_types[i]])
         heads = cfg.heads_per_layer[i]
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        kv.append((k, v))
         ctx = flash_attention(
             q, expand_kv(k, heads), expand_kv(v, heads), causal=True,
             window=(cfg.sliding_window
@@ -259,17 +254,16 @@ def forward_hidden(cfg: LagunaConfig, p, tokens, valid=None):
         x = x + attn_output(lp, ctx.transpose(0, 2, 1, 3), g)
         h2 = rms_norm(x, lp["post_attention_norm"]["weight"],
                       eps=cfg.rms_eps)
-        y, st = ffn(cfg, i, lp, h2.reshape(b * s, -1), valid=valid)
-        stats = fold_stats(stats, st)
-        x = x + y.reshape(b, s, -1)
-    return rms_norm(x, p["final_norm"]["weight"], eps=cfg.rms_eps), kv, stats
+        x = x + ffn(cfg, i, lp, h2.reshape(b * s, -1))[0].reshape(b, s, -1)
+    return rms_norm(x, p["final_norm"]["weight"], eps=cfg.rms_eps)
 
 
 def laguna_forward(cfg: LagunaConfig, p, tokens):
-    """Full causal forward ``tokens [b, s]`` -> logits ``[b, s, vocab]``
-    (the training-shaped pass; serving's prefill is the same stack,
-    ``inference/models.py``)."""
-    return _linear(p["lm_head"], forward_hidden(cfg, p, tokens)[0])
+    """Full causal forward ``tokens [b, s]`` -> logits ``[b, s, vocab]``:
+    the training-shaped pass, the module's own as ``standalone_gpt`` and
+    ``standalone_llama`` keep theirs.  Serving runs the same per-layer
+    pieces through ``inference/models.py``'s loops."""
+    return _linear(p["lm_head"], forward_hidden(cfg, p, tokens))
 
 
 # --------------------------------------------------------------------------

@@ -210,28 +210,120 @@ def test_yarn_table_against_hand_computed_values():
     assert cos.shape == (2, 128) and float(cos[0, 0]) == 1.0
 
 
-def test_what_is_not_built_for_the_kind_is_refused_with_its_reason(tiny):
+#: every (kind, feature) some record refuses, and the words each reason
+#: has carried since ISSUE 30 — kept letter for letter by the records
+REFUSED = [(kind, feature) for kind, rec in models.KINDS.items()
+           for feature in rec.refuses]
+WORDS = {"dense": "serves from the paged cache only",
+         "tp": "tp > 1 is not built for the 'laguna' kind",
+         "verify": "speculative verify is not built for the 'laguna' kind",
+         "host_tier": "the host KV tier is not built for the 'laguna' kind",
+         "fused": "fused_block_decode is not built for the 'laguna' kind",
+         "prefix_sharing": "the 'laguna' kind prefills a prompt whole"}
+#: how each feature is asked of an engine at construction
+ASKED = {"dense": dict(page_size=None, num_pages=None), "tp": dict(tp=2),
+         "verify": dict(spec_k=2),
+         "host_tier": dict(host_tier_bytes=1 << 20),
+         "fused": dict(decode_fusion="1")}
+
+
+@pytest.mark.parametrize("kind,feature", REFUSED)
+def test_what_is_not_built_for_the_kind_is_refused_with_its_reason(
+        tiny, kind, feature):
+    import re
     from apex_tpu.inference import SlotScheduler
-    lcfg, params, _ = tiny
+    lcfg, params, _ = {"laguna": tiny}[kind]    # a kind new here: its toy
+    why = models.KINDS[kind].refuses[feature]
+    assert WORDS[feature] in why
     kw = dict(slots=2, max_seq=64, page_size=4, num_pages=40)
-    for bad, why in (
-            (dict(tp=2), "tp > 1 is not built"),
-            (dict(spec_k=2), "speculative verify is not built"),
-            (dict(host_tier_bytes=1 << 20), "host KV tier is not built"),
-            (dict(decode_fusion="1"), "fused_block_decode is not built")):
-        with pytest.raises(ValueError, match=why):
-            InferenceEngine("laguna", lcfg, params, **kw, **bad)
-    with pytest.raises(ValueError, match="paged cache only"):
-        InferenceEngine("laguna", lcfg, params, slots=2, max_seq=64)
+    if feature in ASKED:
+        with pytest.raises(ValueError, match=re.escape(why)):
+            InferenceEngine(kind, lcfg, params, **dict(kw, **ASKED[feature]))
+    if feature == "tp":
+        with pytest.raises(ValueError, match=re.escape(why)):
+            models.tp_dims(kind, lcfg, 2)
+    if feature == "verify":
+        with pytest.raises(ValueError, match=re.escape(why)):
+            models.verify_forward(kind, lcfg, params, None,
+                                  jnp.zeros((2, 3), jnp.int32))
+    if feature == "fused":
+        with pytest.raises(ValueError, match=re.escape(why)):
+            models.fused_layer_params(kind, lcfg, params)
+    if feature == "prefix_sharing":
+        eng = InferenceEngine(kind, lcfg, params, **kw)
+        assert not eng.supports_prefix_sharing
+        with pytest.raises(ValueError, match="prefix sharing is not built"):
+            SlotScheduler(eng, prefix_cache=True)
+        with pytest.raises(ValueError, match="chunked prefill is not built"):
+            SlotScheduler(eng, prefill_chunk=8)
+        assert SlotScheduler(eng).prefix is None
+        cache = eng.init_cache()
+        with pytest.raises(ValueError, match=re.escape(why)):
+            eng.prefill(cache, list(range(9)), 0, pages=[0, 1, 2],
+                        prefill_from=4)
+        with pytest.raises(ValueError, match=re.escape(why)):
+            models.prefill_forward(kind, lcfg, params,
+                                   jnp.zeros((1, 8), jnp.int32), 5,
+                                   cache=cache, row=cache.page_table[0],
+                                   prefill_from=0)
+
+
+def _step_jaxprs(eng):
+    """The engine's own prefill and decode step bodies, traced."""
+    from apex_tpu.inference import kv_cache
+    cache = eng.init_cache()
+    row = kv_cache.page_row([0, 1], eng.max_pages_per_slot, eng.num_pages)
+    key, step = eng._key, np.int32(0)
+    pre = jax.make_jaxpr(eng._prefill_raw)(
+        cache, eng.params, np.zeros((8,), np.int32), np.int32(0),
+        np.int32(5), row, np.int32(0), key, step)
+    dec = jax.make_jaxpr(eng._decode_raw)(
+        cache, eng.params, np.zeros((eng.slots,), np.int32),
+        np.ones((eng.slots,), bool), key, step)
+    return cache, pre.jaxpr, dec.jaxpr
+
+
+def _token_eqn(jaxpr, cache):
+    """The equation that writes a step's token output (the first output
+    after the cache's leaves)."""
+    tok = jaxpr.outvars[len(jax.tree_util.tree_leaves(cache))]
+    return tok, next(e for e in jaxpr.eqns if tok in e.outvars)
+
+
+def test_a_kind_without_stats_or_rings_traces_neither(tiny):
+    """What keeps GPT's program what it was before the kinds shared one
+    step body: both are static facts of the record, so a kind without
+    them has no concatenate on its tokens and no ring among its operands;
+    ``laguna``'s tail is as long as its record's ``stats``."""
+    from apex_tpu.transformer import parallel_state
+    from apex_tpu.transformer.testing import GPTConfig, gpt_model_provider
+    parallel_state.destroy_model_parallel()
+    parallel_state.initialize_model_parallel(1)
+    gcfg = GPTConfig(num_layers=2, hidden_size=32, num_attention_heads=2,
+                     vocab_size=96, max_seq_length=64)
+    gparams = gpt_model_provider(gcfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    kw = dict(slots=2, max_seq=64, page_size=4, num_pages=40)
+    eng = InferenceEngine("gpt", gcfg, gparams, **kw)
+    rec = models.KINDS["gpt"]
+    assert rec.stats == () and eng.stats_tail == 0
+    assert not rec.dims(gcfg)["window_layers"]
+    cache, pre, dec = _step_jaxprs(eng)
+    assert cache.wk is None and cache.wv is None        # no ring operand
+    ring_free = len(jax.tree_util.tree_leaves((cache, eng.params))) + 4
+    assert len(dec.invars) == ring_free
+    for jaxpr, shape in ((pre, ()), (dec, (2,))):
+        tok, eqn = _token_eqn(jaxpr, cache)
+        assert tok.aval.shape == shape
+        assert eqn.primitive.name != "concatenate"
+
+    lcfg, params, _ = tiny
     eng = InferenceEngine("laguna", lcfg, params, **kw)
-    with pytest.raises(ValueError, match="prefix sharing is not built"):
-        SlotScheduler(eng, prefix_cache=True)
-    with pytest.raises(ValueError, match="chunked prefill is not built"):
-        SlotScheduler(eng, prefill_chunk=8)
-    assert SlotScheduler(eng).prefix is None
-    with pytest.raises(ValueError, match="prefills a prompt whole"):
-        eng.prefill(eng.init_cache(), list(range(9)), 0,
-                    pages=[0, 1, 2], prefill_from=4)
-    with pytest.raises(ValueError, match="speculative verify is not built"):
-        models.verify_forward("laguna", lcfg, params, None,
-                              jnp.zeros((2, 3), jnp.int32))
+    tail = len(models.KINDS["laguna"].stats)
+    assert eng.stats_tail == tail == 4
+    cache, pre, dec = _step_jaxprs(eng)
+    assert cache.wk is not None
+    for jaxpr, shape in ((pre, (1 + tail,)), (dec, (2 + tail,))):
+        tok, eqn = _token_eqn(jaxpr, cache)
+        assert tok.aval.shape == shape
+        assert eqn.primitive.name == "concatenate"
