@@ -20,8 +20,8 @@ Cache layouts (ISSUE 6): the dense slot cache provisions ``max_seq``
 per slot; ``page_size=``/``num_pages=`` switch to the ragged paged
 pool — k/v in fixed-size pages threaded through a traced per-slot page
 table (``paged_decode_attention`` per layer: the ``apex_paged_decode``
-kernel reading the live pages straight from the whole pool, whatever
-the kind or window), the host-side
+kernel walking the step's list of live pages straight through the
+whole pool, whatever the kind or window), the host-side
 ``PageAllocator`` handing out reservations.  Same two executables,
 same donation discipline; only the memory model (and the scheduler's
 admission unit — pages, not slots) changes.

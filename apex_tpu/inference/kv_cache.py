@@ -386,7 +386,9 @@ class PagedKVCache:
 
     A decode step hands ``k``/``v`` WHOLE to
     :func:`~apex_tpu.ops.paged_attention.paged_decode_attention`, which
-    reads the slot's live pages of one layer straight from the pool.
+    reads the slots' live pages of one layer straight from the pool,
+    along the list :func:`~apex_tpu.ops.paged_attention.paged_work_list`
+    makes of the table and the lengths once a step.
 
     Tensor-parallel serving (ISSUE 17) shards ONLY the ``k``/``v``
     pool, over the kv-head dim (``kv_heads/tp`` heads per rank — see

@@ -66,6 +66,7 @@ from apex_tpu.ops.paged_attention import (
     fused_block_decode,
     paged_decode_attention,
     paged_slab_attention,
+    paged_work_list,
 )
 from apex_tpu.transformer.functional.fused_rope import (
     fused_apply_rotary_pos_emb_cached,
@@ -780,16 +781,18 @@ def _slab_attend(cache, layer: int, q, lengths):
                                  lengths)
 
 
-def _cache_attend(cache, layer: int, q, live):
+def _cache_attend(cache, layer: int, q, live, work):
     """Single-token attention against ONE layer of whichever cache
     layout the engine runs: the dense slot window
     (:func:`~apex_tpu.ops.attention.decode_attention`) or the paged
-    pool, handed to the kernel WHOLE and threaded through the slot page
-    table (:func:`~apex_tpu.ops.paged_attention.paged_decode_attention`).
+    pool, handed to the kernel WHOLE and walked along ``work``, the
+    step's list of live pages (None with the dense cache)
+    (:func:`~apex_tpu.ops.paged_attention.paged_decode_attention`).
     Both score the pre-broadcast per-kv-head cache (GQA/MQA grouped)."""
     if isinstance(cache, kv_cache.PagedKVCache):
         return paged_decode_attention(q, cache.k, cache.v,
-                                      cache.page_table, live, layer=layer)
+                                      cache.page_table, live, layer=layer,
+                                      work=work)
     return decode_attention(q, cache.k[:, layer], cache.v[:, layer], live)
 
 
@@ -931,6 +934,12 @@ def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
     flat = rec.rope(cfg, dims, positions, cache.max_seq)    # [slots, r]
     rope = {t: tuple(c[:, None, :] for c in cs) for t, cs in flat.items()}
     live = positions + 1                    # incl. the token written now
+    # the live (slot, page) pairs every pool layer's kernel walks: the
+    # table and the lengths are the step's, so the list is built ONCE
+    work = None
+    if fused is None and isinstance(cache, kv_cache.PagedKVCache):
+        work = paged_work_list(cache.page_table, live,
+                               page_size=cache.page_size)
     cos, sin = flat.get(FULL, (None, None))     # the kernel's: unshaped
     stats = None
     for i, (pooled, n) in enumerate(_cache_layers(dims["layer_types"])):
@@ -951,7 +960,7 @@ def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
         if pooled:
             cache = kv_cache.append_layer(cache, n, k_tok, v_tok)
             # grouped-query scoring straight off the per-kv-head pool
-            ctx = _cache_attend(cache, n, q, live)
+            ctx = _cache_attend(cache, n, q, live, work)
         else:
             cache = kv_cache.append_window(cache, n, k_tok, v_tok)
             ctx = ring_decode_attention(q, cache.wk[n], cache.wv[n],

@@ -22,7 +22,7 @@ from .fused_update import (
     adam_reference,
 )
 from .attention import decode_attention, flash_attention, mha_reference
-from .paged_attention import paged_decode_attention
+from .paged_attention import paged_decode_attention, paged_work_list
 from .ring_attention import ring_attention, ring_attention_reference
 from .ulysses_attention import ulysses_attention
 from .xentropy import softmax_cross_entropy_loss, xentropy_reference
@@ -51,6 +51,7 @@ __all__ = [
     "flash_attention",
     "decode_attention",
     "paged_decode_attention",
+    "paged_work_list",
     "mha_reference",
     "softmax_cross_entropy_loss",
     "xentropy_reference",

@@ -14,18 +14,23 @@ virtual position ``t`` of a slot resolves to physical page
 ``page_table[slot, t // page_size]``, row ``t % page_size``.
 
 ONE implementation, for every kind and window size: the Pallas kernel
-``apex_paged_decode`` on the WHOLE pool.  Grid ``(slots, pages)`` with
-the page table, lengths and layer as SCALAR-PREFETCH operands — the k/v
-BlockSpec index map reads ``(page_table[slot, page], layer)`` so Pallas
-DMAs exactly that slot's live pages of that layer from HBM, page by
-page, with its standard double buffering; neither a per-layer slice of
-the pool nor the gathered ``[slots, max_seq]`` window ever
-materializes.  Online softmax (fp32 running max/normalizer/accumulator
-in VMEM scratch, base-2 log domain like the flash kernels) carries
-across the page loop; dead pages are skipped (``pl.when``) and their
-DMA is deduplicated by clamping the index map to the slot's last live
-page (Pallas skips refetching an unchanged block index).  Dead rows
-inside the last live page mask to ``_NEG_INF``.
+``apex_paged_decode`` on the WHOLE pool.  Its grid is ONE dimension of
+DYNAMIC length: a flat work list of the live (slot, page) pairs, in slot
+order and page order within a slot (:func:`paged_work_list`, built on
+the device from ``page_table`` and ``lengths`` — once a decode step, and
+handed to every layer's call).  The list, the lengths and the layer are
+SCALAR-PREFETCH operands: the q and out BlockSpec index maps read the
+item's slot, the k/v index map ``(item's physical page, layer)``, so
+Pallas DMAs exactly the live pages of that layer from HBM, page by page,
+with its standard double buffering, and walks nothing else — neither a
+per-layer slice of the pool nor the gathered ``[slots, max_seq]`` window
+ever materializes, and a dead table entry costs no grid step.  Online
+softmax (fp32 running max/normalizer/accumulator in VMEM scratch, base-2
+log domain like the flash kernels) carries across a slot's items: it is
+initialised on the slot's first item and the output written on its
+last.  Dead rows inside the last live page mask to ``_NEG_INF``; a slot
+of length 0 has one item that initialises and finishes without a body,
+and so emits zeros.
 
 GQA/MQA: ``kv_heads`` divides the query heads; the kernel loops kv
 heads (static, small) scoring each head's ``group`` query rows against
@@ -38,7 +43,7 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -49,7 +54,8 @@ from apex_tpu.ops.attention import (_LOG2E, _NEG_INF,
                                     slab_decode_attention)
 from apex_tpu.utils import interpret_mode
 
-__all__ = ["paged_decode_attention", "paged_slab_attention",
+__all__ = ["paged_decode_attention", "paged_work_list", "PagedWork",
+           "paged_slab_attention",
            "fused_block_decode", "decode_fusion", "fusion_min_pages",
            "resolve_decode_fusion",
            "fused_block_vmem_bytes", "fused_block_refusal",
@@ -57,9 +63,10 @@ __all__ = ["paged_decode_attention", "paged_slab_attention",
 
 #: pallas_audit registration (analysis hook only, no behavior change):
 #: both kernels run online-softmax in fp32 scratch (APX302) and mask
-#: beyond-length pages in-kernel — the page grid intentionally covers
-#: the slot's max_pages even when length doesn't fill the last page
-#: (APX303 masked_tail).
+#: the rows beyond the length inside a slot's last live page in-kernel
+#: (APX303 masked_tail).  ``_paged_kernel`` walks the live pages only
+#: (one dynamic grid dimension, recorded as -1); ``_fused_block_kernel``
+#: still covers the slot's max_pages and skips the dead ones.
 PALLAS_AUDIT = {
     "_paged_kernel": {"reduction": True, "masked_tail": True},
     "_fused_block_kernel": {"reduction": True, "masked_tail": True},
@@ -67,16 +74,59 @@ PALLAS_AUDIT = {
 
 
 # --------------------------------------------------------------------------
-# Pallas kernel: grid (slots, pages), page table as scalar prefetch
+# the work list: one item a live (slot, page) pair
 # --------------------------------------------------------------------------
 
-def _paged_kernel(scale, kvh, group, ps, mpps,
-                  pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
+class PagedWork(NamedTuple):
+    """What ``apex_paged_decode`` walks (:func:`paged_work_list`).  The
+    first ``start[-1]`` entries of ``slot`` / ``page`` are the items,
+    slot-major and page-minor; the rest of their static capacity ``slots
+    x max_pages_per_slot`` is never read."""
+    slot: jax.Array         # [capacity] int32: the item's slot
+    page: jax.Array         # [capacity] int32: the item's physical page
+    start: jax.Array        # [slots + 1] int32: a slot's first item
+    lengths: jax.Array      # [slots] int32: live tokens, as handed in
+
+
+@functools.partial(jax.jit, static_argnames=("page_size",))
+def paged_work_list(page_table, lengths, *, page_size: int) -> PagedWork:
+    """The flat list of live (slot, page) pairs of ``page_table [slots,
+    max_pages_per_slot]`` under ``lengths [slots]``: a slot contributes
+    its ``ceil(length / page_size)`` leading table entries, in order — a
+    dead entry never appears — and a slot of length 0 ONE item (page 0,
+    never scored: the kernel still has to write the slot's zeros).
+    Everything is computed on the device; a decode step builds it once
+    and every pool layer's :func:`paged_decode_attention` takes it."""
+    slots, mpps = page_table.shape
+    page_table = page_table.astype(jnp.int32)
+    lengths = lengths.astype(jnp.int32)
+    live = jnp.minimum((lengths + page_size - 1) // page_size, mpps)
+    start = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                             jnp.cumsum(jnp.maximum(live, 1),
+                                        dtype=jnp.int32)])
+    item = jnp.arange(slots * mpps, dtype=jnp.int32)
+    # the slot whose stretch holds the item: how many stretches end at
+    # or before it (items past the count land on the last slot)
+    slot = jnp.minimum(jnp.sum(item[:, None] >= start[None, 1:], axis=1,
+                               dtype=jnp.int32), slots - 1)
+    at = jnp.minimum(item - start[slot], mpps - 1)
+    page = jnp.where(at < live[slot], page_table[slot, at], 0)
+    return PagedWork(slot, page, start, lengths)
+
+
+# --------------------------------------------------------------------------
+# Pallas kernel: grid (items,), the work list as scalar prefetch
+# --------------------------------------------------------------------------
+
+def _paged_kernel(scale, kvh, group, ps,
+                  slot_ref, page_ref, start_ref, len_ref, layer_ref,
+                  q_ref, k_ref, v_ref, o_ref,
                   s_scr, m_scr, l_scr, acc_scr):
     # blocks of the whole pool: [1, 1, kvh, ps, d]
     k_ref, v_ref = k_ref.at[0], v_ref.at[0]
-    sid = pl.program_id(0)
-    p = pl.program_id(1)
+    item = pl.program_id(0)
+    sid = slot_ref[item]
+    p = item - start_ref[sid]               # the page's place in the slot
     h = kvh * group
 
     @pl.when(p == 0)
@@ -86,9 +136,8 @@ def _paged_kernel(scale, kvh, group, ps, mpps,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     length = len_ref[sid]
-    live_pages = (length + ps - 1) // ps
 
-    @pl.when(p < live_pages)
+    @pl.when(length > 0)                    # an empty slot's one item
     def _body():
         q = q_ref[0]                                     # [h, d]
         # per-kv-head scoring: each kv head's page block serves its
@@ -118,7 +167,7 @@ def _paged_kernel(scale, kvh, group, ps, mpps,
                 preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
-    @pl.when(p == mpps - 1)
+    @pl.when(item == start_ref[sid + 1] - 1)
     def _finish():
         l = l_scr[...]
         o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)
@@ -126,31 +175,28 @@ def _paged_kernel(scale, kvh, group, ps, mpps,
 
 
 @functools.partial(jax.jit, static_argnames=("scale",))
-def _paged_kernel_call(q, k_pool, v_pool, page_table, lengths, layer, *,
-                       scale):
-    # ``layer`` is a TRACED int32 [1], the third scalar-prefetch operand:
-    # every layer of a decode step is then the same jitted call, traced
-    # and lowered to Mosaic ONCE (a static layer in the index map makes
-    # each layer a kernel of its own: 24 lowerings, seconds of every
-    # process's start, compile cache or not)
+def _paged_kernel_call(q, k_pool, v_pool, work, layer, *, scale):
+    # ``layer`` is a TRACED int32 [1], a scalar-prefetch operand like the
+    # work list: every layer of a decode step is then the same jitted
+    # call, traced and lowered to Mosaic ONCE (a static layer in the
+    # index map makes each layer a kernel of its own: 24 lowerings,
+    # seconds of every process's start, compile cache or not)
     slots, h, d = q.shape
     kvh, ps = k_pool.shape[2], k_pool.shape[3]
-    mpps = page_table.shape[1]
     group = h // kvh
 
-    def page_index(s, p, pt, ln, ly):
-        # clamp dead trailing pages to the slot's last live page: an
-        # unchanged block index lets Pallas skip the (useless) refetch,
-        # and pl.when skips its compute entirely
-        last = jnp.maximum((ln[s] + ps - 1) // ps - 1, 0)
-        return (pt[s, jnp.minimum(p, last)], ly[0], 0, 0, 0)
+    def slot_index(i, slot, page, start, ln, ly):
+        return (slot[i], 0, 0)
+
+    def page_index(i, slot, page, start, ln, ly):
+        return (page[i], ly[0], 0, 0, 0)
 
     page_block = (1, 1, kvh, ps, d)
-    slot_block = pl.BlockSpec((1, h, d), lambda s, p, pt, ln, ly: (s, 0, 0))
+    slot_block = pl.BlockSpec((1, h, d), slot_index)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(slots, mpps),
+        num_scalar_prefetch=5,
+        grid=(work.start[-1],),             # traced: the live items only
         in_specs=[
             slot_block,
             pl.BlockSpec(page_block, page_index),
@@ -164,16 +210,17 @@ def _paged_kernel_call(q, k_pool, v_pool, page_table, lengths, layer, *,
             pltpu.VMEM((h, d), jnp.float32),      # fp32 output accum
         ],
     )
-    kernel = functools.partial(_paged_kernel, scale, kvh, group, ps, mpps)
+    kernel = functools.partial(_paged_kernel, scale, kvh, group, ps)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((slots, h, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret_mode(),
         name="apex_paged_decode",
-    )(page_table, lengths, layer, q, k_pool, v_pool)
+    )(work.slot, work.page, work.start, work.lengths, layer,
+      q, k_pool, v_pool)
 
 
 # --------------------------------------------------------------------------
@@ -181,7 +228,8 @@ def _paged_kernel_call(q, k_pool, v_pool, page_table, lengths, layer, *,
 # --------------------------------------------------------------------------
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
-                           layer: int, sm_scale: Optional[float] = None):
+                           layer: int, sm_scale: Optional[float] = None,
+                           work: Optional[PagedWork] = None):
     """Single-token attention against ONE layer of a paged KV pool.
 
     * ``q``: ``[slots, h, 1, d]`` (or ``[slots, h, d]``) — the current
@@ -199,9 +247,13 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
       conventional filler).
     * ``lengths``: ``[slots]`` int32 — live tokens per slot; a slot
       with length 0 emits zeros.
+    * ``work``: :func:`paged_work_list` of THIS ``page_table`` and
+      ``lengths`` at the pool's page size.  A step whose layers attend
+      through one table builds it once and hands it to each; left out,
+      it is built here.
 
     Always the Pallas kernel (interpret mode off-TPU), whatever the
-    window: it streams the live pages via the page table with no
+    window: it walks the live pages and nothing else, with no
     materialized gather, scoring bf16 operands with fp32 accumulation
     and an fp32 online softmax.
     """
@@ -234,10 +286,12 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
         raise ValueError(
             f"lengths must be [{slots}], got {tuple(lengths.shape)}")
     scale = (d ** -0.5) if sm_scale is None else sm_scale
+    if work is None:
+        work = paged_work_list(page_table, lengths,
+                               page_size=k_pool.shape[3])
     out = _paged_kernel_call(
-        q[:, :, 0, :], k_pool, v_pool, page_table.astype(jnp.int32),
-        lengths.astype(jnp.int32), jnp.full((1,), layer, jnp.int32),
-        scale=float(scale))
+        q[:, :, 0, :], k_pool, v_pool, work,
+        jnp.full((1,), layer, jnp.int32), scale=float(scale))
     return out if squeezed else out[:, :, None, :]
 
 
@@ -308,8 +362,10 @@ def paged_slab_attention(q, k_pages, v_pages, page_table, lengths, *,
 #     slot's live pages INCLUDING the current token -> output
 #     projection -> residual -> [norm2 -> MLP -> residual]
 #
-# Grid (slots, pages), page table + lengths as scalar prefetch exactly
-# like the attention-only kernel above.  The layer's weights ride in
+# Grid (slots, pages), page table + lengths as scalar prefetch (the
+# attention-only kernel above walks a flat list of the live pages; this
+# one keeps the fixed grid and skips the dead ones: no cell runs it,
+# ROADMAP D6).  The layer's weights ride in
 # whole-array VMEM blocks with CONSTANT index maps, so Pallas DMAs each
 # weight from HBM once and keeps it resident for every slot and page
 # of the grid — the q_len = 1 activations (x, q, the fresh k/v, the

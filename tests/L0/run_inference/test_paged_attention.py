@@ -9,7 +9,8 @@ import numpy as np
 import pytest
 
 from apex_tpu.ops.attention import decode_attention
-from apex_tpu.ops.paged_attention import paged_decode_attention
+from apex_tpu.ops.paged_attention import (paged_decode_attention,
+                                          paged_work_list)
 
 LAYERS = 3
 
@@ -63,6 +64,81 @@ def test_kernel_matches_dense_on_ragged_batch(h, kvh, layer, dtype):
     np.testing.assert_allclose(
         np.asarray(kern, np.float32), np.asarray(dense, np.float32),
         rtol=TOL[dtype], atol=TOL[dtype])
+
+
+#: one slot's length by name, at page size 8 and 4 pages a slot
+EDGES = {"empty": 0, "one": 1, "page": 8, "page_plus_one": 9, "full": 32}
+
+
+@pytest.mark.parametrize("edge", list(EDGES))
+@pytest.mark.parametrize("h,kvh", [(4, 4), (8, 2), (4, 1)],
+                         ids=["mha", "gqa", "mqa"])
+def test_kernel_matches_dense_at_a_lengths_edge(h, kvh, edge):
+    """A slot at the edge — first, between others and last in the list —
+    beside slots of other lengths: each slot's answer is its own."""
+    n = EDGES[edge]
+    lengths = [n, 13, n, 32, 0, n]
+    q, k, v, pk, pv, pt, ln = _paged_twin(6, h, kvh, 8, 4, lengths)
+    dense = decode_attention(q, k[1], v[1], ln, use_kernel=False)
+    kern = paged_decode_attention(q, pk, pv, pt, ln, layer=1)
+    np.testing.assert_allclose(np.asarray(kern), np.asarray(dense),
+                               rtol=2e-5, atol=2e-5)
+    if n == 0:
+        assert np.all(np.asarray(kern)[[0, 2, 5]] == 0)
+
+
+def _work_list_reference(pt, lengths, ps):
+    """The live (slot, page) pairs in NumPy: slot-major, page-minor; an
+    empty slot's one item names page 0."""
+    slot, page, start = [], [], [0]
+    for s, n in enumerate(lengths):
+        live = min(-(-int(n) // ps), pt.shape[1])
+        slot += [s] * max(live, 1)
+        page += list(pt[s, :live]) if live else [0]
+        start.append(len(slot))
+    return np.array(slot), np.array(page), np.array(start)
+
+
+@pytest.mark.parametrize("lengths", [
+    RAGGED, [0, 0, 0], [1, 1, 1, 1], [32, 32], [8, 9, 0, 16, 17, 0, 32, 1],
+    [40, 5]], ids=["ragged", "all_empty", "all_one", "all_full", "edges",
+                   "beyond_the_table"])
+def test_work_list_against_numpy(lengths):
+    ps, mpps, slots = 8, 4, len(lengths)
+    rng = np.random.RandomState(len(lengths))
+    # every entry of the table its own page, from 1: a page names its entry
+    pt = 1 + rng.permutation(slots * mpps).reshape(slots, mpps).astype(
+        np.int32)
+    work = paged_work_list(jnp.asarray(pt), jnp.asarray(lengths, jnp.int32),
+                           page_size=ps)
+    slot, page, start = _work_list_reference(pt, lengths, ps)
+    count = int(work.start[-1])
+    assert count == sum(max(min(-(-n // ps), mpps), 1) for n in lengths)
+    assert work.slot.shape == work.page.shape == (slots * mpps,)
+    np.testing.assert_array_equal(np.asarray(work.start), start)
+    np.testing.assert_array_equal(np.asarray(work.slot)[:count], slot)
+    np.testing.assert_array_equal(np.asarray(work.page)[:count], page)
+    np.testing.assert_array_equal(np.asarray(work.lengths), lengths)
+    # no dead entry of the table among the items
+    dead = {int(pt[s, j]) for s, n in enumerate(lengths)
+            for j in range(mpps) if j * ps >= n}
+    assert not dead & set(np.asarray(work.page)[:count].tolist())
+    # what lies past the count is never walked, but stays in range
+    assert np.all((np.asarray(work.slot) >= 0)
+                  & (np.asarray(work.slot) < slots))
+    assert np.all((np.asarray(work.page) >= 0)
+                  & (np.asarray(work.page) <= slots * mpps))
+
+
+def test_a_handed_work_list_is_the_one_built_inside():
+    q, k, v, pk, pv, pt, ln = _paged_twin(6, 8, 2, 8, 4, RAGGED)
+    work = paged_work_list(pt, ln, page_size=8)
+    for layer in range(LAYERS):
+        np.testing.assert_array_equal(
+            np.asarray(paged_decode_attention(q, pk, pv, pt, ln,
+                                              layer=layer, work=work)),
+            np.asarray(paged_decode_attention(q, pk, pv, pt, ln,
+                                              layer=layer)))
 
 
 def test_layers_of_one_pool_differ():
@@ -121,7 +197,8 @@ def test_sm_scale_is_applied():
 
 def test_every_call_is_the_one_kernel():
     """One path: the traced program is the ``apex_paged_decode``
-    pallas_call and holds no gather, whatever the window."""
+    pallas_call, whatever the window, and gathers nothing but what the
+    work list's builder does (int32 table entries): never the pool."""
     for mpps in (1, 3, 64):
         q, k, v, pk, pv, pt, ln = _paged_twin(2, 4, 2, 4, mpps, [3, 1])
         jaxpr = str(jax.make_jaxpr(
@@ -129,7 +206,9 @@ def test_every_call_is_the_one_kernel():
                 q, pk, pv, pt, ln))
         assert jaxpr.count("pallas_call") == 1
         assert "apex_paged_decode" in jaxpr
-        assert "gather" not in jaxpr
+        assert jaxpr.count("gather") == str(jax.make_jaxpr(
+            lambda *a: paged_work_list(*a, page_size=4))(pt, ln)).count(
+                "gather")
 
 
 def _bad_pool(case):

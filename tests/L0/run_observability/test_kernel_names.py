@@ -212,13 +212,18 @@ def test_v5e_gpt_decode_step_reads_the_pool_in_place(one_chip, monkeypatch):
             max_pages_per_slot=512 // ps)))
     step = jax.jit(make_decode_fn("gpt", cfg, SamplingConfig()),
                    donate_argnums=(0,))
-    hlo = step.lower(
-        cache, params, _s((slots,), jnp.int32, sharding=one_chip),
-        _s((slots,), bool, sharding=one_chip),
-        _s((2,), jnp.uint32, sharding=one_chip),
-        _s((), jnp.int32, sharding=one_chip)).compile().as_text()
+    args = (cache, params, _s((slots,), jnp.int32, sharding=one_chip),
+            _s((slots,), bool, sharding=one_chip),
+            _s((2,), jnp.uint32, sharding=one_chip),
+            _s((), jnp.int32, sharding=one_chip))
+    # the step TRACES the work list's builder once, not once a layer (the
+    # compiler would fold equal builds, so the jaxpr is where that shows)
+    assert str(jax.make_jaxpr(step)(*args)).count(
+        "name=paged_work_list") == 1
+    hlo = step.lower(*args).compile().as_text()
     pool = f"bf16[{pages + 1},{layers},{heads},{ps},{d}]"
     made = {}                       # op -> count, of pool-sized results
+    lists = set()                   # the kernels' work-list operands
     kernels = 0
     for line in hlo.splitlines():
         m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\(", line)
@@ -228,9 +233,45 @@ def test_v5e_gpt_decode_step_reads_the_pool_in_place(one_chip, monkeypatch):
         if op == "custom-call" and name.startswith("apex_paged_decode"):
             kernels += 1
             assert line.count(pool) == 2, line[:300]     # k and v, whole
+            # grid bound, slot, page, start, lengths: then layer, q, k, v
+            lists.add(tuple(re.findall(
+                r"%[\w.\-]+", line.split("custom-call(", 1)[1])[:5]))
         if result.startswith(pool) and op != "parameter":
             made[op] = made.get(op, 0) + 1
     assert kernels == layers
+    # every layer's call walks the SAME list: one build a step, whose one
+    # gather reads int32 table entries
+    assert len(lists) == 1, lists
+    assert len(re.findall(r" gather\(.*paged_work_list", hlo)) == 1
     # the appends: one in-place scatter of k and of v a layer, nothing else
     assert set(made) <= {"fusion", "scatter"}, made
     assert sum(made.values()) <= 4 * layers, made
+
+
+@pytest.mark.parametrize("table", ["laguna", "gpt"])
+def test_v5e_paged_decode_takes_the_cells_work_lists(table, one_chip,
+                                                      monkeypatch):
+    """``apex_paged_decode`` at a cell's table compiles for the chip
+    (ISSUE 33): the grid's one dynamic bound, and the work list as
+    scalar-prefetch operands — ``laguna-xs.2-serve``'s is 32 slots x 260
+    pages = 8,320 entries an array in SMEM, beside 8 KV heads."""
+    import apex_tpu.ops.paged_attention as pa
+    monkeypatch.setattr(pa, "interpret_mode", lambda: False)
+    slots, h, kvh, mpps, pages, layers = {
+        "laguna": (32, 48, 8, 260, 4096, 2),
+        "gpt": (16, 16, 16, 32, 640, 24)}[table]
+    ps, d = 64, 128
+    on = lambda shape, dtype: _s(shape, dtype, sharding=one_chip)  # noqa: E731
+    pool = on((pages + 1, layers, kvh, ps, d), BF16)
+    hlo = jax.jit(
+        lambda *a: pa.paged_decode_attention(*a, layer=layers - 1)).lower(
+            on((slots, h, d), BF16), pool, pool,
+            on((slots, mpps), jnp.int32),
+            on((slots,), jnp.int32)).compile().as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and "%apex_paged_decode" in calls[0], calls
+    entries = f"s32[{slots * mpps}]"
+    # the bound, then slot and page lists at their whole capacity
+    assert f"operand_layout_constraints={{s32[], {entries}{{0}}, " \
+           f"{entries}{{0}}, s32[{slots + 1}]{{0}}" in calls[0], calls[0][:600]
