@@ -239,6 +239,26 @@ def _builders():
         return (fn, (cache, params, s((4,), jnp.int32), s((4,), bool),
                      key, s((), jnp.int32)))
 
+    def inference_decode_latent():
+        # ISSUE 34: the decode step of a kind with LATENT attention — one
+        # pool with no KV-head axis and no value array, the absorbed
+        # query through apex_paged_decode_latent, the held experts' loop
+        from apex_tpu.inference import kv_cache
+        from apex_tpu.inference.engine import make_decode_fn
+        from apex_tpu.inference.sampling import SamplingConfig
+        from apex_tpu.transformer.testing import standalone_axk1 as SA
+        cfg = SA.AXK1Config(params_dtype=bf16, held=(4, 8))
+        params = {"params": jax.tree.map(
+            lambda shape: s(shape, bf16), SA.axk1_param_shapes(cfg),
+            is_leaf=lambda x: isinstance(x, tuple))}
+        cache = jax.eval_shape(
+            lambda: kv_cache.init_paged_cache(
+                20, cfg.num_layers, 0, 16, 0, slots=4,
+                max_pages_per_slot=16, latent=cfg.latent_dim))
+        fn = make_decode_fn("axk1", cfg, SamplingConfig())
+        return (fn, (cache, params, s((4,), jnp.int32), s((4,), bool),
+                     s((2,), jnp.uint32), s((), jnp.int32)))
+
     def fused_block_decode_op():
         # the ISSUE 15 fused transformer-block decode kernel at an
         # op-level GPT-shaped fixture (LN + qkv + paged attention incl.
@@ -374,6 +394,13 @@ def _builders():
                                    ("bfloat16", "bfloat16", "int32",
                                     "int32", "int32", "int32",
                                     "float32", "bool"), None),
+        # ISSUE 34: the cache is ONE bf16 pool (then table, lengths,
+        # capacity), the tokens carry the four counters
+        "inference_decode_latent": (inference_decode_latent,
+                                    "apex_tpu/inference/engine.py",
+                                    ("bfloat16", "int32", "int32",
+                                     "int32", "int32", "float32", "bool"),
+                                    None),
         # ISSUE 15: the fused-block kernel (op-level; measured entry
         # upcasts = 11: the norm gains/biases and the projection/MLP
         # biases applied in fp32 by design — layer_norm's budget-2

@@ -228,6 +228,15 @@ def _builders():
                 (s((2, 4, 64), bf16), pool, pool,
                  s((2, 4), jnp.int32), s((2,), jnp.int32)))
 
+    def paged_decode_latent():
+        # ISSUE 34: the latent form — one pool with no KV-head axis, the
+        # values a row's leading columns
+        from apex_tpu.ops import paged_decode_attention as op
+        return (lambda q, pool, pt, n: op(q, pool, None, pt, n, layer=1,
+                                          sm_scale=0.1, values=128),
+                (s((2, 4, 192), bf16), s((9, 2, 192, 16), bf16),
+                 s((2, 4), jnp.int32), s((2,), jnp.int32)))
+
     def fused_block_decode():
         # the jaxpr-audit fixture geometry (hidden 64, GPT kind); the
         # flagship-shape envelope rides fused_block_envelope, not the
@@ -298,6 +307,9 @@ def _builders():
         "paged_decode_attention": (paged_decode_attention,
                                    "apex_tpu/ops/paged_attention.py",
                                    ops + "paged_attention"),
+        "paged_decode_latent": (paged_decode_latent,
+                                "apex_tpu/ops/paged_attention.py",
+                                ops + "paged_attention"),
         "fused_block_decode": (fused_block_decode,
                                "apex_tpu/ops/paged_attention.py",
                                ops + "paged_attention"),

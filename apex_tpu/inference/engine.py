@@ -166,7 +166,7 @@ def make_prefill_fn(kind: str, cfg, sampling: SamplingConfig,
             if rec.stats:
                 tok = jnp.concatenate([
                     tok.astype(jnp.int32)[None],
-                    models.stats_tail(stats, cache)])
+                    models.stats_tail(rec.stats, stats, cache)])
         return cache, tok, last
 
     return prefill_paged_fn if paged else prefill_fn
@@ -207,7 +207,7 @@ def make_decode_fn(kind: str, cfg, sampling: SamplingConfig,
                 # [slots] tokens + the counters' tail: one read for both
                 toks = jnp.concatenate([
                     toks.astype(jnp.int32),
-                    models.stats_tail(stats, cache)])
+                    models.stats_tail(rec.stats, stats, cache)])
         return cache, toks, logits, truncated
 
     return decode_fn
@@ -310,7 +310,7 @@ class PendingSwapOut:
 
 
 class InferenceEngine:
-    """Serving engine over a standalone GPT/LLaMA/Laguna/BERT —
+    """Serving engine over a standalone GPT/LLaMA/Laguna/A.X-K1/BERT —
     single-chip by default, tensor-parallel over a ``tp``-wide mesh on
     request (``gpt``/``llama``).
 
@@ -319,9 +319,12 @@ class InferenceEngine:
     refused at construction with the record's reason — for ``laguna``
     (ISSUE 30: expert FFN, window + full layers, a head count per layer)
     the dense cache, tp > 1, speculative verify, the host KV tier, the
-    fused block kernel, and prefix sharing at the first prefill that asks
-    — and a kind with ``stats`` appends ``stats_tail`` int32 counters to
-    the tokens its prefill and decode return.
+    fused block kernel, and prefix sharing at the first prefill that asks;
+    for ``axk1`` (ISSUE 34: latent attention over a pool with no KV-head
+    axis, a share of the experts held) the same six — and a kind with
+    ``stats`` appends ``stats_tail`` int32 counters to the tokens its
+    prefill and decode return.  The pool's shape and a page's bytes come
+    from the record's ``dims`` (``models.cache_row_values``).
 
     Static shape contract: ``slots`` concurrent sequences, each with a
     ``max_seq``-deep cache line, decode always batched over every slot.
@@ -664,7 +667,8 @@ class InferenceEngine:
                     self.page_size, d["head_dim"], slots=self.slots,
                     max_pages_per_slot=self.max_pages_per_slot,
                     dtype=self.cache_dtype,
-                    window_layers=d["window_layers"], window=d["window"])
+                    window_layers=d["window_layers"], window=d["window"],
+                    latent=d["latent"])
 
             if self.tp == 1:
                 return build()
@@ -700,7 +704,9 @@ class InferenceEngine:
         d = self.dims
         itemsize = jnp.dtype(self.cache_dtype).itemsize
         kvh = self.tp_dims["kv_heads_pool"] // self.tp   # per-rank heads
-        per_layer_tok = 2 * kvh * d["head_dim"] * itemsize
+        # what a position holds a layer is the record's: a key and a
+        # value per KV head, or one latent row
+        per_layer_tok = models.cache_row_values(d, kvh) * itemsize
         if self.paged:
             # the pool's layers, + the window layers' rings: fixed rows a
             # slot whatever the context (none without such layers)
@@ -836,8 +842,8 @@ class InferenceEngine:
                              "slot cache")
         d = self.dims
         itemsize = jnp.dtype(self.cache_dtype).itemsize
-        return (2 * d["pool_layers"] * self.tp_dims["kv_heads_pool"]
-                * self.page_size * d["head_dim"] * itemsize)
+        return (d["pool_layers"] * self.page_size * itemsize
+                * models.cache_row_values(d, self.tp_dims["kv_heads_pool"]))
 
     def swap_out_pages(self, cache, page_ids, defer: bool = False):
         """Copy physical pages ``page_ids`` device→host (ISSUE 18

@@ -57,6 +57,25 @@ Shared design positions:
   position).  Lengths, capacity and the page table are shared.  Models
   without window layers leave ``wk``/``wv`` ``None`` and keep the one
   pool, op for op.
+* **A latent pool (ISSUE 34).**  A model with latent attention caches ONE
+  row a position a layer — the normed latent beside the roped key
+  channels every head shares — and no per-head keys or values.  Its pool
+  under the same :class:`PagedKVCache` is ONE array with no KV-head
+  axis::
+
+      k : [pages, layers, latent_width, page_size]      v : None
+
+  (``init_paged_cache(..., latent=width)``); the values ARE the row's
+  leading channels, so there is no second array — not a zero-sized one,
+  not a copy.  A page holds its positions along the MINOR axis: a row
+  576 wide is 4.5 lane tiles, and the v5e's runtime lays a ``[...,
+  page_size, 576]`` array out transposed, so that the kernel, which
+  needs it row-major, was handed a pool-sized copy every layer
+  (PERF.md section 6, PR 34); ``[..., 576, page_size]`` is row-major as
+  it stands, and both of the kernel's products take it as it is.  Page
+  table, lengths, capacity, allocator and every mutator are the paged
+  pool's own: a prefill inserts ``[layers, s, width]`` rows, a decode
+  step appends ``[slots, width]`` a layer.
 * **The trash page.**  The paged pool carries ONE sacrificial page at
   index ``pages - 1`` that the allocator never hands out; page-table
   entries beyond a slot's reservation point there, so the statically
@@ -208,12 +227,15 @@ def append_layer(cache, layer: int, k_tok, v_tok):
     decode step writes to the same position.  Dispatches on the cache
     layout: dense slot cache or paged pool.
     """
-    if k_tok.shape != (cache.slots, cache.kv_heads, cache.head_dim):
+    paged = isinstance(cache, PagedKVCache)
+    want = (cache.slots, *(cache.row_shape if paged else
+                           (cache.kv_heads, cache.head_dim)))
+    if k_tok.shape != want:
         raise ValueError(
             f"token k/v must be [slots={cache.slots}, "
-            f"kv_heads={cache.kv_heads}, head_dim={cache.head_dim}], "
-            f"got {tuple(k_tok.shape)}")
-    if isinstance(cache, PagedKVCache):
+            f"kv_heads={cache.kv_heads}, head_dim={cache.head_dim}] (a "
+            f"latent pool: [slots, width]), got {tuple(k_tok.shape)}")
+    if paged:
         return _append_layer_paged(cache, layer, k_tok, v_tok)
 
     def write(buf, tok, pos):
@@ -401,7 +423,10 @@ class PagedKVCache:
     invariant.
     """
     k: jax.Array           # [pages, layers, kv_heads, page_size, head_dim]
-    v: jax.Array           # same shape/dtype as k
+    # same shape/dtype as k; None for a LATENT pool (ISSUE 34), whose k is
+    # [pages, layers, latent_width, page_size]: one row a position, no
+    # KV-head axis, the values the row's leading channels
+    v: Optional[jax.Array]
     page_table: jax.Array  # [slots, max_pages_per_slot] int32
     lengths: jax.Array     # [slots] int32: live tokens per slot
     capacity: jax.Array    # [slots] int32: page_size * owned pages
@@ -411,6 +436,11 @@ class PagedKVCache:
     # pages; position t of a slot sits at ring row t % ring
     wk: Optional[jax.Array] = None
     wv: Optional[jax.Array] = None
+
+    @property
+    def latent(self) -> bool:
+        """One row a position and no KV-head axis (module docstring)?"""
+        return self.k.ndim == 4
 
     @property
     def ring(self) -> int:
@@ -437,7 +467,8 @@ class PagedKVCache:
 
     @property
     def kv_heads(self) -> int:
-        return self.k.shape[2]
+        """0 for a latent pool: it has no such axis."""
+        return 0 if self.latent else self.k.shape[2]
 
     @property
     def page_size(self) -> int:
@@ -445,7 +476,15 @@ class PagedKVCache:
 
     @property
     def head_dim(self) -> int:
-        return self.k.shape[4]
+        """A cached row's width: a head's size, or the latent row's."""
+        return self.k.shape[2] if self.latent else self.k.shape[4]
+
+    @property
+    def row_shape(self) -> tuple:
+        """One position's cached values in one layer, per buffer:
+        ``(kv_heads, head_dim)``, or ``(latent_width,)``."""
+        return (self.k.shape[2],) if self.latent else (
+            self.k.shape[2], self.k.shape[4])
 
     @property
     def slots(self) -> int:
@@ -459,24 +498,30 @@ class PagedKVCache:
     def max_seq(self) -> int:
         """The virtual per-slot window: ``max_pages_per_slot *
         page_size`` (what the dense cache calls ``max_seq``)."""
-        return self.page_table.shape[1] * self.k.shape[3]
+        return self.page_table.shape[1] * self.page_size
 
 
 def init_paged_cache(pages: int, layers: int, kv_heads: int,
                      page_size: int, head_dim: int, *, slots: int,
                      max_pages_per_slot: int, dtype=jnp.bfloat16,
-                     window_layers: int = 0,
-                     window: int = 0) -> PagedKVCache:
+                     window_layers: int = 0, window: int = 0,
+                     latent: int = 0) -> PagedKVCache:
     """Allocate an empty pool: ``pages`` allocatable pages (+1 trash
     page appended), every page-table entry pointing at the trash page,
     every slot empty.  ``layers`` counts the layers the POOL holds;
     ``window_layers`` sliding-window layers of ``window`` positions get
-    the second pool of per-slot rings instead (module docstring)."""
+    the second pool of per-slot rings instead (module docstring).
+    ``latent`` (a row's width) makes the pool a LATENT one: one array
+    ``[pages + 1, layers, latent, page_size]``, no value array;
+    ``kv_heads`` / ``head_dim`` are then not read."""
     if pages < 1 or page_size < 1 or max_pages_per_slot < 1:
         raise ValueError(
             f"pages ({pages}), page_size ({page_size}) and "
             f"max_pages_per_slot ({max_pages_per_slot}) must be >= 1")
-    shape = (pages + 1, layers, kv_heads, page_size, head_dim)
+    if latent and window_layers:
+        raise ValueError("a latent pool has no window rings")
+    shape = ((pages + 1, layers, latent, page_size) if latent
+             else (pages + 1, layers, kv_heads, page_size, head_dim))
     rings = {}
     if window_layers:
         if window < 1:
@@ -487,7 +532,8 @@ def init_paged_cache(pages: int, layers: int, kv_heads: int,
         rings = dict(wk=jnp.zeros(wshape, dtype),
                      wv=jnp.zeros(wshape, dtype))
     return PagedKVCache(
-        k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
+        k=jnp.zeros(shape, dtype),
+        v=None if latent else jnp.zeros(shape, dtype),
         page_table=jnp.full((slots, max_pages_per_slot), pages,
                             jnp.int32),
         lengths=jnp.zeros((slots,), jnp.int32),
@@ -514,6 +560,32 @@ def paged_cache_partition_specs(axis: str = TENSOR_AXIS) -> PagedKVCache:
     kv = P(None, None, axis, None, None)
     return PagedKVCache(k=kv, v=kv, page_table=P(), lengths=P(),
                         capacity=P())
+
+
+def _pools(cache: PagedKVCache, fn, k, v) -> dict:
+    """``fn(pool, rows)`` over the key pool and — where the cache has one
+    — the value pool: the ``k=``/``v=`` of a ``cache.replace``."""
+    if cache.v is None and v is not None:
+        raise ValueError("a latent pool holds one row a position and no "
+                         "values beside it: pass v=None")
+    return {"k": fn(cache.k, k),
+            "v": None if cache.v is None else fn(cache.v, v)}
+
+
+def _check_rows(cache: PagedKVCache, k, v, what: str, lead: tuple) -> None:
+    """``k`` (and ``v``) must be ``[*lead, <kv_heads>, n, head_dim]`` —
+    without the KV-head axis for a latent pool, whose ``v`` is None."""
+    heads = () if cache.latent else (cache.kv_heads,)
+    ok = (k.ndim == len(lead) + len(heads) + 2
+          and tuple(k.shape[:len(lead) + len(heads)]) == lead + heads
+          and k.shape[-1] == cache.head_dim
+          and (v is None if cache.latent else
+               v is not None and v.shape == k.shape))
+    if not ok:
+        raise ValueError(
+            f"{what} must be {list(lead + heads) + ['n', cache.head_dim]}"
+            f"{' and v None' if cache.latent else ', k and v alike'}; got "
+            f"k {tuple(k.shape)} v {None if v is None else tuple(v.shape)}")
 
 
 def page_row(page_ids: Sequence[int], max_pages_per_slot: int,
@@ -545,14 +617,8 @@ def insert_pages(cache: PagedKVCache, slot, k, v, length,
     is derived in-program from the row (owned pages x page_size), so
     one compiled insert serves every page assignment.
     """
-    ps, s = cache.page_size, k.shape[2]
-    if k.shape != v.shape or k.shape[0] != cache.layers \
-            or k.shape[1] != cache.kv_heads \
-            or k.shape[3] != cache.head_dim:
-        raise ValueError(
-            f"prefill k/v must be [layers={cache.layers}, "
-            f"kv_heads={cache.kv_heads}, s, head_dim={cache.head_dim}], "
-            f"got k {tuple(k.shape)} v {tuple(v.shape)}")
+    ps, s = cache.page_size, k.shape[-2]
+    _check_rows(cache, k, v, "prefill k/v", (cache.layers,))
     if s % ps or s > cache.max_seq:
         raise ValueError(
             f"prompt slab length {s} must be a multiple of page_size "
@@ -566,21 +632,23 @@ def insert_pages(cache: PagedKVCache, slot, k, v, length,
     zero = jnp.int32(0)
     n = s // ps
 
-    def paged_slab(x):
+    def write(pool, x):
         # [layers, kvh, s, d] -> [n, layers, kvh, ps, d]: one entry per
         # bucket page, scattered to its physical page in ONE op (bucket
         # overhang beyond the reservation targets the trash page; the
         # trash page appearing more than once just stacks garbage)
-        return jnp.moveaxis(
-            x.reshape(x.shape[0], x.shape[1], n, ps, x.shape[3]), 2, 0)
+        if cache.latent:    # [layers, s, w] -> [n, layers, w, ps]
+            slab = jnp.moveaxis(jnp.swapaxes(x, -1, -2).reshape(
+                *x.shape[:-2], x.shape[-1], n, ps), -2, 0)
+        else:
+            slab = jnp.moveaxis(
+                x.reshape(*x.shape[:-2], n, ps, x.shape[-1]), -3, 0)
+        return pool.at[row[:n]].set(slab.astype(pool.dtype), mode="drop")
 
-    new_k = cache.k.at[row[:n]].set(paged_slab(k).astype(cache.k.dtype),
-                                    mode="drop")
-    new_v = cache.v.at[row[:n]].set(paged_slab(v).astype(cache.v.dtype),
-                                    mode="drop")
+    pools = _pools(cache, write, k, v)
     owned = jnp.sum((row != cache.null_page).astype(jnp.int32))
     return cache.replace(
-        k=new_k, v=new_v,
+        **pools,
         page_table=jax.lax.dynamic_update_slice(
             cache.page_table, row[None], (slot, zero)),
         lengths=jax.lax.dynamic_update_slice(
@@ -615,14 +683,8 @@ def insert_tokens(cache: PagedKVCache, slot, k, v, length, row,
     entries), so one compiled insert serves every page assignment and
     every ``start``.
     """
-    ps, mpps, s = cache.page_size, cache.max_pages_per_slot, k.shape[2]
-    if k.shape != v.shape or k.shape[0] != cache.layers \
-            or k.shape[1] != cache.kv_heads \
-            or k.shape[3] != cache.head_dim:
-        raise ValueError(
-            f"prefill k/v must be [layers={cache.layers}, "
-            f"kv_heads={cache.kv_heads}, s, head_dim={cache.head_dim}], "
-            f"got k {tuple(k.shape)} v {tuple(v.shape)}")
+    ps, mpps, s = cache.page_size, cache.max_pages_per_slot, k.shape[-2]
+    _check_rows(cache, k, v, "prefill k/v", (cache.layers,))
     if s < 1 or s > cache.max_seq:
         raise ValueError(
             f"suffix slab length {s} must be in [1, max_seq "
@@ -655,22 +717,32 @@ def insert_tokens(cache: PagedKVCache, slot, k, v, length, row,
         jnp.int32(cache.pages))
 
     def write(pool, x):
-        layers, kvh, _, d = x.shape
         slab = jnp.take(pool, page_ids, axis=0, mode="clip")
+        zero = jnp.int32(0)
+        if cache.latent:
+            # [n, layers, w, ps] -> [layers, w, n * ps]: the positions
+            # lie along the minor axis, token t at (start % ps) + t
+            layers, _, w = x.shape
+            flat = jnp.moveaxis(slab, 0, -2).reshape(layers, w, n * ps)
+            flat = jax.lax.dynamic_update_slice(
+                flat, jnp.swapaxes(x, -1, -2).astype(pool.dtype),
+                (zero, zero, start % ps))
+            slab = jnp.moveaxis(flat.reshape(layers, w, n, ps), -2, 0)
+            return pool.at[page_ids].set(slab, mode="drop")
+        layers, kvh, _, d = x.shape
         # [n, layers, kvh, ps, d] -> [layers, kvh, n * ps, d]: token t
         # of the slab sits at row (start % ps) + t
         flat = jnp.moveaxis(slab, 0, 2).reshape(layers, kvh, n * ps, d)
-        zero = jnp.int32(0)
         flat = jax.lax.dynamic_update_slice(
             flat, x.astype(pool.dtype), (zero, zero, start % ps, zero))
         slab = jnp.moveaxis(flat.reshape(layers, kvh, n, ps, d), 2, 0)
         return pool.at[page_ids].set(slab, mode="drop")
 
-    new_k, new_v = write(cache.k, k), write(cache.v, v)
+    pools = _pools(cache, write, k, v)
     owned = jnp.sum((row != cache.null_page).astype(jnp.int32))
     zero = jnp.int32(0)
     return cache.replace(
-        k=new_k, v=new_v,
+        **pools,
         page_table=jax.lax.dynamic_update_slice(
             cache.page_table, row[None], (slot, zero)),
         lengths=jax.lax.dynamic_update_slice(
@@ -768,18 +840,13 @@ def cow_page(cache: PagedKVCache, src, dst) -> PagedKVCache:
     """
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
-    zero = jnp.int32(0)
-    page_k = jax.lax.dynamic_slice(
-        cache.k, (src, zero, zero, zero, zero),
-        (1,) + cache.k.shape[1:])
-    page_v = jax.lax.dynamic_slice(
-        cache.v, (src, zero, zero, zero, zero),
-        (1,) + cache.v.shape[1:])
-    new_k = jax.lax.dynamic_update_slice(
-        cache.k, page_k, (dst, zero, zero, zero, zero))
-    new_v = jax.lax.dynamic_update_slice(
-        cache.v, page_v, (dst, zero, zero, zero, zero))
-    return cache.replace(k=new_k, v=new_v)
+    def copy(pool, _):
+        rest = (jnp.int32(0),) * (pool.ndim - 1)
+        page = jax.lax.dynamic_slice(pool, (src,) + rest,
+                                     (1,) + pool.shape[1:])
+        return jax.lax.dynamic_update_slice(pool, page, (dst,) + rest)
+
+    return cache.replace(**_pools(cache, copy, None, None))
 
 
 def extract_pages(cache: PagedKVCache, page_ids):
@@ -804,6 +871,8 @@ def extract_pages(cache: PagedKVCache, page_ids):
             f"page_ids must be a rank-1 int32 vector, got shape "
             f"{tuple(page_ids.shape)}")
     k_slab = jnp.take(cache.k, page_ids, axis=0, mode="clip")
+    if cache.v is None:             # a latent pool: one slab, no values
+        return k_slab, None
     v_slab = jnp.take(cache.v, page_ids, axis=0, mode="clip")
     return k_slab, v_slab
 
@@ -828,18 +897,18 @@ def restore_pages(cache: PagedKVCache, page_ids, k_slab,
         raise ValueError(
             f"page_ids must be a rank-1 int32 vector, got shape "
             f"{tuple(page_ids.shape)}")
-    n = page_ids.shape[0]
-    want = (n, cache.layers, cache.kv_heads, cache.page_size,
-            cache.head_dim)
-    if tuple(k_slab.shape) != want or tuple(v_slab.shape) != want:
+    want = (page_ids.shape[0],) + tuple(cache.k.shape[1:])
+    if tuple(k_slab.shape) != want or (
+            v_slab is not None and tuple(v_slab.shape) != want):
         raise ValueError(
             f"swap-in slabs must be {want}, got k "
-            f"{tuple(k_slab.shape)} v {tuple(v_slab.shape)}")
-    new_k = cache.k.at[page_ids].set(k_slab.astype(cache.k.dtype),
-                                     mode="drop")
-    new_v = cache.v.at[page_ids].set(v_slab.astype(cache.v.dtype),
-                                     mode="drop")
-    return cache.replace(k=new_k, v=new_v)
+            f"{tuple(k_slab.shape)} v "
+            f"{None if v_slab is None else tuple(v_slab.shape)}")
+
+    def write(pool, slab):
+        return pool.at[page_ids].set(slab.astype(pool.dtype), mode="drop")
+
+    return cache.replace(**_pools(cache, write, k_slab, v_slab))
 
 
 def _append_layer_paged(cache: PagedKVCache, layer: int, k_tok,
@@ -871,12 +940,13 @@ def _append_layer_paged(cache: PagedKVCache, layer: int, k_tok,
     sid = jnp.arange(cache.slots, dtype=jnp.int32)
 
     def write(pool, tok):
+        # [slots, kv_heads, ps, d] — or a latent page, [slots, w, ps]:
+        # the slot's position is the third axis of either
         cur = pool[pages, layer]
-        cur = cur.at[sid, :, offs, :].set(tok.astype(pool.dtype))
+        cur = cur.at[sid, :, offs].set(tok.astype(pool.dtype))
         return pool.at[pages, layer].set(cur, mode="drop")
 
-    return cache.replace(k=write(cache.k, k_tok),
-                         v=write(cache.v, v_tok))
+    return cache.replace(**_pools(cache, write, k_tok, v_tok))
 
 
 class PageAllocator:

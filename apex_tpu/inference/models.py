@@ -9,7 +9,7 @@ the cache) and a speculative *verify* (a drafted slab per slot).  The
 forwards consume the EXACT param pytree ``model.init`` produces — no
 re-keying, no conversion step.
 
-**A kind is a record** (:class:`Kind`, three entries in :data:`KINDS`):
+**A kind is a record** (:class:`Kind`, four entries in :data:`KINDS`):
 its geometry (``dims`` / ``check``), its per-layer pieces (``embed``,
 ``rope``, ``norm``, ``project``, ``attn_out``, ``ffn``, ``head``), its
 fused-block layout or ``None``, the names of the counters its steps
@@ -21,7 +21,8 @@ lets ``tests/L0/run_inference/test_engine_parity.py`` pin prefill +
 decode against ``model.apply``; ``laguna``'s pieces ARE
 ``standalone_laguna``'s (``attn_project`` / ``attn_output`` / ``ffn`` /
 ``rope_cos_sin``), held to the benchmark's plain reference by
-``test_laguna_parity.py``.
+``test_laguna_parity.py``; ``axk1``'s are ``standalone_axk1``'s
+(ISSUE 34), held to its reference by ``test_axk1_parity.py``.
 
 **A mode is a loop** (:func:`prefill_forward`, :func:`decode_forward`,
 :func:`verify_forward`): each owns its activation layout, where k/v go
@@ -31,6 +32,16 @@ slab), which attention reads them, and — in decode — the fused-block and
 per-slot window ring is read from the record's ``layer_types``, never
 from the kind's name; the engine (``engine.py``) likewise asks the
 record, so adding a kind is adding a record.
+
+**How a layer attends is the record's too.**  Most kinds project q, k
+and v, cache k and v per KV head and attend them in both modes.  A kind
+with LATENT attention (``Kind.latent``, ISSUE 34) caches one row a
+position — the pool has no KV-head axis and no value array — and attends
+it in two forms of the same function: *expanded* in prefill (keys and
+values made from the latent a layer at a time, then the flash kernel with
+a value width of its own) and *absorbed* in decode (the key up-projection
+folded into the query, ``apex_paged_decode_latent`` over the latent pool,
+the value up-projection behind it).
 
 Unsupported training-only configs (scan_layers, the capacity-slot MoE
 FFN of ``transformer/moe/MoELayer``, sequence/context parallelism) fail
@@ -73,12 +84,13 @@ from apex_tpu.transformer.functional.fused_rope import (
 )
 from apex_tpu.transformer.moe.dropless import fold_stats
 from apex_tpu.transformer.parallel_state import TENSOR_AXIS
+from apex_tpu.transformer.testing import standalone_axk1 as axk1
 from apex_tpu.transformer.testing import standalone_laguna as laguna
 from apex_tpu.transformer.testing.standalone_llama import _rope_cos_sin
 
 __all__ = ["Kind", "KINDS", "model_dims", "tp_dims", "check_supported",
-           "prefill_forward", "LAGUNA_STATS", "stats_tail",
-           "laguna_stats_tail", "decode_forward", "verify_forward",
+           "prefill_forward", "EXPERT_STATS", "stats_tail", "Latent",
+           "cache_row_values", "decode_forward", "verify_forward",
            "fused_layer_params", "expand_kv_for_tp",
            "param_partition_specs", "fused_partition_specs"]
 
@@ -171,7 +183,8 @@ def _gpt_dims(cfg) -> dict:
             "kv_heads": cfg.num_attention_heads,
             "head_dim": cfg.hidden_size // cfg.num_attention_heads,
             "layer_types": (FULL,) * cfg.num_layers,
-            "pool_layers": cfg.num_layers, "window_layers": 0, "window": 0}
+            "pool_layers": cfg.num_layers, "window_layers": 0, "window": 0,
+            "latent": 0}
 
 
 def _check_dense(cfg) -> None:
@@ -353,9 +366,12 @@ def _llama_fused_tail(cfg, blk, x, part):
 # token
 # --------------------------------------------------------------------------
 
-#: what a laguna step reports beside its tokens, in this order — the
-#: int32 tail of the token read (``InferenceEngine.stats_tail`` long)
-LAGUNA_STATS = ("moe_assignments", "moe_experts_hit",
+#: what a step of a kind with an expert FFN reports beside its tokens, in
+#: this order — the int32 tail of the token read
+#: (``InferenceEngine.stats_tail`` long, ``ServeTelemetry.expert_pass``'s
+#: arguments).  A kind that HOLDS a share of its experts counts the
+#: assignments that land on a held expert and the held experts hit.
+EXPERT_STATS = ("moe_assignments", "moe_experts_hit",
                 "moe_expert_load_max", "window_pages_live")
 
 
@@ -367,7 +383,7 @@ def _laguna_dims(cfg) -> dict:
             "kv_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
             "pool_layers": len(cfg.full_layers),
             "window_layers": len(cfg.window_layers),
-            "window": cfg.sliding_window}
+            "window": cfg.sliding_window, "latent": 0}
 
 
 def _laguna_check(cfg) -> None:
@@ -404,6 +420,48 @@ def _laguna_ffn(cfg, i, lp, h, valid, tp):
 
 
 # --------------------------------------------------------------------------
+# A.X-K1 (standalone_axk1's per-layer pieces; ISSUE 34): latent attention
+# over a pool with no KV-head axis, a group-limited sigmoid router, an
+# expert FFN that holds a share of its experts
+# --------------------------------------------------------------------------
+
+def _axk1_dims(cfg) -> dict:
+    return {"layers": cfg.num_layers, "heads": cfg.num_heads,
+            "kv_heads": 0, "head_dim": cfg.qk_head_dim,
+            "layer_types": (FULL,) * cfg.num_layers,
+            "pool_layers": cfg.num_layers, "window_layers": 0, "window": 0,
+            "latent": cfg.latent_dim, "latent_values": cfg.kv_lora_rank}
+
+
+def _axk1_check(cfg) -> None:
+    if not isinstance(cfg, axk1.AXK1Config):
+        raise TypeError(
+            f"the 'axk1' kind takes an AXK1Config, got "
+            f"{type(cfg).__name__}")
+
+
+def _axk1_rope(cfg, dims, positions, n):
+    if positions is None:
+        positions = jnp.arange(n, dtype=jnp.int32)
+    return {FULL: axk1.rope_cos_sin(cfg, positions)}
+
+
+def _axk1_attn_out(lp, ctx, extra, tp):
+    return axk1.attn_output(lp, ctx)
+
+
+def _axk1_ffn(cfg, i, lp, h, valid, tp):
+    y, stats = axk1.ffn(cfg, i, lp, h.reshape(-1, h.shape[-1]),
+                        valid=valid)
+    return y.reshape(h.shape), stats
+
+
+def _not_built(what: str, module: str) -> str:
+    return (f"{what} is not built for the 'axk1' kind: {module} would "
+            f"have to change")
+
+
+# --------------------------------------------------------------------------
 # the record
 # --------------------------------------------------------------------------
 
@@ -420,6 +478,24 @@ class Fused:
     tail: Callable
     #: ``cfg -> float``: the norms' epsilon
     eps: Callable
+
+
+@dataclasses.dataclass(frozen=True)
+class Latent:
+    """How a layer of a kind with latent attention attends (ISSUE 34):
+    the cache holds one ``row`` a position, ``dims["latent"]`` wide, whose
+    leading ``dims["latent_values"]`` columns are the values."""
+    #: ``(cfg, lp, h, cos, sin) -> q, k [..., heads, d], v [..., heads,
+    #: dv], row [..., width]``: the EXPANDED form, for prefill
+    expand: Callable
+    #: ``(cfg, lp, h, cos, sin) -> q [..., heads, width], row [...,
+    #: width]``: the ABSORBED query against the cached rows, for decode
+    absorb: Callable
+    #: ``(cfg, lp, u [..., heads, latent_values]) -> ctx [..., heads,
+    #: dv]``: the value up-projection, behind the softmax
+    value_up: Callable
+    #: ``cfg -> float``: the softmax scale (no head size gives it)
+    scale: Callable
 
 
 @dataclasses.dataclass(frozen=True)
@@ -446,8 +522,9 @@ class Kind:
     norm: Callable
     #: ``(cfg, dims, i, lp, h, rope) -> q [..., heads, d], k, v [...,
     #: kv_heads, d], extra`` — roped where the kind ropes; ``extra`` is
-    #: whatever ``attn_out`` wants back (laguna's gate)
-    project: Callable
+    #: whatever ``attn_out`` wants back (laguna's gate).  None for a
+    #: kind with ``latent`` attention, which has no per-head k/v to cache
+    project: Optional[Callable]
     #: ``(lp, ctx [..., heads, d], extra, tp) -> [..., hidden]``
     attn_out: Callable
     #: ``(cfg, i, lp, h, valid, tp) -> (y, stats | None)``
@@ -456,6 +533,8 @@ class Kind:
     head: Callable
     #: the fused-block layout, or None where the kernel is not built
     fused: Optional[Fused] = None
+    #: latent attention's two forms, or None: q/k/v through ``project``
+    latent: Optional[Latent] = None
     #: names of the int32 counters a step appends to its tokens
     stats: Tuple[str, ...] = ()
     #: feature -> why it is not built for the kind; the features are
@@ -482,7 +561,7 @@ KINDS = {
         dims=_laguna_dims, check=_laguna_check, embed=_token_embed,
         rope=_laguna_rope, norm=_rms_norm, project=_laguna_project,
         attn_out=_laguna_attn_out, ffn=_laguna_ffn, head=_untied_head,
-        stats=LAGUNA_STATS,
+        stats=EXPERT_STATS,
         refuses={
             "dense": "the 'laguna' kind serves from the paged cache only "
                      "(its full layers page, its window layers ring): "
@@ -504,13 +583,46 @@ KINDS = {
                               "need are not in the pages a prefix "
                               "shares)",
         }),
+    "axk1": Kind(
+        dims=_axk1_dims, check=_axk1_check, embed=_token_embed,
+        rope=_axk1_rope, norm=_rms_norm, project=None,
+        attn_out=_axk1_attn_out, ffn=_axk1_ffn, head=_untied_head,
+        latent=Latent(expand=axk1.attn_expand, absorb=axk1.attn_absorb,
+                      value_up=axk1.attn_value_up,
+                      scale=axk1.softmax_scale),
+        stats=EXPERT_STATS,
+        refuses={
+            "dense": "the 'axk1' kind serves from the paged cache only "
+                     "(a latent pool, one row a position: "
+                     "ops/attention.py's decode_attention scores per-head "
+                     "k/v): pass page_size=/num_pages=",
+            "tp": _not_built(
+                "tp > 1", "models.param_partition_specs and "
+                "kv_cache.paged_cache_partition_specs (a replicated "
+                "latent, head-sharded up-projections, the all-to-all "
+                "round the held experts)"),
+            "verify": _not_built(
+                "speculative verify", "ops/paged_attention.py's "
+                "paged_slab_attention (it gathers per-head k/v windows) "
+                "and kv_cache.append_slab"),
+            "host_tier": _not_built(
+                "the host KV tier", "kv_cache.HostPageStore and "
+                "engine.swap_in_pages (they move a k and a v slab)"),
+            "fused": _not_built(
+                "fused_block_decode", "ops/paged_attention.py's "
+                "_fused_block_kernel (per-head k/v, a dense FFN)"),
+            "prefix_sharing": _not_built(
+                "prefix sharing, and with it chunked prefill,",
+                "models._suffix_attend (the cached prefix would have to "
+                "be up-projected a chunk at a time)"),
+        }),
 }
 
 
 def _kind(kind: str) -> Kind:
     if kind not in KINDS:
         raise ValueError(f"unknown generative model kind {kind!r} "
-                         "(expected 'gpt', 'llama' or 'laguna')")
+                         f"(expected one of {', '.join(map(repr, KINDS))})")
     return KINDS[kind]
 
 
@@ -522,6 +634,12 @@ def model_dims(kind: str, cfg) -> dict:
     module docstring).  ``laguna`` (ISSUE 30) has no ONE head count:
     its ``heads`` is a per-layer tuple."""
     return _kind(kind).dims(cfg)
+
+
+def cache_row_values(dims: dict, kv_heads: int) -> int:
+    """Values ONE cached position holds in ONE layer, over every buffer:
+    a key and a value per KV head — or the latent row, which is both."""
+    return dims["latent"] or 2 * kv_heads * dims["head_dim"]
 
 
 def tp_dims(kind: str, cfg, tp: int) -> dict:
@@ -796,19 +914,18 @@ def _cache_attend(cache, layer: int, q, live, work):
     return decode_attention(q, cache.k[:, layer], cache.v[:, layer], live)
 
 
-def stats_tail(acc, cache):
-    """A step's counters as ``int32[4]`` in ``LAGUNA_STATS`` order: the
-    expert counters the loop folded over its layers (``acc``), and the
-    ring pages live in ``cache`` once the step has updated it."""
+def stats_tail(names, acc, cache):
+    """A step's counters as ``int32[len(names)]``, ``names`` the record's
+    ``stats``: the expert counters the loop folded over its layers
+    (``acc``, None where no expert layer ran), and the ring pages live in
+    ``cache`` once the step has updated it (0 without rings)."""
+    acc = acc or {}
     zero = jnp.int32(0)
-    acc = acc or {"assignments": zero, "experts_hit": zero,
-                  "load_max": zero}
-    return jnp.stack([acc["assignments"], acc["experts_hit"],
-                      acc["load_max"], kv_cache.window_pages_live(cache)
-                      ]).astype(jnp.int32)
-
-
-laguna_stats_tail = stats_tail          # the name ISSUE 30 gave it
+    values = {"moe_assignments": acc.get("assignments", zero),
+              "moe_experts_hit": acc.get("experts_hit", zero),
+              "moe_expert_load_max": acc.get("load_max", zero),
+              "window_pages_live": kv_cache.window_pages_live(cache)}
+    return jnp.stack([values[n] for n in names]).astype(jnp.int32)
 
 
 # --------------------------------------------------------------------------
@@ -820,7 +937,9 @@ def prefill_forward(kind: str, cfg, params, tokens, length=None, *,
     """Full-prompt forward: ``tokens [1, s]`` -> ``(logits, ks, vs, wks,
     wvs, stats)``.  ``ks`` / ``vs`` ``[pool_layers, kv_heads, s,
     head_dim]`` are the pool layers' k/v, ready for
-    :func:`kv_cache.insert` / ``insert_tokens``; ``wks`` / ``wvs`` the
+    :func:`kv_cache.insert` / ``insert_tokens`` (a kind with latent
+    attention: ``ks [pool_layers, s, width]`` the latent rows, ``vs``
+    None — the expanded k/v are never cached); ``wks`` / ``wvs`` the
     window layers', for ``insert_window`` (None for a kind without
     them); ``stats`` the expert counters folded over the layers (None
     without an expert FFN).
@@ -873,22 +992,33 @@ def prefill_forward(kind: str, cfg, params, tokens, length=None, *,
     valid = None if length is None or not rec.stats else (
         jnp.arange(s, dtype=jnp.int32) < length)
 
+    # a latent kind's softmax scale is its own; None is the head size's
+    scale = rec.latent.scale(cfg) if rec.latent else None
     ks, vs, wks, wvs, stats = [], [], [], [], None
     for i, (pooled, n) in enumerate(_cache_layers(dims["layer_types"])):
         lp = p[f"layer_{i}"]
-        q, k, v, extra = rec.project(
-            cfg, dims, i, lp, rec.norm(cfg, lp, "input", h),
-            rope.get(dims["layer_types"][i]))               # [s, b, n, d]
+        hn = rec.norm(cfg, lp, "input", h)
+        lrope = rope.get(dims["layer_types"][i])
+        if rec.latent:
+            # expanded: k/v made from the latent, the latent row cached
+            q, k, v, latent_row = rec.latent.expand(cfg, lp, hn, *lrope)
+            ks.append(latent_row[:, 0])                     # [s, width]
+            extra = None
+        else:
+            q, k, v, extra = rec.project(cfg, dims, i, lp, hn,
+                                         lrope)             # [s, b, n, d]
         q, k, v = (t.transpose(1, 2, 0, 3) for t in (q, k, v))
-        # cache the PRE-broadcast kv (once per kv head)
-        (ks if pooled else wks).append(k[0])                # [kv, s, d]
-        (vs if pooled else wvs).append(v[0])
+        if not rec.latent:
+            # cache the PRE-broadcast kv (once per kv head)
+            (ks if pooled else wks).append(k[0])            # [kv, s, d]
+            (vs if pooled else wvs).append(v[0])
         if suffix:
             ctx = _suffix_attend(cache, n, row, q, k, v, prefill_from)
         else:
             heads = q.shape[1]
             ctx = flash_attention(
                 q, _expand_kv(k, heads), _expand_kv(v, heads), causal=True,
+                sm_scale=scale,
                 window=None if pooled else dims["window"])
         x = h + rec.attn_out(lp, ctx.transpose(2, 0, 1, 3), extra, tp)
         y, st = rec.ffn(cfg, i, lp, rec.norm(cfg, lp, "post_attention", x),
@@ -954,17 +1084,30 @@ def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
             h = rec.fused.tail(cfg, fused[i], h, out) if tp > 1 else out
             continue
         lp = p[f"layer_{i}"]
-        q, k_tok, v_tok, extra = rec.project(
-            cfg, dims, i, lp, rec.norm(cfg, lp, "input", h),
-            rope.get(dims["layer_types"][i]))
-        if pooled:
-            cache = kv_cache.append_layer(cache, n, k_tok, v_tok)
-            # grouped-query scoring straight off the per-kv-head pool
-            ctx = _cache_attend(cache, n, q, live, work)
+        hn = rec.norm(cfg, lp, "input", h)
+        lrope = rope.get(dims["layer_types"][i])
+        if rec.latent:
+            # absorbed: the query against the latent rows, one pool, one
+            # DMA a page; the value up-projection behind the softmax
+            q, latent_row = rec.latent.absorb(cfg, lp, hn, *lrope)
+            cache = kv_cache.append_layer(cache, n, latent_row, None)
+            u = paged_decode_attention(
+                q, cache.k, None, cache.page_table, live, layer=n,
+                work=work, sm_scale=rec.latent.scale(cfg),
+                values=dims["latent_values"])
+            ctx, extra = rec.latent.value_up(cfg, lp, u), None
         else:
-            cache = kv_cache.append_window(cache, n, k_tok, v_tok)
-            ctx = ring_decode_attention(q, cache.wk[n], cache.wv[n],
-                                        positions, window=dims["window"])
+            q, k_tok, v_tok, extra = rec.project(cfg, dims, i, lp, hn,
+                                                 lrope)
+            if pooled:
+                cache = kv_cache.append_layer(cache, n, k_tok, v_tok)
+                # grouped-query scoring straight off the per-kv-head pool
+                ctx = _cache_attend(cache, n, q, live, work)
+            else:
+                cache = kv_cache.append_window(cache, n, k_tok, v_tok)
+                ctx = ring_decode_attention(
+                    q, cache.wk[n], cache.wv[n], positions,
+                    window=dims["window"])
         x = h + rec.attn_out(lp, ctx, extra, tp)
         y, st = rec.ffn(cfg, i, lp, rec.norm(cfg, lp, "post_attention", x),
                         active, tp)

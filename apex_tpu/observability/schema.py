@@ -176,16 +176,19 @@ METRIC_SPECS: Dict[str, MetricSpec] = {s.name: s for s in [
                "resident and was served by swap-in uploads"),
     # -- expert FFN + window rings (ISSUE 30): counted on the device by
     #    the step itself and read with its tokens, per phase (a prefill
-    #    routes a prompt, a decode step one token a slot).
+    #    routes a prompt, a decode step one token a slot).  A kind that
+    #    holds a share of its experts (ISSUE 34) counts what LANDS here.
     MetricSpec("serve_moe_passes_total", "counter",
                "steps that ran an expert FFN", labels=("phase",)),
     MetricSpec("serve_moe_assignments_total", "counter",
                "(token, expert) assignments routed, summed over the "
-               "expert layers (tokens x experts_per_token x layers)",
-               labels=("phase",)),
+               "expert layers (tokens x experts_per_token x layers); for "
+               "a kind that HOLDS a share of its experts, the assignments "
+               "that landed on a held expert", labels=("phase",)),
     MetricSpec("serve_moe_experts_hit_total", "counter",
                "experts that received at least one token, summed over "
-               "the expert layers of every step", labels=("phase",)),
+               "the expert layers of every step (of the experts held, "
+               "for a kind that holds a share)", labels=("phase",)),
     MetricSpec("serve_moe_expert_load_max_total", "counter",
                "the busiest expert's tokens in a step (max over its "
                "expert layers), summed over steps — over assignments "

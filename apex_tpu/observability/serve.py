@@ -524,7 +524,7 @@ class ServeTelemetry:
 
     def expert_pass(self, phase: str, assignments: int, experts_hit: int,
                     load_max: int, window_pages: int) -> None:
-        """One step's device-side counters (``models.LAGUNA_STATS``
+        """One step's device-side counters (``models.EXPERT_STATS``
         order), ``phase`` ``"prefill"`` or ``"decode"``."""
         self.moe_passes.inc(phase=phase)
         self.moe_assignments.inc(assignments, phase=phase)

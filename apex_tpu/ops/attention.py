@@ -304,6 +304,7 @@ def _fwd_kernel(causal, off, scale, bq, bk, nk, masked, valid, rate,
 def _fwd(q3, k3, v3, mask3, causal, scale, bq, bk, out_dtype=None,
          causal_off=None, valid=None, rate=0.0, seed3=None, window=None):
     bh, sq, d = q3.shape
+    dv = v3.shape[2]        # the values' own width (latent attention)
     out_dtype = out_dtype or q3.dtype
     sk = k3.shape[1]
     off = (sk - sq) if causal_off is None else causal_off
@@ -322,7 +323,7 @@ def _fwd(q3, k3, v3, mask3, causal, scale, bq, bk, out_dtype=None,
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, bk, d), kv_index),
-        pl.BlockSpec((1, bk, d), kv_index),
+        pl.BlockSpec((1, bk, dv), kv_index),
     ]
     operands = [q3, k3, v3]
     if masked:
@@ -341,17 +342,17 @@ def _fwd(q3, k3, v3, mask3, causal, scale, bq, bk, out_dtype=None,
         grid=(bh, nq, nk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bq, _STAT_LANES), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), out_dtype),
+            jax.ShapeDtypeStruct((bh, sq, dv), out_dtype),
             jax.ShapeDtypeStruct((bh, sq, _STAT_LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -851,9 +852,14 @@ def flash_attention(q, k, v, *, causal: bool = False, mask=None,
     behind the window are neither fetched nor computed, the partial one
     is masked.  ``None`` compiles exactly the kernel it always did.
     Forward only: the backward kernels know no window and refuse.
+
+    ``v`` may have a width of its own, ``[b, h, sk, dv]`` (ISSUE 34:
+    latent attention scores over 192 channels and sums values of 128):
+    the output is ``[b, h, sq, dv]``.  Forward only, like the window;
+    ``dv == d`` compiles exactly the kernel it always did.
     """
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, v_width = k.shape[2], v.shape[3]
     scale = (d ** -0.5) if sm_scale is None else sm_scale
     if window is not None and (not causal or window < 1):
         raise ValueError(
@@ -900,7 +906,12 @@ def flash_attention(q, k, v, *, causal: bool = False, mask=None,
     # a mask operand adding a [bq, bk] block per grid step) fall back
     # to the conservative 512 so previously-compiling calls keep
     # compiling.  _plan_block shrinks further for short sequences.
-    default_block = 1024 if (d <= 128 and mask is None) else 512
+    # Latent attention's expanded form (scores over 192 channels, values
+    # of 128) is inside the envelope too: on the v5e 1024x1024 blocks ran
+    # its forward in 4.85 ms against 7.36 at [1, 64, 4096] and 15.9
+    # against 26.0 at 8192 (PERF.md section 6, PR 34).
+    roomy = d <= 128 or (d <= 192 and v_width <= 128)
+    default_block = 1024 if (roomy and mask is None) else 512
     if window is not None:
         # a band `window` wide under 1024-wide blocks is mostly masked
         # work: blocks no wider than the window (but lane-wide)
@@ -915,7 +926,7 @@ def flash_attention(q, k, v, *, causal: bool = False, mask=None,
 
     q3 = q.reshape(b * h, sq, d)
     k3 = k.reshape(b * h, sk, d)
-    v3 = v.reshape(b * h, sk, d)
+    v3 = v.reshape(b * h, sk, v_width)
     mask3 = None
     if mask is not None:
         # shape already validated ahead of the use_kernel dispatch
@@ -957,6 +968,10 @@ def flash_attention(q, k, v, *, causal: bool = False, mask=None,
             raise NotImplementedError(
                 "flash_attention(window=) is forward-only: the backward "
                 "kernels recompute scores without the window mask")
+        if v_width != d:
+            raise NotImplementedError(
+                "flash_attention with a value width of its own is "
+                "forward-only: the backward kernels hold one head size")
         q3, k3, v3, mask3, seed3, out, lse = res
         dq, dk, dv = _bwd_impl(q3, k3, v3, mask3, out, lse, do3,
                                causal, scale, bq, bk,
@@ -968,7 +983,7 @@ def flash_attention(q, k, v, *, causal: bool = False, mask=None,
     out = run(q3, k3, v3, mask3, seed3)
     if padded:
         out = out[:, :sq, :]
-    return out.reshape(b, h, sq, d)
+    return out.reshape(b, h, sq, v_width)
 
 
 # --------------------------------------------------------------------------

@@ -36,6 +36,24 @@ GQA/MQA: ``kv_heads`` divides the query heads; the kernel loops kv
 heads (static, small) scoring each head's ``group`` query rows against
 the once-per-kv-head page — no broadcast materialized anywhere.
 
+A third form, for LATENT attention (ISSUE 34): ``apex_paged_decode_latent``
+walks the SAME work list over a pool with no KV-head axis and no value
+array —
+
+    pool : [pages, layers, width, page_size]
+
+— one row a position that every query head scores whole (the absorbed
+query is ``width`` wide) and whose leading ``values`` channels ARE the
+values: an item is ONE DMA of a ``[width, page_size]`` block, not two,
+and the output is ``[slots, h, values]`` (the caller applies the value
+up-projection per head).  A page holds its positions along the minor
+axis (``kv_cache`` module docstring: a 576-wide minor axis is laid out
+transposed by the chip's runtime and would be copied for the kernel);
+the scores are then a plain product and the values contract the minor
+axis of both operands, the form q k^T has in the other kernel.  Same scalar-prefetch operands, same online
+softmax, its own ``pallas_call`` name so a trace tells the forms apart;
+the per-head-K/V calls compile what they always did.
+
 The speculative verify slab (:func:`paged_slab_attention`) still
 gathers the slot windows and scores them with the dense XLA chain.
 """
@@ -69,6 +87,7 @@ __all__ = ["paged_decode_attention", "paged_work_list", "PagedWork",
 #: still covers the slot's max_pages and skips the dead ones.
 PALLAS_AUDIT = {
     "_paged_kernel": {"reduction": True, "masked_tail": True},
+    "_latent_kernel": {"reduction": True, "masked_tail": True},
     "_fused_block_kernel": {"reduction": True, "masked_tail": True},
 }
 
@@ -224,12 +243,101 @@ def _paged_kernel_call(q, k_pool, v_pool, work, layer, *, scale):
 
 
 # --------------------------------------------------------------------------
+# the latent form: one pool, every head scores the whole row
+# --------------------------------------------------------------------------
+
+def _latent_kernel(scale, ps, dv,
+                   slot_ref, page_ref, start_ref, len_ref, layer_ref,
+                   q_ref, c_ref, o_ref, m_scr, l_scr, acc_scr):
+    c_ref = c_ref.at[0, 0]                  # the block [1, 1, width, ps]
+    item = pl.program_id(0)
+    sid = slot_ref[item]
+    p = item - start_ref[sid]               # the page's place in the slot
+
+    @pl.when(p == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    length = len_ref[sid]
+
+    @pl.when(length > 0)                    # an empty slot's one item
+    def _body():
+        q = q_ref[0]                                     # [h, width]
+        rows = c_ref[...]                                # [width, ps]
+        s = jax.lax.dot(
+            q, rows,
+            preferred_element_type=jnp.float32) * (scale * _LOG2E)
+        cols = p * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(cols < length, s, _NEG_INF)
+        m_prev = m_scr[...]                              # [h, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp2(m_prev - m_new)
+        pmat = jnp.exp2(s - m_new)
+        l_scr[...] = l_scr[...] * alpha + \
+            jnp.sum(pmat, axis=1, keepdims=True)
+        # the values are the rows' leading channels: no second block
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            pmat.astype(rows.dtype), rows[:dv, :],
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
+
+    @pl.when(item == start_ref[sid + 1] - 1)
+    def _finish():
+        l = l_scr[...]
+        o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)
+                    ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "values"))
+def _latent_kernel_call(q, pool, work, layer, *, scale, values):
+    # as _paged_kernel_call: the layer a traced scalar-prefetch operand,
+    # one trace and one Mosaic lowering for every layer of a step
+    slots, h, width = q.shape
+    ps = pool.shape[3]
+
+    def slot_index(i, slot, page, start, ln, ly):
+        return (slot[i], 0, 0)
+
+    def page_index(i, slot, page, start, ln, ly):
+        return (page[i], ly[0], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(work.start[-1],),             # traced: the live items only
+        in_specs=[
+            pl.BlockSpec((1, h, width), slot_index),
+            pl.BlockSpec((1, 1, width, ps), page_index),
+        ],
+        out_specs=pl.BlockSpec((1, h, values), slot_index),
+        scratch_shapes=[
+            pltpu.VMEM((h, 1), jnp.float32),      # running max (base 2)
+            pltpu.VMEM((h, 1), jnp.float32),      # running normalizer
+            pltpu.VMEM((h, values), jnp.float32),  # fp32 output accum
+        ],
+    )
+    kernel = functools.partial(_latent_kernel, scale, ps, values)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((slots, h, values), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret_mode(),
+        name="apex_paged_decode_latent",
+    )(work.slot, work.page, work.start, work.lengths, layer, q, pool)
+
+
+# --------------------------------------------------------------------------
 # public entry
 # --------------------------------------------------------------------------
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
                            layer: int, sm_scale: Optional[float] = None,
-                           work: Optional[PagedWork] = None):
+                           work: Optional[PagedWork] = None,
+                           values: Optional[int] = None):
     """Single-token attention against ONE layer of a paged KV pool.
 
     * ``q``: ``[slots, h, 1, d]`` (or ``[slots, h, d]``) — the current
@@ -252,6 +360,12 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
       through one table builds it once and hands it to each; left out,
       it is built here.
 
+    The LATENT form (module docstring): ``v_pool=None``, ``k_pool`` the
+    pool ``[pages, layers, d, page_size]`` of rows ``d`` wide like the
+    (absorbed) queries, ``values`` how many of a row's leading channels
+    are its values; the output is ``[slots, h, (1,) values]`` and
+    ``sm_scale`` is the caller's (``d`` is no head size).
+
     Always the Pallas kernel (interpret mode off-TPU), whatever the
     window: it walks the live pages and nothing else, with no
     materialized gather, scoring bf16 operands with fp32 accumulation
@@ -265,13 +379,22 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
         raise ValueError(
             f"paged_decode_attention is the q_len == 1 path, got q_len "
             f"{q_len}; use flash_attention for prefill")
-    if k_pool.shape != v_pool.shape or k_pool.ndim != 5 \
+    latent = v_pool is None
+    if latent:
+        if k_pool.ndim != 4 or k_pool.shape[2] != d or sm_scale is None \
+                or values is None or not 0 < values <= d:
+            raise ValueError(
+                f"the latent form takes the pool [pages, layers, {d}, "
+                f"page_size], values in (0, {d}] and sm_scale; got "
+                f"pool {tuple(k_pool.shape)}, values {values}, sm_scale "
+                f"{sm_scale}")
+    elif k_pool.shape != v_pool.shape or k_pool.ndim != 5 \
             or k_pool.shape[4] != d:
         raise ValueError(
             f"k/v must be the whole pool [pages, layers, kv_heads, "
             f"page_size, {d}] and equal-shaped; got k "
             f"{tuple(k_pool.shape)} v {tuple(v_pool.shape)}")
-    layers, kvh = k_pool.shape[1], k_pool.shape[2]
+    layers, kvh = k_pool.shape[1], 1 if latent else k_pool.shape[2]
     if not 0 <= layer < layers:
         raise ValueError(
             f"layer {layer} is outside the pool's {layers} layers")
@@ -289,9 +412,14 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
     if work is None:
         work = paged_work_list(page_table, lengths,
                                page_size=k_pool.shape[3])
-    out = _paged_kernel_call(
-        q[:, :, 0, :], k_pool, v_pool, work,
-        jnp.full((1,), layer, jnp.int32), scale=float(scale))
+    if latent:
+        out = _latent_kernel_call(
+            q[:, :, 0, :], k_pool, work, jnp.full((1,), layer, jnp.int32),
+            scale=float(scale), values=int(values))
+    else:
+        out = _paged_kernel_call(
+            q[:, :, 0, :], k_pool, v_pool, work,
+            jnp.full((1,), layer, jnp.int32), scale=float(scale))
     return out if squeezed else out[:, :, None, :]
 
 

@@ -30,10 +30,11 @@ from apex_tpu.transformer.moe.router import (TopKRouter,
                                              load_balancing_loss, sinkhorn)
 from apex_tpu.transformer.moe.experts import GroupedMLP
 from apex_tpu.transformer.moe.dropless import (dropless_moe_ffn,
+                                               route_group_limited,
                                                route_top_k)
 from apex_tpu.transformer.moe.layer import (MoELayer, reduce_moe_grads,
                                             resolve_dispatch_mode)
 
 __all__ = ["TopKRouter", "GroupedMLP", "MoELayer", "load_balancing_loss",
            "reduce_moe_grads", "resolve_dispatch_mode", "sinkhorn",
-           "dropless_moe_ffn", "route_top_k"]
+           "dropless_moe_ffn", "route_top_k", "route_group_limited"]

@@ -21,16 +21,40 @@ HIT and not the experts held.  Everything else is plain XLA.
 
 Experts are SwiGLU (``down(silu(gate(x)) * up(x))``) and the routing
 weight is applied to the expert's OUTPUT.  Forward only is exercised by
-serving; every op used is differentiable, so training can call it too.
+serving; every op of the path above is differentiable, so training can
+call it too — NOT the ``held=`` path below, whose loop of dynamic length
+has no reverse-mode derivative (forward only).
+
+**The experts held here (ISSUE 34).**  Under expert parallelism a chip
+holds a contiguous share of a layer's experts.  ``held=(first, count)``
+tells the layer so: the router still scores ALL experts (its weight keeps
+every row), the stacks are ``[count, ...]``, and an assignment to an
+expert that is not held is computed by nobody here and adds nothing — the
+result is this chip's PART of the routed sum (plus the shared expert,
+which every chip holds), and the parts of all the shares add up to the
+uncut layer.  The cost follows the assignments that LAND: they sort ahead
+of the others and are consumed in fixed blocks of rows under a dynamic
+trip count, so no ``[tokens x k, hidden]`` array of rows no held expert
+takes is ever made, and however many land — all of them on one expert —
+none is dropped.  ``held=None`` is the path above, text for text.
+
+Two routers: :func:`route_top_k` (softmax, top-k renormalised) and
+:func:`route_group_limited` (sigmoid scores, the best groups kept, top-k
+among their experts); ``router=`` takes any ``(x, router_w) -> (weights,
+experts)``.
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["route_top_k", "dropless_moe_ffn", "swiglu", "fold_stats"]
+__all__ = ["route_top_k", "route_group_limited", "dropless_moe_ffn",
+           "swiglu", "fold_stats", "HELD_ROW_BLOCK"]
+
+#: rows of landed assignments one trip of the held-experts loop takes
+HELD_ROW_BLOCK = 512
 
 
 def swiglu(x, w_gate, w_up, w_down):
@@ -52,9 +76,86 @@ def route_top_k(x, router_w, top_k: int, scale: float):
     return weights, top_e.astype(jnp.int32)
 
 
+def route_group_limited(x, router_w, top_k: int, scale: float, *,
+                        n_group: int, topk_group: int):
+    """Group-limited sigmoid router: ``(weights [T, k] float32, experts
+    [T, k] int32)``.
+
+    ``sigma = sigmoid(x W^T)`` in float32 over ALL experts, which lie in
+    ``n_group`` groups of consecutive experts; a group's score is the sum
+    of its two largest ``sigma``; the ``topk_group`` best groups are
+    kept; the ``top_k`` largest ``sigma`` among their experts are the
+    token's experts, weighted ``scale * sigma_e / (sum of the chosen +
+    1e-20)``.  No selection bias term."""
+    n_exp = router_w.shape[0]
+    logits = jnp.matmul(x, router_w.T, preferred_element_type=jnp.float32)
+    sig = jax.nn.sigmoid(logits.astype(jnp.float32))            # [T, E]
+    grouped = sig.reshape(-1, n_group, n_exp // n_group)
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    _, keep = jax.lax.top_k(group_score, topk_group)            # [T, g]
+    kept = jnp.any(keep[..., None] == jnp.arange(n_group), axis=1)
+    masked = jnp.where(jnp.repeat(kept, n_exp // n_group, axis=-1),
+                       sig, -1.0)              # sigma > 0: never chosen
+    top_s, top_e = jax.lax.top_k(masked, top_k)
+    weights = scale * top_s / (jnp.sum(top_s, axis=-1, keepdims=True)
+                               + 1e-20)
+    return weights, top_e.astype(jnp.int32)
+
+
+def _held_products(x, weights, experts, w_gate, w_up, w_down,
+                   held: Tuple[int, int], valid):
+    """The routed part of the experts ``[first, first + count)`` — see the
+    module docstring — as ``(y [T, hidden] float32, group_sizes
+    [count])``."""
+    first, count = held
+    t, hidden = x.shape
+    top_k = experts.shape[1]
+    n = t * top_k
+    block = min(HELD_ROW_BLOCK, n)
+    with jax.named_scope("apex_moe_sort"):
+        local = experts.reshape(-1) - first
+        landed = (local >= 0) & (local < count)
+        if valid is not None:
+            landed = landed & jnp.repeat(valid, top_k)
+        # what lands sorts by held expert, ahead of everything else
+        key = jnp.where(landed, local, count)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        group_sizes = jnp.bincount(key, length=count + 1)[:count].astype(
+            jnp.int32)
+        ends = jnp.cumsum(group_sizes)
+        n_landed = ends[-1]
+        # a whole number of blocks to slice from, whatever n
+        pad = -n % block
+        order = jnp.concatenate([order, jnp.zeros((pad,), jnp.int32)])
+        w_flat = weights.reshape(-1)
+
+    def trip(i, y):
+        lo = i * block
+        ids = jax.lax.dynamic_slice(order, (lo,), (block,))
+        live = lo + jnp.arange(block, dtype=jnp.int32) < n_landed
+        rows = ids // top_k
+        xs = jnp.take(x, rows, axis=0)                  # [block, hidden]
+        # the block's rows of each held expert's group
+        sizes = jnp.clip(ends, lo, lo + block) \
+            - jnp.clip(ends - group_sizes, lo, lo + block)
+        act = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, sizes)) \
+            * jax.lax.ragged_dot(xs, w_up, sizes)
+        ys = jax.lax.ragged_dot(act.astype(x.dtype), w_down, sizes)
+        w = jnp.where(live, jnp.take(w_flat, ids), 0.0)
+        # rows past the landed ones belong to no group: take none of them
+        ys = jnp.where(live[:, None], ys.astype(jnp.float32), 0.0)
+        return y.at[rows].add(ys * w[:, None])
+
+    with jax.named_scope("apex_moe_experts"):
+        y = jax.lax.fori_loop(0, (n_landed + block - 1) // block, trip,
+                              jnp.zeros((t, hidden), jnp.float32))
+    return y, group_sizes
+
+
 def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                      scale: float = 1.0, shared: Optional[dict] = None,
-                     valid=None):
+                     valid=None, held: Optional[Tuple[int, int]] = None,
+                     router: Optional[Callable] = None):
     """``x [T, hidden]`` -> ``(y [T, hidden], stats)``.
 
     ``router_w [E, hidden]``; ``w_gate``/``w_up`` ``[E, hidden, ffn]`` and
@@ -65,13 +166,27 @@ def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     nowhere (they cost no expert work, hit no expert and return only the
     shared expert's output).
 
+    ``router`` (optional) is ``(x, router_w) -> (weights [T, k] float32,
+    experts [T, k] int32)``; left out, :func:`route_top_k`.  ``held``
+    (optional) is ``(first, count)``, the experts whose stacks ``w_gate``
+    / ``w_up`` / ``w_down`` ``[count, ...]`` are: ``y`` is then their part
+    of the routed sum (module docstring) plus the shared expert.
+
     ``stats`` are int32 scalars computed on the device: ``assignments``
-    (valid tokens x ``top_k``), ``experts_hit`` (experts with at least one
-    token), ``load_max`` (the busiest expert's tokens)."""
+    (valid tokens x ``top_k``; with ``held``, those that LAND on a held
+    expert), ``experts_hit`` (experts — of those held — with at least one
+    token), ``load_max`` (the busiest of them's tokens)."""
     t, hidden = x.shape
     n_exp = router_w.shape[0]
     with jax.named_scope("apex_moe_route"):
-        weights, experts = route_top_k(x, router_w, top_k, scale)
+        weights, experts = (
+            route_top_k(x, router_w, top_k, scale) if router is None
+            else router(x, router_w))
+    if held is not None:
+        y, group_sizes = _held_products(x, weights, experts, w_gate, w_up,
+                                        w_down, held, valid)
+        y = y.astype(x.dtype)
+        return _finish(x, y, shared, group_sizes)
     with jax.named_scope("apex_moe_sort"):
         flat = experts.reshape(-1)
         if valid is not None:
@@ -96,6 +211,11 @@ def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
             # the grouped product writes nothing: take none of it
             y = jnp.where(valid[:, None, None], y, 0.0)
         y = jnp.sum(y * weights[..., None], axis=1).astype(x.dtype)
+    return _finish(x, y, shared, group_sizes)
+
+
+def _finish(x, y, shared, group_sizes):
+    """The shared expert on top of the routed sum, and the step's stats."""
     if shared is not None:
         with jax.named_scope("apex_moe_shared"):
             y = y + swiglu(x, shared["gate_proj"]["weight"],

@@ -214,12 +214,27 @@ def test_yarn_table_against_hand_computed_values():
 #: has carried since ISSUE 30 — kept letter for letter by the records
 REFUSED = [(kind, feature) for kind, rec in models.KINDS.items()
            for feature in rec.refuses]
-WORDS = {"dense": "serves from the paged cache only",
-         "tp": "tp > 1 is not built for the 'laguna' kind",
-         "verify": "speculative verify is not built for the 'laguna' kind",
-         "host_tier": "the host KV tier is not built for the 'laguna' kind",
-         "fused": "fused_block_decode is not built for the 'laguna' kind",
-         "prefix_sharing": "the 'laguna' kind prefills a prompt whole"}
+WORDS = {
+    "laguna": {
+        "dense": "serves from the paged cache only",
+        "tp": "tp > 1 is not built for the 'laguna' kind",
+        "verify": "speculative verify is not built for the 'laguna' kind",
+        "host_tier": "the host KV tier is not built for the 'laguna' kind",
+        "fused": "fused_block_decode is not built for the 'laguna' kind",
+        "prefix_sharing": "the 'laguna' kind prefills a prompt whole"},
+    # ISSUE 34: each with the module that would have to change
+    "axk1": {
+        "dense": "serves from the paged cache only",
+        "tp": "tp > 1 is not built for the 'axk1' kind: "
+              "models.param_partition_specs",
+        "verify": "speculative verify is not built for the 'axk1' kind: "
+                  "ops/paged_attention.py",
+        "host_tier": "the host KV tier is not built for the 'axk1' kind: "
+                     "kv_cache.HostPageStore",
+        "fused": "fused_block_decode is not built for the 'axk1' kind: "
+                 "ops/paged_attention.py",
+        "prefix_sharing": "chunked prefill, is not built for the 'axk1' "
+                          "kind: models._suffix_attend"}}
 #: how each feature is asked of an engine at construction
 ASKED = {"dense": dict(page_size=None, num_pages=None), "tp": dict(tp=2),
          "verify": dict(spec_k=2),
@@ -227,14 +242,24 @@ ASKED = {"dense": dict(page_size=None, num_pages=None), "tp": dict(tp=2),
          "fused": dict(decode_fusion="1")}
 
 
+@pytest.fixture(scope="module")
+def toys(tiny):
+    """kind -> (config, params) at toy size; a kind new here: its toy."""
+    from apex_tpu.transformer.testing import standalone_axk1 as SA
+    acfg = SA.AXK1Config()
+    return {"laguna": tiny[:2],
+            "axk1": (acfg, SA.axk1_model_provider(acfg).init(
+                jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))}
+
+
 @pytest.mark.parametrize("kind,feature", REFUSED)
 def test_what_is_not_built_for_the_kind_is_refused_with_its_reason(
-        tiny, kind, feature):
+        toys, kind, feature):
     import re
     from apex_tpu.inference import SlotScheduler
-    lcfg, params, _ = {"laguna": tiny}[kind]    # a kind new here: its toy
+    lcfg, params = toys[kind]
     why = models.KINDS[kind].refuses[feature]
-    assert WORDS[feature] in why
+    assert WORDS[kind][feature] in why
     kw = dict(slots=2, max_seq=64, page_size=4, num_pages=40)
     if feature in ASKED:
         with pytest.raises(ValueError, match=re.escape(why)):

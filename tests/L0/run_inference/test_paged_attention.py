@@ -244,3 +244,100 @@ def test_layer_is_required():
     q, k, v, pk, pv, pt, ln = _paged_twin(3, 4, 2, 4, 3, [5, 3, 1])
     with pytest.raises(TypeError):
         paged_decode_attention(q, pk, pv, pt, ln)
+
+
+# --------------------------------------------------------------------------
+# the latent form (ISSUE 34): one pool, no KV-head axis, no value array
+# --------------------------------------------------------------------------
+
+from apex_tpu.ops.attention import mha_reference  # noqa: E402
+
+WIDTH, VALUES = 24, 16          # a cached row, and its leading columns
+
+
+def _latent_twin(lengths, ps=8, mpps=4, h=4, seed=0, layers=LAYERS):
+    """(q, the rows of every slot ``[layers, slots, max_seq, WIDTH]``, the
+    same rows paged under a scrambled table, lengths); dead pages hold
+    garbage."""
+    rng = np.random.RandomState(seed)
+    slots = len(lengths)
+    n_pages = slots * mpps
+    q = rng.randn(slots, h, WIDTH).astype(np.float32)
+    rows = rng.randn(layers, slots, ps * mpps, WIDTH).astype(np.float32)
+    pool = rng.randn(n_pages + 1, layers, WIDTH, ps).astype(np.float32)
+    perm = rng.permutation(n_pages).reshape(slots, mpps)
+    for s in range(slots):
+        for j in range(mpps):       # a page: its positions the minor axis
+            pool[perm[s, j]] = rows[:, s, j * ps:(j + 1) * ps].transpose(
+                0, 2, 1)
+    return (jnp.asarray(q), rows, jnp.asarray(pool),
+            jnp.asarray(perm, jnp.int32), jnp.asarray(lengths, jnp.int32))
+
+
+def _gathered(q, rows, lengths, layer, scale):
+    """``mha_reference`` over each slot's LIVE rows, gathered: the keys the
+    whole row, the values its leading columns; an empty slot gives zeros."""
+    out = np.zeros((len(lengths), q.shape[1], VALUES), np.float32)
+    for s, n in enumerate(np.asarray(lengths)):
+        if n:
+            live = jnp.asarray(rows[layer, s, :n])
+            k = jnp.broadcast_to(live[None, None], (1, q.shape[1], n, WIDTH))
+            out[s] = np.asarray(mha_reference(
+                q[s][None, :, None, :], k, k[..., :VALUES],
+                sm_scale=scale))[0, :, 0]
+    return out
+
+
+@pytest.mark.parametrize("lengths", [
+    [0, 1, 29], [8, 9, 7], [32, 0, 16, 1], [0], [1], [17]],
+    ids=["none_one_many", "a_page_boundary", "full_empty_two_one",
+         "empty_slot", "one_row", "mid_page"])
+@pytest.mark.parametrize("layer", [0, LAYERS - 1])
+def test_latent_form_matches_a_gathered_reference(lengths, layer):
+    q, rows, pool, pt, ln = _latent_twin(lengths)
+    got = paged_decode_attention(q, pool, None, pt, ln, layer=layer,
+                                 sm_scale=0.3, values=VALUES)
+    assert got.shape == (len(lengths), 4, VALUES)
+    np.testing.assert_allclose(
+        np.asarray(got), _gathered(q, rows, lengths, layer, 0.3),
+        rtol=2e-5, atol=2e-5)
+
+
+def test_latent_form_in_bf16_and_through_a_handed_work_list():
+    q, rows, pool, pt, ln = _latent_twin([5, 0, 32, 9])
+    work = paged_work_list(pt, ln, page_size=8)
+    got = paged_decode_attention(
+        q.astype(jnp.bfloat16), pool.astype(jnp.bfloat16), None, pt, ln,
+        layer=1, sm_scale=0.3, values=VALUES, work=work)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        _gathered(q, rows, [5, 0, 32, 9], 1, 0.3), rtol=3e-2, atol=3e-2)
+
+
+def test_latent_form_is_its_own_named_call_on_the_same_work_list():
+    """One ``pallas_call`` named for the form, one pool operand; the
+    per-head-K/V form keeps its name."""
+    q, _, pool, pt, ln = _latent_twin([5, 0, 32])
+    text = str(jax.make_jaxpr(lambda *a: paged_decode_attention(
+        a[0], a[1], None, a[2], a[3], layer=0, sm_scale=0.3,
+        values=VALUES))(q, pool, pt, ln))
+    assert text.count("apex_paged_decode_latent") >= 1
+    assert "name=apex_paged_decode " not in text.replace(",", " ")
+
+
+@pytest.mark.parametrize("case", ["no_scale", "no_values", "five_dim_pool",
+                                  "values_too_wide"])
+def test_latent_form_validates(case):
+    q, _, pool, pt, ln = _latent_twin([5, 0, 32])
+    kw = dict(layer=0, sm_scale=0.3, values=VALUES)
+    if case == "no_scale":
+        kw["sm_scale"] = None
+    elif case == "no_values":
+        kw["values"] = None
+    elif case == "values_too_wide":
+        kw["values"] = WIDTH + 1
+    else:
+        pool = pool[:, :, None]
+    with pytest.raises(ValueError, match="latent form"):
+        paged_decode_attention(q, pool, None, pt, ln, **kw)

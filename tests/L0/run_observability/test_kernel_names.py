@@ -66,7 +66,7 @@ _LN = (_s((128, 256), BF16), _s((256,), F32), _s((256,), F32))
 _QKV = (_s((1, 2, 256, 64), BF16),) * 3
 _QKV_LONG = (_s((1, 1, 8192, 128), BF16),) * 3     # past the fused backward
 
-#: name -> () -> (function, abstract arguments): one way to each of the 16
+#: name -> () -> (function, abstract arguments): one way to each of the 17
 #: ``pallas_call`` sites under ``apex_tpu/ops``
 KERNELS = {
     "apex_amp_unscale": lambda: (_unscale, (_flat(),)),
@@ -94,6 +94,7 @@ KERNELS = {
     "apex_flash_bwd_dq": lambda: (_flash_bwd, _QKV_LONG),
     "apex_flash_bwd_dkv": lambda: (_flash_bwd, _QKV_LONG),
     "apex_paged_decode": lambda: _registered("paged_decode_attention"),
+    "apex_paged_decode_latent": lambda: _registered("paged_decode_latent"),
     "apex_fused_block_decode": lambda: _registered("fused_block_decode"),
 }
 
@@ -124,7 +125,7 @@ def test_every_pallas_call_site_is_named_and_no_name_is_used_twice():
         src = f.read_text()
         calls += len(re.findall(r"\bpl\.pallas_call\(", src))
         names += re.findall(r'^\s+name="(apex_\w+)",$', src, re.M)
-    assert calls == len(names) == 16
+    assert calls == len(names) == 17
     assert sorted(names) == sorted(KERNELS)
 
 
@@ -275,3 +276,76 @@ def test_v5e_paged_decode_takes_the_cells_work_lists(table, one_chip,
     # the bound, then slot and page lists at their whole capacity
     assert f"operand_layout_constraints={{s32[], {entries}{{0}}, " \
            f"{entries}{{0}}, s32[{slots + 1}]{{0}}" in calls[0], calls[0][:600]
+
+
+def test_v5e_latent_decode_step_reads_the_one_pool_in_place(one_chip,
+                                                            monkeypatch):
+    """A paged ``axk1`` decode step compiled for the chip (ISSUE 34): one
+    ``apex_paged_decode_latent`` custom call a layer, each reading the ONE
+    latent pool whole where ``append_layer`` wrote it, all along the same
+    work list — no value pool, and no copy, slice or any other op whose
+    result is pool-sized besides the appends' in-place updates."""
+    import apex_tpu.ops.attention as at
+    import apex_tpu.ops.paged_attention as pa
+    from apex_tpu.inference import kv_cache
+    from apex_tpu.inference.engine import make_decode_fn
+    from apex_tpu.inference.sampling import SamplingConfig
+    from apex_tpu.transformer.testing import standalone_axk1 as SA
+    from apex_tpu.transformer.testing.standalone_laguna import YarnRope
+
+    for mod in (at, ln, pa):
+        monkeypatch.setattr(mod, "interpret_mode", lambda: False)
+    # the published latent row (512 + 64) and page; a pool of 0.45 GB
+    layers, slots, ps, pages, mpps = 3, 8, 256, 512, 4
+    cfg = SA.AXK1Config(
+        vocab_size=256, hidden_size=256, num_layers=layers, num_heads=8,
+        q_lora_rank=128, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, ffn_hidden_size=256,
+        moe_ffn_hidden_size=128, shared_ffn_hidden_size=128,
+        num_experts=32, held=(0, 4), experts_per_token=4, n_group=4,
+        topk_group=2, max_seq_length=ps * mpps,
+        rope=YarnRope(theta=10000.0, rotary_dim=64, factor=32.0,
+                      original_max_position=4096, beta_fast=32.0,
+                      beta_slow=1.0, attention_factor=1.0),
+        params_dtype=BF16)
+    on_chip = lambda x: _s(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+    params = {"params": jax.tree.map(
+        lambda shape: _s(shape, BF16, sharding=one_chip),
+        SA.axk1_param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))}
+    cache = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: kv_cache.init_paged_cache(
+            pages, layers, 0, ps, 0, slots=slots, max_pages_per_slot=mpps,
+            latent=cfg.latent_dim)))
+    assert cache.v is None
+    step = jax.jit(make_decode_fn("axk1", cfg, SamplingConfig()),
+                   donate_argnums=(0,))
+    args = (cache, params, _s((slots,), jnp.int32, sharding=one_chip),
+            _s((slots,), bool, sharding=one_chip),
+            _s((2,), jnp.uint32, sharding=one_chip),
+            _s((), jnp.int32, sharding=one_chip))
+    assert str(jax.make_jaxpr(step)(*args)).count(
+        "name=paged_work_list") == 1
+    hlo = step.lower(*args).compile().as_text()
+    pool = f"bf16[{pages + 1},{layers},{cfg.latent_dim},{ps}]"
+    made, bounds, kernels = {}, set(), 0
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\(", line)
+        if not m:
+            continue
+        name, result, op = m.groups()
+        if op == "custom-call" and name.startswith("apex_paged_decode"):
+            assert name.startswith("apex_paged_decode_latent"), name
+            kernels += 1
+            assert line.count(pool) == 1, line[:300]      # ONE pool, whole
+            # the grid's bound: the one work list's count of live items
+            # (the lists themselves reach each call through the
+            # compiler's own copies of 32 entries into fast memory)
+            bounds.add(re.findall(
+                r"%[\w.\-]+", line.split("custom-call(", 1)[1])[0])
+        if result.startswith(pool) and op != "parameter":
+            made[op] = made.get(op, 0) + 1
+    assert kernels == layers
+    assert len(bounds) == 1, bounds
+    # the appends: one in-place scatter of the row a layer, nothing else
+    assert set(made) <= {"fusion", "scatter"}, made
+    assert sum(made.values()) <= 2 * layers, made

@@ -30,9 +30,10 @@ from apex_tpu.transformer.testing import GPTConfig, gpt_model_provider
 # 18 at ISSUE 12; ISSUE 15 consciously added the fused-block decode
 # twin and the speculative verify step; ISSUE 17 the three tp=2
 # tensor-parallel serving executables; ISSUE 18 the two host-tier
-# swap copy programs (the only legitimate way this number moves: a
-# new REGISTERED executable, never a serving-path side effect)
-BUDGETED_EXECUTABLES = 25
+# swap copy programs; ISSUE 34 the latent-attention kind's decode step
+# (the only legitimate way this number moves: a new REGISTERED
+# executable, never a serving-path side effect)
+BUDGETED_EXECUTABLES = 26
 
 
 def _engine():
@@ -107,7 +108,7 @@ def test_budget_ledger_untouched_by_prefix_sharing():
         "inference_prefill", "inference_decode",
         "inference_prefill_paged", "inference_decode_paged",
         "inference_decode_fused_paged", "inference_verify_paged",
-        "inference_prefill_paged_tp2", "inference_decode_fused_paged_tp2",
+        "inference_decode_latent", "inference_prefill_paged_tp2", "inference_decode_fused_paged_tp2",
         "inference_verify_paged_tp2",
         "inference_swap_out_paged", "inference_swap_in_paged"}
     # the serving-side program set is closed: the COW copy rides the
