@@ -320,6 +320,15 @@ def _builders():
         return (kvc.cow_page, (cache, s((), jnp.int32),
                                s((), jnp.int32)))
 
+    def inference_evict_slot():
+        # retirement's device half (ISSUE 35): the slot's length,
+        # capacity and page-table row reset inside the donated cache —
+        # like the COW copy it moves no pool data, adds no collective
+        # and carries no budget entry
+        from apex_tpu.inference import kv_cache as kvc
+        _, _, _, cache, _ = _paged_engine_audit_pieces()
+        return (kvc.evict, (cache, s((), jnp.int32)))
+
     def inference_swap_out_paged():
         # the ISSUE 18 host-tier offload gather: one fixed-width batch
         # of page slabs read out of the pool (D2H happens at the
@@ -428,6 +437,10 @@ def _builders():
                                "apex_tpu/inference/kv_cache.py",
                                ("bfloat16", "bfloat16", "int32",
                                 "int32", "int32"), 0),
+        "inference_evict_slot": (inference_evict_slot,
+                                 "apex_tpu/inference/kv_cache.py",
+                                 ("bfloat16", "bfloat16", "int32",
+                                  "int32", "int32"), 0),
         # ISSUE 18: the two host-tier copy programs — pure gathers/
         # scatters over the pool (no collectives, no host callbacks,
         # no entry upcasts); the swap-in returns the whole cache (cow's

@@ -59,6 +59,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from apex_tpu.inference.kv_cache import PageAllocator
+from apex_tpu.inference.step_vector import host_vector
 
 __all__ = [
     "StubEngine", "StubKVCache", "StubPendingSwapOut", "Template",
@@ -240,7 +241,9 @@ class StubEngine:
             cache.content[page] = np.int64(_mix(base, int(last[s])))
             cache.lengths[s] = length + 1
             toks[s] = tok
-        return cache, toks, None, truncated
+        # the engine's one array for the host, packed as the compiled
+        # step packs it (the scheduler reads nothing else)
+        return cache, host_vector(toks, truncated, xp=np), None, truncated
 
     def cow_page(self, cache: StubKVCache, src: int, dst: int):
         cache.content[int(dst)] = cache.content[int(src)]

@@ -29,8 +29,11 @@ admission unit — pages, not slots) changes.
 No host transfer appears anywhere in either jaxpr (audited by
 ``analysis/jaxpr_audit.py`` — the inference entries trace these exact
 step builders); the only device<->host traffic is the scheduler reading
-sampled tokens *between* steps, which is the continuous-batching control
-loop by construction.
+ONE small int32 vector a step (:func:`host_vector`: sampled tokens,
+``truncated`` / ``n_emit`` flags, a kind's counters) *between* steps,
+which is the continuous-batching control loop by construction.
+Retiring a slot is one more donated executable
+(:meth:`InferenceEngine.evict_slot`), never an eagerly applied op.
 
 Weights: any checkpoint that can produce the flat fp32 master restores
 straight into the engine — :meth:`InferenceEngine.from_train_state`
@@ -55,6 +58,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from apex_tpu import observability as obs
 from apex_tpu.inference import kv_cache, models
+from apex_tpu.inference.step_vector import host_vector
 from apex_tpu.inference.sampling import SamplingConfig, greedy, sample_token
 from apex_tpu.inference.speculative import default_spec_k
 from apex_tpu.ops.paged_attention import (decode_fusion as
@@ -164,9 +168,8 @@ def make_prefill_fn(kind: str, cfg, sampling: SamplingConfig,
             tok = sample_token(last, jax.random.fold_in(key, step),
                                sampling)
             if rec.stats:
-                tok = jnp.concatenate([
-                    tok.astype(jnp.int32)[None],
-                    models.stats_tail(rec.stats, stats, cache)])
+                tok = host_vector(
+                    tok, tail=models.stats_tail(rec.stats, stats, cache))
         return cache, tok, last
 
     return prefill_paged_fn if paged else prefill_fn
@@ -175,12 +178,20 @@ def make_prefill_fn(kind: str, cfg, sampling: SamplingConfig,
 def make_decode_fn(kind: str, cfg, sampling: SamplingConfig,
                    fused: bool = False, tp: int = 1):
     """Pure decode step: ``(cache, params, tokens [slots], active
-    [slots], key, step) -> (cache, next_tokens, logits, truncated)``.
+    [slots], key, step) -> (cache, host, logits, truncated)``.
     Every slot computes (static shape); only active slots advance their
     length, and ``truncated`` flags active slots already at capacity
     whose emitted token could NOT be appended (the caller must retire
     them — nothing is clamped silently).  Serves both cache layouts:
     the paged pool threads its page table through the same signature.
+
+    ``host`` is :func:`host_vector`'s ``[next_tokens [slots] |
+    truncated [slots] | stats tail]``, peeled by ``step_vector.peel_step``
+    (``peel_step(host, slots)[0]`` are the tokens): the scheduler's pass
+    reads that ONE array and nothing else of the step.  ``logits`` and
+    ``truncated`` stay outputs of their own, never read by the serving
+    loop: they are what the parity tests and the packed-layout test hold
+    ``host`` to, and the capacity contract's tests read the flags there.
 
     ``fused`` (ISSUE 15, paged engines): the ``params`` operand becomes
     the pair ``(tree, fused_layers)`` and every transformer block runs
@@ -203,12 +214,12 @@ def make_decode_fn(kind: str, cfg, sampling: SamplingConfig,
                                 sampling)
         with obs.named_scope("apex_decode_advance"):
             cache, truncated = kv_cache.advance(cache, active)
-            if rec.stats:
-                # [slots] tokens + the counters' tail: one read for both
-                toks = jnp.concatenate([
-                    toks.astype(jnp.int32),
-                    models.stats_tail(rec.stats, stats, cache)])
-        return cache, toks, logits, truncated
+            # tokens, flags and the counters' tail: one read for all
+            host = host_vector(
+                toks, truncated,
+                tail=models.stats_tail(rec.stats, stats, cache)
+                if rec.stats else None)
+        return cache, host, logits, truncated
 
     return decode_fn
 
@@ -216,8 +227,14 @@ def make_decode_fn(kind: str, cfg, sampling: SamplingConfig,
 def make_verify_fn(kind: str, cfg, sampling: SamplingConfig, k: int,
                    tp: int = 1):
     """Pure speculative-verify step (ISSUE 15): ``(cache, params, slab
-    [slots, k+1], active [slots], key, step) -> (cache, tokens
-    [slots, k+1], n_emit [slots], truncated)``.
+    [slots, k+1], active [slots], key, step) -> (cache, host, n_emit
+    [slots], truncated)``; ``host`` is :func:`host_vector`'s ``[tokens
+    [slots * (k+1)] | n_emit [slots] | truncated [slots]]`` — the ONE
+    array the scheduler's pass reads; ``peel_step(host, slots * (k+1))``
+    gives the ``tokens`` of the paragraphs below (reshape ``[slots,
+    k+1]``) and the two flag vectors (no stats tail: every kind with
+    ``stats`` refuses verify).  ``n_emit`` and ``truncated`` stay
+    outputs of their own for the tests that hold ``host`` to them.
 
     ``slab`` column 0 is each slot's last confirmed (pending) token,
     columns ``1..k`` the drafted continuation.  ONE batched forward
@@ -262,7 +279,8 @@ def make_verify_fn(kind: str, cfg, sampling: SamplingConfig, k: int,
         with obs.named_scope("apex_verify_advance"):
             cache, truncated = kv_cache.advance_by(cache, active,
                                                    n_emit)
-        return cache, toks, n_emit, truncated
+        return cache, host_vector(toks, n_emit, truncated), n_emit, \
+            truncated
 
     return verify_fn
 
@@ -547,6 +565,13 @@ class InferenceEngine:
                                        donate_argnums=(0,))
             else:
                 self._verify_raw = self._verify = None
+            # retirement's device half (ISSUE 35): one donated metadata
+            # update, the slot a traced int32 — ONE executable for every
+            # slot of either layout; the pool's leaves pass through
+            # aliased to themselves, the compiled program holds no copy
+            self._evict = jax.jit(
+                self._tp_wrap(kv_cache.evict, in_specs=(cs, P()),
+                              out_specs=cs), donate_argnums=(0,))
             if self.paged:
                 # the COW write barrier (ISSUE 12): one donated page
                 # copy, compiled once, dispatched only when a slot must
@@ -600,6 +625,8 @@ class InferenceEngine:
                 "infer_decode_dispatch_total")
             self._cow_dispatches = reg.declared(
                 "infer_cow_dispatch_total")
+            self._evict_dispatches = reg.declared(
+                "infer_evict_dispatch_total")
             self._fused_decode_dispatches = reg.declared(
                 "infer_decode_fused_dispatch_total")
             self._verify_dispatches = reg.declared(
@@ -822,14 +849,21 @@ class InferenceEngine:
         """Device-side metadata evict of one slot (paged or dense):
         zero its length and re-park its page-table row on the trash
         page so the idle slot's masked decode appends can never land
-        in another request's pages.  The retire half of the engine's
+        in another request's pages.  ONE launch of ONE compiled, donated
+        program (``kv_cache.evict`` jitted in the constructor beside the
+        COW copy; ``slot`` is a traced int32, so every retirement of
+        the engine's lifetime rides it and nothing is applied eagerly
+        between two passes — ISSUE 35).  The retire half of the engine's
         device surface — the scheduler releases the slot's page
-        REFERENCES host-side only after this returns, so a stub engine
-        (protocol audit) can mirror the whole lifecycle without a
-        device."""
+        REFERENCES host-side only after this returns (the launch is
+        queued ahead of any later prefill or decode that could reuse
+        the pages), so a stub engine (protocol audit) can mirror the
+        whole lifecycle without a device."""
+        self._refresh_dispatch_counters()
+        self._evict_dispatches.inc()
         with obs.trace_annotation("apex_tpu.inference.evict_slot",
                                   slot=int(slot)):
-            return kv_cache.evict(cache, slot)
+            return self._evict(cache, np.int32(slot))
 
     def page_host_bytes(self) -> int:
         """Host-DRAM bytes ONE page's k+v slabs occupy in the host
@@ -927,9 +961,11 @@ class InferenceEngine:
         return cache
 
     def decode(self, cache, last_tokens, active=None):
-        """One token for every slot: returns ``(cache, next_tokens,
-        logits, truncated)``; only ``active`` slots advance their cache
-        length.
+        """One token for every slot: returns ``(cache, host, logits,
+        truncated)`` — ``host`` is the step's ONE array for the host
+        (:func:`host_vector`: ``[next_tokens [slots] | truncated
+        [slots] | stats tail]``); only ``active`` slots advance their
+        cache length.
 
         Capacity contract: a slot whose length has reached its capacity
         (``max_seq`` dense; its page reservation paged) must be retired
@@ -957,10 +993,13 @@ class InferenceEngine:
     def verify(self, cache, slab, active=None):
         """One speculative-verify step (ISSUE 15): ``slab [slots,
         spec_k + 1]`` (column 0 = each slot's last confirmed token,
-        the rest drafts) -> ``(cache, tokens [slots, spec_k + 1],
-        n_emit [slots], truncated)``.  ``tokens[:, :n_emit]`` per slot
-        is the emitted stream — the target's own greedy continuation
-        (accepted drafts + bonus token); lengths advanced by
+        the rest drafts) -> ``(cache, host, n_emit [slots],
+        truncated)``, ``host`` the step's ONE array for the host
+        (:func:`host_vector`: ``[tokens [slots * (spec_k + 1)] | n_emit
+        | truncated]``).
+        ``tokens[:, :n_emit]`` per slot is the emitted stream — the
+        target's own greedy continuation (accepted drafts + bonus
+        token); lengths advanced by
         ``n_emit`` in-program (the accept/reject rollback).  The same
         capacity contract as :meth:`decode`: the caller clamps emitted
         tokens to the slot's remaining capacity and retires truncated

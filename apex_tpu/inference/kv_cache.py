@@ -364,8 +364,12 @@ def advance(cache, active):
 def evict(cache, slot):
     """Retire a slot: zero its length (and, paged, its capacity, with
     the page-table row re-parked on the trash page).  Metadata-only —
-    the k/v rows/pages are left in place; a paged slot's page IDs are
-    reclaimed host-side by the :class:`PageAllocator`.
+    the k/v rows/pages (and rings) pass through untouched; a paged
+    slot's page IDs are reclaimed host-side by the
+    :class:`PageAllocator`.  A pure function of a traced ``slot``: the
+    engine serves every retirement with ONE donated jit of it
+    (``InferenceEngine.evict_slot``, ISSUE 35), in which the pool is
+    aliased to itself and only these three small arrays are written.
 
     Paged eviction MUST run before the slot's pages are reassigned:
     unlike the dense cache's slot-private rows, a stale page-table row
@@ -373,7 +377,6 @@ def evict(cache, slot):
     pages that now belong to another request.  Resetting the row to the
     trash page makes the idle slot's writes land where the pool absorbs
     them by design."""
-    slot = jnp.asarray(slot, jnp.int32)
     zero = jnp.zeros((1,), jnp.int32)
     new_len = jax.lax.dynamic_update_slice(cache.lengths, zero, (slot,))
     if isinstance(cache, PagedKVCache):
@@ -384,7 +387,7 @@ def evict(cache, slot):
             capacity=jax.lax.dynamic_update_slice(
                 cache.capacity, zero, (slot,)),
             page_table=jax.lax.dynamic_update_slice(
-                cache.page_table, null_row, (slot, jnp.int32(0))))
+                cache.page_table, null_row, (slot, 0)))
     return cache.replace(lengths=new_len)
 
 

@@ -29,8 +29,10 @@ retires requests between device steps:
              at most ``max_chunks_per_pass`` chunks run between
              consecutive decode steps.
     step:    one decode executable over every slot (inactive slots
-             compute garbage that is masked and never advances)
-    retire:  EOS, the token budget, or slot capacity frees the slot;
+             compute garbage that is masked and never advances); the
+             host reads ONE array of it — tokens, flags, counters
+    retire:  EOS, the token budget, or slot capacity frees the slot:
+             one compiled metadata update on the device, then
              a retired slot only RELEASES its page references — a page
              another request (or the prefix cache) still maps goes
              back to the free list only when its LAST owner lets go.
@@ -74,11 +76,13 @@ import dataclasses
 import os
 from typing import Dict, Optional
 
+import jax
 import numpy as np
 
 from apex_tpu.inference import kv_cache
 from apex_tpu.inference.prefix_cache import PrefixCache, prefix_cache_enabled
 from apex_tpu.inference.speculative import Drafter, NGramDrafter
+from apex_tpu.inference.step_vector import peel_step
 from apex_tpu.observability import ServeTelemetry, trace_annotation
 from apex_tpu.observability.slo import SLOTracker
 
@@ -662,10 +666,11 @@ class SlotScheduler:
         self._run_results[st.uid] = gen
         self.finish_reasons[st.uid] = reason
         if st.pages is not None:
-            # device-side metadata evict BEFORE any page could be
-            # reassigned: it re-parks the slot's page-table row on
-            # the trash page, so the idle slot's masked decode
-            # appends can never land in another request's pages.
+            # device-side metadata evict (one launch of one compiled
+            # program) BEFORE any page could be reassigned: it
+            # re-parks the slot's page-table row on the trash page,
+            # so the idle slot's masked decode appends can never
+            # land in another request's pages.
             # Host-side the slot then only RELEASES its references
             # — a page the prefix cache or a prefix-sharing
             # neighbour still maps stays live until its LAST owner
@@ -680,16 +685,25 @@ class SlotScheduler:
             self.drafter.retire(slot)
         self.telemetry.request_finished(st.uid, reason, len(gen))
 
-    def _peel_stats(self, toks, phase: str):
-        """Tokens as read from the device, less the counters a kind
-        with an expert FFN appends to them (``engine.stats_tail`` int32
-        values — ISSUE 30: they ride the token read the pass makes
-        anyway).  The counters go to the telemetry."""
-        tail = getattr(self.engine, "stats_tail", 0)
-        if not tail:
-            return toks
-        self.telemetry.expert_pass(phase, *(int(v) for v in toks[-tail:]))
-        return toks[:-tail]
+    def _read_step(self, host, phase: str, tokens: int):
+        """A pass's ONE device→host read (ISSUE 35): everything the
+        host needs of a step arrives in the one int32 vector the engine
+        laid out as ``[tokens | flags | stats tail]`` — so this is the
+        only place the host waits for the device, and it asks for
+        nothing else.  The read is explicit (``jax.device_get``, which
+        requests the copy and waits for it; requesting it earlier, at
+        dispatch, was measured and shortened nothing): under
+        ``jax.transfer_guard_device_to_host("disallow")`` a pass runs
+        clean.  Returns ``(tokens, flags)`` (``step_vector.peel_step``); the
+        counters of a kind with an expert FFN (``engine.stats_tail``
+        int32 values, ISSUE 30) go to the telemetry."""
+        with trace_annotation("apex_tpu.scheduler.token_read"):
+            host = np.asarray(jax.device_get(host)).reshape(-1)
+        toks, flags, tail = peel_step(
+            host, tokens, getattr(self.engine, "stats_tail", 0))
+        if tail.size:
+            self.telemetry.expert_pass(phase, *(int(v) for v in tail))
+        return toks, flags
 
     def _prefill_piece(self, slot: int) -> None:
         """Advance one slot's prefill by one chunk (or the whole
@@ -711,10 +725,7 @@ class SlotScheduler:
                     prefill_from=start)
                 # the one place of a prefill where the host waits
                 # for the device
-                with trace_annotation("apex_tpu.scheduler.token_read"):
-                    tok = np.asarray(tok).reshape(-1)
-                tok = self._peel_stats(tok, "prefill")
-                tok = int(tok[0])
+                tok = int(self._read_step(tok, "prefill", 1)[0][0])
             st.prefilled = end
             if st.chunked:
                 tel.prefill_chunked(st.uid, start, end - start)
@@ -795,13 +806,21 @@ class SlotScheduler:
         priority/fairness ordered), advance at most
         ``max_chunks_per_pass`` prefill chunks, then ONE batched
         decode (or verify) step over the decoding slots.  The device
-        sees only the fixed-shape prefill/decode (+COW copy)
+        sees only the fixed-shape prefill/decode (+COW copy, +evict)
         executables; everything else here is host-side bookkeeping on
         ints."""
         with trace_annotation("apex_tpu.scheduler.pass"):
             self._pass()
 
     def _pass(self) -> None:
+        """The pass's body.  What crosses the host/device boundary in it
+        (ISSUE 35): each prefill and the one decode (or verify) step is
+        ONE launch followed by ONE device→host read (:meth:`_read_step`:
+        tokens, ``truncated`` / ``n_emit`` flags and a kind's counters
+        arrive in one int32 vector); each retirement is ONE launch of
+        the compiled evict and reads nothing; between the step's read
+        and the next pass's first launch the host applies no primitive
+        of its own."""
         eng, tel = self.engine, self.telemetry
         slots = self._run_slots
         with trace_annotation("apex_tpu.scheduler.admit",
@@ -897,12 +916,12 @@ class SlotScheduler:
                                   active=n_active), \
                     tel.verify_step(n_active,
                                     capacity=eng.slots) as vstep:
-                self.cache, toks, n_emit, truncated = eng.verify(
+                self.cache, host, _, _ = eng.verify(
                     self.cache, slab, active)
-                with trace_annotation("apex_tpu.scheduler.token_read"):
-                    toks = np.asarray(toks)
-                    n_emit = np.asarray(n_emit)
-                    truncated = np.asarray(truncated)
+                toks, flags = self._read_step(
+                    host, "verify", eng.slots * (k + 1))
+                toks = toks.reshape(eng.slots, k + 1)
+                n_emit, truncated = flags.reshape(2, eng.slots)
                 # per-token latency back-channel: the bracket's
                 # histogram sample divides by mean emitted/slot.
                 # Clamped the way the consumption loop below will
@@ -955,12 +974,9 @@ class SlotScheduler:
         with trace_annotation("apex_tpu.scheduler.decode",
                               active=n_active), \
                 tel.decode_step(n_active, capacity=eng.slots):
-            self.cache, toks, _, truncated = eng.decode(
+            self.cache, host, _, _ = eng.decode(
                 self.cache, self._run_last, active)
-            with trace_annotation("apex_tpu.scheduler.token_read"):
-                toks = np.asarray(toks)
-                truncated = np.asarray(truncated)
-            toks = self._peel_stats(toks, "decode")
+            toks, truncated = self._read_step(host, "decode", eng.slots)
         with trace_annotation("apex_tpu.scheduler.retire"):
             for slot, st in enumerate(slots):
                 if st is None or not active[slot]:

@@ -40,6 +40,8 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from apex_tpu.inference.step_vector import peel_step
+
 __all__ = ["default_spec_k", "Drafter", "NGramDrafter", "ReplayDrafter",
            "EngineDrafter"]
 
@@ -274,7 +276,7 @@ class EngineDrafter(Drafter):
         for j in range(k):
             self.cache, toks, _, _ = self.engine.decode(self.cache,
                                                         feed, act)
-            toks = np.asarray(toks)
+            toks = peel_step(np.asarray(toks), slots)[0]
             drafts[:, j] = np.where(act, toks, 0)
             feed = np.where(act, toks, feed).astype(np.int32)
         # the rollback: drafted rows go dead-by-mask, pending stays

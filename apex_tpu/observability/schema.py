@@ -292,6 +292,9 @@ METRIC_SPECS: Dict[str, MetricSpec] = {s.name: s for s in [
     MetricSpec("infer_cow_dispatch_total", "counter",
                "InferenceEngine.cow_page dispatches (copy-on-write "
                "page duplications)"),
+    MetricSpec("infer_evict_dispatch_total", "counter",
+               "InferenceEngine.evict_slot dispatches (one compiled "
+               "metadata update a retired request)"),
     MetricSpec("infer_decode_fused_dispatch_total", "counter",
                "decode dispatches lowered through the fused "
                "transformer-block kernel (APEX_TPU_DECODE_FUSION; a "

@@ -852,6 +852,7 @@ def _microbench_infer(rtt: float, on_tpu: bool):
     from apex_tpu.inference.engine import make_decode_fn, make_prefill_fn
     from apex_tpu.inference.kv_cache import default_page_size, page_row
     from apex_tpu.inference.sampling import SamplingConfig
+    from apex_tpu.inference.step_vector import peel_step
     from apex_tpu.ops.attention import decode_xla_max_seq
     from apex_tpu.transformer import parallel_state
     from apex_tpu.transformer.testing import GPTConfig, gpt_model_provider
@@ -957,9 +958,9 @@ def _microbench_infer(rtt: float, on_tpu: bool):
     def decode_step(state, batch):
         cache, toks, step = state
         active, key_ = batch
-        cache, toks, _, _ = decode_fn(cache, engine.params, toks, active,
+        cache, host, _, _ = decode_fn(cache, engine.params, toks, active,
                                       key_, step)
-        return (cache, toks, step + 1)
+        return (cache, peel_step(host, slots)[0], step + 1)
 
     state = (cache, jnp.zeros((slots,), jnp.int32), jnp.int32(0))
     decode_iters = min(iters, max_seq - prefill_len - 1)
@@ -1220,10 +1221,10 @@ def _microbench_infer(rtt: float, on_tpu: bool):
         def fused_decode_step(state, batch):
             cache_, toks, step = state
             active, key_ = batch
-            cache_, toks, _, _ = fused_decode_fn(
+            cache_, host, _, _ = fused_decode_fn(
                 cache_, (engine.params, fused_layers), toks, active,
                 key_, step)
-            return (cache_, toks, step + 1)
+            return (cache_, peel_step(host, slots)[0], step + 1)
 
         t_fdec = _bench_loop(
             fused_decode_step,
@@ -1341,9 +1342,9 @@ def _microbench_infer(rtt: float, on_tpu: bool):
         def tp_decode_step(state, batch):
             cache_, toks, step = state
             active, key_ = batch
-            cache_, toks, _, _ = eng_tp._decode_raw(
+            cache_, host, _, _ = eng_tp._decode_raw(
                 cache_, dparams_t, toks, active, key_, step)
-            return (cache_, toks, step + 1)
+            return (cache_, peel_step(host, slots)[0], step + 1)
 
         t_tdec = _bench_loop(
             tp_decode_step,

@@ -27,6 +27,7 @@ if str(REPO) not in sys.path:
 
 from apex_tpu.inference import InferenceEngine, SamplingConfig  # noqa: E402
 from apex_tpu.inference import models  # noqa: E402
+from apex_tpu.inference.step_vector import peel_step  # noqa: E402
 from apex_tpu.transformer.testing import standalone_axk1 as SA  # noqa: E402
 from apex_tpu.transformer.testing.standalone_laguna import (  # noqa: E402
     YarnRope, yarn_inv_freq)
@@ -166,11 +167,12 @@ def test_prefill_then_decode_through_the_latent_pool(tiny):
         last[slot] = tok[0]
     for _ in range(steps):
         cache, toks, logits, truncated = eng.decode(cache, last)
-        toks = np.asarray(toks)
-        assert toks.shape == (3 + 4,) and not np.asarray(truncated).any()
+        toks, flags, tail = peel_step(np.asarray(toks), 3, eng.stats_tail)
+        assert (toks.shape, flags.shape, tail.shape) == ((3,), (3,), (4,))
+        assert not flags.any() and not np.asarray(truncated).any()
         for slot in range(3):
             seqs[slot].append(int(toks[slot]))
-        last = toks[:3].copy()
+        last = toks.copy()
         step_logits = np.asarray(logits)
     for slot, p in enumerate(prompts):
         seq = np.asarray(seqs[slot][:-1])
@@ -181,7 +183,6 @@ def test_prefill_then_decode_through_the_latent_pool(tiny):
         assert list(greedy) == seqs[slot][len(p):]
     # the counters rode the token read: of 3 tokens x 4 x 2 expert layers
     # only those that landed on the 8 held experts are counted
-    tail = toks[3:]
     assert 0 <= tail[0] <= 3 * 4 * 2 and tail[1] <= 2 * 8
     assert tail[2] <= 3 and tail[3] == 0          # no window rings
 

@@ -25,6 +25,7 @@ if str(REPO) not in sys.path:
 
 from apex_tpu.inference import InferenceEngine, SamplingConfig  # noqa: E402
 from apex_tpu.inference import models  # noqa: E402
+from apex_tpu.inference.step_vector import peel_step  # noqa: E402
 from apex_tpu.transformer.testing import standalone_laguna as SL  # noqa: E402
 from benchmark.bindings import moe_laguna as binding  # noqa: E402
 from benchmark.references import laguna_lm  # noqa: E402
@@ -154,11 +155,12 @@ def test_prefill_then_decode_through_both_pools(tiny):
         last[slot] = tok[0]
     for _ in range(steps):
         cache, toks, logits, truncated = eng.decode(cache, last)
-        toks = np.asarray(toks)
-        assert toks.shape == (3 + 4,) and not np.asarray(truncated).any()
+        toks, flags, tail = peel_step(np.asarray(toks), 3, eng.stats_tail)
+        assert (toks.shape, flags.shape, tail.shape) == ((3,), (3,), (4,))
+        assert not flags.any() and not np.asarray(truncated).any()
         for slot in range(3):
             seqs[slot].append(int(toks[slot]))
-        last = toks[:3].copy()
+        last = toks.copy()
         step_logits = np.asarray(logits)
     # the last step's logits of every slot, and each slot's whole greedy
     # stream, against one reference pass per slot
@@ -171,7 +173,6 @@ def test_prefill_then_decode_through_both_pools(tiny):
         assert list(greedy) == seqs[slot][len(p):]
     # the counters rode the token read: assignments = live tokens x top_k
     # x expert layers; window pages never above slots x ring pages
-    tail = toks[3:]
     assert tail[0] == 3 * 2 * 3 and 1 <= tail[1] <= 3 * 8
     assert tail[2] <= 3 and tail[3] == 3 * 3
 
@@ -318,8 +319,10 @@ def _token_eqn(jaxpr, cache):
 def test_a_kind_without_stats_or_rings_traces_neither(tiny):
     """What keeps GPT's program what it was before the kinds shared one
     step body: both are static facts of the record, so a kind without
-    them has no concatenate on its tokens and no ring among its operands;
-    ``laguna``'s tail is as long as its record's ``stats``."""
+    them has no counters behind its tokens and no ring among its operands
+    (a prefill's token is a bare scalar, a decode step's one array for the
+    host is ``[tokens | truncated]`` and no longer); ``laguna``'s tail is
+    as long as its record's ``stats``."""
     from apex_tpu.transformer import parallel_state
     from apex_tpu.transformer.testing import GPTConfig, gpt_model_provider
     parallel_state.destroy_model_parallel()
@@ -337,10 +340,10 @@ def test_a_kind_without_stats_or_rings_traces_neither(tiny):
     assert cache.wk is None and cache.wv is None        # no ring operand
     ring_free = len(jax.tree_util.tree_leaves((cache, eng.params))) + 4
     assert len(dec.invars) == ring_free
-    for jaxpr, shape in ((pre, ()), (dec, (2,))):
-        tok, eqn = _token_eqn(jaxpr, cache)
-        assert tok.aval.shape == shape
-        assert eqn.primitive.name != "concatenate"
+    tok, eqn = _token_eqn(pre, cache)
+    assert tok.aval.shape == () and eqn.primitive.name != "concatenate"
+    tok, eqn = _token_eqn(dec, cache)
+    assert tok.aval.shape == (2 + 2,) and len(eqn.invars) == 2
 
     lcfg, params, _ = tiny
     eng = InferenceEngine("laguna", lcfg, params, **kw)
@@ -348,7 +351,9 @@ def test_a_kind_without_stats_or_rings_traces_neither(tiny):
     assert eng.stats_tail == tail == 4
     cache, pre, dec = _step_jaxprs(eng)
     assert cache.wk is not None
-    for jaxpr, shape in ((pre, (1 + tail,)), (dec, (2 + tail,))):
+    for jaxpr, shape, parts in ((pre, (1 + tail,), 2),
+                                (dec, (2 + 2 + tail,), 3)):
         tok, eqn = _token_eqn(jaxpr, cache)
         assert tok.aval.shape == shape
         assert eqn.primitive.name == "concatenate"
+        assert len(eqn.invars) == parts

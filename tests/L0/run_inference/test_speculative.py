@@ -16,6 +16,7 @@ from apex_tpu.inference import (
     SlotScheduler,
 )
 from apex_tpu.inference import kv_cache
+from apex_tpu.inference.step_vector import peel_step
 from apex_tpu.observability import MetricsRegistry, ServeTelemetry
 from apex_tpu.transformer import parallel_state
 from apex_tpu.transformer.testing import (
@@ -150,9 +151,13 @@ def test_verify_rollback_lengths_and_pages():
     slab = np.zeros((2, 4), np.int32)
     slab[:, 0] = toks
     slab[:, 1:] = 63
-    cache, out, n_emit, truncated = eng.verify(cache, slab)
+    cache, host, n_emit, truncated = eng.verify(cache, slab)
     n_emit = np.asarray(n_emit)
-    out = np.asarray(out)
+    # the step's one array for the host: [tokens | n_emit | truncated]
+    out, flags, _ = peel_step(np.asarray(host), 8)
+    out, (host_emit, host_trunc) = out.reshape(2, 4), flags.reshape(2, 2)
+    np.testing.assert_array_equal(host_emit, n_emit)
+    np.testing.assert_array_equal(host_trunc, np.asarray(truncated))
     assert not np.asarray(truncated).any()
     np.testing.assert_array_equal(np.asarray(cache.page_table),
                                   table_before)
@@ -173,7 +178,8 @@ def test_verify_rollback_lengths_and_pages():
     c, t = cache2, np.asarray(toks, np.int32)
     for _ in range(3):
         c, t, _, _ = eng.decode(c, t)
-        base_stream.append(np.asarray(t).copy())
+        t = peel_step(np.asarray(t), 2)[0]
+        base_stream.append(t.copy())
     slab3 = np.zeros((2, 4), np.int32)
     slab3[:, 0] = toks
     for j in range(3):
@@ -184,11 +190,11 @@ def test_verify_rollback_lengths_and_pages():
                                    pages=[int(p) for p in
                                           table_before[slot]
                                           if p != cache.null_page])
-    cache3, out3, n_emit3, _ = eng.verify(cache3, slab3)
+    cache3, host3, n_emit3, _ = eng.verify(cache3, slab3)
     assert (np.asarray(n_emit3) == 4).all()
+    out3 = peel_step(np.asarray(host3), 8)[0].reshape(2, 4)
     for j in range(3):
-        np.testing.assert_array_equal(np.asarray(out3)[:, j],
-                                      base_stream[j])
+        np.testing.assert_array_equal(out3[:, j], base_stream[j])
 
 
 def test_append_slab_paged_drops_past_window():

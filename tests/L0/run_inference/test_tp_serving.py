@@ -17,6 +17,7 @@ import pytest
 
 from apex_tpu.inference import InferenceEngine, SlotScheduler
 from apex_tpu.inference.sampling import SamplingConfig
+from apex_tpu.inference.step_vector import peel_step
 from apex_tpu.transformer import parallel_state
 from apex_tpu.transformer.testing import (
     GPTConfig,
@@ -73,13 +74,15 @@ def _serve(kind, cfg, params, tp, fusion="0", spec_k=0):
     for _ in range(4):
         cache, nt, _, _ = eng.decode(cache, last, active)
         toks.append(int(np.asarray(nt)[0]))
-        last = np.asarray(nt)
+        last = peel_step(np.asarray(nt), 2)[0]
     spec = None
     if spec_k:
         slab = np.zeros((2, spec_k + 1), np.int32)
         slab[0, 0] = toks[-1]
         cache, vt, n_emit, _ = eng.verify(cache, slab, active)
-        spec = (np.asarray(vt)[0].tolist(), int(np.asarray(n_emit)[0]))
+        vt = peel_step(np.asarray(vt), 2 * (spec_k + 1))[0]
+        spec = (vt.reshape(2, spec_k + 1)[0].tolist(),
+                int(np.asarray(n_emit)[0]))
     return toks, np.asarray(logits), spec, eng
 
 

@@ -349,3 +349,69 @@ def test_v5e_latent_decode_step_reads_the_one_pool_in_place(one_chip,
     # the appends: one in-place scatter of the row a layer, nothing else
     assert set(made) <= {"fusion", "scatter"}, made
     assert sum(made.values()) <= 2 * layers, made
+
+
+#: the serving cells' caches: (pool shape, rings' shape or None, slots,
+#: pages a slot) — gpt3-1.3b-serve, laguna-xs.2-serve, a.x-k1-serve
+_CELL_CACHES = {
+    "gpt": ((641, 24, 16, 64, 128), None, 16, 32),
+    "laguna": ((4097, 2, 8, 64, 128), (3, 32, 8, 576, 128), 32, 260),
+    "latent": ((1537, 6, 576, 256), None, 64, 35),
+}
+
+
+@pytest.mark.parametrize("pool", sorted(_CELL_CACHES))
+def test_v5e_evict_writes_the_metadata_and_copies_no_pool(pool, one_chip):
+    """Retirement's device half compiled for the chip (ISSUE 35): the
+    program an engine jits in its constructor (``_evict``: the cache
+    donated, the slot a traced int32 — the same for every kind, so a toy
+    engine's is lowered) at each serving cell's cache: every leaf aliased
+    to itself, the pool (``laguna``'s rings, the latent array) handed from
+    parameter to result with no op in between, no temporary — only the
+    page table, the lengths and the capacity are written."""
+    from apex_tpu.inference import InferenceEngine, kv_cache
+    from apex_tpu.transformer import parallel_state
+    from apex_tpu.transformer.testing import GPTConfig, gpt_model_provider
+
+    if not parallel_state.model_parallel_is_initialized():
+        parallel_state.initialize_model_parallel(1)
+    cfg = GPTConfig(vocab_size=64, hidden_size=32, num_layers=1,
+                    num_attention_heads=2, max_seq_length=64,
+                    hidden_dropout=0.0, attention_dropout=0.0)
+    evict = InferenceEngine(
+        "gpt", cfg, gpt_model_provider(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32)),
+        slots=2, page_size=8, num_pages=8)._evict
+    shape, rings, slots, mpps = _CELL_CACHES[pool]
+    on = lambda s, dt: _s(s, dt, sharding=one_chip)  # noqa: E731
+    latent = len(shape) == 4
+    cache = kv_cache.PagedKVCache(
+        k=on(shape, BF16), v=None if latent else on(shape, BF16),
+        page_table=on((slots, mpps), jnp.int32),
+        lengths=on((slots,), jnp.int32), capacity=on((slots,), jnp.int32),
+        wk=rings and on(rings, BF16), wv=rings and on(rings, BF16))
+    leaves = len(jax.tree_util.tree_leaves(cache))
+    compiled = evict.lower(cache, on((), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    head = hlo.split("\n", 1)[0]
+    for i in range(leaves):
+        assert f"{{{i}}}: ({i}, {{}}, may-alias)" in head, head[:400]
+    big = {"bf16[" + ",".join(map(str, s)) + "]"
+           for s in (shape, rings) if s}
+    written = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\(", line)
+        if not m:
+            continue
+        _, result, op = m.groups()
+        if op not in ("parameter", "tuple"):
+            assert not any(result.startswith(b) for b in big), line[:200]
+            if op == "dynamic-update-slice":
+                written.append(result.split("{")[0])
+    assert sorted(written) == sorted(
+        [f"s32[{slots}]", f"s32[{slots}]", f"s32[{slots},{mpps}]"])
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes < 1 << 16, stats
+    small = 4 * (slots * mpps + 2 * slots)
+    assert stats.argument_size_in_bytes - stats.alias_size_in_bytes \
+        <= small, stats

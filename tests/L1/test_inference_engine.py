@@ -26,6 +26,7 @@ sys.path.insert(0, os.path.abspath(
 
 from apex_tpu.analysis.jaxpr_audit import run_jaxpr_audit
 from apex_tpu.inference import InferenceEngine
+from apex_tpu.inference.step_vector import peel_step
 from apex_tpu.transformer import parallel_state
 from apex_tpu.transformer.testing import GPTConfig, gpt_model_provider
 
@@ -87,7 +88,7 @@ def test_decode_is_one_executable_and_donates():
         events.clear()
         for _ in range(5):
             cache, toks, _, _ = eng.decode(cache, last, active)
-            last = np.asarray(toks)
+            last = peel_step(np.asarray(toks), eng.slots)[0]
         jax.block_until_ready(cache)
         n = sum(1 for e in events if "compile_requests" in e)
         assert n == 1, f"5 decode steps compiled {n} executables"
