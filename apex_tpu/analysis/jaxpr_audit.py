@@ -259,6 +259,28 @@ def _builders():
         return (fn, (cache, params, s((4,), jnp.int32), s((4,), bool),
                      s((2,), jnp.uint32), s((), jnp.int32)))
 
+    def inference_decode_select():
+        # ISSUE 36: the decode step of a kind whose layers SELECT — an
+        # index-key pool beside k and v, index scores along the work list
+        # (apex_dsa_index), the picked set, attention over the picked
+        # rows (apex_dsa_attend), an expert FFN in every layer
+        from apex_tpu.inference import kv_cache
+        from apex_tpu.inference.engine import make_decode_fn
+        from apex_tpu.inference.sampling import SamplingConfig
+        from apex_tpu.transformer.testing import standalone_keye as SK
+        cfg = SK.KeyeConfig(params_dtype=bf16)
+        params = {"params": jax.tree.map(
+            lambda shape: s(shape, bf16), SK.keye_param_shapes(cfg),
+            is_leaf=lambda x: isinstance(x, tuple))}
+        cache = jax.eval_shape(
+            lambda: kv_cache.init_paged_cache(
+                20, cfg.num_layers, cfg.num_kv_heads, 16, cfg.head_dim,
+                slots=4, max_pages_per_slot=16,
+                index=cfg.index_head_dim))
+        fn = make_decode_fn("keye", cfg, SamplingConfig())
+        return (fn, (cache, params, s((4,), jnp.int32), s((4,), bool),
+                     s((2,), jnp.uint32), s((), jnp.int32)))
+
     def fused_block_decode_op():
         # the ISSUE 15 fused transformer-block decode kernel at an
         # op-level GPT-shaped fixture (LN + qkv + paged attention incl.
@@ -410,6 +432,13 @@ def _builders():
                                     ("bfloat16", "int32", "int32",
                                      "int32", "int32", "float32", "bool"),
                                     None),
+        # ISSUE 36: k, v, table, lengths, capacity and the index-key pool,
+        # then the tokens with the seven counters
+        "inference_decode_select": (inference_decode_select,
+                                    "apex_tpu/inference/engine.py",
+                                    ("bfloat16", "bfloat16", "int32",
+                                     "int32", "int32", "bfloat16", "int32",
+                                     "float32", "bool"), None),
         # ISSUE 15: the fused-block kernel (op-level; measured entry
         # upcasts = 11: the norm gains/biases and the projection/MLP
         # biases applied in fp32 by design — layer_norm's budget-2

@@ -237,6 +237,34 @@ def _builders():
                 (s((2, 4, 192), bf16), s((9, 2, 192, 16), bf16),
                  s((2, 4), jnp.int32), s((2,), jnp.int32)))
 
+    def dsa_index_scores():
+        # ISSUE 36: a block of query rows' index scores against the keys
+        from apex_tpu.ops.attention import index_scores as op
+        return (op, (s((128, 4, 64), bf16), s((128, 4), jnp.float32),
+                     s((256, 64), bf16)))
+
+    def paged_index_scores():
+        # ISSUE 36: index scores of the live positions along the work
+        # list, over the index-key pool (a page's positions minor)
+        from apex_tpu.ops.paged_attention import (
+            paged_index_scores as op, paged_work_list)
+        return (lambda qi, wi, ik, pt, n: op(
+            qi, wi, ik, paged_work_list(pt, n, page_size=16), layer=1),
+                (s((2, 4, 64), bf16), s((2, 4), jnp.float32),
+                 s((9, 2, 64, 16), bf16), s((2, 4), jnp.int32),
+                 s((2,), jnp.int32)))
+
+    def paged_select_attention():
+        # ISSUE 36: attention over the picked positions only
+        from apex_tpu.ops.paged_attention import (
+            paged_select_attention as op, paged_work_list)
+        pool = s((9, 2, 4, 16, 64), bf16)
+        return (lambda q, kp, vp, picked, pt, n: op(
+            q, kp, vp, picked, paged_work_list(pt, n, page_size=16),
+            layer=1),
+                (s((2, 4, 64), bf16), pool, pool, s((2, 64), bool),
+                 s((2, 4), jnp.int32), s((2,), jnp.int32)))
+
     def fused_block_decode():
         # the jaxpr-audit fixture geometry (hidden 64, GPT kind); the
         # flagship-shape envelope rides fused_block_envelope, not the
@@ -310,6 +338,15 @@ def _builders():
         "paged_decode_latent": (paged_decode_latent,
                                 "apex_tpu/ops/paged_attention.py",
                                 ops + "paged_attention"),
+        "dsa_index_scores": (dsa_index_scores,
+                             "apex_tpu/ops/attention.py",
+                             ops + "attention"),
+        "paged_index_scores": (paged_index_scores,
+                               "apex_tpu/ops/paged_attention.py",
+                               ops + "paged_attention"),
+        "paged_select_attention": (paged_select_attention,
+                                   "apex_tpu/ops/paged_attention.py",
+                                   ops + "paged_attention"),
         "fused_block_decode": (fused_block_decode,
                                "apex_tpu/ops/paged_attention.py",
                                ops + "paged_attention"),

@@ -581,6 +581,12 @@ def _builders():
             lambda: _inference("inference_decode_latent"),
             "apex_tpu/inference/engine.py", (0,), True, False, False,
             False),
+        # ISSUE 36: the selecting kind's decode step — three pools under
+        # one table, donated like the others
+        "inference_decode_select": (
+            lambda: _inference("inference_decode_select"),
+            "apex_tpu/inference/engine.py", (0,), True, False, False,
+            False),
         # ISSUE 15: the fused-block decode lowering
         # (APEX_TPU_DECODE_FUSION=1 twin of inference_decode_paged —
         # same signature, same donation, one Pallas kernel per layer)

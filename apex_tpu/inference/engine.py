@@ -149,17 +149,18 @@ def make_prefill_fn(kind: str, cfg, sampling: SamplingConfig,
         # the pool layers' k/v go to the slot's pages; a kind with window
         # layers (ISSUE 30) also writes their last positions to its
         # rings, and prefills every prompt whole (prefill_from is held
-        # to 0 by the engine); a kind with stats returns them as the
+        # to 0 by the engine); a kind that selects (ISSUE 36) writes its
+        # index keys with its k/v; a kind with stats returns them as the
         # sampled token's tail, read in the one transfer the scheduler
         # already makes
         suffix = dict(cache=cache, row=row,
                       prefill_from=prefill_from) if shares else {}
         with obs.named_scope("apex_prefill_forward"):
-            logits, ks, vs, wks, wvs, stats = models.prefill_forward(
+            logits, ks, vs, wks, wvs, iks, stats = models.prefill_forward(
                 kind, cfg, params, tokens[None], length, tp=tp, **suffix)
         with obs.named_scope("apex_prefill_cache_insert"):
             cache = kv_cache.insert_tokens(cache, slot, ks, vs, length,
-                                           row, prefill_from)
+                                           row, prefill_from, iks)
             if rings:
                 cache = kv_cache.insert_window(cache, slot, wks, wvs,
                                                length)
@@ -381,9 +382,11 @@ class InferenceEngine:
             models.check_supported(kind, cfg)   # an unknown kind raises
             rec = models.KINDS[kind]
         self._refuses = rec.refuses if rec else {}
-        #: int32 counters a step appends to the tokens it returns
-        #: (the record's ``stats``); 0 for kinds without an expert FFN
-        self.stats_tail = len(rec.stats) if rec else 0
+        #: the int32 counters a step appends to the tokens it returns,
+        #: by name (the record's ``stats``), and how many they are; none
+        #: for kinds without an expert FFN
+        self.stats_names = rec.stats if rec else ()
+        self.stats_tail = len(self.stats_names)
         #: can a cached prefix's pages be mapped into another slot, or
         #: a prefill resume mid-prompt?  Not over window rings
         self.supports_prefix_sharing = "prefix_sharing" not in self._refuses
@@ -589,7 +592,7 @@ class InferenceEngine:
                 sb = cs.k if self.tp > 1 else None
                 self._swap_out_raw = self._tp_wrap(
                     kv_cache.extract_pages, in_specs=(cs, P()),
-                    out_specs=(sb, sb))
+                    out_specs=(sb, sb, None))
                 # NOT donated: extract is a pure read — the pool stays
                 # live (eviction is host-side bookkeeping)
                 self._swap_out = jax.jit(self._swap_out_raw)
@@ -695,7 +698,7 @@ class InferenceEngine:
                     max_pages_per_slot=self.max_pages_per_slot,
                     dtype=self.cache_dtype,
                     window_layers=d["window_layers"], window=d["window"],
-                    latent=d["latent"])
+                    latent=d["latent"], index=d["index"])
 
             if self.tp == 1:
                 return build()
@@ -913,7 +916,8 @@ class InferenceEngine:
                 padded = np.full((B,), self.num_pages, np.int32)
                 padded[:chunk.shape[0]] = chunk
                 self._swap_out_dispatches.inc()
-                k_s, v_s = self._swap_out(cache, padded)
+                # the host tier is refused for a kind with index keys
+                k_s, v_s, _ = self._swap_out(cache, padded)
                 pending.append((k_s, v_s, chunk.shape[0]))
             if defer:
                 return PendingSwapOut(pending)

@@ -76,6 +76,23 @@ Shared design positions:
   table, lengths, capacity, allocator and every mutator are the paged
   pool's own: a prefill inserts ``[layers, s, width]`` rows, a decode
   step appends ``[slots, width]`` a layer.
+* **An index-key pool beside the K/V pool (ISSUE 36).**  A model whose
+  layers SELECT the positions they attend by a learned index caches a
+  third kind of per-position state: one small index key a position a
+  layer, in a pool array of its own beside ``k`` and ``v`` ::
+
+      ik : [pages, layers, index_width, page_size]
+
+  (``init_paged_cache(..., index=width)``; a page's positions on the
+  minor axis, as the latent pool's are: the index kernel's product takes
+  the block as it stands).  It lives under the SAME page table, lengths,
+  capacity and :class:`PageAllocator` — one reservation a request covers
+  all three arrays — and every mutator treats a page as all its arrays:
+  a prefill inserts the prompt's index keys with its k/v, a decode step
+  appends one a slot a layer, :func:`cow_page`, :func:`extract_pages` and
+  :func:`restore_pages` move the page's index keys with the rest.  A model
+  without an indexer holds no such array (``ik`` is ``None``, not a
+  zero-sized array).
 * **The trash page.**  The paged pool carries ONE sacrificial page at
   index ``pages - 1`` that the allocator never hands out; page-table
   entries beyond a slot's reservation point there, so the statically
@@ -216,7 +233,7 @@ def insert(cache: KVCache, slot, k, v, length) -> KVCache:
     return cache.replace(k=new_k, v=new_v, lengths=new_len)
 
 
-def append_layer(cache, layer: int, k_tok, v_tok):
+def append_layer(cache, layer: int, k_tok, v_tok, ik_tok=None):
     """Decode write for ONE layer: each slot's token row lands at that
     slot's current length.
 
@@ -225,7 +242,9 @@ def append_layer(cache, layer: int, k_tok, v_tok):
     unrolled python loop over layers).  Lengths do NOT advance here —
     call :func:`advance` once after the last layer so every layer of a
     decode step writes to the same position.  Dispatches on the cache
-    layout: dense slot cache or paged pool.
+    layout: dense slot cache or paged pool.  ``ik_tok`` ``[slots,
+    index_width]``: the token's index key a slot, for a paged cache with
+    an index-key pool (and only for one).
     """
     paged = isinstance(cache, PagedKVCache)
     want = (cache.slots, *(cache.row_shape if paged else
@@ -236,7 +255,12 @@ def append_layer(cache, layer: int, k_tok, v_tok):
             f"kv_heads={cache.kv_heads}, head_dim={cache.head_dim}] (a "
             f"latent pool: [slots, width]), got {tuple(k_tok.shape)}")
     if paged:
-        return _append_layer_paged(cache, layer, k_tok, v_tok)
+        if cache.ik is not None:
+            _check_index(ik_tok, (cache.slots, cache.ik.shape[2]),
+                         "token k/v")
+        return _append_layer_paged(cache, layer, k_tok, v_tok, ik_tok)
+    if ik_tok is not None:
+        raise ValueError("the dense slot cache has no index-key pool")
 
     def write(buf, tok, pos):
         # buf [kv_heads, max_seq, d], tok [kv_heads, d]: one token row
@@ -439,6 +463,10 @@ class PagedKVCache:
     # pages; position t of a slot sits at ring row t % ring
     wk: Optional[jax.Array] = None
     wv: Optional[jax.Array] = None
+    # the index keys of a kind whose layers SELECT (ISSUE 36), None
+    # without an indexer: [pages, layers, index_width, page_size], under
+    # the same page table as k and v
+    ik: Optional[jax.Array] = None
 
     @property
     def latent(self) -> bool:
@@ -508,7 +536,7 @@ def init_paged_cache(pages: int, layers: int, kv_heads: int,
                      page_size: int, head_dim: int, *, slots: int,
                      max_pages_per_slot: int, dtype=jnp.bfloat16,
                      window_layers: int = 0, window: int = 0,
-                     latent: int = 0) -> PagedKVCache:
+                     latent: int = 0, index: int = 0) -> PagedKVCache:
     """Allocate an empty pool: ``pages`` allocatable pages (+1 trash
     page appended), every page-table entry pointing at the trash page,
     every slot empty.  ``layers`` counts the layers the POOL holds;
@@ -516,7 +544,9 @@ def init_paged_cache(pages: int, layers: int, kv_heads: int,
     the second pool of per-slot rings instead (module docstring).
     ``latent`` (a row's width) makes the pool a LATENT one: one array
     ``[pages + 1, layers, latent, page_size]``, no value array;
-    ``kv_heads`` / ``head_dim`` are then not read."""
+    ``kv_heads`` / ``head_dim`` are then not read.  ``index`` (an index
+    key's width) adds the index-key pool ``[pages + 1, layers, index,
+    page_size]`` under the same table; 0 adds no array."""
     if pages < 1 or page_size < 1 or max_pages_per_slot < 1:
         raise ValueError(
             f"pages ({pages}), page_size ({page_size}) and "
@@ -540,7 +570,9 @@ def init_paged_cache(pages: int, layers: int, kv_heads: int,
         page_table=jnp.full((slots, max_pages_per_slot), pages,
                             jnp.int32),
         lengths=jnp.zeros((slots,), jnp.int32),
-        capacity=jnp.zeros((slots,), jnp.int32), **rings)
+        capacity=jnp.zeros((slots,), jnp.int32), **rings,
+        ik=jnp.zeros((pages + 1, layers, index, page_size), dtype)
+        if index else None)
 
 
 def ring_rows(window: int, page_size: int) -> int:
@@ -565,19 +597,37 @@ def paged_cache_partition_specs(axis: str = TENSOR_AXIS) -> PagedKVCache:
                         capacity=P())
 
 
-def _pools(cache: PagedKVCache, fn, k, v) -> dict:
+def _pools(cache: PagedKVCache, fn, k, v, ik=None) -> dict:
     """``fn(pool, rows)`` over the key pool and — where the cache has one
-    — the value pool: the ``k=``/``v=`` of a ``cache.replace``."""
+    — the value pool and the index-key pool: the ``k=``/``v=``/``ik=`` of
+    a ``cache.replace``.  A page is all its arrays: index keys for a cache
+    without that pool, or none for a cache with it, raise."""
     if cache.v is None and v is not None:
         raise ValueError("a latent pool holds one row a position and no "
                          "values beside it: pass v=None")
+    if k is not None and (cache.ik is None) != (ik is None):
+        raise ValueError(
+            "a cache with an index-key pool takes the index keys with "
+            "every k/v it is handed, and a cache without one takes none; "
+            f"got ik {None if ik is None else tuple(ik.shape)} for a cache "
+            f"with{'out' if cache.ik is None else ''} the pool")
     return {"k": fn(cache.k, k),
-            "v": None if cache.v is None else fn(cache.v, v)}
+            "v": None if cache.v is None else fn(cache.v, v),
+            "ik": None if cache.ik is None else fn(cache.ik, ik)}
 
 
-def _check_rows(cache: PagedKVCache, k, v, what: str, lead: tuple) -> None:
+def _rows_minor(pool) -> bool:
+    """Does a page of ``pool`` hold its positions on the minor axis (the
+    latent pool and the index-key pool: ``[pages, layers, width,
+    page_size]``) and not one row a position a KV head?"""
+    return pool.ndim == 4
+
+
+def _check_rows(cache: PagedKVCache, k, v, what: str, lead: tuple,
+                ik=None) -> None:
     """``k`` (and ``v``) must be ``[*lead, <kv_heads>, n, head_dim]`` —
-    without the KV-head axis for a latent pool, whose ``v`` is None."""
+    without the KV-head axis for a latent pool, whose ``v`` is None —
+    and ``ik``, where given, ``[*lead, n, index_width]``."""
     heads = () if cache.latent else (cache.kv_heads,)
     ok = (k.ndim == len(lead) + len(heads) + 2
           and tuple(k.shape[:len(lead) + len(heads)]) == lead + heads
@@ -589,6 +639,16 @@ def _check_rows(cache: PagedKVCache, k, v, what: str, lead: tuple) -> None:
             f"{what} must be {list(lead + heads) + ['n', cache.head_dim]}"
             f"{' and v None' if cache.latent else ', k and v alike'}; got "
             f"k {tuple(k.shape)} v {None if v is None else tuple(v.shape)}")
+    if cache.ik is not None:
+        _check_index(ik, (*lead, k.shape[-2], cache.ik.shape[2]), what)
+
+
+def _check_index(ik, want: tuple, what: str) -> None:
+    """Index keys handed to a cache with that pool must be ``want``-shaped
+    (their ABSENCE is :func:`_pools`' to refuse)."""
+    if ik is not None and tuple(ik.shape) != tuple(want):
+        raise ValueError(f"{what}: the index keys must be {list(want)}, "
+                         f"got {tuple(ik.shape)}")
 
 
 def page_row(page_ids: Sequence[int], max_pages_per_slot: int,
@@ -606,7 +666,7 @@ def page_row(page_ids: Sequence[int], max_pages_per_slot: int,
 
 
 def insert_pages(cache: PagedKVCache, slot, k, v, length,
-                 row) -> PagedKVCache:
+                 row, ik=None) -> PagedKVCache:
     """Prefill write: park a prompt's k/v into the slot's pages.
 
     ``k``/``v``: ``[layers, kv_heads, s, head_dim]`` with ``s`` the
@@ -618,10 +678,12 @@ def insert_pages(cache: PagedKVCache, slot, k, v, length,
     prompt's pages, later owned entries are decode headroom, trash-page
     entries absorb any static overhang harmlessly.  The slot's capacity
     is derived in-program from the row (owned pages x page_size), so
-    one compiled insert serves every page assignment.
+    one compiled insert serves every page assignment.  ``ik`` ``[layers,
+    s, index_width]``: the prompt's index keys, for a cache with that
+    pool.
     """
     ps, s = cache.page_size, k.shape[-2]
-    _check_rows(cache, k, v, "prefill k/v", (cache.layers,))
+    _check_rows(cache, k, v, "prefill k/v", (cache.layers,), ik)
     if s % ps or s > cache.max_seq:
         raise ValueError(
             f"prompt slab length {s} must be a multiple of page_size "
@@ -640,7 +702,7 @@ def insert_pages(cache: PagedKVCache, slot, k, v, length,
         # bucket page, scattered to its physical page in ONE op (bucket
         # overhang beyond the reservation targets the trash page; the
         # trash page appearing more than once just stacks garbage)
-        if cache.latent:    # [layers, s, w] -> [n, layers, w, ps]
+        if _rows_minor(pool):   # [layers, s, w] -> [n, layers, w, ps]
             slab = jnp.moveaxis(jnp.swapaxes(x, -1, -2).reshape(
                 *x.shape[:-2], x.shape[-1], n, ps), -2, 0)
         else:
@@ -648,7 +710,7 @@ def insert_pages(cache: PagedKVCache, slot, k, v, length,
                 x.reshape(*x.shape[:-2], n, ps, x.shape[-1]), -3, 0)
         return pool.at[row[:n]].set(slab.astype(pool.dtype), mode="drop")
 
-    pools = _pools(cache, write, k, v)
+    pools = _pools(cache, write, k, v, ik)
     owned = jnp.sum((row != cache.null_page).astype(jnp.int32))
     return cache.replace(
         **pools,
@@ -661,7 +723,7 @@ def insert_pages(cache: PagedKVCache, slot, k, v, length,
 
 
 def insert_tokens(cache: PagedKVCache, slot, k, v, length, row,
-                  start) -> PagedKVCache:
+                  start, ik=None) -> PagedKVCache:
     """Suffix prefill write (ISSUE 12): scatter a bucket-padded slab of
     ``s`` token rows into the slot's pages at positions ``[start,
     start + s)`` — ANY alignment, so a prefix-cache hit can resume
@@ -684,10 +746,11 @@ def insert_tokens(cache: PagedKVCache, slot, k, v, length, row,
     The page-table row, lengths, and capacity update exactly as in
     :func:`insert_pages` (capacity derived in-program from the owned
     entries), so one compiled insert serves every page assignment and
-    every ``start``.
+    every ``start``.  ``ik`` ``[layers, s, index_width]``: the slab's
+    index keys, for a cache with that pool.
     """
     ps, mpps, s = cache.page_size, cache.max_pages_per_slot, k.shape[-2]
-    _check_rows(cache, k, v, "prefill k/v", (cache.layers,))
+    _check_rows(cache, k, v, "prefill k/v", (cache.layers,), ik)
     if s < 1 or s > cache.max_seq:
         raise ValueError(
             f"suffix slab length {s} must be in [1, max_seq "
@@ -722,7 +785,7 @@ def insert_tokens(cache: PagedKVCache, slot, k, v, length, row,
     def write(pool, x):
         slab = jnp.take(pool, page_ids, axis=0, mode="clip")
         zero = jnp.int32(0)
-        if cache.latent:
+        if _rows_minor(pool):
             # [n, layers, w, ps] -> [layers, w, n * ps]: the positions
             # lie along the minor axis, token t at (start % ps) + t
             layers, _, w = x.shape
@@ -741,7 +804,7 @@ def insert_tokens(cache: PagedKVCache, slot, k, v, length, row,
         slab = jnp.moveaxis(flat.reshape(layers, kvh, n, ps, d), 2, 0)
         return pool.at[page_ids].set(slab, mode="drop")
 
-    pools = _pools(cache, write, k, v)
+    pools = _pools(cache, write, k, v, ik)
     owned = jnp.sum((row != cache.null_page).astype(jnp.int32))
     zero = jnp.int32(0)
     return cache.replace(
@@ -849,6 +912,7 @@ def cow_page(cache: PagedKVCache, src, dst) -> PagedKVCache:
                                      (1,) + pool.shape[1:])
         return jax.lax.dynamic_update_slice(pool, page, (dst,) + rest)
 
+    # every array of the page: k, v and the index keys alike
     return cache.replace(**_pools(cache, copy, None, None))
 
 
@@ -867,21 +931,23 @@ def extract_pages(cache: PagedKVCache, page_ids):
     page IDs to the free list host-side, no device-side erase needed).
     Under tensor parallelism each rank gathers its own ``kv_heads/tp``
     shard of the requested pages; the host-side ``device_get``
-    assembles the global slab."""
+    assembles the global slab.  Returns ``(k, v, ik)`` whatever the
+    cache holds — :func:`restore_pages`' three slabs."""
     page_ids = jnp.asarray(page_ids, jnp.int32)
     if page_ids.ndim != 1:
         raise ValueError(
             f"page_ids must be a rank-1 int32 vector, got shape "
             f"{tuple(page_ids.shape)}")
-    k_slab = jnp.take(cache.k, page_ids, axis=0, mode="clip")
-    if cache.v is None:             # a latent pool: one slab, no values
-        return k_slab, None
-    v_slab = jnp.take(cache.v, page_ids, axis=0, mode="clip")
-    return k_slab, v_slab
+    slabs = _pools(cache, lambda pool, _: jnp.take(
+        pool, page_ids, axis=0, mode="clip"), None, None)
+    # always the triple (k, v, ik), None for a pool the cache does not
+    # hold, as the cache itself has it: a latent pool has no values, and
+    # only a kind that selects has index keys
+    return slabs["k"], slabs["v"], slabs["ik"]
 
 
 def restore_pages(cache: PagedKVCache, page_ids, k_slab,
-                  v_slab) -> PagedKVCache:
+                  v_slab, ik_slab=None) -> PagedKVCache:
     """Swap-in scatter (ISSUE 18 host page tier): write host-tier page
     slabs back into freshly acquired physical pages ``page_ids`` — the
     :func:`insert_pages` slab scatter aimed by an explicit page-ID
@@ -894,7 +960,10 @@ def restore_pages(cache: PagedKVCache, page_ids, k_slab,
     ``mode="drop"`` discards the padding rows — one compiled restore
     serves every page set.  Pure donated update like every other cache
     mutation.  Under tensor parallelism each rank scatters its own
-    ``kv_heads/tp`` shard of the (globally sharded) slab operand."""
+    ``kv_heads/tp`` shard of the (globally sharded) slab operand.
+    ``ik_slab``: the pages' index keys (:func:`extract_pages`' third
+    slab), for a cache with that pool — a page is restored whole or not
+    at all."""
     page_ids = jnp.asarray(page_ids, jnp.int32)
     if page_ids.ndim != 1:
         raise ValueError(
@@ -911,11 +980,14 @@ def restore_pages(cache: PagedKVCache, page_ids, k_slab,
     def write(pool, slab):
         return pool.at[page_ids].set(slab.astype(pool.dtype), mode="drop")
 
-    return cache.replace(**_pools(cache, write, k_slab, v_slab))
+    if cache.ik is not None:
+        _check_index(ik_slab, (page_ids.shape[0], *cache.ik.shape[1:]),
+                     "swap-in slabs")
+    return cache.replace(**_pools(cache, write, k_slab, v_slab, ik_slab))
 
 
 def _append_layer_paged(cache: PagedKVCache, layer: int, k_tok,
-                        v_tok) -> PagedKVCache:
+                        v_tok, ik_tok=None) -> PagedKVCache:
     """Paged decode write for ONE layer: slot ``i``'s token row lands in
     page ``page_table[i, lengths[i] // page_size]`` at row
     ``lengths[i] % page_size``.  One gather + one scatter of the
@@ -949,7 +1021,7 @@ def _append_layer_paged(cache: PagedKVCache, layer: int, k_tok,
         cur = cur.at[sid, :, offs].set(tok.astype(pool.dtype))
         return pool.at[pages, layer].set(cur, mode="drop")
 
-    return cache.replace(**_pools(cache, write, k_tok, v_tok))
+    return cache.replace(**_pools(cache, write, k_tok, v_tok, ik_tok))
 
 
 class PageAllocator:

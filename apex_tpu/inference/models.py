@@ -9,7 +9,7 @@ the cache) and a speculative *verify* (a drafted slab per slot).  The
 forwards consume the EXACT param pytree ``model.init`` produces — no
 re-keying, no conversion step.
 
-**A kind is a record** (:class:`Kind`, four entries in :data:`KINDS`):
+**A kind is a record** (:class:`Kind`, five entries in :data:`KINDS`):
 its geometry (``dims`` / ``check``), its per-layer pieces (``embed``,
 ``rope``, ``norm``, ``project``, ``attn_out``, ``ffn``, ``head``), its
 fused-block layout or ``None``, the names of the counters its steps
@@ -22,7 +22,9 @@ decode against ``model.apply``; ``laguna``'s pieces ARE
 ``standalone_laguna``'s (``attn_project`` / ``attn_output`` / ``ffn`` /
 ``rope_cos_sin``), held to the benchmark's plain reference by
 ``test_laguna_parity.py``; ``axk1``'s are ``standalone_axk1``'s
-(ISSUE 34), held to its reference by ``test_axk1_parity.py``.
+(ISSUE 34), held to its reference by ``test_axk1_parity.py``; ``keye``'s
+are ``standalone_keye``'s (ISSUE 36), held to its reference by
+``test_keye_parity.py``.
 
 **A mode is a loop** (:func:`prefill_forward`, :func:`decode_forward`,
 :func:`verify_forward`): each owns its activation layout, where k/v go
@@ -41,7 +43,16 @@ it in two forms of the same function: *expanded* in prefill (keys and
 values made from the latent a layer at a time, then the flash kernel with
 a value width of its own) and *absorbed* in decode (the key up-projection
 folded into the query, ``apex_paged_decode_latent`` over the latent pool,
-the value up-projection behind it).
+the value up-projection behind it).  A kind whose layers SELECT
+(``Kind.select``, ISSUE 36) projects, beside q, k and v, a few small index
+queries and ONE index key a position, which the cache keeps in a pool of
+its own; a query attends the ``topk`` cached positions of largest index
+score and no other.  Prefill scores, picks and attends a block of query
+rows at a time (``ops.attention.select_attention``); decode runs three
+stages a layer over the paged pools — index scores along the step's work
+list (``apex_dsa_index``), the picked set of each slot on the device
+(``select_top_mask``), attention over the picked rows
+(``apex_dsa_attend``).
 
 Unsupported training-only configs (scan_layers, the capacity-slot MoE
 FFN of ``transformer/moe/MoELayer``, sequence/context parallelism) fail
@@ -71,11 +82,15 @@ from apex_tpu.ops.attention import (
     flash_attention,
     prefix_window_attention,
     ring_decode_attention,
+    select_attention,
+    select_top_mask,
     slab_decode_attention,
 )
 from apex_tpu.ops.paged_attention import (
     fused_block_decode,
     paged_decode_attention,
+    paged_index_scores,
+    paged_select_attention,
     paged_slab_attention,
     paged_work_list,
 )
@@ -85,18 +100,23 @@ from apex_tpu.transformer.functional.fused_rope import (
 from apex_tpu.transformer.moe.dropless import fold_stats
 from apex_tpu.transformer.parallel_state import TENSOR_AXIS
 from apex_tpu.transformer.testing import standalone_axk1 as axk1
+from apex_tpu.transformer.testing import standalone_keye as keye
 from apex_tpu.transformer.testing import standalone_laguna as laguna
 from apex_tpu.transformer.testing.standalone_llama import _rope_cos_sin
 
 __all__ = ["Kind", "KINDS", "model_dims", "tp_dims", "check_supported",
-           "prefill_forward", "EXPERT_STATS", "stats_tail", "Latent",
-           "cache_row_values", "decode_forward", "verify_forward",
+           "prefill_forward", "EXPERT_STATS", "SELECT_STATS", "stats_tail",
+           "Latent", "Select", "cache_row_values", "decode_forward",
+           "verify_forward",
            "fused_layer_params", "expand_kv_for_tp",
            "param_partition_specs", "fused_partition_specs"]
 
 #: a layer of this type keeps every position, in the paged pool; any
 #: other type keeps its last ``window`` positions in a per-slot ring
 FULL = laguna.FULL
+#: the key under which a kind that SELECTS hands the loops its indexer's
+#: RoPE table, beside the layer types' tables
+INDEX = "index"
 
 
 # --------------------------------------------------------------------------
@@ -184,7 +204,7 @@ def _gpt_dims(cfg) -> dict:
             "head_dim": cfg.hidden_size // cfg.num_attention_heads,
             "layer_types": (FULL,) * cfg.num_layers,
             "pool_layers": cfg.num_layers, "window_layers": 0, "window": 0,
-            "latent": 0}
+            "latent": 0, "index": 0}
 
 
 def _check_dense(cfg) -> None:
@@ -368,8 +388,8 @@ def _llama_fused_tail(cfg, blk, x, part):
 
 #: what a step of a kind with an expert FFN reports beside its tokens, in
 #: this order — the int32 tail of the token read
-#: (``InferenceEngine.stats_tail`` long, ``ServeTelemetry.expert_pass``'s
-#: arguments).  A kind that HOLDS a share of its experts counts the
+#: (``InferenceEngine.stats_tail`` long; the scheduler hands them to
+#: ``ServeTelemetry.step_counters`` under these names).  A kind that HOLDS a share of its experts counts the
 #: assignments that land on a held expert and the held experts hit.
 EXPERT_STATS = ("moe_assignments", "moe_experts_hit",
                 "moe_expert_load_max", "window_pages_live")
@@ -383,7 +403,7 @@ def _laguna_dims(cfg) -> dict:
             "kv_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
             "pool_layers": len(cfg.full_layers),
             "window_layers": len(cfg.window_layers),
-            "window": cfg.sliding_window, "latent": 0}
+            "window": cfg.sliding_window, "latent": 0, "index": 0}
 
 
 def _laguna_check(cfg) -> None:
@@ -430,7 +450,8 @@ def _axk1_dims(cfg) -> dict:
             "kv_heads": 0, "head_dim": cfg.qk_head_dim,
             "layer_types": (FULL,) * cfg.num_layers,
             "pool_layers": cfg.num_layers, "window_layers": 0, "window": 0,
-            "latent": cfg.latent_dim, "latent_values": cfg.kv_lora_rank}
+            "latent": cfg.latent_dim, "latent_values": cfg.kv_lora_rank,
+            "index": 0}
 
 
 def _axk1_check(cfg) -> None:
@@ -456,9 +477,61 @@ def _axk1_ffn(cfg, i, lp, h, valid, tp):
     return y.reshape(h.shape), stats
 
 
-def _not_built(what: str, module: str) -> str:
-    return (f"{what} is not built for the 'axk1' kind: {module} would "
+def _not_built(kind: str, what: str, module: str) -> str:
+    return (f"{what} is not built for the {kind!r} kind: {module} would "
             f"have to change")
+
+
+# --------------------------------------------------------------------------
+# Keye-VL-2.0's language model (standalone_keye's per-layer pieces;
+# ISSUE 36): grouped-query attention over the positions a learned indexer
+# picks, an index-key pool beside the K/V pool, every layer an expert FFN
+# --------------------------------------------------------------------------
+
+#: what a step of a kind that SELECTS reports behind the expert counters:
+#: query rows x layers that went through the indexer, those of them whose
+#: context exceeded the selection's size (so that it cut something), and
+#: the positions attended, summed
+SELECT_STATS = ("dsa_rows", "dsa_rows_sparse", "dsa_selected")
+
+
+def _keye_dims(cfg) -> dict:
+    return {"layers": cfg.num_layers, "heads": cfg.num_heads,
+            "kv_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
+            "layer_types": (FULL,) * cfg.num_layers,
+            "pool_layers": cfg.num_layers, "window_layers": 0, "window": 0,
+            "latent": 0, "index": cfg.index_head_dim}
+
+
+def _keye_check(cfg) -> None:
+    if not isinstance(cfg, keye.KeyeConfig):
+        raise TypeError(
+            f"the 'keye' kind takes a KeyeConfig, got "
+            f"{type(cfg).__name__}")
+
+
+def _keye_rope(cfg, dims, positions, n):
+    """The engine hands text positions (one index a token), which is plain
+    RoPE; a triple of position arrays (temporal, height, width) takes the
+    three-section form.  The indexer ropes by the temporal one."""
+    if positions is None:
+        positions = jnp.arange(n, dtype=jnp.int32)
+    axes = positions if isinstance(positions, tuple) else (positions,) * 3
+    return {FULL: keye.mrope_cos_sin(cfg, *axes),
+            INDEX: keye.index_rope_cos_sin(cfg, axes[0])}
+
+
+def _keye_project(cfg, dims, i, lp, h, rope):
+    return (*keye.attn_project(cfg, lp, h, *rope), None)
+
+
+def _keye_attn_out(lp, ctx, extra, tp):
+    return keye.attn_output(lp, ctx)
+
+
+def _keye_ffn(cfg, i, lp, h, valid, tp):
+    y, stats = keye.ffn(cfg, lp, h.reshape(-1, h.shape[-1]), valid=valid)
+    return y.reshape(h.shape), stats
 
 
 # --------------------------------------------------------------------------
@@ -499,6 +572,22 @@ class Latent:
 
 
 @dataclasses.dataclass(frozen=True)
+class Select:
+    """How a layer of a kind with a learned indexer picks the positions it
+    attends (ISSUE 36): the cache holds one index key a position a layer,
+    ``dims["index"]`` wide, beside the layer's k and v."""
+    #: ``(cfg, lp, h, cos, sin) -> qi [..., index_heads, width], wi [...,
+    #: index_heads] float32, ki [..., width]``: the index queries, their
+    #: weights and the ONE index key the cache keeps; ``cos``/``sin`` the
+    #: ``INDEX`` table of the kind's ``rope``
+    index: Callable
+    #: ``cfg -> int``: positions a query attends at most
+    topk: Callable
+    #: ``cfg -> int``: query rows a prefill scores and selects at a time
+    block: Callable
+
+
+@dataclasses.dataclass(frozen=True)
 class Kind:
     """What the three layer loops and the engine ask of a model kind:
     pure functions of ``(cfg, p | lp, ...)`` and static facts.  ``p`` is
@@ -535,6 +624,9 @@ class Kind:
     fused: Optional[Fused] = None
     #: latent attention's two forms, or None: q/k/v through ``project``
     latent: Optional[Latent] = None
+    #: the learned selection of the positions a layer attends, or None:
+    #: every causal (or window) position
+    select: Optional[Select] = None
     #: names of the int32 counters a step appends to its tokens
     stats: Tuple[str, ...] = ()
     #: feature -> why it is not built for the kind; the features are
@@ -597,24 +689,61 @@ KINDS = {
                      "ops/attention.py's decode_attention scores per-head "
                      "k/v): pass page_size=/num_pages=",
             "tp": _not_built(
-                "tp > 1", "models.param_partition_specs and "
+                "axk1", "tp > 1", "models.param_partition_specs and "
                 "kv_cache.paged_cache_partition_specs (a replicated "
                 "latent, head-sharded up-projections, the all-to-all "
                 "round the held experts)"),
             "verify": _not_built(
-                "speculative verify", "ops/paged_attention.py's "
+                "axk1", "speculative verify", "ops/paged_attention.py's "
                 "paged_slab_attention (it gathers per-head k/v windows) "
                 "and kv_cache.append_slab"),
             "host_tier": _not_built(
-                "the host KV tier", "kv_cache.HostPageStore and "
+                "axk1", "the host KV tier", "kv_cache.HostPageStore and "
                 "engine.swap_in_pages (they move a k and a v slab)"),
             "fused": _not_built(
-                "fused_block_decode", "ops/paged_attention.py's "
+                "axk1", "fused_block_decode", "ops/paged_attention.py's "
                 "_fused_block_kernel (per-head k/v, a dense FFN)"),
             "prefix_sharing": _not_built(
-                "prefix sharing, and with it chunked prefill,",
+                "axk1", "prefix sharing, and with it chunked prefill,",
                 "models._suffix_attend (the cached prefix would have to "
                 "be up-projected a chunk at a time)"),
+        }),
+    "keye": Kind(
+        dims=_keye_dims, check=_keye_check, embed=_token_embed,
+        rope=_keye_rope, norm=_rms_norm, project=_keye_project,
+        attn_out=_keye_attn_out, ffn=_keye_ffn, head=_untied_head,
+        select=Select(index=keye.index_project,
+                      topk=lambda cfg: cfg.index_topk,
+                      block=lambda cfg: cfg.index_q_chunk),
+        stats=EXPERT_STATS + SELECT_STATS,
+        refuses={
+            "dense": "the 'keye' kind serves from the paged cache only "
+                     "(its index keys live in a pool beside the K/V pool; "
+                     "kv_cache.KVCache has no such array and "
+                     "ops/attention.py's decode_attention attends every "
+                     "live position): pass page_size=/num_pages=",
+            "tp": _not_built(
+                "keye", "tp > 1", "models.param_partition_specs and "
+                "kv_cache.paged_cache_partition_specs (index keys "
+                "replicated beside head-sharded K/V, the selection made "
+                "once for all ranks, the expert stacks' all-to-all)"),
+            "verify": _not_built(
+                "keye", "speculative verify", "ops/paged_attention.py's "
+                "paged_slab_attention (a slab row would score the cached "
+                "index keys and pick its own set) and "
+                "kv_cache.append_slab (no index keys)"),
+            "host_tier": _not_built(
+                "keye", "the host KV tier", "kv_cache.HostPageStore and "
+                "engine.swap_in_pages (they move a k and a v slab, not "
+                "the page's index keys)"),
+            "fused": _not_built(
+                "keye", "fused_block_decode", "ops/paged_attention.py's "
+                "_fused_block_kernel (it attends every live page, with a "
+                "dense FFN)"),
+            "prefix_sharing": _not_built(
+                "keye", "prefix sharing, and with it chunked prefill,",
+                "models._suffix_attend (a resumed prefill would have to "
+                "score the CACHED index keys and pick among cached rows)"),
         }),
 }
 
@@ -638,8 +767,10 @@ def model_dims(kind: str, cfg) -> dict:
 
 def cache_row_values(dims: dict, kv_heads: int) -> int:
     """Values ONE cached position holds in ONE layer, over every buffer:
-    a key and a value per KV head — or the latent row, which is both."""
-    return dims["latent"] or 2 * kv_heads * dims["head_dim"]
+    a key and a value per KV head — or the latent row, which is both —
+    and the index key of a kind that selects."""
+    return (dims["latent"] or 2 * kv_heads * dims["head_dim"]) \
+        + dims.get("index", 0)
 
 
 def tp_dims(kind: str, cfg, tp: int) -> dict:
@@ -924,8 +1055,22 @@ def stats_tail(names, acc, cache):
     values = {"moe_assignments": acc.get("assignments", zero),
               "moe_experts_hit": acc.get("experts_hit", zero),
               "moe_expert_load_max": acc.get("load_max", zero),
-              "window_pages_live": kv_cache.window_pages_live(cache)}
+              "window_pages_live": kv_cache.window_pages_live(cache),
+              **{n: acc.get(n, zero) for n in SELECT_STATS}}
     return jnp.stack([values[n] for n in names]).astype(jnp.int32)
+
+
+def _select_stats(acc, counted, context, picked, topk: int):
+    """Fold one selecting layer into a step's :data:`SELECT_STATS`: per
+    query row its ``context`` (the positions it could attend) and
+    ``picked`` (those it did); ``counted`` (bool) the rows that carry a
+    token."""
+    layer = {"dsa_rows": jnp.sum(counted, dtype=jnp.int32),
+             "dsa_rows_sparse": jnp.sum(counted & (context > topk),
+                                        dtype=jnp.int32),
+             "dsa_selected": jnp.sum(jnp.where(counted, picked, 0),
+                                     dtype=jnp.int32)}
+    return {n: layer[n] + (acc or {}).get(n, 0) for n in SELECT_STATS}
 
 
 # --------------------------------------------------------------------------
@@ -935,14 +1080,16 @@ def stats_tail(names, acc, cache):
 def prefill_forward(kind: str, cfg, params, tokens, length=None, *,
                     cache=None, row=None, prefill_from=None, tp=1):
     """Full-prompt forward: ``tokens [1, s]`` -> ``(logits, ks, vs, wks,
-    wvs, stats)``.  ``ks`` / ``vs`` ``[pool_layers, kv_heads, s,
+    wvs, iks, stats)``.  ``ks`` / ``vs`` ``[pool_layers, kv_heads, s,
     head_dim]`` are the pool layers' k/v, ready for
     :func:`kv_cache.insert` / ``insert_tokens`` (a kind with latent
     attention: ``ks [pool_layers, s, width]`` the latent rows, ``vs``
     None — the expanded k/v are never cached); ``wks`` / ``wvs`` the
     window layers', for ``insert_window`` (None for a kind without
-    them); ``stats`` the expert counters folded over the layers (None
-    without an expert FFN).
+    them); ``iks [pool_layers, s, width]`` the index keys of a kind that
+    selects (None without an indexer); ``stats`` the expert counters
+    folded over the layers, with the selection's for a kind that selects
+    (None without either).
 
     With ``length`` (the real prompt length inside a bucket-padded
     ``s``, traced OK) the lm head runs on ONLY the last real position —
@@ -984,9 +1131,11 @@ def prefill_forward(kind: str, cfg, params, tokens, length=None, *,
             + jnp.arange(s, dtype=jnp.int32),
             jnp.int32(cache.max_seq - 1))
     h = rec.embed(p, tokens, positions, tp).transpose(1, 0, 2)  # [s, b, h]
+    tables = rec.rope(cfg, dims, positions, cache.max_seq if suffix else s)
     rope = {t: tuple(c[:, None, None, :] for c in cs)       # [s, 1, 1, r]
-            for t, cs in rec.rope(cfg, dims, positions,
-                                  cache.max_seq if suffix else s).items()}
+            for t, cs in tables.items() if t != INDEX}
+    # the indexer's table, against its one key a position: [s, 1, width]
+    irope = tuple(c[:, None, :] for c in tables.get(INDEX, ()))
     # the rows that carry a token, for a kind whose FFN routes (it is the
     # one that reports stats): bucket padding goes to no expert
     valid = None if length is None or not rec.stats else (
@@ -994,7 +1143,7 @@ def prefill_forward(kind: str, cfg, params, tokens, length=None, *,
 
     # a latent kind's softmax scale is its own; None is the head size's
     scale = rec.latent.scale(cfg) if rec.latent else None
-    ks, vs, wks, wvs, stats = [], [], [], [], None
+    ks, vs, wks, wvs, iks, stats, picks = [], [], [], [], [], None, None
     for i, (pooled, n) in enumerate(_cache_layers(dims["layer_types"])):
         lp = p[f"layer_{i}"]
         hn = rec.norm(cfg, lp, "input", h)
@@ -1014,6 +1163,21 @@ def prefill_forward(kind: str, cfg, params, tokens, length=None, *,
             (vs if pooled else wvs).append(v[0])
         if suffix:
             ctx = _suffix_attend(cache, n, row, q, k, v, prefill_from)
+        elif rec.select:
+            # each row attends the positions of largest index score among
+            # its causal ones, a block of rows at a time; the index keys
+            # are cached with k and v
+            qi, wi, ki = rec.select.index(cfg, lp, hn, *irope)
+            iks.append(ki[:, 0])                            # [s, width]
+            heads, topk = q.shape[1], rec.select.topk(cfg)
+            ctx, picked = select_attention(
+                q, _expand_kv(k, heads), _expand_kv(v, heads), qi[:, 0],
+                wi[:, 0], ki[:, 0], topk=topk,
+                block_q=rec.select.block(cfg), sm_scale=scale)
+            rows = jnp.arange(s, dtype=jnp.int32)
+            picks = _select_stats(
+                picks, rows >= 0 if length is None else rows < length,
+                rows + 1, picked, topk)
         else:
             heads = q.shape[1]
             ctx = flash_attention(
@@ -1030,8 +1194,10 @@ def prefill_forward(kind: str, cfg, params, tokens, length=None, *,
     if length is not None:      # the slab's own index of the last real row
         h = _last_row(h, length - prefill_from if suffix else length)
     logits = _gather_logits(rec.head(p, h, tp), tp)
+    if picks:
+        stats = {**(stats or {}), **picks}
     return (logits, *(jnp.stack(x) if x else None
-                      for x in (ks, vs, wks, wvs)), stats)
+                      for x in (ks, vs, wks, wvs, iks)), stats)
 
 
 def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
@@ -1062,7 +1228,8 @@ def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
     positions = cache.lengths                               # [slots]
     h = rec.embed(p, tokens, positions, tp)                 # [slots, hid]
     flat = rec.rope(cfg, dims, positions, cache.max_seq)    # [slots, r]
-    rope = {t: tuple(c[:, None, :] for c in cs) for t, cs in flat.items()}
+    rope = {t: tuple(c[:, None, :] for c in cs) for t, cs in flat.items()
+            if t != INDEX}
     live = positions + 1                    # incl. the token written now
     # the live (slot, page) pairs every pool layer's kernel walks: the
     # table and the lengths are the step's, so the list is built ONCE
@@ -1071,7 +1238,7 @@ def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
         work = paged_work_list(cache.page_table, live,
                                page_size=cache.page_size)
     cos, sin = flat.get(FULL, (None, None))     # the kernel's: unshaped
-    stats = None
+    stats, picks = None, None
     for i, (pooled, n) in enumerate(_cache_layers(dims["layer_types"])):
         if fused is not None:
             out, k_tok, v_tok = fused_block_decode(
@@ -1099,7 +1266,28 @@ def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
         else:
             q, k_tok, v_tok, extra = rec.project(cfg, dims, i, lp, hn,
                                                  lrope)
-            if pooled:
+            if rec.select:
+                # the token's index key is cached with its k and v, so it
+                # is a candidate like any other; then index scores of the
+                # live positions, the picked set of each slot, attention
+                # over the picked rows only
+                qi, wi, ki = rec.select.index(cfg, lp, hn, *flat[INDEX])
+                cache = kv_cache.append_layer(cache, n, k_tok, v_tok, ki)
+                topk = rec.select.topk(cfg)
+                with jax.named_scope("apex_dsa_index"):
+                    scores = paged_index_scores(qi, wi, cache.ik, work,
+                                                layer=n)
+                with jax.named_scope("apex_dsa_select"):
+                    cols = jnp.arange(cache.max_seq, dtype=jnp.int32)
+                    picked = select_top_mask(scores, topk,
+                                             cols[None] < live[:, None])
+                with jax.named_scope("apex_dsa_attend"):
+                    ctx = paged_select_attention(q, cache.k, cache.v,
+                                                 picked, work, layer=n)
+                picks = _select_stats(
+                    picks, live > 0 if active is None else active, live,
+                    jnp.sum(picked, axis=1, dtype=jnp.int32), topk)
+            elif pooled:
                 cache = kv_cache.append_layer(cache, n, k_tok, v_tok)
                 # grouped-query scoring straight off the per-kv-head pool
                 ctx = _cache_attend(cache, n, q, live, work)
@@ -1115,6 +1303,8 @@ def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
         h = x + y
 
     h = rec.norm(cfg, p, "final", h)
+    if picks:
+        stats = {**(stats or {}), **picks}
     return _gather_logits(rec.head(p, h, tp), tp), cache, stats
 
 

@@ -695,14 +695,16 @@ class SlotScheduler:
         dispatch, was measured and shortened nothing): under
         ``jax.transfer_guard_device_to_host("disallow")`` a pass runs
         clean.  Returns ``(tokens, flags)`` (``step_vector.peel_step``); the
-        counters of a kind with an expert FFN (``engine.stats_tail``
-        int32 values, ISSUE 30) go to the telemetry."""
+        counters of a kind that reports any (``engine.stats_names``, the
+        record's ``stats``: the expert FFN's, ISSUE 30, the selection's,
+        ISSUE 36) go to the telemetry BY NAME."""
         with trace_annotation("apex_tpu.scheduler.token_read"):
             host = np.asarray(jax.device_get(host)).reshape(-1)
-        toks, flags, tail = peel_step(
-            host, tokens, getattr(self.engine, "stats_tail", 0))
-        if tail.size:
-            self.telemetry.expert_pass(phase, *(int(v) for v in tail))
+        names = getattr(self.engine, "stats_names", ())
+        toks, flags, tail = peel_step(host, tokens, len(names))
+        if names:
+            self.telemetry.step_counters(
+                phase, {n: int(v) for n, v in zip(names, tail)})
         return toks, flags
 
     def _prefill_piece(self, slot: int) -> None:

@@ -199,6 +199,22 @@ METRIC_SPECS: Dict[str, MetricSpec] = {s.name: s for s in [
                "still attend (never above slots x ring pages)"),
     MetricSpec("serve_window_pages_live_peak", "gauge",
                "the largest serve_window_pages_live any step reported"),
+    # -- learned sparse selection (ISSUE 36): a kind whose layers pick
+    #    the cached positions they attend by a learned index counts, on
+    #    the device and behind the expert counters of the same read
+    MetricSpec("serve_dsa_rows_total", "counter",
+               "query rows that went through the indexer, summed over "
+               "the selecting layers (a prefill: prompt tokens x layers; "
+               "a decode step: active slots x layers)", labels=("phase",)),
+    MetricSpec("serve_dsa_rows_sparse_total", "counter",
+               "those of serve_dsa_rows_total whose context held more "
+               "positions than a query may attend, so that the selection "
+               "cut something", labels=("phase",)),
+    MetricSpec("serve_dsa_selected_total", "counter",
+               "positions attended, summed over the rows of "
+               "serve_dsa_rows_total: over the positions those rows could "
+               "have attended it is the share of the cache a step really "
+               "read", labels=("phase",)),
     # -- speculative decoding (ISSUE 15): the verify step's accept/
     #    reject accounting.  Drafted counts what the verify executable
     #    SCORED (k per active slot per round, padding drafts

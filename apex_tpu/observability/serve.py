@@ -36,7 +36,15 @@ from apex_tpu.observability.timers import StepTimer
 
 __all__ = ["ServeTelemetry", "FleetTelemetry", "SPEC_METRIC_FAMILIES",
            "TIER_METRIC_FAMILIES", "FLEET_METRIC_FAMILIES",
-           "EXPERT_METRIC_FAMILIES"]
+           "EXPERT_METRIC_FAMILIES", "SELECT_METRIC_FAMILIES"]
+
+#: the ISSUE 36 families of a kind that SELECTS the positions it attends
+#: (same schema-guard contract as the expert families)
+SELECT_METRIC_FAMILIES = (
+    "serve_dsa_rows_total",
+    "serve_dsa_rows_sparse_total",
+    "serve_dsa_selected_total",
+)
 
 #: the ISSUE 30 expert-FFN / window-ring families (same schema-guard
 #: contract as SPEC/TIER_METRIC_FAMILIES)
@@ -251,6 +259,10 @@ class ServeTelemetry:
         self.moe_expert_load_max = d("serve_moe_expert_load_max_total")
         self.window_pages_live = d("serve_window_pages_live")
         self.window_pages_live_peak = d("serve_window_pages_live_peak")
+        # learned sparse selection (ISSUE 36): counted the same way
+        self.dsa_rows = d("serve_dsa_rows_total")
+        self.dsa_rows_sparse = d("serve_dsa_rows_sparse_total")
+        self.dsa_selected = d("serve_dsa_selected_total")
         # request tracing (ISSUE 13): spans ride the SAME host
         # boundaries the methods below already occupy — arming the
         # tracer (trace= or APEX_TPU_TRACE) adds zero device work
@@ -522,16 +534,22 @@ class ServeTelemetry:
     def backpressured(self) -> None:
         self.backpressure_waits.inc()
 
-    def expert_pass(self, phase: str, assignments: int, experts_hit: int,
-                    load_max: int, window_pages: int) -> None:
-        """One step's device-side counters (``models.EXPERT_STATS``
-        order), ``phase`` ``"prefill"`` or ``"decode"``."""
-        self.moe_passes.inc(phase=phase)
-        self.moe_assignments.inc(assignments, phase=phase)
-        self.moe_experts_hit.inc(experts_hit, phase=phase)
-        self.moe_expert_load_max.inc(load_max, phase=phase)
-        self.window_pages_live.set(window_pages)
-        self.window_pages_live_peak.set_max(window_pages)
+    def step_counters(self, phase: str, counters) -> None:
+        """One step's device-side counters, BY NAME (a kind's record names
+        what its steps report: ``models.EXPERT_STATS``, and
+        ``models.SELECT_STATS`` for a kind that selects the positions it
+        attends); ``phase`` ``"prefill"`` or ``"decode"``.  A name the
+        telemetry has no family for raises: a counter is never dropped
+        in silence."""
+        counters = dict(counters)
+        if "moe_assignments" in counters:
+            self.moe_passes.inc(phase=phase)
+        if "window_pages_live" in counters:
+            pages = counters.pop("window_pages_live")
+            self.window_pages_live.set(pages)
+            self.window_pages_live_peak.set_max(pages)
+        for name, value in counters.items():
+            getattr(self, name).inc(value, phase=phase)
 
     def request_finished(self, uid: int, reason: str,
                          n_tokens: int) -> None:

@@ -62,7 +62,8 @@ from apex_tpu.utils import cdiv, interpret_mode
 
 __all__ = ["flash_attention", "mha_reference", "decode_attention",
            "ring_decode_attention", "prefix_window_attention",
-           "slab_decode_attention"]
+           "slab_decode_attention", "index_scores",
+           "index_scores_reference", "select_top_mask", "select_attention"]
 
 #: pallas_audit registration (analysis hook only, no behavior change):
 #: every attention kernel carries online-softmax (m/l/acc) or wgrad
@@ -72,6 +73,7 @@ PALLAS_AUDIT = {
     "_dq_kernel": {"reduction": True},
     "_dkv_kernel": {"reduction": True},
     "_bwd_fused_kernel": {"reduction": True},
+    "_index_kernel": {},
 }
 
 _NEG_INF = -1e30          # finite "masked" score: keeps exp()/where() NaN-free
@@ -984,6 +986,162 @@ def flash_attention(q, k, v, *, causal: bool = False, mask=None,
     if padded:
         out = out[:, :sq, :]
     return out.reshape(b, h, sq, v_width)
+
+
+# --------------------------------------------------------------------------
+# learned sparse selection (ISSUE 36): index scores, the picked set of a
+# row, and prefill attention over the picked sets
+# --------------------------------------------------------------------------
+
+def index_scores_reference(qi, wi, ki):
+    """Pure-jnp oracle of the index scores: ``qi [rows, heads, di]``,
+    ``wi [rows, heads]``, ``ki [keys, di]`` -> ``I [rows, keys]`` float32,
+    ``I[t, s] = sum_j wi[t, j] * relu(qi[t, j] . ki[s])``."""
+    dots = jnp.einsum("thd,sd->ths", qi.astype(jnp.float32),
+                      ki.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+    return jnp.sum(wi.astype(jnp.float32)[..., None]
+                   * jnp.maximum(dots, 0.0), axis=1)
+
+
+def _index_kernel(heads, qi_ref, wi_ref, ki_ref, o_ref):
+    # qi [heads, bq, di], wi [bq, heads] f32, ki [bk, di] -> o [bq, bk]:
+    # one small product a head, rectified and weighted in float32
+    ki = ki_ref[...]
+    wi = wi_ref[...]
+    acc = jnp.zeros(o_ref.shape, jnp.float32)
+    for j in range(heads):
+        dots = jax.lax.dot_general(qi_ref[j], ki, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+        acc = acc + wi[:, j:j + 1] * jnp.maximum(dots, 0.0)
+    o_ref[...] = acc
+
+
+def index_scores(qi, wi, ki, *, block_k: int = 512):
+    """The index scores of a block of query rows against the keys (module
+    section note; :func:`index_scores_reference` is the oracle): ``qi
+    [rows, heads, di]``, ``wi [rows, heads]`` float32, ``ki [keys, di]``
+    -> ``[rows, keys]`` float32.  The Pallas kernel ``apex_dsa_index_fwd``
+    (grid over key blocks): the per-head products never leave VMEM."""
+    rows, heads, di = qi.shape
+    keys = ki.shape[0]
+    bk, keys_pad = _plan_block(keys, block_k)
+    if keys_pad != keys:
+        ki = jnp.pad(ki, ((0, keys_pad - keys), (0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_index_kernel, heads),
+        grid=(keys_pad // bk,),
+        in_specs=[
+            pl.BlockSpec((heads, rows, di), lambda j: (0, 0, 0)),
+            pl.BlockSpec((rows, heads), lambda j: (0, 0)),
+            pl.BlockSpec((bk, di), lambda j: (j, 0)),
+        ],
+        out_specs=pl.BlockSpec((rows, bk), lambda j: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((rows, keys_pad), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret_mode(),
+        name="apex_dsa_index_fwd",
+    )(jnp.swapaxes(qi, 0, 1), wi.astype(jnp.float32), ki)
+    return out[:, :keys] if keys_pad != keys else out
+
+
+def select_top_mask(scores, k: int, live):
+    """The picked set of every row: ``scores [rows, n]`` float32, ``live
+    [rows, n]`` bool (the candidates) -> bool ``[rows, n]`` with, in each
+    row, the ``min(k, candidates)`` candidates of largest score set; equal
+    scores go to the lower position (``jax.lax.top_k``'s order).  EXACT:
+    the k-th largest score is found bit by bit on an order-preserving
+    integer image of the float (32 compare-and-count passes over the
+    row), never by a sort of the row or a sampled threshold."""
+    bits = jax.lax.bitcast_convert_type(scores.astype(jnp.float32),
+                                        jnp.uint32)
+    top = jnp.uint32(1 << 31)
+    # larger float <-> larger unsigned image; no candidate's image is 0
+    image = jnp.where(bits >= top, ~bits, bits | top)
+    image = jnp.where(live, image, jnp.uint32(0))
+    want = jnp.minimum(jnp.sum(live, axis=1, dtype=jnp.int32), k)
+
+    def count(mask):
+        return jnp.sum(mask, axis=1, dtype=jnp.int32)
+
+    def bit(i, kth):
+        cand = kth | (top >> i.astype(jnp.uint32))
+        return jnp.where(count(image >= cand[:, None]) >= want, cand, kth)
+
+    kth = jax.lax.fori_loop(0, 32, bit,
+                            jnp.zeros((scores.shape[0],), jnp.uint32))
+    above = image > kth[:, None]
+    ties = (image == kth[:, None]) & live
+    room = want - count(above)          # ties that still fit, per row
+
+    def lower_ties(ties):
+        return ties & (jnp.cumsum(ties, axis=1, dtype=jnp.int32)
+                       <= room[:, None])
+
+    # only a row with more ties than room has to rank them by position
+    ties = jax.lax.cond(jnp.any(count(ties) > room), lower_ties,
+                        lambda t: t, ties)
+    return above | ties
+
+
+def select_attention(q, k, v, qi, wi, ki, *, topk: int, block_q: int = 512,
+                     sm_scale: Optional[float] = None):
+    """Causal attention of ONE sequence in which query ``t`` attends the
+    ``min(topk, t + 1)`` causal positions of largest index score and no
+    other (the others get no probability mass — not a bias).
+
+    ``q``/``k``/``v``: ``[1, h, s, d]`` (k/v per query head); ``qi [s,
+    heads, di]``, ``wi [s, heads]``, ``ki [s, di]`` the indexer's.
+    Returns ``(ctx [1, h, s, d], picked [s] int32)``, ``picked`` the
+    positions each row attended.
+
+    Rows under ``topk`` are plain causal rows (one flash call over the
+    first ``topk`` keys).  The others go ``block_q`` rows at a time: index
+    scores against the causal keys (``apex_dsa_index_fwd``), the picked
+    set (:func:`select_top_mask`), the flash kernel under that mask — so
+    no ``[s, s]`` array outlives a block.  Blocks are grouped (at most
+    eight groups) so that a block scores and attends the keys up to its
+    GROUP's last row, not the sequence's."""
+    b, h, s, d = q.shape
+    if b != 1:
+        raise ValueError(f"select_attention takes one sequence, got "
+                         f"batch {b}")
+    with jax.named_scope("apex_dsa_attend"):
+        head = flash_attention(q[:, :, :topk], k[:, :, :topk],
+                               v[:, :, :topk], causal=True,
+                               sm_scale=sm_scale)
+    if s <= topk:
+        return head, jnp.arange(1, s + 1, dtype=jnp.int32)
+    bq = int(np.gcd(block_q, s - topk))
+    group = max(bq, cdiv(cdiv(s - topk, 8), bq) * bq)
+    ctxs, counts = [head], [jnp.arange(1, topk + 1, dtype=jnp.int32)]
+    for r0 in range(topk, s, group):
+        r1 = min(r0 + group, s)         # the group's rows; its keys [0, r1)
+        kg, vg, kig = k[:, :, :r1], v[:, :, :r1], ki[:r1]
+        cols = jnp.arange(r1, dtype=jnp.int32)
+
+        def block(t0, kg=kg, vg=vg, kig=kig, cols=cols):
+            rows = t0 + jnp.arange(bq, dtype=jnp.int32)
+            with jax.named_scope("apex_dsa_index"):
+                scores = index_scores(
+                    jax.lax.dynamic_slice_in_dim(qi, t0, bq),
+                    jax.lax.dynamic_slice_in_dim(wi, t0, bq), kig)
+            with jax.named_scope("apex_dsa_select"):
+                picked = select_top_mask(scores, topk,
+                                         cols[None, :] <= rows[:, None])
+            with jax.named_scope("apex_dsa_attend"):
+                ctx = flash_attention(
+                    jax.lax.dynamic_slice_in_dim(q, t0, bq, axis=2), kg, vg,
+                    mask=~picked[None, None], sm_scale=sm_scale,
+                    use_kernel=True)
+            return ctx[0], jnp.sum(picked, axis=1, dtype=jnp.int32)
+
+        ctx, n = jax.lax.map(block, jnp.arange(r0, r1, bq, dtype=jnp.int32))
+        # [blocks, h, bq, d] -> [1, h, rows, d]
+        ctxs.append(jnp.moveaxis(ctx, 0, 1).reshape(1, h, r1 - r0, d))
+        counts.append(n.reshape(-1))
+    return jnp.concatenate(ctxs, axis=2), jnp.concatenate(counts)
 
 
 # --------------------------------------------------------------------------

@@ -54,6 +54,30 @@ axis of both operands, the form q k^T has in the other kernel.  Same scalar-pref
 softmax, its own ``pallas_call`` name so a trace tells the forms apart;
 the per-head-K/V calls compile what they always did.
 
+A kind whose layers SELECT the positions they attend by a learned index
+(ISSUE 36) runs a decode layer in three stages over the same work list,
+select-then-attend:
+
+    ik_pool : [pages, layers, index_width, page_size]   (beside k and v)
+
+*index* — :func:`paged_index_scores`, the kernel ``apex_dsa_index``: each
+slot's few index queries against the index keys of its live pages (one
+``[index_width, page_size]`` block an item: 1/16 of the page's K/V
+bytes), rectified, weighted and summed over the index heads, one
+``[page_size]`` stretch of float32 scores an item; *select* — the
+``topk`` best positions of each slot, exactly, on the device
+(:func:`apex_tpu.ops.attention.select_top_mask`: XLA, no kernel);
+*attend* — :func:`paged_select_attention`, the kernel
+``apex_dsa_attend``: ``apex_paged_decode``'s own body and walk with the
+picked positions of each page as one more block (an operand the other
+callers do not trace), so that an unpicked position gets no probability
+mass and a slot that picked every live position gets
+``apex_paged_decode``'s answer bit for bit.  The mathematics is the
+sparse one; the walk still reads every live page (gathering the picked
+rows costs more than it saves at this pool's layout: PERF.md section 6,
+PR 36).  No stage makes a pool-sized or ``[slots, max_seq, heads,
+d]``-sized array.
+
 The speculative verify slab (:func:`paged_slab_attention`) still
 gathers the slot windows and scores them with the dense XLA chain.
 """
@@ -73,7 +97,8 @@ from apex_tpu.ops.attention import (_LOG2E, _NEG_INF,
 from apex_tpu.utils import interpret_mode
 
 __all__ = ["paged_decode_attention", "paged_work_list", "PagedWork",
-           "paged_slab_attention",
+           "paged_slab_attention", "paged_index_scores",
+           "paged_select_attention",
            "fused_block_decode", "decode_fusion", "fusion_min_pages",
            "resolve_decode_fusion",
            "fused_block_vmem_bytes", "fused_block_refusal",
@@ -88,6 +113,7 @@ __all__ = ["paged_decode_attention", "paged_work_list", "PagedWork",
 PALLAS_AUDIT = {
     "_paged_kernel": {"reduction": True, "masked_tail": True},
     "_latent_kernel": {"reduction": True, "masked_tail": True},
+    "_index_kernel": {"masked_tail": True},
     "_fused_block_kernel": {"reduction": True, "masked_tail": True},
 }
 
@@ -137,10 +163,14 @@ def paged_work_list(page_table, lengths, *, page_size: int) -> PagedWork:
 # Pallas kernel: grid (items,), the work list as scalar prefetch
 # --------------------------------------------------------------------------
 
-def _paged_kernel(scale, kvh, group, ps,
+def _paged_kernel(scale, kvh, group, ps, picks,
                   slot_ref, page_ref, start_ref, len_ref, layer_ref,
-                  q_ref, k_ref, v_ref, o_ref,
-                  s_scr, m_scr, l_scr, acc_scr):
+                  q_ref, *refs):
+    # ``picks`` (static): one more operand stands before k and v, which of
+    # the page's positions the slot PICKED (ISSUE 36).  Without it nothing
+    # of it is traced: the kernel is the one it always was
+    pick_ref = refs[0] if picks else None
+    k_ref, v_ref, o_ref, s_scr, m_scr, l_scr, acc_scr = refs[picks:]
     # blocks of the whole pool: [1, 1, kvh, ps, d]
     k_ref, v_ref = k_ref.at[0], v_ref.at[0]
     item = pl.program_id(0)
@@ -168,7 +198,10 @@ def _paged_kernel(scale, kvh, group, ps,
                 q[seg], k_ref[0, i], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * (scale * _LOG2E)
         cols = p * ps + jax.lax.broadcasted_iota(jnp.int32, (h, ps), 1)
-        s = jnp.where(cols < length, s_scr[...], _NEG_INF)
+        keep = cols < length
+        if picks:       # an unpicked position gets no probability mass
+            keep = keep & (pick_ref[0, 0] > 0.0)
+        s = jnp.where(keep, s_scr[...], _NEG_INF)
         # online softmax, base-2 log domain (scale absorbed log2e):
         # within a live page every row has >= 1 live column, so no
         # fully-masked-row guard is needed here (length-0 slots never
@@ -177,6 +210,10 @@ def _paged_kernel(scale, kvh, group, ps,
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp2(m_prev - m_new)
         pmat = jnp.exp2(s - m_new)
+        if picks:
+            # a page may hold no picked position at all: _NEG_INF is
+            # finite, so its columns would each weigh exp2(0) = 1
+            pmat = jnp.where(keep, pmat, 0.0)
         l_scr[...] = l_scr[...] * alpha + \
             jnp.sum(pmat, axis=1, keepdims=True)
         for i in range(kvh):
@@ -194,12 +231,15 @@ def _paged_kernel(scale, kvh, group, ps,
 
 
 @functools.partial(jax.jit, static_argnames=("scale",))
-def _paged_kernel_call(q, k_pool, v_pool, work, layer, *, scale):
+def _paged_kernel_call(q, k_pool, v_pool, work, layer, picked=None, *,
+                       scale):
     # ``layer`` is a TRACED int32 [1], a scalar-prefetch operand like the
     # work list: every layer of a decode step is then the same jitted
     # call, traced and lowered to Mosaic ONCE (a static layer in the
     # index map makes each layer a kernel of its own: 24 lowerings,
-    # seconds of every process's start, compile cache or not)
+    # seconds of every process's start, compile cache or not).
+    # ``picked [slots, max_seq]`` (bool), where given, is one more block
+    # an item — the page's picked positions — and the kernel's other name
     slots, h, d = q.shape
     kvh, ps = k_pool.shape[2], k_pool.shape[3]
     group = h // kvh
@@ -207,17 +247,24 @@ def _paged_kernel_call(q, k_pool, v_pool, work, layer, *, scale):
     def slot_index(i, slot, page, start, ln, ly):
         return (slot[i], 0, 0)
 
+    def pick_index(i, slot, page, start, ln, ly):
+        return (slot[i], i - start[slot[i]], 0, 0)
+
     def page_index(i, slot, page, start, ln, ly):
         return (page[i], ly[0], 0, 0, 0)
 
     page_block = (1, 1, kvh, ps, d)
     slot_block = pl.BlockSpec((1, h, d), slot_index)
+    name = "apex_paged_decode" if picked is None else "apex_dsa_attend"
+    picks = () if picked is None else (picked.astype(jnp.float32).reshape(
+        slots, picked.shape[1] // ps, 1, ps),)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(work.start[-1],),             # traced: the live items only
         in_specs=[
             slot_block,
+            *[pl.BlockSpec((1, 1, 1, ps), pick_index) for _ in picks],
             pl.BlockSpec(page_block, page_index),
             pl.BlockSpec(page_block, page_index),
         ],
@@ -229,7 +276,8 @@ def _paged_kernel_call(q, k_pool, v_pool, work, layer, *, scale):
             pltpu.VMEM((h, d), jnp.float32),      # fp32 output accum
         ],
     )
-    kernel = functools.partial(_paged_kernel, scale, kvh, group, ps)
+    kernel = functools.partial(_paged_kernel, scale, kvh, group, ps,
+                               len(picks))
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -237,9 +285,9 @@ def _paged_kernel_call(q, k_pool, v_pool, work, layer, *, scale):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret_mode(),
-        name="apex_paged_decode",
+        name=name,
     )(work.slot, work.page, work.start, work.lengths, layer,
-      q, k_pool, v_pool)
+      q, *picks, k_pool, v_pool)
 
 
 # --------------------------------------------------------------------------
@@ -328,6 +376,137 @@ def _latent_kernel_call(q, pool, work, layer, *, scale, values):
         interpret=interpret_mode(),
         name="apex_paged_decode_latent",
     )(work.slot, work.page, work.start, work.lengths, layer, q, pool)
+
+
+# --------------------------------------------------------------------------
+# learned sparse selection (ISSUE 36): index scores of the live positions,
+# then attention over the PICKED positions only
+# --------------------------------------------------------------------------
+
+def _index_kernel(ps, slot_ref, page_ref, start_ref, len_ref, layer_ref,
+                  qi_ref, wi_ref, ik_ref, o_ref):
+    # one item: the slot's index queries [hi, di] against a page of index
+    # keys [di, ps]; rectified, weighted and summed over the index heads
+    item = pl.program_id(0)
+    sid = slot_ref[item]
+    p = item - start_ref[sid]
+    dots = jax.lax.dot(qi_ref[0], ik_ref[0, 0],
+                       preferred_element_type=jnp.float32)      # [hi, ps]
+    score = jnp.sum(wi_ref[0] * jnp.maximum(dots, 0.0), axis=0,
+                    keepdims=True)                              # [1, ps]
+    cols = p * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+    o_ref[0, 0] = jnp.where(cols < len_ref[sid], score, _NEG_INF)
+
+
+@jax.jit
+def _index_kernel_call(qi, wi, ik_pool, work, layer):
+    slots, hi, di = qi.shape
+    ps = ik_pool.shape[3]
+    mpps = work.slot.shape[0] // slots
+
+    def slot_index(i, slot, page, start, ln, ly):
+        return (slot[i], 0, 0)
+
+    def page_index(i, slot, page, start, ln, ly):
+        return (page[i], ly[0], 0, 0)
+
+    def out_index(i, slot, page, start, ln, ly):
+        return (slot[i], i - start[slot[i]], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(work.start[-1],),             # traced: the live items only
+        in_specs=[
+            pl.BlockSpec((1, hi, di), slot_index),
+            pl.BlockSpec((1, hi, 1), slot_index),
+            pl.BlockSpec((1, 1, di, ps), page_index),
+        ],
+        out_specs=pl.BlockSpec((1, 1, 1, ps), out_index),
+    )
+    out = pl.pallas_call(
+        functools.partial(_index_kernel, ps),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((slots, mpps, 1, ps), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret_mode(),
+        name="apex_dsa_index",
+    )(work.slot, work.page, work.start, work.lengths, layer,
+      qi, wi.astype(jnp.float32)[..., None], ik_pool)
+    # a page no item visited was never written: every position at or past
+    # a slot's length reads "masked", whatever the buffer held
+    out = out.reshape(slots, mpps * ps)
+    dead = jnp.arange(mpps * ps, dtype=jnp.int32)[None] \
+        >= work.lengths[:, None]
+    return jnp.where(dead, _NEG_INF, out)
+
+
+def paged_index_scores(qi, wi, ik_pool, work: PagedWork, *, layer: int):
+    """Index scores of every slot's live positions (ISSUE 36, the first of
+    the three stages of a decode step that SELECTS): ``qi [slots, heads,
+    di]`` the index queries, ``wi [slots, heads]`` their weights, ``ik_pool
+    [pages, layers, di, page_size]`` the WHOLE index-key pool (a page's
+    positions on the minor axis), ``work`` the step's
+    :func:`paged_work_list`.  Returns ``[slots, max_seq]`` float32:
+    ``sum_j wi[j] * relu(qi[j] . ik[s])`` at a live position ``s``, a large
+    negative number at every other.
+
+    The Pallas kernel ``apex_dsa_index`` walks the work list as the
+    attention kernels do — one ``[di, page_size]`` block an item, 1/16 of
+    the K/V bytes of the page — and writes one ``[page_size]`` stretch of
+    scores an item."""
+    slots, hi, di = qi.shape
+    if ik_pool.ndim != 4 or ik_pool.shape[2] != di or wi.shape != (slots,
+                                                                   hi):
+        raise ValueError(
+            f"index queries [slots, heads, {di}] and weights [slots, "
+            f"heads] score the pool [pages, layers, {di}, page_size]; got "
+            f"qi {tuple(qi.shape)} wi {tuple(wi.shape)} pool "
+            f"{tuple(ik_pool.shape)}")
+    if not 0 <= layer < ik_pool.shape[1]:
+        raise ValueError(f"layer {layer} is outside the pool's "
+                         f"{ik_pool.shape[1]} layers")
+    return _index_kernel_call(qi, wi, ik_pool, work,
+                              jnp.full((1,), layer, jnp.int32))
+
+
+def paged_select_attention(q, k_pool, v_pool, picked, work: PagedWork, *,
+                           layer: int, sm_scale: Optional[float] = None):
+    """Single-token attention over the PICKED positions of ONE layer of a
+    paged K/V pool (ISSUE 36, the last of the three stages): ``softmax(q .
+    k) . v`` over the rows ``picked [slots, max_seq]`` (bool) names, found
+    through the page table at token granularity; a live position that was
+    not picked gets no probability mass.  ``q [slots, h, d]``, the pools
+    WHOLE as :func:`paged_decode_attention` takes them, ``work`` the
+    step's :func:`paged_work_list`.
+
+    The Pallas kernel ``apex_dsa_attend`` IS ``apex_paged_decode``'s (one
+    body, ``_paged_kernel``, one call): its walk of the live pages with the
+    picked positions of each page as one more block, so a slot that picked
+    all its live positions (every slot whose context is at most the
+    selection's size) gets ``apex_paged_decode``'s answer.  Measured against gathering the picked rows into ``[slots,
+    picked, kv_heads, d]`` (PERF.md section 6, PR 36): a row of this pool
+    is ``kv_heads`` stretches of 256 B, and XLA's gather of them costs
+    more than the walk reads."""
+    slots, h, d = q.shape
+    if k_pool.shape != v_pool.shape or k_pool.ndim != 5 \
+            or k_pool.shape[4] != d or h % k_pool.shape[2]:
+        raise ValueError(
+            f"k/v must be the whole pool [pages, layers, kv_heads, "
+            f"page_size, {d}], equal-shaped, kv_heads dividing {h}; got k "
+            f"{tuple(k_pool.shape)} v {tuple(v_pool.shape)}")
+    ps = k_pool.shape[3]
+    if picked.ndim != 2 or picked.shape[0] != slots or picked.shape[1] % ps:
+        raise ValueError(
+            f"picked must be [{slots}, max_seq] with max_seq whole pages "
+            f"of {ps}, got {tuple(picked.shape)}")
+    if not 0 <= layer < k_pool.shape[1]:
+        raise ValueError(f"layer {layer} is outside the pool's "
+                         f"{k_pool.shape[1]} layers")
+    scale = (d ** -0.5) if sm_scale is None else sm_scale
+    return _paged_kernel_call(q, k_pool, v_pool, work,
+                              jnp.full((1,), layer, jnp.int32), picked,
+                              scale=float(scale))
 
 
 # --------------------------------------------------------------------------

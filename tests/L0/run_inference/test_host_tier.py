@@ -46,7 +46,7 @@ def _fill(c, seed=0):
 def test_extract_restore_roundtrip_moves_pages():
     c = _fill(_cache())
     k0, v0 = np.asarray(c.k), np.asarray(c.v)
-    ks, vs = kv_cache.extract_pages(c, jnp.asarray([4, 1], jnp.int32))
+    ks, vs, _ = kv_cache.extract_pages(c, jnp.asarray([4, 1], jnp.int32))
     np.testing.assert_array_equal(np.asarray(ks), k0[[4, 1]])
     np.testing.assert_array_equal(np.asarray(vs), v0[[4, 1]])
     # restore the slabs at DIFFERENT pages: content lands there bitwise
@@ -66,7 +66,7 @@ def test_extract_pads_with_trash_restore_drops_oob():
     direction can touch live data."""
     c = _fill(_cache())
     k0 = np.asarray(c.k)
-    ks, _ = kv_cache.extract_pages(
+    ks, _, _ = kv_cache.extract_pages(
         c, jnp.asarray([2, PAGES, PAGES], jnp.int32))   # trash-padded
     np.testing.assert_array_equal(np.asarray(ks)[0], k0[2])
     # restore with OOB sentinel ids: whole cache stays bitwise

@@ -235,6 +235,19 @@ WORDS = {
         "fused": "fused_block_decode is not built for the 'axk1' kind: "
                  "ops/paged_attention.py",
         "prefix_sharing": "chunked prefill, is not built for the 'axk1' "
+                          "kind: models._suffix_attend"},
+    # ISSUE 36: likewise
+    "keye": {
+        "dense": "serves from the paged cache only",
+        "tp": "tp > 1 is not built for the 'keye' kind: "
+              "models.param_partition_specs",
+        "verify": "speculative verify is not built for the 'keye' kind: "
+                  "ops/paged_attention.py",
+        "host_tier": "the host KV tier is not built for the 'keye' kind: "
+                     "kv_cache.HostPageStore",
+        "fused": "fused_block_decode is not built for the 'keye' kind: "
+                 "ops/paged_attention.py",
+        "prefix_sharing": "chunked prefill, is not built for the 'keye' "
                           "kind: models._suffix_attend"}}
 #: how each feature is asked of an engine at construction
 ASKED = {"dense": dict(page_size=None, num_pages=None), "tp": dict(tp=2),
@@ -247,10 +260,14 @@ ASKED = {"dense": dict(page_size=None, num_pages=None), "tp": dict(tp=2),
 def toys(tiny):
     """kind -> (config, params) at toy size; a kind new here: its toy."""
     from apex_tpu.transformer.testing import standalone_axk1 as SA
-    acfg = SA.AXK1Config()
+    from apex_tpu.transformer.testing import standalone_keye as SK
+    acfg, kcfg = SA.AXK1Config(), SK.KeyeConfig()
+    tokens = jnp.zeros((1, 8), jnp.int32)
     return {"laguna": tiny[:2],
             "axk1": (acfg, SA.axk1_model_provider(acfg).init(
-                jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))}
+                jax.random.PRNGKey(0), tokens)),
+            "keye": (kcfg, SK.keye_model_provider(kcfg).init(
+                jax.random.PRNGKey(0), tokens))}
 
 
 @pytest.mark.parametrize("kind,feature", REFUSED)

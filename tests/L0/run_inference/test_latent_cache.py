@@ -109,7 +109,7 @@ def test_extract_restore_and_cow_round_trip():
     x = rows(3 * PS, seed=5)
     c = kv_cache.insert_pages(c, 2, x, None, 3 * PS, row)
     ids = jnp.asarray([5, 8, PAGES], jnp.int32)            # + trash padding
-    k_slab, v_slab = kv_cache.extract_pages(c, ids)
+    k_slab, v_slab, _ = kv_cache.extract_pages(c, ids)
     assert v_slab is None and k_slab.shape == (3, LAYERS, WIDTH, PS)
     fresh = kv_cache.restore_pages(
         pool(), jnp.asarray([0, 4, PAGES + 1], jnp.int32), k_slab, None)

@@ -341,3 +341,112 @@ def test_latent_form_validates(case):
         pool = pool[:, :, None]
     with pytest.raises(ValueError, match="latent form"):
         paged_decode_attention(q, pool, None, pt, ln, **kw)
+
+
+# --------------------------------------------------------------------------
+# attention over PICKED positions only (ISSUE 36): apex_dsa_attend
+# --------------------------------------------------------------------------
+
+def _picked_case(case, lengths, max_seq, rng):
+    """Which positions each slot picked, ``[slots, max_seq]`` bool."""
+    picked = np.zeros((len(lengths), max_seq), bool)
+    for s, n in enumerate(lengths):
+        if not n:
+            continue
+        if case == "all":
+            picked[s, :n] = True
+        elif case == "one_row":
+            picked[s, rng.randint(n)] = True
+        elif case == "one_page":            # all picks inside page 1 (or 0)
+            lo = 8 if n > 8 else 0
+            picked[s, lo:min(n, lo + 8)] = True
+        elif case == "straddle":            # rows either side of a boundary
+            picked[s, max(0, min(n, 8) - 3):min(n, 8 + 3)] = True
+        elif case == "none":
+            pass
+        else:                               # scattered: about a third
+            picked[s, :n] = rng.rand(n) < 0.35
+            picked[s, n - 1] = True
+    return picked
+
+
+@pytest.mark.parametrize("case", ["scattered", "one_row", "one_page",
+                                  "straddle", "all", "none"])
+@pytest.mark.parametrize("h,kvh", [(8, 2), (4, 4)], ids=["gqa", "mha"])
+def test_select_attention_matches_the_picked_rows_gathered_by_hand(
+        case, h, kvh):
+    """Slots of 0, 1 and many pages in one call — an empty slot, one of a
+    single row, a straggler — against ``mha_reference`` over the picked
+    rows gathered by hand from the dense twin."""
+    from apex_tpu.ops.attention import mha_reference
+    from apex_tpu.ops.paged_attention import paged_select_attention
+    lengths = [32, 0, 1, 7, 9, 25]
+    q, k, v, pk, pv, pt, ln = _paged_twin(6, h, kvh, 8, 4, lengths, seed=4)
+    picked = _picked_case(case, lengths, 32, np.random.RandomState(5))
+    work = paged_work_list(pt, ln, page_size=8)
+    layer = 1
+    got = np.asarray(paged_select_attention(
+        q, pk, pv, jnp.asarray(picked), work, layer=layer))
+    group = h // kvh
+    for s in range(6):
+        rows = np.flatnonzero(picked[s])
+        if not len(rows):                   # nothing attended: zeros
+            assert not got[s].any()
+            continue
+        ks = np.repeat(np.asarray(k[layer, s])[:, rows], group, axis=0)
+        vs = np.repeat(np.asarray(v[layer, s])[:, rows], group, axis=0)
+        want = mha_reference(q[s][None, :, None, :], jnp.asarray(ks)[None],
+                             jnp.asarray(vs)[None])[0, :, 0]
+        np.testing.assert_allclose(got[s], np.asarray(want), atol=2e-5,
+                                   rtol=2e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+def test_a_slot_that_picked_everything_gets_the_dense_kernels_answer(dtype):
+    """Every slot whose context is at most the selection's size attends
+    all of it: ``apex_dsa_attend`` then gives ``apex_paged_decode``'s
+    answer bit for bit (the same walk, the same products)."""
+    from apex_tpu.ops.paged_attention import paged_select_attention
+    q, k, v, pk, pv, pt, ln = _paged_twin(6, 8, 2, 8, 4, RAGGED)
+    q, pk, pv = (t.astype(dtype) for t in (q, pk, pv))
+    live = jnp.arange(32)[None] < ln[:, None]
+    work = paged_work_list(pt, ln, page_size=8)
+    for layer in (0, LAYERS - 1):
+        want = paged_decode_attention(q, pk, pv, pt, ln, layer=layer,
+                                      work=work)
+        got = paged_select_attention(q, pk, pv, live, work, layer=layer)
+        assert np.array_equal(np.asarray(got, np.float32),
+                              np.asarray(want, np.float32))
+        # picks beyond a slot's length are dead whatever they say
+        got = paged_select_attention(q, pk, pv, jnp.ones_like(live), work,
+                                     layer=layer)
+        assert np.array_equal(np.asarray(got, np.float32),
+                              np.asarray(want, np.float32))
+
+
+def test_the_selecting_stages_are_named_calls_on_the_same_work_list():
+    from apex_tpu.ops.paged_attention import (paged_index_scores,
+                                              paged_select_attention)
+    q, k, v, pk, pv, pt, ln = _paged_twin(6, 8, 2, 8, 4, RAGGED)
+    ik = jnp.zeros((pk.shape[0], LAYERS, 4, 8), jnp.float32)
+    work = paged_work_list(pt, ln, page_size=8)
+
+    def step(q, pk, pv, ik, work):
+        scores = paged_index_scores(q[:, :2, :4], jnp.ones((6, 2)), ik,
+                                    work, layer=1)
+        return paged_select_attention(q, pk, pv, scores > -1.0, work,
+                                      layer=1)
+    text = str(jax.make_jaxpr(step)(q, pk, pv, ik, work))
+    assert text.count("name=apex_dsa_index") == 1
+    assert text.count("name=apex_dsa_attend") == 1
+    assert "name=apex_paged_decode" not in text
+    with pytest.raises(ValueError, match="whole pool"):
+        paged_select_attention(q, pk[:, 0], pv[:, 0],
+                               jnp.ones((6, 32), bool), work, layer=0)
+    with pytest.raises(ValueError, match="whole pages"):
+        paged_select_attention(q, pk, pv, jnp.ones((6, 30), bool), work,
+                               layer=0)
+    with pytest.raises(ValueError, match="outside the pool"):
+        paged_index_scores(q[:, :2, :4], jnp.ones((6, 2)), ik, work,
+                           layer=LAYERS)

@@ -9,6 +9,7 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from apex_tpu.analysis.pallas_audit import kernel_specs
@@ -66,7 +67,7 @@ _LN = (_s((128, 256), BF16), _s((256,), F32), _s((256,), F32))
 _QKV = (_s((1, 2, 256, 64), BF16),) * 3
 _QKV_LONG = (_s((1, 1, 8192, 128), BF16),) * 3     # past the fused backward
 
-#: name -> () -> (function, abstract arguments): one way to each of the 17
+#: name -> () -> (function, abstract arguments): one way to each of the 20
 #: ``pallas_call`` sites under ``apex_tpu/ops``
 KERNELS = {
     "apex_amp_unscale": lambda: (_unscale, (_flat(),)),
@@ -96,6 +97,9 @@ KERNELS = {
     "apex_paged_decode": lambda: _registered("paged_decode_attention"),
     "apex_paged_decode_latent": lambda: _registered("paged_decode_latent"),
     "apex_fused_block_decode": lambda: _registered("fused_block_decode"),
+    "apex_dsa_index_fwd": lambda: _registered("dsa_index_scores"),
+    "apex_dsa_index": lambda: _registered("paged_index_scores"),
+    "apex_dsa_attend": lambda: _registered("paged_select_attention"),
 }
 
 
@@ -120,13 +124,18 @@ def test_the_pallas_call_equation_carries_its_stable_name(name):
 
 
 def test_every_pallas_call_site_is_named_and_no_name_is_used_twice():
-    calls, names = 0, []
+    # a site's ``name=`` is a literal, or a variable assigned from literals
+    # (the paged walk runs under two names: with and without picked
+    # positions): 19 sites, 20 names
+    calls, named, names = 0, 0, []
     for f in sorted(OPS.glob("*.py")):
         src = f.read_text()
         calls += len(re.findall(r"\bpl\.pallas_call\(", src))
-        names += re.findall(r'^\s+name="(apex_\w+)",$', src, re.M)
-    assert calls == len(names) == 17
-    assert sorted(names) == sorted(KERNELS)
+        named += len(re.findall(r"^\s+name=[\w\"]+,$", src, re.M))
+        for line in re.findall(r"^\s+name ?= ?.*$", src, re.M):
+            names += re.findall(r'"(apex_\w+)"', line)
+    assert calls == named == 19
+    assert len(names) == 20 and sorted(names) == sorted(KERNELS)
 
 
 # -- compiled for the described chip (no chip attached, nothing runs) --------
@@ -351,12 +360,96 @@ def test_v5e_latent_decode_step_reads_the_one_pool_in_place(one_chip,
     assert sum(made.values()) <= 2 * layers, made
 
 
+def test_v5e_selecting_decode_step_reads_three_pools_in_place(one_chip,
+                                                             monkeypatch):
+    """A paged ``keye`` decode step compiled for the chip (ISSUE 36) at the
+    published widths of a page (4 KV heads x 128, index keys of 64, page
+    128): one ``apex_dsa_index`` and one ``apex_dsa_attend`` custom call a
+    layer and NO ``apex_paged_decode`` — the index kernel reads the
+    index-key pool whole, the attention kernel the K and V pools whole,
+    all along the one work list — and no copy, slice or any other op whose
+    result is pool-sized besides the appends' in-place updates, nor one
+    that is ``[slots, max_seq, heads, d]``-sized."""
+    import apex_tpu.ops.attention as at
+    import apex_tpu.ops.paged_attention as pa
+    from apex_tpu.inference import kv_cache
+    from apex_tpu.inference.engine import make_decode_fn
+    from apex_tpu.inference.sampling import SamplingConfig
+    from apex_tpu.transformer.testing import standalone_keye as SK
+
+    for mod in (at, ln, pa):
+        monkeypatch.setattr(mod, "interpret_mode", lambda: False)
+    # pools too large for the compiler to park in fast memory, as the
+    # cell's are (a 12 MB index-key pool it prefetches whole)
+    layers, slots, ps, pages, mpps = 3, 8, 128, 2048, 32
+    cfg = SK.KeyeConfig(
+        vocab_size=256, hidden_size=256, num_layers=layers, num_heads=32,
+        num_kv_heads=4, head_dim=128, mrope_section=(16, 24, 24),
+        index_heads=16, index_head_dim=64, index_topk=2048,
+        index_q_chunk=512, moe_ffn_hidden_size=128, num_experts=16,
+        experts_per_token=4, max_seq_length=ps * mpps, params_dtype=BF16)
+    on_chip = lambda x: _s(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+    params = {"params": jax.tree.map(
+        lambda shape: _s(shape, BF16, sharding=one_chip),
+        SK.keye_param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))}
+    cache = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: kv_cache.init_paged_cache(
+            pages, layers, 4, ps, 128, slots=slots, max_pages_per_slot=mpps,
+            index=64)))
+    step = jax.jit(make_decode_fn("keye", cfg, SamplingConfig()),
+                   donate_argnums=(0,))
+    args = (cache, params, _s((slots,), jnp.int32, sharding=one_chip),
+            _s((slots,), bool, sharding=one_chip),
+            _s((2,), jnp.uint32, sharding=one_chip),
+            _s((), jnp.int32, sharding=one_chip))
+    assert str(jax.make_jaxpr(step)(*args)).count(
+        "name=paged_work_list") == 1
+    compiled = step.lower(*args).compile()
+    hlo = compiled.as_text()
+    kv_pool = f"bf16[{pages + 1},{layers},4,{ps},128]"
+    ik_pool = f"bf16[{pages + 1},{layers},64,{ps}]"
+    window = slots * ps * mpps * 4 * 128        # [slots, max_seq, kvh, d]
+    made, calls = {}, {"apex_dsa_index": 0, "apex_dsa_attend": 0}
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\(", line)
+        if not m:
+            continue
+        name, result, op = m.groups()
+        if op == "custom-call":
+            assert not name.startswith("apex_paged_decode"), name
+            kind = re.sub(r"\.\d+$", "", name)
+            if kind in calls:
+                calls[kind] += 1
+                # each pool it reads, whole, once
+                want = {"apex_dsa_index": (0, 1),
+                        "apex_dsa_attend": (2, 0)}[kind]
+                assert (line.count(kv_pool), line.count(ik_pool)) == want, \
+                    line[:400]
+        for pool in (kv_pool, ik_pool):
+            if result.startswith(pool) and op != "parameter":
+                made[op] = made.get(op, 0) + 1
+        # no gathered window of the slots' whole contexts
+        dims = re.match(r"\w+\[([\d,]+)\]", result)
+        if dims and op not in ("parameter", "get-tuple-element", "bitcast"):
+            size = np.prod([int(x) for x in dims.group(1).split(",")])
+            assert size < window or result.startswith((kv_pool, ik_pool)), \
+                line[:300]
+    assert calls == {"apex_dsa_index": layers, "apex_dsa_attend": layers}
+    # the appends: in-place scatters of the token's rows, nothing else
+    assert set(made) <= {"fusion", "scatter"}, made
+    assert sum(made.values()) <= 2 * 3 * layers, made
+    pool_bytes = 2 * (pages + 1) * layers * ps * (2 * 4 * 128 + 64)
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+
+
 #: the serving cells' caches: (pool shape, rings' shape or None, slots,
-#: pages a slot) — gpt3-1.3b-serve, laguna-xs.2-serve, a.x-k1-serve
+#: pages a slot[, the index-key pool's shape]) — gpt3-1.3b-serve,
+#: laguna-xs.2-serve, a.x-k1-serve, keye-vl-2.0-30b-a3b-serve
 _CELL_CACHES = {
     "gpt": ((641, 24, 16, 64, 128), None, 16, 32),
     "laguna": ((4097, 2, 8, 64, 128), (3, 32, 8, 576, 128), 32, 260),
     "latent": ((1537, 6, 576, 256), None, 64, 35),
+    "select": ((2817, 5, 4, 128, 128), None, 16, 264, (2817, 5, 64, 128)),
 }
 
 
@@ -382,14 +475,15 @@ def test_v5e_evict_writes_the_metadata_and_copies_no_pool(pool, one_chip):
         "gpt", cfg, gpt_model_provider(cfg).init(
             jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32)),
         slots=2, page_size=8, num_pages=8)._evict
-    shape, rings, slots, mpps = _CELL_CACHES[pool]
+    shape, rings, slots, mpps, *index = _CELL_CACHES[pool]
     on = lambda s, dt: _s(s, dt, sharding=one_chip)  # noqa: E731
     latent = len(shape) == 4
     cache = kv_cache.PagedKVCache(
         k=on(shape, BF16), v=None if latent else on(shape, BF16),
         page_table=on((slots, mpps), jnp.int32),
         lengths=on((slots,), jnp.int32), capacity=on((slots,), jnp.int32),
-        wk=rings and on(rings, BF16), wv=rings and on(rings, BF16))
+        wk=rings and on(rings, BF16), wv=rings and on(rings, BF16),
+        ik=on(index[0], BF16) if index else None)
     leaves = len(jax.tree_util.tree_leaves(cache))
     compiled = evict.lower(cache, on((), jnp.int32)).compile()
     hlo = compiled.as_text()
@@ -397,7 +491,7 @@ def test_v5e_evict_writes_the_metadata_and_copies_no_pool(pool, one_chip):
     for i in range(leaves):
         assert f"{{{i}}}: ({i}, {{}}, may-alias)" in head, head[:400]
     big = {"bf16[" + ",".join(map(str, s)) + "]"
-           for s in (shape, rings) if s}
+           for s in (shape, rings, *index) if s}
     written = []
     for line in hlo.splitlines():
         m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\(", line)

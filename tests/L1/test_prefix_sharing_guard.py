@@ -33,7 +33,7 @@ from apex_tpu.transformer.testing import GPTConfig, gpt_model_provider
 # swap copy programs; ISSUE 34 the latent-attention kind's decode step
 # (the only legitimate way this number moves: a new REGISTERED
 # executable, never a serving-path side effect)
-BUDGETED_EXECUTABLES = 26
+BUDGETED_EXECUTABLES = 27
 
 
 def _engine():
@@ -108,7 +108,8 @@ def test_budget_ledger_untouched_by_prefix_sharing():
         "inference_prefill", "inference_decode",
         "inference_prefill_paged", "inference_decode_paged",
         "inference_decode_fused_paged", "inference_verify_paged",
-        "inference_decode_latent", "inference_prefill_paged_tp2", "inference_decode_fused_paged_tp2",
+        "inference_decode_latent", "inference_decode_select",
+        "inference_prefill_paged_tp2", "inference_decode_fused_paged_tp2",
         "inference_verify_paged_tp2",
         "inference_swap_out_paged", "inference_swap_in_paged"}
     # the serving-side program set is closed: the COW copy rides the
