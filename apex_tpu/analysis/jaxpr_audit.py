@@ -410,35 +410,36 @@ def _builders():
         "inference_prefill": (inference_prefill,
                               "apex_tpu/inference/engine.py",
                               ("bfloat16", "bfloat16", "int32", "int32",
-                               "float32"), None),
+                               "int32", "float32"), None),
         "inference_decode": (inference_decode,
                              "apex_tpu/inference/engine.py",
                              ("bfloat16", "bfloat16", "int32", "int32",
-                              "float32", "bool"), None),
+                              "int32", "float32", "bool"), None),
         "inference_prefill_paged": (inference_prefill_paged,
                                     "apex_tpu/inference/engine.py",
                                     ("bfloat16", "bfloat16", "int32",
-                                     "int32", "int32", "int32",
+                                     "int32", "int32", "int32", "int32",
                                      "float32"), None),
         "inference_decode_paged": (inference_decode_paged,
                                    "apex_tpu/inference/engine.py",
                                    ("bfloat16", "bfloat16", "int32",
-                                    "int32", "int32", "int32",
+                                    "int32", "int32", "int32", "int32",
                                     "float32", "bool"), None),
         # ISSUE 34: the cache is ONE bf16 pool (then table, lengths,
-        # capacity), the tokens carry the four counters
+        # capacity, last_tokens), the tokens carry the four counters
         "inference_decode_latent": (inference_decode_latent,
                                     "apex_tpu/inference/engine.py",
                                     ("bfloat16", "int32", "int32",
-                                     "int32", "int32", "float32", "bool"),
+                                     "int32", "int32", "int32", "float32",
+                                     "bool"),
                                     None),
-        # ISSUE 36: k, v, table, lengths, capacity and the index-key pool,
-        # then the tokens with the seven counters
+        # ISSUE 36: k, v, table, lengths, capacity, last_tokens and the
+        # index-key pool, then the tokens with the seven counters
         "inference_decode_select": (inference_decode_select,
                                     "apex_tpu/inference/engine.py",
                                     ("bfloat16", "bfloat16", "int32",
-                                     "int32", "int32", "bfloat16", "int32",
-                                     "float32", "bool"), None),
+                                     "int32", "int32", "int32", "bfloat16",
+                                     "int32", "float32", "bool"), None),
         # ISSUE 15: the fused-block kernel (op-level; measured entry
         # upcasts = 11: the norm gains/biases and the projection/MLP
         # biases applied in fp32 by design — layer_norm's budget-2
@@ -455,21 +456,22 @@ def _builders():
                                          "apex_tpu/inference/engine.py",
                                          ("bfloat16", "bfloat16",
                                           "int32", "int32", "int32",
-                                          "int32", "float32", "bool"),
+                                          "int32", "int32", "float32",
+                                          "bool"),
                                          None),
         "inference_verify_paged": (inference_verify_paged,
                                    "apex_tpu/inference/engine.py",
                                    ("bfloat16", "bfloat16", "int32",
-                                    "int32", "int32", "int32",
+                                    "int32", "int32", "int32", "int32",
                                     "int32", "bool"), None),
         "inference_cow_page": (inference_cow_page,
                                "apex_tpu/inference/kv_cache.py",
                                ("bfloat16", "bfloat16", "int32",
-                                "int32", "int32"), 0),
+                                "int32", "int32", "int32"), 0),
         "inference_evict_slot": (inference_evict_slot,
                                  "apex_tpu/inference/kv_cache.py",
                                  ("bfloat16", "bfloat16", "int32",
-                                  "int32", "int32"), 0),
+                                  "int32", "int32", "int32"), 0),
         # ISSUE 18: the two host-tier copy programs — pure gathers/
         # scatters over the pool (no collectives, no host callbacks,
         # no entry upcasts); the swap-in returns the whole cache (cow's
@@ -480,7 +482,7 @@ def _builders():
         "inference_swap_in_paged": (inference_swap_in_paged,
                                     "apex_tpu/inference/kv_cache.py",
                                     ("bfloat16", "bfloat16", "int32",
-                                     "int32", "int32"), 0),
+                                     "int32", "int32", "int32"), 0),
     }
 
 

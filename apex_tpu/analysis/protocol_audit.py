@@ -35,7 +35,8 @@ APX406   host-store byte budget: ``bytes_used == pages * page_bytes <=
          capacity``, store handles mirror the host edges exactly
 APX407   lifecycle + wave-boundary + fleet: per-replica ``submitted ==
          finished + active + rejected``; NO unresolved PendingSwapOut
-         (deferred offload or handoff extract) survives a wave
+         (deferred offload or handoff extract) and no decode step
+         launched ahead of its read (ISSUE 37) survives a wave
          boundary; the router's three-level conservation holds
 =======  ==============================================================
 
@@ -126,7 +127,8 @@ INVARIANTS: Dict[str, dict] = {
         "description": "submitted == finished + active + rejected per "
                        "replica; no unresolved PendingSwapOut across "
                        "a wave boundary (deferred offloads AND "
-                       "handoff extracts); router three-level "
+                       "handoff extracts) and no launched decode "
+                       "step left unread there; router three-level "
                        "conservation holds",
         "covers": ("lifecycle-conservation", "wave-boundary-swaps",
                    "fleet-three-level"),
@@ -477,6 +479,11 @@ def _check_lifecycle(h: ProtocolHarness) -> List[Tuple[str, str]]:
                         f"({c})"))
         if rep.wave_open:
             continue
+        if rep._ahead is not None:
+            out.append(("APX407",
+                        f"replica {r}: a decode step launched ahead "
+                        f"of its read is still unread with the wave "
+                        f"closed"))
         if rep.pending_swaps:
             out.append(("APX407",
                         f"replica {r}: {rep.pending_swaps} deferred "

@@ -110,6 +110,10 @@ class StubKVCache:
         self.page_table = np.full((slots, max_pages_per_slot), -1,
                                   np.int32)
         self.lengths = np.zeros((slots,), np.int32)
+        # each slot's next decode input, kept where the steps run
+        # (ISSUE 37): the scheduler launches a step before it has read
+        # the one before, so it hands the engine no token
+        self.last_tokens = np.zeros((slots,), np.int32)
         self.content = np.zeros((num_pages,), np.int64)
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
@@ -213,10 +217,15 @@ class StubEngine:
             cache.content[int(pages[j])] = np.int64(
                 _tag(toks[j * ps:min(n, (j + 1) * ps)]))
         cache.lengths[slot] = n
-        return cache, np.int32(self._first_token(toks)), None
+        first = np.int32(self._first_token(toks))
+        cache.last_tokens[slot] = first
+        return cache, first, None
 
-    def decode(self, cache: StubKVCache, last, active):
-        last = np.asarray(last)
+    def decode(self, cache: StubKVCache, last=None, active=None):
+        # the step's input tokens are the cache's own, as the compiled
+        # step's are; every call runs at once, so a step "launched
+        # ahead" has simply run by the time its vector is read
+        last = np.asarray(cache.last_tokens if last is None else last)
         active = np.asarray(active, bool)
         toks = np.zeros((self.slots,), np.int32)
         truncated = np.zeros((self.slots,), bool)
@@ -241,6 +250,7 @@ class StubEngine:
             cache.content[page] = np.int64(_mix(base, int(last[s])))
             cache.lengths[s] = length + 1
             toks[s] = tok
+            cache.last_tokens[s] = tok
         # the engine's one array for the host, packed as the compiled
         # step packs it (the scheduler reads nothing else)
         return cache, host_vector(toks, truncated, xp=np), None, truncated
@@ -581,9 +591,14 @@ class ProtocolHarness:
             slots = tuple(
                 None if st is None else
                 (self.uid_template.get((r, st.uid), -1),
-                 st.prefilled, tuple(st.generated), st.capacity,
-                 tuple(int(p) for p in (st.pages or ())))
+                 st.prefilled, tuple(st.generated), st.issued,
+                 st.capacity, tuple(int(p) for p in (st.pages or ())))
                 for st in rep.slot_states())
+            # the decode step launched and not yet read (ISSUE 37): its
+            # vector and which slots' states it was launched for
+            ahead = (None if rep._ahead is None else
+                     (tuple(int(x) for x in rep._ahead[0]),
+                      tuple(st is not None for st in rep._ahead[1])))
             # per-tenant admission recency as a RANK order (the
             # fairness tiebreak reads only the order)
             tla = sorted(rep._tenant_last_admit.items(),
@@ -592,10 +607,11 @@ class ProtocolHarness:
             ctup = (() if cache is None else
                     (tuple(int(x) for x in cache.content),
                      tuple(int(x) for x in cache.lengths),
+                     tuple(int(x) for x in cache.last_tokens),
                      tuple(int(x) for x in cache.page_table.ravel())))
             parts.append((
                 snap["free"], tuple(sorted(snap["refs"].items())),
-                etup, stup, queue, rep.wave_open, slots,
+                etup, stup, queue, rep.wave_open, slots, ahead,
                 tuple(rep._run_free), rep.pending_swaps,
                 tuple(t for t, _ in tla), ctup))
         if self.router is not None:

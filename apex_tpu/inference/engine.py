@@ -14,7 +14,10 @@ Workload split (the flash-attention/Megatron serving shape):
   with the cache donated, so the executable's cache output aliases its
   input and no per-step reallocation exists.  The step's PRNG key is
   derived in-program (``fold_in(key, step)``), so sampled decoding adds
-  no second executable.
+  no second executable.  The step's INPUT tokens are device state too
+  (``cache.last_tokens``, ISSUE 37): each step writes the tokens it
+  sampled where the next reads them, so the serving loop launches step
+  N+1 before it has read step N and uploads no token.
 
 Cache layouts (ISSUE 6): the dense slot cache provisions ``max_seq``
 per slot; ``page_size=``/``num_pages=`` switch to the ragged paged
@@ -30,8 +33,9 @@ No host transfer appears anywhere in either jaxpr (audited by
 ``analysis/jaxpr_audit.py`` — the inference entries trace these exact
 step builders); the only device<->host traffic is the scheduler reading
 ONE small int32 vector a step (:func:`host_vector`: sampled tokens,
-``truncated`` / ``n_emit`` flags, a kind's counters) *between* steps,
-which is the continuous-batching control loop by construction.
+``truncated`` / ``n_emit`` flags, a kind's counters) — one step late,
+while the next step runs — and uploading a decode step's ``active``
+mask, which is the continuous-batching control loop by construction.
 Retiring a slot is one more donated executable
 (:meth:`InferenceEngine.evict_slot`), never an eagerly applied op.
 
@@ -121,7 +125,10 @@ def make_prefill_fn(kind: str, cfg, sampling: SamplingConfig,
     prefix + real tail inside the padded bucket).  Both operands are
     traced, so ONE compiled executable per bucket serves cold
     prefills, prefix-cache hits, and chunked-prefill chunks alike —
-    sharing changes page-table rows, never device programs."""
+    sharing changes page-table rows, never device programs.
+
+    The sampled token is also written to ``cache.last_tokens[slot]``
+    (ISSUE 37): the slot's first decode step takes it from the device."""
 
     rec = models.KINDS[kind]
     # static facts of the kind: a kind without them traces none of it
@@ -142,6 +149,8 @@ def make_prefill_fn(kind: str, cfg, sampling: SamplingConfig,
             last = logits[0].astype(jnp.float32)            # [vocab]
             tok = sample_token(last, jax.random.fold_in(key, step),
                                sampling)
+            # the slot's next decode step reads it from the device
+            cache = kv_cache.feed_back(cache, tok, slot)
         return cache, tok, last
 
     def prefill_paged_fn(cache, params, tokens, slot, length, row,
@@ -168,6 +177,10 @@ def make_prefill_fn(kind: str, cfg, sampling: SamplingConfig,
             last = logits[0].astype(jnp.float32)            # [vocab]
             tok = sample_token(last, jax.random.fold_in(key, step),
                                sampling)
+            # the slot's next decode step reads it from the device (a
+            # chunk's that is not the prompt's last is overwritten by
+            # the next chunk's)
+            cache = kv_cache.feed_back(cache, tok, slot)
             if rec.stats:
                 tok = host_vector(
                     tok, tail=models.stats_tail(rec.stats, stats, cache))
@@ -178,13 +191,21 @@ def make_prefill_fn(kind: str, cfg, sampling: SamplingConfig,
 
 def make_decode_fn(kind: str, cfg, sampling: SamplingConfig,
                    fused: bool = False, tp: int = 1):
-    """Pure decode step: ``(cache, params, tokens [slots], active
-    [slots], key, step) -> (cache, host, logits, truncated)``.
+    """Pure decode step: ``(cache, params, tokens [slots] | None,
+    active [slots], key, step) -> (cache, host, logits, truncated)``.
     Every slot computes (static shape); only active slots advance their
     length, and ``truncated`` flags active slots already at capacity
     whose emitted token could NOT be appended (the caller must retire
     them — nothing is clamped silently).  Serves both cache layouts:
     the paged pool threads its page table through the same signature.
+
+    ``tokens=None`` (ISSUE 37, how the engine serves): the step's input
+    tokens are the device's own ``cache.last_tokens`` — what the last
+    prefill or decode step sampled for each slot — and the tokens sampled
+    here are written back there where ``active``, so step N+1 can be
+    launched before the host has read step N.  An array in that place is
+    a caller feeding tokens of its choice (a drafter's catch-up, a test);
+    they are fed back the same way.
 
     ``host`` is :func:`host_vector`'s ``[next_tokens [slots] |
     truncated [slots] | stats tail]``, peeled by ``step_vector.peel_step``
@@ -205,6 +226,8 @@ def make_decode_fn(kind: str, cfg, sampling: SamplingConfig,
 
     def decode_fn(cache, params, tokens, active, key, step):
         tree, fused_layers = params if fused else (params, None)
+        if tokens is None:
+            tokens = cache.last_tokens
         with obs.named_scope("apex_decode_forward"):
             logits, cache, stats = models.decode_forward(
                 kind, cfg, tree, cache, tokens, fused=fused_layers, tp=tp,
@@ -215,6 +238,9 @@ def make_decode_fn(kind: str, cfg, sampling: SamplingConfig,
                                 sampling)
         with obs.named_scope("apex_decode_advance"):
             cache, truncated = kv_cache.advance(cache, active)
+            # the active slots' next step takes these tokens from here,
+            # whether or not the host has read them yet
+            cache = kv_cache.feed_back(cache, toks, active)
             # tokens, flags and the counters' tail: one read for all
             host = host_vector(
                 toks, truncated,
@@ -964,12 +990,20 @@ class InferenceEngine:
                 cache = self._swap_in(cache, padded, pk, pv)
         return cache
 
-    def decode(self, cache, last_tokens, active=None):
+    def decode(self, cache, last_tokens=None, active=None):
         """One token for every slot: returns ``(cache, host, logits,
         truncated)`` — ``host`` is the step's ONE array for the host
         (:func:`host_vector`: ``[next_tokens [slots] | truncated
         [slots] | stats tail]``); only ``active`` slots advance their
         cache length.
+
+        The step's input tokens are ``cache.last_tokens`` (ISSUE 37):
+        each slot's last sampled token, left on the device by the
+        prefill or decode step that sampled it.  The serving loop passes
+        none, so a launch uploads ``active`` and nothing else of a
+        token's size, and needs no earlier step's vector on the host.
+        ``last_tokens`` given (a drafter feeding confirmed tokens, a
+        test) is uploaded and used in their place.
 
         Capacity contract: a slot whose length has reached its capacity
         (``max_seq`` dense; its page reservation paged) must be retired
@@ -988,9 +1022,10 @@ class InferenceEngine:
             self._fused_decode_dispatches.inc()
         params = ((self.params, self._fused_layers) if self.decode_fused
                   else self.params)
+        tokens = (None if last_tokens is None
+                  else np.asarray(last_tokens, np.int32))
         with obs.trace_annotation("apex_tpu.inference.decode"):
-            return self._decode(cache, params,
-                                np.asarray(last_tokens, np.int32),
+            return self._decode(cache, params, tokens,
                                 np.asarray(active, bool),
                                 self._key, self._next_step())
 

@@ -20,6 +20,7 @@ step:
       page_table : [slots, max_pages_per_slot]  int32
       lengths    : [slots]  int32   live tokens per slot
       capacity   : [slots]  int32   page_size * pages owned by the slot
+      last_tokens: [slots]  int32   the next decode step's input tokens
 
   A slot's tokens live in whichever fixed-size pages the host-side
   :class:`PageAllocator` handed it; the page table (a small int32
@@ -117,7 +118,8 @@ __all__ = ["KVCache", "init_cache", "PagedKVCache", "init_paged_cache",
            "PageAllocator", "HostPageStore", "default_page_size",
            "default_swap_batch_pages", "insert_tokens", "cow_page",
            "extract_pages", "restore_pages", "append_slab",
-           "advance_by", "set_lengths", "paged_cache_partition_specs"]
+           "advance_by", "set_lengths", "feed_back",
+           "paged_cache_partition_specs"]
 
 _PAGE_SIZE_ENV = "APEX_TPU_PAGE_SIZE"
 _DEFAULT_PAGE_SIZE = 64
@@ -172,6 +174,10 @@ class KVCache:
     k: jax.Array          # [slots, layers, kv_heads, max_seq, head_dim]
     v: jax.Array          # same shape/dtype as k
     lengths: jax.Array    # [slots] int32: live tokens per slot
+    # [slots] int32: the token each slot's NEXT decode step takes as its
+    # input (ISSUE 37) — written by the step that sampled it, never by
+    # the host (:func:`feed_back`)
+    last_tokens: jax.Array
 
     @property
     def slots(self) -> int:
@@ -199,7 +205,8 @@ def init_cache(slots: int, layers: int, kv_heads: int, max_seq: int,
     """Allocate an empty cache (every slot free, length 0)."""
     shape = (slots, layers, kv_heads, max_seq, head_dim)
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
-                   lengths=jnp.zeros((slots,), jnp.int32))
+                   lengths=jnp.zeros((slots,), jnp.int32),
+                   last_tokens=jnp.zeros((slots,), jnp.int32))
 
 
 def insert(cache: KVCache, slot, k, v, length) -> KVCache:
@@ -385,6 +392,22 @@ def advance(cache, active):
     return cache.replace(lengths=new_len), truncated
 
 
+def feed_back(cache, tokens, where):
+    """The tokens a step sampled become the next decode step's input, on
+    the device (ISSUE 37): ``where`` is a decode step's ``active [slots]``
+    mask beside its ``tokens [slots]``, or a prefill's traced ``slot``
+    beside its one token.  The host never uploads a token it was sent,
+    so the next step can be launched before this one's vector is read."""
+    tokens = jnp.asarray(tokens, jnp.int32)
+    if tokens.ndim:
+        new = jnp.where(jnp.asarray(where, bool), tokens,
+                        cache.last_tokens)
+    else:
+        new = jax.lax.dynamic_update_slice(cache.last_tokens,
+                                           tokens[None], (where,))
+    return cache.replace(last_tokens=new)
+
+
 def evict(cache, slot):
     """Retire a slot: zero its length (and, paged, its capacity, with
     the page-table row re-parked on the trash page).  Metadata-only —
@@ -457,6 +480,10 @@ class PagedKVCache:
     page_table: jax.Array  # [slots, max_pages_per_slot] int32
     lengths: jax.Array     # [slots] int32: live tokens per slot
     capacity: jax.Array    # [slots] int32: page_size * owned pages
+    # [slots] int32: the token each slot's NEXT decode step takes as its
+    # input (ISSUE 37), written by the step that sampled it
+    # (:func:`feed_back`); replicated under tensor parallelism
+    last_tokens: jax.Array
     # the window layers' rings (ISSUE 30), None without window layers:
     # [window_layers, slots, kv_heads, ring, head_dim] (layer-major: a
     # decode step rewrites one layer at a time), ring a whole number of
@@ -570,7 +597,8 @@ def init_paged_cache(pages: int, layers: int, kv_heads: int,
         page_table=jnp.full((slots, max_pages_per_slot), pages,
                             jnp.int32),
         lengths=jnp.zeros((slots,), jnp.int32),
-        capacity=jnp.zeros((slots,), jnp.int32), **rings,
+        capacity=jnp.zeros((slots,), jnp.int32),
+        last_tokens=jnp.zeros((slots,), jnp.int32), **rings,
         ik=jnp.zeros((pages + 1, layers, index, page_size), dtype)
         if index else None)
 
@@ -594,7 +622,7 @@ def paged_cache_partition_specs(axis: str = TENSOR_AXIS) -> PagedKVCache:
     from jax.sharding import PartitionSpec as P
     kv = P(None, None, axis, None, None)
     return PagedKVCache(k=kv, v=kv, page_table=P(), lengths=P(),
-                        capacity=P())
+                        capacity=P(), last_tokens=P())
 
 
 def _pools(cache: PagedKVCache, fn, k, v, ik=None) -> dict:

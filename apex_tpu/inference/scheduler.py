@@ -30,7 +30,15 @@ retires requests between device steps:
              consecutive decode steps.
     step:    one decode executable over every slot (inactive slots
              compute garbage that is masked and never advances); the
-             host reads ONE array of it — tokens, flags, counters
+             host reads ONE array of it — tokens, flags, counters —
+             and reads it one step LATE (ISSUE 37): step N+1 is
+             launched before step N's array is read, its input tokens
+             fed back on the device (``cache.last_tokens``), so the
+             chip never waits for a launch or for a read's tail.  The
+             host names step N+1's active slots from COUNTS of what it
+             has launched (token budget, capacity, prefill progress);
+             only an EOS is seen a step late, and its one extra token
+             is thrown away
     retire:  EOS, the token budget, or slot capacity frees the slot:
              one compiled metadata update on the device, then
              a retired slot only RELEASES its page references — a page
@@ -72,6 +80,7 @@ their SLOs through the storm.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import os
 from typing import Dict, Optional
@@ -178,6 +187,10 @@ class _SlotState:
     prompt: Optional[list] = None  # full prompt (chunked prefill)
     prefilled: int = 0             # prompt tokens already in the cache
     chunked: bool = False          # prefill split into >1 chunk
+    issued: int = 0                # tokens LAUNCHED for the request: its
+    #                                prefill's first and one a decode step
+    #                                it was active in; len(generated) of
+    #                                them have been READ
 
     def prefilling(self) -> bool:
         """Still inserting prompt tokens — not decoding yet."""
@@ -190,11 +203,22 @@ class _SlotState:
         return len(self.generated) >= self.max_new_tokens
 
     def cache_len(self) -> int:
-        """The slot's device cache length, derived host-side: the
-        prompt plus one append per decode step taken (the first
-        generated token comes from prefill and is written by the NEXT
-        decode) — so the capacity guard never reads the device."""
+        """The slot's device cache length once every token READ so far
+        was appended, derived host-side: the prompt plus one append per
+        decode step taken (the first generated token comes from prefill
+        and is written by the NEXT decode) — so the capacity guard
+        never reads the device."""
         return self.prompt_len + len(self.generated) - 1
+
+    def can_issue(self) -> bool:
+        """May the next decode step launched carry this slot?  Answered
+        from what the host has LAUNCHED, never from a token it has yet
+        to read (ISSUE 37): the prompt's last piece is launched (its
+        token is ``issued`` 1), the token budget has room, and the step
+        would append inside the slot's capacity.  Everything that ends
+        a request except an EOS is such a count."""
+        return (0 < self.issued < self.max_new_tokens
+                and self.prompt_len + self.issued - 1 < self.capacity)
 
 
 class SlotScheduler:
@@ -325,8 +349,11 @@ class SlotScheduler:
         self._wave_open = False
         self._run_slots: list = []
         self._run_free: list = []
-        self._run_last = np.zeros((engine.slots,), np.int32)
         self._run_results: dict = {}
+        # the decode step launched and not yet read: (its vector on the
+        # device, the slots' states as they were at the launch — None
+        # where inactive), or None when no step is in flight
+        self._ahead = None
         if self.alloc is not None:
             self.telemetry.pool(self.alloc.free_pages,
                                 self.engine.num_pages)
@@ -599,14 +626,15 @@ class SlotScheduler:
             self.cache = cache
         self._run_slots = [None] * eng.slots
         self._run_free = list(range(eng.slots))
-        self._run_last = np.zeros((eng.slots,), np.int32)
         self._run_results = {}
+        self._ahead = None
         self._wave_open = True
 
     def run_pending(self) -> bool:
         """True while the open wave still has queued or in-flight
-        requests — i.e. another :meth:`run_pass` would do work."""
-        return bool(self.queue
+        requests, or a launched decode step whose vector is unread —
+        i.e. another :meth:`run_pass` would do work."""
+        return bool(self.queue or self._ahead is not None
                     or any(s is not None for s in self._run_slots))
 
     @property
@@ -627,7 +655,9 @@ class SlotScheduler:
         return list(self._run_slots)
 
     def finish_run(self) -> dict:
-        """Close the wave: force any deferred eviction drains to land
+        """Close the wave: read the decode step still in flight, if the
+        caller closes before the wave drained (ISSUE 37: nothing stays
+        launched and unread), force any deferred eviction drains to land
         (ISSUE 19 — the dispatches have been pipelining behind the
         wave's real work; the gets happen here, out of line), close one
         SLO accounting window (burn rate / budget gauges +
@@ -637,6 +667,11 @@ class SlotScheduler:
         for the wave."""
         if not self._wave_open:
             raise RuntimeError("finish_run without an open wave")
+        if self._ahead is not None:
+            # a wave closed before it drained: nothing stays in flight
+            (host, launched), self._ahead = self._ahead, None
+            self._settle(launched, *self._read_step(
+                host, "decode", self.engine.slots))
         self.drain_pending_swaps()
         self.slo.observe_window()
         self.telemetry.registry.export()
@@ -686,11 +721,15 @@ class SlotScheduler:
         self.telemetry.request_finished(st.uid, reason, len(gen))
 
     def _read_step(self, host, phase: str, tokens: int):
-        """A pass's ONE device→host read (ISSUE 35): everything the
+        """ONE device→host read of ONE launch (ISSUE 35): everything the
         host needs of a step arrives in the one int32 vector the engine
         laid out as ``[tokens | flags | stats tail]`` — so this is the
         only place the host waits for the device, and it asks for
-        nothing else.  The read is explicit (``jax.device_get``, which
+        nothing else.  Since ISSUE 37 the vector read here is, for a
+        decode step, that of the step launched a pass EARLIER, and the
+        pass's own launches — its prefills, the next decode step — are
+        already queued behind it: while the host waits here the chip
+        has work.  The read is explicit (``jax.device_get``, which
         requests the copy and waits for it; requesting it earlier, at
         dispatch, was measured and shortened nothing): under
         ``jax.transfer_guard_device_to_host("disallow")`` a pass runs
@@ -707,9 +746,14 @@ class SlotScheduler:
                 phase, {n: int(v) for n, v in zip(names, tail)})
         return toks, flags
 
-    def _prefill_piece(self, slot: int) -> None:
-        """Advance one slot's prefill by one chunk (or the whole
-        uncached tail when chunking is off / the tail fits)."""
+    def _launch_prefill(self, slot: int):
+        """Launch one slot's next prefill piece (one chunk, or the whole
+        uncached tail when chunking is off / the tail fits) and read
+        nothing: returns what :meth:`_read_prefill` needs to read its
+        vector later in the pass, behind the pass's decode launch.  The
+        piece's sampled token stays on the device
+        (``cache.last_tokens[slot]``); the prompt's LAST piece makes the
+        slot one the next decode step can carry (``issued`` 1)."""
         eng, tel = self.engine, self.telemetry
         st = self._run_slots[slot]
         total = st.prompt_len
@@ -717,36 +761,50 @@ class SlotScheduler:
         end = (total if not self.prefill_chunk
                else min(total, start + self.prefill_chunk))
         bucket = eng.bucket_for(end - start)
+        # the telemetry's bracket closes at the read: dispatch + the
+        # wait for the first token, as before
+        bracket = contextlib.ExitStack()
         with trace_annotation("apex_tpu.scheduler.prefill", uid=st.uid,
                               slot=slot, tokens=end - start, bucket=bucket):
-            with tel.prefill_step(
-                    prompt_len=end - start, bucket_len=bucket,
-                    uid=st.uid, start_tok=start):
-                self.cache, tok, _ = eng.prefill(
-                    self.cache, st.prompt[:end], slot, pages=st.pages,
-                    prefill_from=start)
-                # the one place of a prefill where the host waits
-                # for the device
-                tok = int(self._read_step(tok, "prefill", 1)[0][0])
-            st.prefilled = end
-            if st.chunked:
-                tel.prefill_chunked(st.uid, start, end - start)
-            if end < total:
-                return                     # more chunks to go
-            # final piece: the sampled token is the request's first
-            tel.first_token(st.uid)
-            st.generated.append(tok)
-            self._run_last[slot] = tok
-            if self.drafter is not None and eng.spec_k:
-                self.drafter.begin(slot, st.prompt, tok)
-            if self.prefix is not None:
-                ps = eng.page_size
-                new = self.prefix.insert(
-                    st.prompt, st.pages[:-(-total // ps)])
-                if new:
-                    self._pool_gauges()
-            if st.done():
-                self._retire(slot, REASON_LENGTH)
+            bracket.enter_context(tel.prefill_step(
+                prompt_len=end - start, bucket_len=bucket,
+                uid=st.uid, start_tok=start))
+            self.cache, host, _ = eng.prefill(
+                self.cache, st.prompt[:end], slot, pages=st.pages,
+                prefill_from=start)
+        st.prefilled = end
+        if st.chunked:
+            tel.prefill_chunked(st.uid, start, end - start)
+        if end == total:
+            st.issued = 1
+        return slot, host, bracket
+
+    def _read_prefill(self, slot: int, host, bracket) -> None:
+        """Read the vector of the prefill piece this pass launched for
+        ``slot`` — the one place of a prefill where the host waits for
+        the device — and, if it was the prompt's last piece, deliver the
+        request's first token."""
+        eng, tel = self.engine, self.telemetry
+        st = self._run_slots[slot]
+        with bracket:
+            tok = int(self._read_step(host, "prefill", 1)[0][0])
+        if st.prefilling():
+            return                         # more chunks to go
+        tel.first_token(st.uid)
+        st.generated.append(tok)
+        if self.drafter is not None and eng.spec_k:
+            self.drafter.begin(slot, st.prompt, tok)
+        if self.prefix is not None:
+            ps = eng.page_size
+            new = self.prefix.insert(
+                st.prompt, st.pages[:-(-st.prompt_len // ps)])
+            if new:
+                self._pool_gauges()
+        if st.done():
+            # a budget of one token, or an EOS for a first token (then
+            # the step launched ahead carried the slot: one token thrown
+            # away when its vector is read)
+            self._retire(slot, REASON_LENGTH)
 
     def _admit_one(self) -> bool:
         eng, tel = self.engine, self.telemetry
@@ -805,24 +863,48 @@ class SlotScheduler:
 
     def run_pass(self) -> None:
         """One pass of the wave loop: admit what fits (slots, pages —
-        priority/fairness ordered), advance at most
-        ``max_chunks_per_pass`` prefill chunks, then ONE batched
-        decode (or verify) step over the decoding slots.  The device
-        sees only the fixed-shape prefill/decode (+COW copy, +evict)
-        executables; everything else here is host-side bookkeeping on
-        ints."""
+        priority/fairness ordered), launch at most
+        ``max_chunks_per_pass`` prefill chunks, launch ONE batched
+        decode step over the slots that can take a token, and only then
+        read — the decode step the PREVIOUS pass launched, then this
+        pass's prefills (ISSUE 37).  The device sees only the
+        fixed-shape prefill/decode (+COW copy, +evict) executables;
+        everything else here is host-side bookkeeping on ints.
+
+        What a caller sees after a pass: every request gains the tokens
+        of the steps the pass READ — a request admitted in this pass
+        its first token (its prefill is read in the pass that launched
+        it), every other decoding request one token — while the device
+        is one decode step further on.  The token of a request's first
+        decode step therefore arrives one pass after its first token
+        (that step is launched in the admission pass and read in the
+        next), and the wave's last pass launches nothing and reads the
+        step in flight.  A speculative wave (``engine.spec_k``) drafts
+        from tokens the host has read, so its verify step is launched
+        and read in the same pass."""
         with trace_annotation("apex_tpu.scheduler.pass"):
             self._pass()
 
     def _pass(self) -> None:
         """The pass's body.  What crosses the host/device boundary in it
-        (ISSUE 35): each prefill and the one decode (or verify) step is
-        ONE launch followed by ONE device→host read (:meth:`_read_step`:
-        tokens, ``truncated`` / ``n_emit`` flags and a kind's counters
-        arrive in one int32 vector); each retirement is ONE launch of
-        the compiled evict and reads nothing; between the step's read
-        and the next pass's first launch the host applies no primitive
-        of its own."""
+        (ISSUE 35, 37): every launch — a prefill piece, the decode (or
+        verify) step — is followed by ONE device→host read
+        (:meth:`_read_step`: tokens, ``truncated`` / ``n_emit`` flags
+        and a kind's counters arrive in one int32 vector), and a decode
+        launch uploads its ``active`` mask and no token; each retirement
+        is ONE launch of the compiled evict and reads nothing; the host
+        applies no primitive of its own.
+
+        The ORDER is launches first, reads after: prefill pieces, then
+        decode step N+1 (its slots named by :meth:`_SlotState.can_issue`
+        — counts of what was launched), then the read of step N that
+        the previous pass launched, then the reads of this pass's
+        prefills.  A slot whose step-N token turns out to be its EOS
+        was still active in step N+1: it is retired at the read (its
+        ``evict_slot`` queues behind step N+1, before any page is
+        released), and its entry in step N+1's vector is thrown away
+        (``serve_ahead_tokens_discarded_total``), as is any entry whose
+        slot was retired or re-admitted since the launch."""
         eng, tel = self.engine, self.telemetry
         slots = self._run_slots
         with trace_annotation("apex_tpu.scheduler.admit",
@@ -848,150 +930,182 @@ class SlotScheduler:
                 if not self._admit_one():
                     blocked = True
                     break
-        # advance prefills.  Chunking off: every pending admission
+        if blocked and all(s is None for s in slots):
+            # nothing holds a page and the picked request still can't
+            # be admitted: the POOL itself is too small (prefix-cache
+            # eviction already ran)
+            req = self.queue[self._pick_index()]
+            raise RuntimeError(
+                f"request {req.uid} needs more pages than the "
+                f"pool frees up (prompt {len(req.prompt)} + "
+                f"budget {req.max_new_tokens} tokens vs "
+                f"{self.alloc.free_pages} free pages of "
+                f"{self.alloc.page_size}); grow num_pages or "
+                f"shrink the request")
+        # launch prefills.  Chunking off: every pending admission
         # prefills now (the classic loop).  Chunking on: at most
         # max_chunks_per_pass chunks run BETWEEN decode steps, so a
         # long-prompt burst cannot starve in-flight decodes.
         budget = (self.max_chunks_per_pass if self.prefill_chunk
                   else eng.slots)
-        chunks = 0
+        prefills = []
         for slot in range(eng.slots):
             st = slots[slot]
             if st is None or not st.prefilling():
                 continue
-            self._prefill_piece(slot)
-            chunks += 1
-            if chunks >= budget:
+            prefills.append(self._launch_prefill(slot))
+            if len(prefills) >= budget:
                 break
-        active = np.array(
-            [s is not None and not s.prefilling()
-             and bool(s.generated) for s in slots], bool)
-        if not active.any():
-            if any(s is not None for s in slots):
-                return                 # still prefilling: next pass
-            if self.queue:
-                if not blocked:
-                    # slots opened up mid-pass (a request finished
-                    # at its prefill): admit on the next pass
-                    return
-                # nothing running and the picked request still
-                # can't be admitted: the POOL itself is too small
-                # (prefix-cache eviction already ran)
-                req = self.queue[self._pick_index()]
-                raise RuntimeError(
-                    f"request {req.uid} needs more pages than the "
-                    f"pool frees up (prompt {len(req.prompt)} + "
-                    f"budget {req.max_new_tokens} tokens vs "
-                    f"{self.alloc.free_pages} free pages of "
-                    f"{self.alloc.page_size}); grow num_pages or "
-                    f"shrink the request")
-            return
+        spec = bool(getattr(eng, "spec_k", 0))
+        if spec:
+            # the drafter drafts from first tokens the host has read
+            for piece in prefills:
+                self._read_prefill(*piece)
+            prefills = []
         # guard: a slot at its capacity cannot take another token.
         # Lengths are derived host-side (_SlotState.cache_len) — no
         # device readback in the control loop beyond the sampled
-        # tokens themselves.  The decode step's `truncated` output
-        # is the device-side belt to this suspender.
+        # tokens themselves.  It fires once the slot's last token is
+        # READ: can_issue() has kept the slot out of every step past
+        # its capacity, so nothing of it is in flight.  The decode
+        # step's `truncated` output is the device-side belt to this
+        # suspender.
         for slot, st in enumerate(slots):
-            if st is not None and active[slot] \
+            if st is not None and st.generated \
                     and st.cache_len() >= st.capacity:
                 with trace_annotation("apex_tpu.scheduler.retire"):
                     self._retire(slot, REASON_TRUNCATED)
-                active[slot] = False
-        if not active.any():
-            return
+        active = np.array([s is not None and s.can_issue()
+                           for s in slots], bool)
         # counted AFTER the capacity guard: peak_active measures
         # requests that actually decode concurrently this step
         n_active = int(active.sum())
         self.peak_active = max(self.peak_active, n_active)
-        if getattr(eng, "spec_k", 0):
-            # speculative wave (ISSUE 15): drafts in, the verify
-            # step scores one (k+1)-slab per slot, accepted drafts
-            # + bonus come out.  The emitted stream is ALWAYS the
-            # target's own greedy stream; rejection already rolled
-            # the device lengths back in-program, and pages were
-            # reserved at admission so nothing is released here.
-            k = eng.spec_k
-            slab = np.zeros((eng.slots, k + 1), np.int32)
-            slab[:, 0] = self._run_last
-            slab[:, 1:] = self.drafter.draft_batch(active, k)
-            with trace_annotation("apex_tpu.scheduler.verify",
-                                  active=n_active), \
-                    tel.verify_step(n_active,
-                                    capacity=eng.slots) as vstep:
-                self.cache, host, _, _ = eng.verify(
-                    self.cache, slab, active)
-                toks, flags = self._read_step(
-                    host, "verify", eng.slots * (k + 1))
-                toks = toks.reshape(eng.slots, k + 1)
-                n_emit, truncated = flags.reshape(2, eng.slots)
-                # per-token latency back-channel: the bracket's
-                # histogram sample divides by mean emitted/slot.
-                # Clamped the way the consumption loop below will
-                # clamp (capacity AND token budget) so a final
-                # short round cannot under-report per-token
-                # latency; only an eos landing mid-slab (terminal
-                # for the stream) escapes the host-side mirror.
-                vstep["tokens"] = float(sum(
-                    min(int(n_emit[s]),
-                        slots[s].capacity - slots[s].cache_len(),
-                        slots[s].max_new_tokens
-                        - len(slots[s].generated))
-                    for s in range(eng.slots)
-                    if slots[s] is not None and active[s]))
-            with trace_annotation("apex_tpu.scheduler.retire"):
-                for slot, st in enumerate(slots):
-                    if st is None or not active[slot]:
-                        continue
-                    # the host capacity mirror clamps exactly like the
-                    # device's advance_by did (same inputs, same min)
-                    remaining = st.capacity - st.cache_len()
-                    usable = int(min(int(n_emit[slot]), remaining))
-                    emitted = []
-                    reason = None
-                    for t in toks[slot, :usable]:
-                        st.generated.append(int(t))
-                        emitted.append(int(t))
-                        if st.done():
-                            reason = REASON_LENGTH
-                            break
-                    # emitted counts tokens that actually reached the
-                    # request (capacity- AND budget-clamped), so
-                    # spec_emitted == tokens_generated minus the
-                    # prefill-sampled firsts — conservation-testable
-                    tel.speculation(k, int(n_emit[slot]) - 1,
-                                    len(emitted))
-                    if emitted:
-                        self._run_last[slot] = emitted[-1]
-                        self.drafter.observe(slot, emitted)
-                    if reason is not None:
-                        self._retire(slot, reason)
-                    elif usable < int(n_emit[slot]) or truncated[slot]:
-                        # capacity cut the emitted stream short
-                        self._retire(slot, REASON_TRUNCATED)
+        if spec:
+            if n_active:
+                self._verify(active, n_active)
             return
-        # the decode bracket closes after the token host-read the
-        # loop performs anyway, so the histogram sample is the true
-        # per-token latency (dispatch + sync), and its recompile
-        # flag feeds serve_recompiles_total (pinned 0 by tests)
-        with trace_annotation("apex_tpu.scheduler.decode",
+        ahead, self._ahead = self._ahead, None
+        if n_active or ahead is not None:
+            # the decode bracket closes after the token host-read the
+            # loop performs anyway — the read of the step launched a
+            # pass earlier, so in a steady wave its sample is still one
+            # step's period — and its recompile flag feeds
+            # serve_recompiles_total (pinned 0 by tests)
+            with trace_annotation("apex_tpu.scheduler.decode",
+                                  active=n_active), \
+                    (tel.decode_step(n_active, capacity=eng.slots,
+                                     ahead=ahead is not None
+                                     or bool(prefills))
+                     if n_active else contextlib.nullcontext()):
+                if n_active:
+                    # no token is handed over: the step takes the
+                    # device's own (cache.last_tokens)
+                    self.cache, host, _, _ = eng.decode(self.cache, None,
+                                                        active)
+                    for slot in np.flatnonzero(active):
+                        slots[slot].issued += 1
+                    self._ahead = (host, [st if active[slot] else None
+                                          for slot, st in enumerate(slots)])
+                if ahead is not None:
+                    read = self._read_step(ahead[0], "decode", eng.slots)
+            if ahead is not None:
+                self._settle(ahead[1], *read)
+        for piece in prefills:
+            self._read_prefill(*piece)
+
+    def _settle(self, launched, toks, truncated) -> None:
+        """Hand the tokens of a decode step that was just read to the
+        requests that were active in it, and retire those it finished.
+        ``launched`` are the slots' states AT THE LAUNCH (None where
+        inactive): an entry whose slot was retired since (an EOS read one
+        step late) or re-admitted belongs to nobody and is thrown away."""
+        slots = self._run_slots
+        with trace_annotation("apex_tpu.scheduler.retire"):
+            for slot, st in enumerate(launched):
+                if st is None:
+                    continue
+                if slots[slot] is not st:
+                    self.telemetry.ahead_token_discarded()
+                    continue
+                if truncated[slot]:
+                    # the host guard should have kept this slot out of
+                    # the step; trust the device flag regardless
+                    self._retire(slot, REASON_TRUNCATED)
+                    continue
+                st.generated.append(int(toks[slot]))
+                if st.done():
+                    self._retire(slot, REASON_LENGTH)
+
+    def _verify(self, active, n_active: int) -> None:
+        """A speculative wave's step (ISSUE 15), launched and read in the
+        same pass: drafts in, the verify step scores one (k+1)-slab per
+        slot, accepted drafts + bonus come out.  The emitted stream is
+        ALWAYS the target's own greedy stream; rejection already rolled
+        the device lengths back in-program, and pages were reserved at
+        admission so nothing is released here."""
+        eng, tel = self.engine, self.telemetry
+        slots = self._run_slots
+        k = eng.spec_k
+        slab = np.zeros((eng.slots, k + 1), np.int32)
+        for slot in np.flatnonzero(active):
+            slab[slot, 0] = slots[slot].generated[-1]
+        slab[:, 1:] = self.drafter.draft_batch(active, k)
+        with trace_annotation("apex_tpu.scheduler.verify",
                               active=n_active), \
-                tel.decode_step(n_active, capacity=eng.slots):
-            self.cache, host, _, _ = eng.decode(
-                self.cache, self._run_last, active)
-            toks, truncated = self._read_step(host, "decode", eng.slots)
+                tel.verify_step(n_active,
+                                capacity=eng.slots) as vstep:
+            self.cache, host, _, _ = eng.verify(
+                self.cache, slab, active)
+            toks, flags = self._read_step(
+                host, "verify", eng.slots * (k + 1))
+            toks = toks.reshape(eng.slots, k + 1)
+            n_emit, truncated = flags.reshape(2, eng.slots)
+            # per-token latency back-channel: the bracket's
+            # histogram sample divides by mean emitted/slot.
+            # Clamped the way the consumption loop below will
+            # clamp (capacity AND token budget) so a final
+            # short round cannot under-report per-token
+            # latency; only an eos landing mid-slab (terminal
+            # for the stream) escapes the host-side mirror.
+            vstep["tokens"] = float(sum(
+                min(int(n_emit[s]),
+                    slots[s].capacity - slots[s].cache_len(),
+                    slots[s].max_new_tokens
+                    - len(slots[s].generated))
+                for s in range(eng.slots)
+                if slots[s] is not None and active[s]))
         with trace_annotation("apex_tpu.scheduler.retire"):
             for slot, st in enumerate(slots):
                 if st is None or not active[slot]:
                     continue
-                if truncated[slot]:
-                    # the host guard above should have retired this
-                    # slot first; trust the device flag regardless
+                # the host capacity mirror clamps exactly like the
+                # device's advance_by did (same inputs, same min)
+                remaining = st.capacity - st.cache_len()
+                usable = int(min(int(n_emit[slot]), remaining))
+                emitted = []
+                reason = None
+                for t in toks[slot, :usable]:
+                    st.generated.append(int(t))
+                    emitted.append(int(t))
+                    if st.done():
+                        reason = REASON_LENGTH
+                        break
+                # what was launched for the slot has been read
+                st.issued = len(st.generated)
+                # emitted counts tokens that actually reached the
+                # request (capacity- AND budget-clamped), so
+                # spec_emitted == tokens_generated minus the
+                # prefill-sampled firsts — conservation-testable
+                tel.speculation(k, int(n_emit[slot]) - 1,
+                                len(emitted))
+                if emitted:
+                    self.drafter.observe(slot, emitted)
+                if reason is not None:
+                    self._retire(slot, reason)
+                elif usable < int(n_emit[slot]) or truncated[slot]:
+                    # capacity cut the emitted stream short
                     self._retire(slot, REASON_TRUNCATED)
-                    continue
-                st.generated.append(int(toks[slot]))
-                self._run_last[slot] = toks[slot]
-                if st.done():
-                    self._retire(slot, REASON_LENGTH)
 
     def run(self, cache=None) -> dict:
         """Drain the queue; returns ``{uid: generated token list}``.

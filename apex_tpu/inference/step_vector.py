@@ -1,8 +1,10 @@
 """A step's ONE array for the host (ISSUE 35), and nothing else.
 
-Between two device steps the serving loop makes one device→host read:
+For every device step the serving loop makes one device→host read:
 the int32 vector the step lays out here as ``[tokens | flags | stats
-tail]``.  The layout is known in THIS module and nowhere else —
+tail]``.  Since ISSUE 37 a decode step's vector is read one step late —
+the next step is launched first, its input tokens fed back on the device
+(``cache.last_tokens``) — and the layout is what it was.  It is known in THIS module and nowhere else —
 :func:`host_vector` packs it inside the compiled step (or on the host,
 for the protocol audit's stub engine), :func:`peel_step` is the inverse,
 and whoever holds such a vector (the scheduler, a drafter, a benchmark

@@ -86,6 +86,13 @@ METRIC_SPECS: Dict[str, MetricSpec] = {s.name: s for s in [
                "tokens returned to finished requests"),
     MetricSpec("serve_decode_steps_total", "counter",
                "batched decode executions (one token per active slot)"),
+    MetricSpec("serve_decode_steps_ahead_total", "counter",
+               "decode steps launched while an earlier launch's vector "
+               "was still unread (the device one step ahead of the "
+               "host's read)"),
+    MetricSpec("serve_ahead_tokens_discarded_total", "counter",
+               "tokens a step launched ahead computed for a slot that "
+               "had already ended (an EOS is read one step late)"),
     MetricSpec("serve_recompiles_total", "counter",
                "decode steps that triggered a NEW compile after warmup "
                "(must stay 0: decode is ONE donated executable)"),

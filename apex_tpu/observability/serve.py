@@ -201,6 +201,10 @@ class ServeTelemetry:
         self.backpressure_waits = d("serve_backpressure_waits_total")
         self.tokens_generated = d("serve_tokens_generated_total")
         self.decode_steps = d("serve_decode_steps_total")
+        # the device one decode step ahead of the host's read (ISSUE 37)
+        self.decode_steps_ahead = d("serve_decode_steps_ahead_total")
+        self.ahead_tokens_discarded = d(
+            "serve_ahead_tokens_discarded_total")
         self.recompiles = d("serve_recompiles_total")
         self.queue_depth = d("serve_queue_depth")
         self.active_slots = d("serve_active_slots")
@@ -267,10 +271,9 @@ class ServeTelemetry:
         # boundaries the methods below already occupy — arming the
         # tracer (trace= or APEX_TPU_TRACE) adds zero device work
         self.tracer = RequestTracer(reg, sample=trace)
-        # separate timers: prefill legitimately compiles once per prompt
-        # bucket, and must not advance the decode timer past its warmup
+        # the decode steps' own timer: prefill legitimately compiles once
+        # per prompt bucket, and must not advance it past its warmup
         # step (which would mislabel decode's one compile a recompile)
-        self._prefill_timer = StepTimer()
         self._decode_timer = StepTimer()
         self._submit_ts: dict = {}
         self._first_token_seen: set = set()
@@ -415,6 +418,9 @@ class ServeTelemetry:
                      bucket_len: Optional[int] = None,
                      uid: Optional[int] = None, start_tok: int = 0):
         """Bracket one admission's prefill dispatch + first-token read.
+        Brackets may overlap (ISSUE 37: a pass launches its prefills and
+        its decode step before it reads any of them), so each keeps its
+        own clock.
 
         ``prompt_len``/``bucket_len`` (when the scheduler knows them)
         feed the padding-badput counter: the bucket positions beyond
@@ -423,18 +429,17 @@ class ServeTelemetry:
         them) close a ``prefill_chunk`` span on the request's trace —
         one span per dispatched piece, monolithic prefill included."""
         t_begin = time.perf_counter()
-        self._prefill_timer.start()
         try:
             yield
         finally:
-            sample = self._prefill_timer.stop()
-            self.prefill_seconds.observe(sample.seconds)
+            seconds = time.perf_counter() - t_begin
+            self.prefill_seconds.observe(seconds)
             if prompt_len is not None and bucket_len is not None \
                     and bucket_len > prompt_len:
                 self.prefill_pad_tokens.inc(bucket_len - prompt_len)
             if uid is not None:
                 self.tracer.prefill_chunk(
-                    uid, t_begin, sample.seconds, start_tok,
+                    uid, t_begin, seconds, start_tok,
                     prompt_len if prompt_len is not None else 0,
                     bucket=bucket_len)
 
@@ -487,14 +492,27 @@ class ServeTelemetry:
                 self.idle_slot_tokens.inc(capacity - active)
 
     @contextlib.contextmanager
-    def decode_step(self, active: int, capacity: Optional[int] = None):
-        """Bracket one batched decode: dispatch + the scheduler's token
-        read.  One sample = one token per active slot.  ``capacity``
+    def decode_step(self, active: int, capacity: Optional[int] = None,
+                    ahead: bool = False):
+        """Bracket one batched decode: its dispatch + the scheduler's
+        token read of that pass — since ISSUE 37 the read of the step
+        launched a pass EARLIER, so in a steady wave one sample is still
+        one step's period, one token per active slot.  ``capacity``
         (the executable's slot width) feeds the idle-slot badput
-        counter: inactive slots compute masked garbage every step."""
+        counter: inactive slots compute masked garbage every step.
+        ``ahead``: the step was launched while an earlier launch's
+        vector was still unread (the chip had work queued when the host
+        came to wait)."""
+        if ahead:
+            self.decode_steps_ahead.inc()
         with self._step_bracket(self.decode_steps, active, capacity,
                                 spec=False):
             yield
+
+    def ahead_token_discarded(self) -> None:
+        """A step launched ahead computed a token for a slot that had
+        already ended (its EOS was read one step late, ISSUE 37)."""
+        self.ahead_tokens_discarded.inc()
 
     @contextlib.contextmanager
     def verify_step(self, active: int, capacity: Optional[int] = None):

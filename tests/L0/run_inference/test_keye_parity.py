@@ -416,11 +416,14 @@ def test_the_expert_layer_without_a_shared_expert(tiny):
     assert np.array_equal(np.asarray(got), np.asarray(same))
 
 
-#: sha1 of the text of the steps' jaxprs at the parent commit (606b07b):
-#: the other expert kinds trace what they traced before this kind came
+#: sha1 of the text of the steps' jaxprs: the other expert kinds trace
+#: what they traced before this kind came.  Until ISSUE 37 the parent's
+#: (606b07b: f31617db…, 5df58311…); since, every kind's steps carry one
+#: more cache leaf (``last_tokens``: the sampled token written to it, a
+#: decode step's input read from it), and these are the digests with it
 PARENT_JAXPRS = {
-    "laguna": "f31617db88380135c9e1cb16e2ee20306a72845b",
-    "axk1": "5df58311fdadd007ad14d433a2839138820d9b96",
+    "laguna": "7f0c9d20fbec3163b7f40e86d8783d9975b86b43",
+    "axk1": "1dedd3e7ff7d45d7e85ed20e7561453d48e464b3",
 }
 
 

@@ -199,9 +199,12 @@ def host_reads(monkeypatch):
 
 @pytest.mark.parametrize("step", ["decode", "verify"])
 def test_a_pass_makes_one_device_to_host_transfer(step, model, host_reads):
-    """A pass that only decodes (or verifies) reads ONE of the step's
-    outputs, once and explicitly: the ``[tokens | flags | tail]`` vector.
-    The parent read ``toks`` then ``truncated`` (verify: and ``n_emit``)."""
+    """A pass that only decodes (or verifies) reads ONE of a step's
+    outputs, once and explicitly: the ``[tokens | flags | tail]`` vector
+    — a verify step's own, a decode step's of the step the pass BEFORE
+    launched (ISSUE 37: the pass launches the next step first; the order
+    is held in ``test_run_ahead.py``).  Until ISSUE 35 a pass read
+    ``toks`` then ``truncated`` (verify: and ``n_emit``)."""
     k = 3 if step == "verify" else 0
     eng = _engine(model, "gpt", spec_k=k)
     reads = host_reads(eng)
@@ -210,7 +213,7 @@ def test_a_pass_makes_one_device_to_host_transfer(step, model, host_reads):
     for prompt in ([5, 6, 7, 8, 9], [11, 12, 13]):
         sched.submit(prompt, max_new_tokens=24)
     sched.begin_run()
-    sched.run_pass()                    # admits and prefills both, decodes
+    sched.run_pass()            # admits and prefills both, launches a step
     steps = {"decode": sched.telemetry.decode_steps,
              "verify": sched.telemetry.spec_verify_steps}[step]
     done = steps.total()

@@ -85,8 +85,12 @@ def test_a_profile_changes_neither_the_tokens_nor_the_compiles(
     assert spans["apex_tpu.inference.evict_slot"] == requests
     assert spans[SCH + "prefill"] == spans["apex_tpu.inference.prefill"] \
         == requests
-    assert spans[SCH + "decode"] == spans["apex_tpu.inference.decode"] > 0
-    assert spans[SCH + "token_read"] == spans[SCH + "decode"] + requests
+    # one read a launch over the wave; a decode span holds the launch of
+    # a step, the read of the step launched a pass earlier, or both
+    # (ISSUE 37), so a wave has as many more of them as it drains
+    launches = spans["apex_tpu.inference.decode"]
+    assert 0 < launches < spans[SCH + "decode"] <= launches + requests
+    assert spans[SCH + "token_read"] == launches + requests
 
 
 def test_the_spans_work_over_the_auditors_stub_engine(tmp_path):

@@ -150,7 +150,8 @@ def test_kinds_without_window_layers_keep_their_one_pool(kind):
                           num_pages=8)
     cache = eng.init_cache()
     assert cache.wk is None and cache.wv is None and cache.ring == 0
-    assert len(jax.tree_util.tree_leaves(cache)) == 5
+    # k, v, page table, lengths, capacity, last_tokens
+    assert len(jax.tree_util.tree_leaves(cache)) == 6
     assert cache.k.shape[1] == 2            # every layer in the one pool
     assert eng.stats_tail == 0 and eng.supports_prefix_sharing
     tok = np.asarray(eng.prefill(cache, [1, 2, 3], 0, pages=[0, 1])[1])

@@ -482,6 +482,7 @@ def test_v5e_evict_writes_the_metadata_and_copies_no_pool(pool, one_chip):
         k=on(shape, BF16), v=None if latent else on(shape, BF16),
         page_table=on((slots, mpps), jnp.int32),
         lengths=on((slots,), jnp.int32), capacity=on((slots,), jnp.int32),
+        last_tokens=on((slots,), jnp.int32),
         wk=rings and on(rings, BF16), wv=rings and on(rings, BF16),
         ik=on(index[0], BF16) if index else None)
     leaves = len(jax.tree_util.tree_leaves(cache))
