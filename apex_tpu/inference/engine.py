@@ -509,6 +509,8 @@ class InferenceEngine:
         self.params = params
         self._key = jax.random.PRNGKey(seed)
         self._step = 0
+        # (executable, shape) keys whose op -> scope table is kept
+        self._captured = set()
         # dispatch counters are GLOBAL-registry families (engine-level,
         # process-wide — per-wave serving metrics live in the
         # scheduler's ServeTelemetry registry); cached so declared()'s
@@ -627,6 +629,15 @@ class InferenceEngine:
                     in_specs=(cs, P(), sb, sb), out_specs=cs)
                 self._swap_in = jax.jit(self._swap_in_raw,
                                         donate_argnums=(0,))
+
+    def _capture(self, key, jitted, *args) -> None:
+        """At the first dispatch of each compiled shape, keep the op ->
+        scope table of the executable ``jitted`` runs at ``args``
+        (``xla_stats.capture``: it shares the call's one compile; ISSUE
+        38).  Every later dispatch pays one membership test."""
+        if key not in self._captured:
+            self._captured.add(key)
+            obs.xla_stats.capture(jitted, *args)
 
     def _fused_block_dims(self) -> dict:
         """The per-rank layer geometry the fused block kernel would run
@@ -852,9 +863,11 @@ class InferenceEngine:
         # with the request tracer's prefill_chunk spans (ISSUE 13).
         self._refresh_dispatch_counters()
         self._prefill_dispatches.inc()
+        args += (self._key, self._next_step())
+        self._capture(("prefill", bucket), self._prefill, *args)
         with obs.trace_annotation("apex_tpu.inference.prefill",
                                   slot=int(slot), prefill_from=start):
-            return self._prefill(*args, self._key, self._next_step())
+            return self._prefill(*args)
 
     def cow_page(self, cache, src, dst):
         """Copy-on-write page duplication (paged mode): copy physical
@@ -870,9 +883,11 @@ class InferenceEngine:
                              "this engine runs the dense slot cache")
         self._refresh_dispatch_counters()
         self._cow_dispatches.inc()
+        args = (cache, np.int32(src), np.int32(dst))
+        self._capture("cow", self._cow, *args)
         with obs.trace_annotation("apex_tpu.inference.cow_page",
                                   src=int(src), dst=int(dst)):
-            return self._cow(cache, np.int32(src), np.int32(dst))
+            return self._cow(*args)
 
     def evict_slot(self, cache, slot: int):
         """Device-side metadata evict of one slot (paged or dense):
@@ -890,6 +905,7 @@ class InferenceEngine:
         whole lifecycle without a device."""
         self._refresh_dispatch_counters()
         self._evict_dispatches.inc()
+        self._capture("evict", self._evict, cache, np.int32(slot))
         with obs.trace_annotation("apex_tpu.inference.evict_slot",
                                   slot=int(slot)):
             return self._evict(cache, np.int32(slot))
@@ -1024,10 +1040,11 @@ class InferenceEngine:
                   else self.params)
         tokens = (None if last_tokens is None
                   else np.asarray(last_tokens, np.int32))
+        args = (cache, params, tokens, np.asarray(active, bool),
+                self._key, self._next_step())
+        self._capture(("decode", tokens is None), self._decode, *args)
         with obs.trace_annotation("apex_tpu.inference.decode"):
-            return self._decode(cache, params, tokens,
-                                np.asarray(active, bool),
-                                self._key, self._next_step())
+            return self._decode(*args)
 
     def verify(self, cache, slab, active=None):
         """One speculative-verify step (ISSUE 15): ``slab [slots,
@@ -1057,11 +1074,12 @@ class InferenceEngine:
             active = np.ones((self.slots,), bool)
         self._refresh_dispatch_counters()
         self._verify_dispatches.inc()
+        args = (cache, self.params, slab, np.asarray(active, bool),
+                self._key, self._next_step())
+        self._capture("verify", self._verify, *args)
         with obs.trace_annotation("apex_tpu.inference.verify",
                                   k=self.spec_k):
-            return self._verify(cache, self.params, slab,
-                                np.asarray(active, bool),
-                                self._key, self._next_step())
+            return self._verify(*args)
 
     def generate(self, prompts, max_new_tokens: int = 16,
                  eos_id: Optional[int] = None):

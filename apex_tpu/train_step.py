@@ -44,6 +44,7 @@ from apex_tpu.amp.scaler import (
     unscale_flat_grads,
     update_scale,
 )
+from apex_tpu.observability import xla_stats
 from apex_tpu.optimizers.functional import (FlatState, _layout_master,
                                             _normalize_prefetch)
 
@@ -207,6 +208,9 @@ def make_train_step(loss_fn, tx, *, has_aux: bool = False,
     """
 
     def step(state: TrainState, batch):
+        # its caller jits it: the op -> scope table is captured when the
+        # tables are read (ISSUE 38), from the shapes of this trace
+        xla_stats.capture_when_read(step, state, batch, donate_argnums=(0,))
         opt, scaler = state.opt, state.scaler
         scale = (scaler.loss_scale if scaler is not None
                  else jnp.float32(1.0))
@@ -219,6 +223,12 @@ def make_train_step(loss_fn, tx, *, has_aux: bool = False,
         n, padded = opt.global_numel, (opt.padded_numel if zero else 0)
 
         def flat_loss(flat):
+            # the backward of everything in here is named
+            # transpose(jvp(apex_train_forward)) in the compiled program
+            with jax.named_scope("apex_train_forward"):
+                return forward(flat)
+
+        def forward(flat):
             full = flat.astype(opt.flat_dtype)
             if zero and dp > 1:
                 if opt.spans:
@@ -270,13 +280,17 @@ def make_train_step(loss_fn, tx, *, has_aux: bool = False,
             # fused unscale + overflow detection; found_inf feeds the
             # update kernel's noop predicate in-program (pmax'd
             # replica-uniform under ZeRO)
-            flat_g, scaler = unscale_flat_grads(
-                flat_g, scaler,
-                axis_name=axis if zero and dp > 1 else None)
-            new_opt = tx.update(opt, flat_g, noop_flag=scaler.found_inf)
-            scaler = update_scale(scaler)
+            with jax.named_scope("apex_train_unscale"):
+                flat_g, scaler = unscale_flat_grads(
+                    flat_g, scaler,
+                    axis_name=axis if zero and dp > 1 else None)
+            with jax.named_scope("apex_train_optimizer"):
+                new_opt = tx.update(opt, flat_g,
+                                    noop_flag=scaler.found_inf)
+                scaler = update_scale(scaler)
         else:
-            new_opt = tx.update(opt, flat_g)
+            with jax.named_scope("apex_train_optimizer"):
+                new_opt = tx.update(opt, flat_g)
         probes = None
         if numerics:
             # in-program numerics probes over the UNSCALED grads the
