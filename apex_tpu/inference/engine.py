@@ -168,8 +168,12 @@ def make_prefill_fn(kind: str, cfg, sampling: SamplingConfig,
             logits, ks, vs, wks, wvs, iks, stats = models.prefill_forward(
                 kind, cfg, params, tokens[None], length, tp=tp, **suffix)
         with obs.named_scope("apex_prefill_cache_insert"):
-            cache = kv_cache.insert_tokens(cache, slot, ks, vs, length,
-                                           row, prefill_from, iks)
+            # a kind that never resumes starts on page 0: its write is the
+            # aligned one, chosen here with no branch; a kind that shares
+            # picks aligned or mid-page by the traced start, in-program
+            cache = kv_cache.insert_tokens(
+                cache, slot, ks, vs, length, row,
+                prefill_from if shares else 0, iks)
             if rings:
                 cache = kv_cache.insert_window(cache, slot, wks, wvs,
                                                length)
@@ -661,6 +665,8 @@ class InferenceEngine:
             self._tel_registry = reg
             self._prefill_dispatches = reg.declared(
                 "infer_prefill_dispatch_total")
+            self._prefill_aligned = reg.declared(
+                "serve_prefill_aligned_total")
             self._decode_dispatches = reg.declared(
                 "infer_decode_dispatch_total")
             self._cow_dispatches = reg.declared(
@@ -863,6 +869,8 @@ class InferenceEngine:
         # with the request tracer's prefill_chunk spans (ISSUE 13).
         self._refresh_dispatch_counters()
         self._prefill_dispatches.inc()
+        if self.paged and start % self.page_size == 0:
+            self._prefill_aligned.inc()
         args += (self._key, self._next_step())
         self._capture(("prefill", bucket), self._prefill, *args)
         with obs.trace_annotation("apex_tpu.inference.prefill",

@@ -693,9 +693,82 @@ def page_row(page_ids: Sequence[int], max_pages_per_slot: int,
                       np.int32)
 
 
+def _slot_pages(cache: PagedKVCache, row, first, n: int):
+    """The physical pages behind the slot's page ordinals ``[first, first
+    + n)`` (``first`` traced OK).  Ordinals past the virtual window get an
+    OUT-OF-BOUNDS page index so a ``mode="drop"`` scatter discards them —
+    clamping them onto the last owned page would clobber live rows
+    whenever the slab fills the window; ordinals past the reservation hold
+    the trash page by construction."""
+    mpps = cache.max_pages_per_slot
+    if isinstance(first, (int, np.integer)) and first + n <= mpps:
+        return row[first:first + n]
+    ords = first + jnp.arange(n, dtype=jnp.int32)
+    return jnp.where(ords < jnp.int32(mpps),
+                     jnp.take(row, jnp.minimum(ords, jnp.int32(mpps - 1))),
+                     jnp.int32(cache.pages))
+
+
+def _as_pages(pool, x, n: int):
+    """A slab that starts on a page boundary as ``n`` whole pages of
+    ``pool``'s layout: ``[layers, kvh, n * ps, d] -> [n, layers, kvh, ps,
+    d]``, or ``[layers, n * ps, w] -> [n, layers, w, ps]`` for a pool whose
+    pages hold their positions on the minor axis — a free reshape and one
+    transpose; no page of the pool is read."""
+    ps = x.shape[-2] // n
+    if _rows_minor(pool):
+        slab = jnp.moveaxis(jnp.swapaxes(x, -1, -2).reshape(
+            *x.shape[:-2], x.shape[-1], n, ps), -2, 0)
+    else:
+        slab = jnp.moveaxis(
+            x.reshape(*x.shape[:-2], n, ps, x.shape[-1]), -3, 0)
+    return slab.astype(pool.dtype)
+
+
+def _rows_in_pages(pool, x, ids, off):
+    """A slab that starts at row ``off`` of the first of the pages ``ids``,
+    with the rows of those pages around it: its rows are contiguous in the
+    pages' row space, so gather the pages, drop the slab in with one
+    ``dynamic_update_slice``, and hand the pages' rows back in the slab's
+    own layout (``[..., n * ps, d]``) — the rows below ``off`` (the copied
+    prefix of a boundary page) as they were."""
+    n, ps = ids.shape[0], pool.shape[-1 if _rows_minor(pool) else -2]
+    pages = jnp.take(pool, ids, axis=0, mode="clip")
+    zero = jnp.int32(0)
+    if _rows_minor(pool):
+        # [n, layers, w, ps] -> [layers, w, n * ps]: the positions lie
+        # along the minor axis, token t at off + t
+        layers, _, w = x.shape
+        flat = jnp.moveaxis(pages, 0, -2).reshape(layers, w, n * ps)
+        flat = jax.lax.dynamic_update_slice(
+            flat, jnp.swapaxes(x, -1, -2).astype(pool.dtype),
+            (zero, zero, off))
+        return jnp.swapaxes(flat, -1, -2)
+    # [n, layers, kvh, ps, d] -> [layers, kvh, n * ps, d]: token t of the
+    # slab sits at row off + t
+    layers, kvh, _, d = x.shape
+    flat = jnp.moveaxis(pages, 0, 2).reshape(layers, kvh, n * ps, d)
+    return jax.lax.dynamic_update_slice(
+        flat, x.astype(pool.dtype), (zero, zero, off, zero))
+
+
+def _write_pages(pool, ids, pages):
+    """THE write of every prefill: whole pages into the pool, ONE scatter
+    on the page axis — never a row scatter, which indexed on (page,
+    row-in-page), dims 0 and 3, makes XLA relayout the entire pool around
+    it: one pool-sized temporary and two pool-sized copies per call
+    (observed on the v5e: 2 GiB of temporaries for a 4 GiB pool; a 24 GiB
+    pool over tp=4 could not prefill).  ``pages`` holds one entry
+    per page id of ``ids``; an id past the pool is dropped, and the trash
+    page appearing more than once just stacks garbage."""
+    return pool.at[ids].set(pages, mode="drop")
+
+
 def insert_pages(cache: PagedKVCache, slot, k, v, length,
                  row, ik=None) -> PagedKVCache:
-    """Prefill write: park a prompt's k/v into the slot's pages.
+    """Prefill write of a cold prompt: park its k/v into the slot's pages
+    from position 0 — :func:`insert_tokens` at ``start = 0``, whose
+    aligned write it is.
 
     ``k``/``v``: ``[layers, kv_heads, s, head_dim]`` with ``s`` the
     bucket-padded prompt length — ``s`` must tile into whole pages (the
@@ -711,73 +784,54 @@ def insert_pages(cache: PagedKVCache, slot, k, v, length,
     pool.
     """
     ps, s = cache.page_size, k.shape[-2]
-    _check_rows(cache, k, v, "prefill k/v", (cache.layers,), ik)
     if s % ps or s > cache.max_seq:
         raise ValueError(
             f"prompt slab length {s} must be a multiple of page_size "
             f"{ps} and <= max_seq {cache.max_seq}")
-    row = jnp.asarray(row, jnp.int32)
-    if row.shape != (cache.max_pages_per_slot,):
-        raise ValueError(
-            f"page row must be [{cache.max_pages_per_slot}], got "
-            f"{tuple(row.shape)}")
-    slot = jnp.asarray(slot, jnp.int32)
-    zero = jnp.int32(0)
-    n = s // ps
-
-    def write(pool, x):
-        # [layers, kvh, s, d] -> [n, layers, kvh, ps, d]: one entry per
-        # bucket page, scattered to its physical page in ONE op (bucket
-        # overhang beyond the reservation targets the trash page; the
-        # trash page appearing more than once just stacks garbage)
-        if _rows_minor(pool):   # [layers, s, w] -> [n, layers, w, ps]
-            slab = jnp.moveaxis(jnp.swapaxes(x, -1, -2).reshape(
-                *x.shape[:-2], x.shape[-1], n, ps), -2, 0)
-        else:
-            slab = jnp.moveaxis(
-                x.reshape(*x.shape[:-2], n, ps, x.shape[-1]), -3, 0)
-        return pool.at[row[:n]].set(slab.astype(pool.dtype), mode="drop")
-
-    pools = _pools(cache, write, k, v, ik)
-    owned = jnp.sum((row != cache.null_page).astype(jnp.int32))
-    return cache.replace(
-        **pools,
-        page_table=jax.lax.dynamic_update_slice(
-            cache.page_table, row[None], (slot, zero)),
-        lengths=jax.lax.dynamic_update_slice(
-            cache.lengths, jnp.asarray(length, jnp.int32)[None], (slot,)),
-        capacity=jax.lax.dynamic_update_slice(
-            cache.capacity, (owned * ps)[None], (slot,)))
+    return insert_tokens(cache, slot, k, v, length, row, 0, ik)
 
 
 def insert_tokens(cache: PagedKVCache, slot, k, v, length, row,
                   start, ik=None) -> PagedKVCache:
-    """Suffix prefill write (ISSUE 12): scatter a bucket-padded slab of
-    ``s`` token rows into the slot's pages at positions ``[start,
-    start + s)`` — ANY alignment, so a prefix-cache hit can resume
-    mid-page after its boundary COW.
+    """Prefill write: put a bucket-padded slab of ``s`` token rows into
+    the slot's pages at positions ``[start, start + s)``.
 
     ``k``/``v``: ``[layers, kv_heads, s, head_dim]``; ``start`` (traced
     OK) is the first virtual position the slab covers — ``0`` for a
     cold prefill, the shared-prefix coverage for a hit, a chunk
     boundary for chunked prefill.  ``length`` is the slot's TOTAL live
-    length after this write (prefix + real suffix tokens).  Unlike
-    :func:`insert_pages`' page-aligned slab scatter, the slab may start
-    mid-page: the pages it touches are gathered, the slab's rows
-    (contiguous in those pages' row space) dropped in, and the pages
-    scattered back whole — positions past the reservation land in the
-    trash page exactly like the slab insert's bucket overhang, and
-    rows mapping into SHARED prefix pages never occur by contract (the
-    scheduler COWs the boundary page before admitting a mid-page
-    suffix, so every touched page is private or trash).
+    length after this write (prefix + real suffix tokens).
 
-    The page-table row, lengths, and capacity update exactly as in
-    :func:`insert_pages` (capacity derived in-program from the owned
-    entries), so one compiled insert serves every page assignment and
-    every ``start``.  ``ik`` ``[layers, s, index_width]``: the slab's
-    index keys, for a cache with that pool.
+    Every write ends in :func:`_write_pages`, one scatter of whole pages
+    on the page axis; what it is handed depends on ``start``:
+
+    * **Aligned** (``start`` a multiple of ``page_size`` and ``s`` whole
+      pages — every cold prefill, every chunk, every hit on a page
+      boundary): the slab itself as pages (:func:`_as_pages`, a reshape
+      and a transpose); no page of the pool is read.
+    * **Mid-page** (a prefix-cache hit resuming inside a page after its
+      boundary COW): the ``ceil(s / page_size) + 1`` pages the slab
+      touches, read and rewritten with the slab inside them
+      (:func:`_rows_in_pages`), which keeps the copied prefix rows below
+      ``start`` in the boundary page.  Rows mapping into SHARED prefix
+      pages never occur by contract (the scheduler COWs the boundary page
+      before admitting a mid-page suffix, so every touched page is
+      private or trash).
+
+    Both leave every live row alike.  A ``start`` known when the program
+    is traced (a python int: a kind that never resumes passes 0) picks
+    its write there, with no branch; a traced one picks it with one
+    ``lax.cond`` a pool array, so one compiled insert still serves every
+    ``start``.  Positions past the reservation land in the trash page;
+    past the virtual window they are dropped.
+
+    The page-table row, lengths, and capacity update alike on both
+    (capacity derived in-program from the owned entries), so one compiled
+    insert serves every page assignment.  ``ik``
+    ``[layers, s, index_width]``: the slab's index keys, for a cache with
+    that pool.
     """
-    ps, mpps, s = cache.page_size, cache.max_pages_per_slot, k.shape[-2]
+    ps, s = cache.page_size, k.shape[-2]
     _check_rows(cache, k, v, "prefill k/v", (cache.layers,), ik)
     if s < 1 or s > cache.max_seq:
         raise ValueError(
@@ -788,57 +842,50 @@ def insert_tokens(cache: PagedKVCache, slot, k, v, length, row,
         raise ValueError(
             f"page row must be [{cache.max_pages_per_slot}], got "
             f"{tuple(row.shape)}")
-    slot = jnp.asarray(slot, jnp.int32)
-    start = jnp.asarray(start, jnp.int32)
-    # Read-modify-write of WHOLE pages, never a row scatter into the
-    # pool: a scatter indexed on (page, row-in-page) — dims 0 and 3 —
-    # makes XLA relayout the entire pool around it, one pool-sized
-    # temporary and two pool-sized copies per call (observed on the
-    # v5e, PR 21: 2 GiB of temporaries for a 4 GiB pool; a 24 GiB pool
-    # over tp=4 could not prefill).  The slab's rows are contiguous in
-    # the row space of the n pages it touches, so: gather those pages,
-    # drop the slab in with one dynamic_update_slice, and put the pages
-    # back with a scatter on the leading (page) dim only.
-    n = -(-s // ps) + 1           # pages s rows can touch, any alignment
-    ords = start // ps + jnp.arange(n, dtype=jnp.int32)
-    # ordinals past the virtual window get an OUT-OF-BOUNDS page index
-    # so mode="drop" discards them — clamping them onto the last owned
-    # page would clobber live rows whenever the prompt fills the window;
-    # ordinals past the reservation hold the trash page by construction
-    page_ids = jnp.where(
-        ords < jnp.int32(mpps),
-        jnp.take(row, jnp.minimum(ords, jnp.int32(mpps - 1))),
-        jnp.int32(cache.pages))
+    static = isinstance(start, (int, np.integer))
+    if not static:
+        start = jnp.asarray(start, jnp.int32)
+    first, off, n = start // ps, start % ps, -(-s // ps)
 
-    def write(pool, x):
-        slab = jnp.take(pool, page_ids, axis=0, mode="clip")
-        zero = jnp.int32(0)
-        if _rows_minor(pool):
-            # [n, layers, w, ps] -> [layers, w, n * ps]: the positions
-            # lie along the minor axis, token t at (start % ps) + t
-            layers, _, w = x.shape
-            flat = jnp.moveaxis(slab, 0, -2).reshape(layers, w, n * ps)
-            flat = jax.lax.dynamic_update_slice(
-                flat, jnp.swapaxes(x, -1, -2).astype(pool.dtype),
-                (zero, zero, start % ps))
-            slab = jnp.moveaxis(flat.reshape(layers, w, n, ps), -2, 0)
-            return pool.at[page_ids].set(slab, mode="drop")
-        layers, kvh, _, d = x.shape
-        # [n, layers, kvh, ps, d] -> [layers, kvh, n * ps, d]: token t
-        # of the slab sits at row (start % ps) + t
-        flat = jnp.moveaxis(slab, 0, 2).reshape(layers, kvh, n * ps, d)
-        flat = jax.lax.dynamic_update_slice(
-            flat, x.astype(pool.dtype), (zero, zero, start % ps, zero))
-        slab = jnp.moveaxis(flat.reshape(layers, kvh, n, ps, d), 2, 0)
-        return pool.at[page_ids].set(slab, mode="drop")
+    def aligned(pool, x):
+        return _write_pages(pool, _slot_pages(cache, row, first, n),
+                            _as_pages(pool, x, n))
 
+    def mid_page(pool, x):
+        ids = _slot_pages(cache, row, first, n + 1)
+        return _write_pages(pool, ids, _as_pages(
+            pool, _rows_in_pages(pool, x, ids, off), n + 1))
+
+    def either(pool, x):
+        # the cond picks the ROWS to write: the slab as it is, or the slab
+        # with its pages' rows around it.  The pool is read in it and
+        # written after it (a branch that wrote the pool would be handed
+        # a pool-sized copy on the v5e): the first n pages, then the page
+        # after them, which only a mid-page start fills (an out-of-bounds
+        # id drops the aligned write's empty one)
+        ids = _slot_pages(cache, row, first, n + 1)
+
+        def around(pool, x):
+            rows = _rows_in_pages(pool, x, ids, off)
+            return rows[..., :n * ps, :], rows[..., n * ps:, :]
+
+        head, tail = jax.lax.cond(
+            off == 0, lambda pool, x: (x.astype(pool.dtype), jnp.zeros_like(
+                x[..., :ps, :], pool.dtype)), around, pool, x)
+        pool = _write_pages(pool, ids[:n], _as_pages(pool, head, n))
+        return _write_pages(
+            pool, jnp.where(off == 0, jnp.int32(cache.pages), ids[n:]),
+            _as_pages(pool, tail, 1))
+
+    write = (mid_page if s % ps or (static and off)
+             else aligned if static else either)
     pools = _pools(cache, write, k, v, ik)
+    slot = jnp.asarray(slot, jnp.int32)
     owned = jnp.sum((row != cache.null_page).astype(jnp.int32))
-    zero = jnp.int32(0)
     return cache.replace(
         **pools,
         page_table=jax.lax.dynamic_update_slice(
-            cache.page_table, row[None], (slot, zero)),
+            cache.page_table, row[None], (slot, jnp.int32(0))),
         lengths=jax.lax.dynamic_update_slice(
             cache.lengths, jnp.asarray(length, jnp.int32)[None], (slot,)),
         capacity=jax.lax.dynamic_update_slice(

@@ -154,6 +154,12 @@ METRIC_SPECS: Dict[str, MetricSpec] = {s.name: s for s in [
     MetricSpec("serve_prefill_chunks_total", "counter",
                "chunked-prefill continuation chunks dispatched "
                "(long prompts split so decode steps interleave)"),
+    MetricSpec("serve_prefill_aligned_total", "counter",
+               "paged prefill dispatches whose slab starts on a page "
+               "boundary, so the K/V write is one whole-page scatter "
+               "(the rest read-modify-write the pages they touch); "
+               "counted by InferenceEngine.prefill in the process-wide "
+               "registry, beside infer_prefill_dispatch_total"),
     MetricSpec("serve_tenant_admitted_total", "counter",
                "requests admitted, keyed by tenant (fairness "
                "observable under overload)", labels=("tenant",)),

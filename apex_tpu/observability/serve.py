@@ -233,6 +233,9 @@ class ServeTelemetry:
         self.prefix_evictions = d("serve_prefix_cache_evictions_total")
         self.cow_copies = d("serve_cow_copies_total")
         self.prefill_chunks = d("serve_prefill_chunks_total")
+        # paged prefills written as whole pages: the engine counts them
+        # in the process-wide registry, the default here
+        self.prefill_aligned = d("serve_prefill_aligned_total")
         self.tenant_admitted = d("serve_tenant_admitted_total")
         self.tenant_rejected = d("serve_tenant_rejected_total")
         self.shed = d("serve_requests_shed_total")

@@ -418,12 +418,14 @@ def test_the_expert_layer_without_a_shared_expert(tiny):
 
 #: sha1 of the text of the steps' jaxprs: the other expert kinds trace
 #: what they traced before this kind came.  Until ISSUE 37 the parent's
-#: (606b07b: f31617db…, 5df58311…); since, every kind's steps carry one
-#: more cache leaf (``last_tokens``: the sampled token written to it, a
-#: decode step's input read from it), and these are the digests with it
+#: (606b07b: f31617db…, 5df58311…); then every kind's steps carried one
+#: more cache leaf (``last_tokens``: 7f0c9d20…, 1dedd3e7…); now a prefill
+#: of a kind that never resumes writes its K/V as whole pages, with no
+#: gather of the pages it writes (the decode steps are unchanged), and
+#: these are the digests with it
 PARENT_JAXPRS = {
-    "laguna": "7f0c9d20fbec3163b7f40e86d8783d9975b86b43",
-    "axk1": "1dedd3e7ff7d45d7e85ed20e7561453d48e464b3",
+    "laguna": "4283809ef6580852bfe719214b0715a2b51a3733",
+    "axk1": "3789250dc24b4f4e9e356a3ee791799b2d3d3dd2",
 }
 
 

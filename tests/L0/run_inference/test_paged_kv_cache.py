@@ -232,19 +232,92 @@ def test_pool_is_scan_carryable():
 # token-granular suffix insert + copy-on-write (ISSUE 12)
 # --------------------------------------------------------------------------
 
-def test_insert_tokens_cold_matches_slab_insert():
-    """start=0 insert_tokens places exactly what insert_pages places —
-    the cold path is the slab path at token granularity."""
-    k = _rand((LAYERS, KVH, 2 * PS, D), 1)
-    v = _rand((LAYERS, KVH, 2 * PS, D), 2)
-    a = kv_cache.insert_pages(_cache(), 1, k, v, 5, _row([4, 1]))
-    b = kv_cache.insert_tokens(_cache(), 1, k, v, 5, _row([4, 1]), 0)
-    np.testing.assert_array_equal(np.asarray(a.k), np.asarray(b.k))
-    np.testing.assert_array_equal(np.asarray(a.v), np.asarray(b.v))
-    np.testing.assert_array_equal(np.asarray(a.page_table),
-                                  np.asarray(b.page_table))
-    assert np.asarray(b.lengths).tolist() == [0, 5, 0]
-    assert np.asarray(b.capacity).tolist() == [0, 2 * PS, 0]
+#: the three pool layouts a prefill writes: K/V, a latent pool with no
+#: values, K/V beside index keys (widths of the latent row / index key)
+_LAYOUTS = {"kv": {}, "latent": {"latent": 12}, "index": {"index": 6}}
+
+
+def _filled(layout):
+    """A cache of ``layout`` whose every pool row holds data: what a write
+    must keep or replace is then visible row by row."""
+    c = _cache(**_LAYOUTS[layout])
+    return c.replace(**{name: _rand(getattr(c, name).shape, seed)
+                        for seed, name in enumerate(("k", "v", "ik"), 20)
+                        if getattr(c, name) is not None})
+
+
+def _slabs(c, s, seed):
+    """A prefill's ``(k, v, ik)`` of ``s`` positions for cache ``c``."""
+    rows = (LAYERS, s, c.head_dim) if c.latent else (LAYERS, KVH, s, D)
+    return (_rand(rows, seed), None if c.latent else _rand(rows, seed + 1),
+            None if c.ik is None else _rand((LAYERS, s, c.ik.shape[2]),
+                                            seed + 2))
+
+
+def _rmw_pools(c, k, v, row, start, ik):
+    """The pools after the read-modify-write of the pages a slab touches,
+    whatever its start: the reference the aligned write is held to."""
+    ps, s = c.page_size, k.shape[-2]
+    ids = kv_cache._slot_pages(c, row, start // ps, -(-s // ps) + 1)
+    return kv_cache._pools(c, lambda pool, x: kv_cache._write_pages(
+        pool, ids, kv_cache._as_pages(pool, kv_cache._rows_in_pages(
+            pool, x, ids, start % ps), ids.shape[0])), k, v, ik)
+
+
+#: slab length and pages the slot owns, by case: a slab inside the
+#: reservation with an owned page after it; one overhanging the
+#: reservation into the trash page; one overhanging the virtual window,
+#: whose rows past it are dropped, not clamped
+_OVERHANG = {"within": (PS, MPPS), "reservation": (2 * PS, None),
+             "window": (MPPS * PS, MPPS)}
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["static", "traced"])
+@pytest.mark.parametrize("case", sorted(_OVERHANG))
+@pytest.mark.parametrize("start", [0, PS, 2 * PS, PS + 1])
+@pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+def test_aligned_write_matches_the_read_modify_write(layout, start, case,
+                                                     traced):
+    """A slab that starts on a page boundary is written with one scatter of
+    whole pages, and every row it leaves live is bitwise what the
+    read-modify-write of the pages it touches leaves: every page but the
+    trash page, the page table, lengths and capacity are identical.  A
+    start mid-page still takes the read-modify-write (the prefix rows
+    below it in its boundary page are kept).  ``static``: a python start,
+    as a kind that never resumes passes; ``traced``: a jitted one, which
+    the insert's ``cond`` decides on."""
+    c = _filled(layout)
+    s, owned = _OVERHANG[case]
+    owned = owned or start // PS + 1
+    row = _row([5, 0, 3, 1][:owned])
+    k, v, ik = _slabs(c, s, 7)
+    length = min(start + s - 1, owned * PS)
+    insert = (jax.jit(kv_cache.insert_tokens) if traced
+              else kv_cache.insert_tokens)
+    got = insert(c, 1, k, v, length, row, start, ik)
+    want = _rmw_pools(c, k, v, jnp.asarray(row), start, ik)
+    for name in ("k", "v", "ik"):
+        if getattr(c, name) is not None:
+            np.testing.assert_array_equal(
+                np.asarray(getattr(got, name))[:-1],
+                np.asarray(want[name])[:-1], err_msg=name)
+    assert np.asarray(got.page_table).tolist() == [
+        [PAGES] * MPPS, row.tolist(), [PAGES] * MPPS]
+    assert np.asarray(got.lengths).tolist() == [0, length, 0]
+    assert np.asarray(got.capacity).tolist() == [0, owned * PS, 0]
+    # the slab's first row landed at its start, in its first page, and a
+    # mid-page start kept the rows below it there (row axis leading)
+    page = row[start // PS]
+    rows = lambda pool: np.moveaxis(  # noqa: E731
+        np.asarray(pool)[page], -1 if c.latent else 2, 0)
+    np.testing.assert_array_equal(rows(got.k)[start % PS],
+                                  np.moveaxis(np.asarray(k), -2, 0)[0])
+    np.testing.assert_array_equal(rows(got.k)[:start % PS],
+                                  rows(c.k)[:start % PS])
+    if start == 0 and not traced:
+        # a cold prompt's insert is this write
+        cold = kv_cache.insert_pages(c, 1, k, v, length, row, ik)
+        np.testing.assert_array_equal(np.asarray(cold.k), np.asarray(got.k))
 
 
 def test_insert_tokens_mid_page_preserves_earlier_rows():

@@ -157,6 +157,27 @@ def test_retire_releases_survivor_decode_bitwise_unchanged(engine):
     assert run(with_churn=True) == run(with_churn=False)
 
 
+def test_aligned_prefills_are_counted(engine):
+    """``serve_prefill_aligned_total`` counts the prefills whose slab
+    starts on a page boundary (their K/V go in as whole pages): a cold
+    prefill and a chunk at a page boundary, and not the suffix of a hit
+    that resumes mid-page.  The engine counts in the process-wide
+    registry, which a ``ServeTelemetry`` built on it reads as its own."""
+    from apex_tpu import observability as obs
+    reg = obs.global_registry()
+    aligned = ServeTelemetry(reg).prefill_aligned
+    assert aligned is reg.declared("serve_prefill_aligned_total")
+    dispatched = reg.declared("infer_prefill_dispatch_total")
+    a0, d0 = aligned.total(), dispatched.total()
+    alloc, cache = engine.new_allocator(), engine.init_cache()
+    prompt = PREFIX + [1, 2, 3]                      # 27 tokens, ps 8
+    for slot, start in ((0, 0), (1, 16), (2, 25)):   # cold, chunk, hit
+        cache = engine.prefill(cache, prompt, slot, pages=alloc.acquire(4),
+                               prefill_from=start)[0]
+        assert aligned.total() - a0 == (1 if slot == 0 else 2)
+    assert dispatched.total() - d0 == 3
+
+
 def test_chunked_prefill_interleaves_decode_steps(engine):
     """SLO path (ISSUE 12 satellite): a long prompt admitted behind a
     decoding stream prefills in chunks with decode steps interleaved —

@@ -187,26 +187,16 @@ def test_v5e_compiles_a_custom_call_under_the_kernels_name(
                for line in calls), [c[:120] for c in calls]
 
 
-def test_v5e_gpt_decode_step_reads_the_pool_in_place(one_chip, monkeypatch):
-    """A paged ``gpt`` decode step compiled for the chip (ISSUE 31): one
-    ``apex_paged_decode`` custom call a layer, each reading the WHOLE pool
-    where ``append_layer`` wrote it — no copy, slice or any other op whose
-    result is pool-sized besides the appends' in-place updates.  (Interpret
-    mode cannot show this: there the kernel is a loop that carries the
-    pool.)"""
-    import apex_tpu.ops.attention as at
-    import apex_tpu.ops.paged_attention as pa
+def _toy_gpt(one_chip):
+    """A paged ``gpt`` for the described chip at a pool of 0.4 GB (one
+    small enough is prefetched to on-chip memory): ``(cfg, params, cache,
+    the pool's type)``, shapes only."""
     from apex_tpu.inference import kv_cache
-    from apex_tpu.inference.engine import make_decode_fn
-    from apex_tpu.inference.sampling import SamplingConfig
     from apex_tpu.transformer import parallel_state
     from apex_tpu.transformer.testing import GPTConfig, gpt_model_provider
 
-    for mod in (at, ln, pa):
-        monkeypatch.setattr(mod, "interpret_mode", lambda: False)
     if not parallel_state.model_parallel_is_initialized():
         parallel_state.initialize_model_parallel(1)
-    # a pool of 0.4 GB: one small enough is prefetched to on-chip memory
     layers, heads, d, ps, slots, pages = 3, 2, 128, 64, 8, 4096
     cfg = GPTConfig(vocab_size=256, hidden_size=heads * d, num_layers=layers,
                     num_attention_heads=heads, max_seq_length=512,
@@ -220,6 +210,57 @@ def test_v5e_gpt_decode_step_reads_the_pool_in_place(one_chip, monkeypatch):
         lambda: kv_cache.init_paged_cache(
             pages, layers, heads, ps, d, slots=slots,
             max_pages_per_slot=512 // ps)))
+    return cfg, params, cache, f"bf16[{pages + 1},{layers},{heads},{ps},{d}]"
+
+
+def _toy_axk1(one_chip):
+    """A paged ``axk1`` for the described chip at the published latent row
+    (512 + 64) and page, a pool of 0.45 GB: ``(cfg, params, cache, the
+    pool's type)``, shapes only."""
+    from apex_tpu.inference import kv_cache
+    from apex_tpu.transformer.testing import standalone_axk1 as SA
+    from apex_tpu.transformer.testing.standalone_laguna import YarnRope
+
+    layers, slots, ps, pages, mpps = 3, 8, 256, 512, 4
+    cfg = SA.AXK1Config(
+        vocab_size=256, hidden_size=256, num_layers=layers, num_heads=8,
+        q_lora_rank=128, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, ffn_hidden_size=256,
+        moe_ffn_hidden_size=128, shared_ffn_hidden_size=128,
+        num_experts=32, held=(0, 4), experts_per_token=4, n_group=4,
+        topk_group=2, max_seq_length=ps * mpps,
+        rope=YarnRope(theta=10000.0, rotary_dim=64, factor=32.0,
+                      original_max_position=4096, beta_fast=32.0,
+                      beta_slow=1.0, attention_factor=1.0),
+        params_dtype=BF16)
+    on_chip = lambda x: _s(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+    params = {"params": jax.tree.map(
+        lambda shape: _s(shape, BF16, sharding=one_chip),
+        SA.axk1_param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))}
+    cache = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: kv_cache.init_paged_cache(
+            pages, layers, 0, ps, 0, slots=slots, max_pages_per_slot=mpps,
+            latent=cfg.latent_dim)))
+    return cfg, params, cache, (
+        f"bf16[{pages + 1},{layers},{cfg.latent_dim},{ps}]")
+
+
+def test_v5e_gpt_decode_step_reads_the_pool_in_place(one_chip, monkeypatch):
+    """A paged ``gpt`` decode step compiled for the chip (ISSUE 31): one
+    ``apex_paged_decode`` custom call a layer, each reading the WHOLE pool
+    where ``append_layer`` wrote it — no copy, slice or any other op whose
+    result is pool-sized besides the appends' in-place updates.  (Interpret
+    mode cannot show this: there the kernel is a loop that carries the
+    pool.)"""
+    import apex_tpu.ops.attention as at
+    import apex_tpu.ops.paged_attention as pa
+    from apex_tpu.inference.engine import make_decode_fn
+    from apex_tpu.inference.sampling import SamplingConfig
+
+    for mod in (at, ln, pa):
+        monkeypatch.setattr(mod, "interpret_mode", lambda: False)
+    cfg, params, cache, pool = _toy_gpt(one_chip)
+    layers, slots = cache.layers, cache.slots
     step = jax.jit(make_decode_fn("gpt", cfg, SamplingConfig()),
                    donate_argnums=(0,))
     args = (cache, params, _s((slots,), jnp.int32, sharding=one_chip),
@@ -231,7 +272,6 @@ def test_v5e_gpt_decode_step_reads_the_pool_in_place(one_chip, monkeypatch):
     assert str(jax.make_jaxpr(step)(*args)).count(
         "name=paged_work_list") == 1
     hlo = step.lower(*args).compile().as_text()
-    pool = f"bf16[{pages + 1},{layers},{heads},{ps},{d}]"
     made = {}                       # op -> count, of pool-sized results
     lists = set()                   # the kernels' work-list operands
     kernels = 0
@@ -256,6 +296,77 @@ def test_v5e_gpt_decode_step_reads_the_pool_in_place(one_chip, monkeypatch):
     # the appends: one in-place scatter of k and of v a layer, nothing else
     assert set(made) <= {"fusion", "scatter"}, made
     assert sum(made.values()) <= 4 * layers, made
+
+
+def _prefill_setup(kind, one_chip):
+    """A paged prefill of ``kind`` compiled for the chip with the cache
+    donated: ``(compiled HLO text, the pool's type, memory stats)``."""
+    from apex_tpu.inference.engine import make_prefill_fn
+    from apex_tpu.inference.sampling import SamplingConfig
+
+    cfg, params, cache, pool = {"gpt": _toy_gpt,
+                                "axk1": _toy_axk1}[kind](one_chip)
+    step = jax.jit(make_prefill_fn(kind, cfg, SamplingConfig(), paged=True),
+                   donate_argnums=(0,))
+    i32 = lambda *shape: _s(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    compiled = step.lower(
+        cache, params, i32(2 * cache.page_size), i32(), i32(),
+        i32(cache.max_pages_per_slot), i32(),
+        _s((2,), jnp.uint32, sharding=one_chip), i32()).compile()
+    return compiled.as_text(), pool, compiled.memory_analysis()
+
+
+def _computation(hlo: str, name: str) -> str:
+    """The text of the HLO computation called ``name``."""
+    return re.search(rf"^%{re.escape(name)} .*?^}}", hlo, re.M | re.S)[0]
+
+
+@pytest.mark.parametrize("kind", ["gpt", "axk1"])
+def test_v5e_gpt_prefill_writes_the_pool_in_place(kind, one_chip,
+                                                  monkeypatch):
+    """A paged prefill compiled for the chip writes its slab
+    into the donated pool in place: no op besides the insert's scatters
+    (one a pool array) has a pool-sized result.  ``gpt`` shares prefixes,
+    so its start is traced and a ``cond`` of the insert picks the rows to
+    write: the pool goes in read-only and is written after it — the
+    aligned branch does not touch the pool at all (no gather, no copy),
+    the mid-page one reads the pages it touches.  ``axk1`` never resumes:
+    its insert is the aligned write with no ``cond`` at all."""
+    import apex_tpu.ops.attention as at
+    import apex_tpu.ops.paged_attention as pa
+
+    for mod in (at, ln, pa):
+        monkeypatch.setattr(mod, "interpret_mode", lambda: False)
+    hlo, pool, stats = _prefill_setup(kind, one_chip)
+    made = {}
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\(", line)
+        if m and m.group(2).startswith(pool) and m.group(3) not in (
+                "parameter", "get-tuple-element", "tuple"):
+            made[m.group(3)] = made.get(m.group(3), 0) + 1
+    # the write, in place and never a copy: a scatter of whole pages a pool
+    # array (a fusion round a scatter); ``gpt`` also writes the page after
+    # them, which only its mid-page write fills (a fusion round a
+    # one-page dynamic-update-slice)
+    arrays, writes = (2, 2) if kind == "gpt" else (1, 1)
+    assert set(made) <= {"fusion", "scatter", "dynamic-update-slice"}, made
+    assert sum(made.values()) <= 2 * arrays * writes, made
+    conds = [line for line in hlo.splitlines() if " conditional(" in line
+             and "apex_prefill_cache_insert" in line]
+    if kind != "gpt":
+        assert not conds, conds
+    else:
+        assert len(conds) == arrays, conds
+        for cond in conds:
+            # branch 1 is the true branch: the start on a page boundary
+            mid, aligned = re.search(r"branch_computations=\{%([\w.]+), "
+                                     r"%([\w.]+)\}", cond).groups()
+            assert pool not in _computation(hlo, aligned)
+            assert " gather(" not in _computation(hlo, aligned)
+            assert pool in _computation(hlo, mid)
+            # the branches hand back rows, never the pool
+            assert pool not in cond.split(" conditional(")[0], cond[:300]
+    assert stats.temp_size_in_bytes < 64 << 20, stats
 
 
 @pytest.mark.parametrize("table", ["laguna", "gpt"])
@@ -296,35 +407,13 @@ def test_v5e_latent_decode_step_reads_the_one_pool_in_place(one_chip,
     result is pool-sized besides the appends' in-place updates."""
     import apex_tpu.ops.attention as at
     import apex_tpu.ops.paged_attention as pa
-    from apex_tpu.inference import kv_cache
     from apex_tpu.inference.engine import make_decode_fn
     from apex_tpu.inference.sampling import SamplingConfig
-    from apex_tpu.transformer.testing import standalone_axk1 as SA
-    from apex_tpu.transformer.testing.standalone_laguna import YarnRope
 
     for mod in (at, ln, pa):
         monkeypatch.setattr(mod, "interpret_mode", lambda: False)
-    # the published latent row (512 + 64) and page; a pool of 0.45 GB
-    layers, slots, ps, pages, mpps = 3, 8, 256, 512, 4
-    cfg = SA.AXK1Config(
-        vocab_size=256, hidden_size=256, num_layers=layers, num_heads=8,
-        q_lora_rank=128, kv_lora_rank=512, qk_nope_head_dim=128,
-        qk_rope_head_dim=64, v_head_dim=128, ffn_hidden_size=256,
-        moe_ffn_hidden_size=128, shared_ffn_hidden_size=128,
-        num_experts=32, held=(0, 4), experts_per_token=4, n_group=4,
-        topk_group=2, max_seq_length=ps * mpps,
-        rope=YarnRope(theta=10000.0, rotary_dim=64, factor=32.0,
-                      original_max_position=4096, beta_fast=32.0,
-                      beta_slow=1.0, attention_factor=1.0),
-        params_dtype=BF16)
-    on_chip = lambda x: _s(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
-    params = {"params": jax.tree.map(
-        lambda shape: _s(shape, BF16, sharding=one_chip),
-        SA.axk1_param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))}
-    cache = jax.tree.map(on_chip, jax.eval_shape(
-        lambda: kv_cache.init_paged_cache(
-            pages, layers, 0, ps, 0, slots=slots, max_pages_per_slot=mpps,
-            latent=cfg.latent_dim)))
+    cfg, params, cache, pool = _toy_axk1(one_chip)
+    layers, slots = cache.layers, cache.slots
     assert cache.v is None
     step = jax.jit(make_decode_fn("axk1", cfg, SamplingConfig()),
                    donate_argnums=(0,))
@@ -335,7 +424,6 @@ def test_v5e_latent_decode_step_reads_the_one_pool_in_place(one_chip,
     assert str(jax.make_jaxpr(step)(*args)).count(
         "name=paged_work_list") == 1
     hlo = step.lower(*args).compile().as_text()
-    pool = f"bf16[{pages + 1},{layers},{cfg.latent_dim},{ps}]"
     made, bounds, kernels = {}, set(), 0
     for line in hlo.splitlines():
         m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\(", line)
