@@ -265,6 +265,18 @@ def _builders():
                 (s((2, 4, 64), bf16), pool, pool, s((2, 64), bool),
                  s((2, 4), jnp.int32), s((2,), jnp.int32)))
 
+    def paged_select_attention_latent():
+        # latent attention over the picked positions only, with
+        # a sink a head
+        from apex_tpu.ops.paged_attention import (
+            paged_select_attention_latent as op, paged_work_list)
+        return (lambda q, pool, picked, sink, pt, n: op(
+            q, pool, picked, paged_work_list(pt, n, page_size=16), layer=1,
+            sm_scale=0.1, values=128, sink=sink),
+                (s((2, 4, 192), bf16), s((9, 2, 192, 16), bf16),
+                 s((2, 64), bool), s((4,), jnp.float32),
+                 s((2, 4), jnp.int32), s((2,), jnp.int32)))
+
     def fused_block_decode():
         # the jaxpr-audit fixture geometry (hidden 64, GPT kind); the
         # flagship-shape envelope rides fused_block_envelope, not the
@@ -347,6 +359,9 @@ def _builders():
         "paged_select_attention": (paged_select_attention,
                                    "apex_tpu/ops/paged_attention.py",
                                    ops + "paged_attention"),
+        "paged_select_attention_latent": (paged_select_attention_latent,
+                                          "apex_tpu/ops/paged_attention.py",
+                                          ops + "paged_attention"),
         "fused_block_decode": (fused_block_decode,
                                "apex_tpu/ops/paged_attention.py",
                                ops + "paged_attention"),

@@ -741,7 +741,8 @@ class InferenceEngine:
                     max_pages_per_slot=self.max_pages_per_slot,
                     dtype=self.cache_dtype,
                     window_layers=d["window_layers"], window=d["window"],
-                    latent=d["latent"], index=d["index"])
+                    latent=d["latent"], index=d["index"],
+                    index_layers=d.get("index_layers"))
 
             if self.tp == 1:
                 return build()
@@ -778,14 +779,15 @@ class InferenceEngine:
         itemsize = jnp.dtype(self.cache_dtype).itemsize
         kvh = self.tp_dims["kv_heads_pool"] // self.tp   # per-rank heads
         # what a position holds a layer is the record's: a key and a
-        # value per KV head, or one latent row
+        # value per KV head, or one latent row (and an index key)
         per_layer_tok = models.cache_row_values(d, kvh) * itemsize
         if self.paged:
-            # the pool's layers, + the window layers' rings: fixed rows a
-            # slot whatever the context (none without such layers)
+            # the pool's layers (with the index keys of those the index
+            # pool keeps), + the window layers' rings: fixed rows a slot
+            # whatever the context (none without such layers)
             ring = kv_cache.ring_rows(d["window"], self.page_size)
             return ((self.num_pages + 1) * self.page_size
-                    * d["pool_layers"] * per_layer_tok
+                    * models.cache_position_values(d, kvh) * itemsize
                     + self.slots * ring * d["window_layers"]
                     * per_layer_tok)
         return self.slots * self.max_seq * d["layers"] * per_layer_tok
@@ -929,8 +931,8 @@ class InferenceEngine:
                              "slot cache")
         d = self.dims
         itemsize = jnp.dtype(self.cache_dtype).itemsize
-        return (d["pool_layers"] * self.page_size * itemsize
-                * models.cache_row_values(d, self.tp_dims["kv_heads_pool"]))
+        return (self.page_size * itemsize * models.cache_position_values(
+            d, self.tp_dims["kv_heads_pool"]))
 
     def swap_out_pages(self, cache, page_ids, defer: bool = False):
         """Copy physical pages ``page_ids`` device→host (ISSUE 18

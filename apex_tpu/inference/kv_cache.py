@@ -93,7 +93,12 @@ Shared design positions:
   appends one a slot a layer, :func:`cow_page`, :func:`extract_pages` and
   :func:`restore_pages` move the page's index keys with the rest.  A model
   without an indexer holds no such array (``ik`` is ``None``, not a
-  zero-sized array).
+  zero-sized array).  A model whose later layers REUSE the picks of an
+  earlier one keeps keys for its picking layers only: ``ik``
+  then has fewer layers than ``k`` (``init_paged_cache(...,
+  index_layers=n)``), a prefill inserts ``[index_layers, s, width]`` keys,
+  and a decode step appends a layer's rows with :func:`append_layer` and
+  its key, where it has one, with :func:`append_index`.
 * **The trash page.**  The paged pool carries ONE sacrificial page at
   index ``pages - 1`` that the allocator never hands out; page-table
   entries beyond a slot's reservation point there, so the statically
@@ -251,7 +256,8 @@ def append_layer(cache, layer: int, k_tok, v_tok, ik_tok=None):
     decode step writes to the same position.  Dispatches on the cache
     layout: dense slot cache or paged pool.  ``ik_tok`` ``[slots,
     index_width]``: the token's index key a slot, for a paged cache with
-    an index-key pool (and only for one).
+    an index-key pool of every layer (and only for one); a pool that keeps
+    some layers' keys takes them by :func:`append_index`.
     """
     paged = isinstance(cache, PagedKVCache)
     want = (cache.slots, *(cache.row_shape if paged else
@@ -262,6 +268,13 @@ def append_layer(cache, layer: int, k_tok, v_tok, ik_tok=None):
             f"kv_heads={cache.kv_heads}, head_dim={cache.head_dim}] (a "
             f"latent pool: [slots, width]), got {tuple(k_tok.shape)}")
     if paged:
+        if _some_layers_indexed(cache):
+            if ik_tok is not None:
+                raise ValueError(
+                    "this pool keeps the index keys of some layers only: "
+                    "append a layer's key with append_index")
+            return _append_layer_paged(cache.replace(ik=None), layer, k_tok,
+                                       v_tok).replace(ik=cache.ik)
         if cache.ik is not None:
             _check_index(ik_tok, (cache.slots, cache.ik.shape[2]),
                          "token k/v")
@@ -282,6 +295,25 @@ def append_layer(cache, layer: int, k_tok, v_tok, ik_tok=None):
     new_v = cache.v.at[:, layer].set(
         upd(cache.v[:, layer], v_tok, cache.lengths))
     return cache.replace(k=new_k, v=new_v)
+
+
+def _some_layers_indexed(cache) -> bool:
+    """Does the paged pool keep index keys for fewer layers than k/v?"""
+    return cache.ik is not None and cache.ik.shape[1] != cache.k.shape[1]
+
+
+def append_index(cache, index_layer: int, ik_tok):
+    """Decode write of ONE index key a slot, ``ik_tok [slots,
+    index_width]``, into layer ``index_layer`` of a pool that keeps the
+    keys of some layers only, at each slot's current length —
+    :func:`append_layer`'s write on the index-key pool alone."""
+    if not isinstance(cache, PagedKVCache) or cache.ik is None:
+        raise ValueError("append_index needs a paged cache with an "
+                         "index-key pool")
+    _check_index(ik_tok, (cache.slots, cache.ik.shape[2]), "token index key")
+    k_only = cache.replace(k=cache.ik, v=None, ik=None)
+    return cache.replace(ik=_append_layer_paged(k_only, index_layer,
+                                                ik_tok, None).k)
 
 
 def append_slab(cache, layer: int, k_slab, v_slab):
@@ -563,7 +595,8 @@ def init_paged_cache(pages: int, layers: int, kv_heads: int,
                      page_size: int, head_dim: int, *, slots: int,
                      max_pages_per_slot: int, dtype=jnp.bfloat16,
                      window_layers: int = 0, window: int = 0,
-                     latent: int = 0, index: int = 0) -> PagedKVCache:
+                     latent: int = 0, index: int = 0,
+                     index_layers: Optional[int] = None) -> PagedKVCache:
     """Allocate an empty pool: ``pages`` allocatable pages (+1 trash
     page appended), every page-table entry pointing at the trash page,
     every slot empty.  ``layers`` counts the layers the POOL holds;
@@ -572,8 +605,9 @@ def init_paged_cache(pages: int, layers: int, kv_heads: int,
     ``latent`` (a row's width) makes the pool a LATENT one: one array
     ``[pages + 1, layers, latent, page_size]``, no value array;
     ``kv_heads`` / ``head_dim`` are then not read.  ``index`` (an index
-    key's width) adds the index-key pool ``[pages + 1, layers, index,
-    page_size]`` under the same table; 0 adds no array."""
+    key's width) adds the index-key pool ``[pages + 1, index_layers,
+    index, page_size]`` under the same table (``index_layers`` defaults to
+    ``layers``); 0 adds no array."""
     if pages < 1 or page_size < 1 or max_pages_per_slot < 1:
         raise ValueError(
             f"pages ({pages}), page_size ({page_size}) and "
@@ -599,8 +633,8 @@ def init_paged_cache(pages: int, layers: int, kv_heads: int,
         lengths=jnp.zeros((slots,), jnp.int32),
         capacity=jnp.zeros((slots,), jnp.int32),
         last_tokens=jnp.zeros((slots,), jnp.int32), **rings,
-        ik=jnp.zeros((pages + 1, layers, index, page_size), dtype)
-        if index else None)
+        ik=jnp.zeros((pages + 1, index_layers or layers, index, page_size),
+                     dtype) if index else None)
 
 
 def ring_rows(window: int, page_size: int) -> int:
@@ -668,7 +702,9 @@ def _check_rows(cache: PagedKVCache, k, v, what: str, lead: tuple,
             f"{' and v None' if cache.latent else ', k and v alike'}; got "
             f"k {tuple(k.shape)} v {None if v is None else tuple(v.shape)}")
     if cache.ik is not None:
-        _check_index(ik, (*lead, k.shape[-2], cache.ik.shape[2]), what)
+        # a pool that keeps some layers' keys takes as many layers of them
+        _check_index(ik, (cache.ik.shape[1], *lead[1:], k.shape[-2],
+                          cache.ik.shape[2]), what)
 
 
 def _check_index(ik, want: tuple, what: str) -> None:

@@ -52,7 +52,13 @@ rows at a time (``ops.attention.select_attention``); decode runs three
 stages a layer over the paged pools — index scores along the step's work
 list (``apex_dsa_index``), the picked set of each slot on the device
 (``select_top_mask``), attention over the picked rows
-(``apex_dsa_attend``).
+(``apex_dsa_attend``).  A kind may do both (``hy4``): latent
+attention over the picked positions only (``apex_dsa_attend_latent`` in
+decode), with the picks of one layer REUSED by the layers after it that
+hold no indexer (``Select.sources``) — so the index-key pool keeps the
+picking layers' keys alone — and a residual carried in several streams
+(``Kind.residual``: where the other kinds write ``h + sublayer``, the loops
+ask the record for the sublayer's input and for the streams after it).
 
 Unsupported training-only configs (scan_layers, the capacity-slot MoE
 FFN of ``transformer/moe/MoELayer``, sequence/context parallelism) fail
@@ -82,7 +88,9 @@ from apex_tpu.ops.attention import (
     flash_attention,
     prefix_window_attention,
     ring_decode_attention,
+    select_attend,
     select_attention,
+    select_picks,
     select_top_mask,
     slab_decode_attention,
 )
@@ -91,6 +99,7 @@ from apex_tpu.ops.paged_attention import (
     paged_decode_attention,
     paged_index_scores,
     paged_select_attention,
+    paged_select_attention_latent,
     paged_slab_attention,
     paged_work_list,
 )
@@ -100,13 +109,15 @@ from apex_tpu.transformer.functional.fused_rope import (
 from apex_tpu.transformer.moe.dropless import fold_stats
 from apex_tpu.transformer.parallel_state import TENSOR_AXIS
 from apex_tpu.transformer.testing import standalone_axk1 as axk1
+from apex_tpu.transformer.testing import standalone_hy4 as hy4
 from apex_tpu.transformer.testing import standalone_keye as keye
 from apex_tpu.transformer.testing import standalone_laguna as laguna
 from apex_tpu.transformer.testing.standalone_llama import _rope_cos_sin
 
 __all__ = ["Kind", "KINDS", "model_dims", "tp_dims", "check_supported",
-           "prefill_forward", "EXPERT_STATS", "SELECT_STATS", "stats_tail",
-           "Latent", "Select", "cache_row_values", "decode_forward",
+           "prefill_forward", "EXPERT_STATS", "SELECT_STATS", "REUSE_STATS",
+           "stats_tail", "Latent", "Select", "Residual", "cache_row_values",
+           "cache_position_values", "decode_forward",
            "verify_forward",
            "fused_layer_params", "expand_kv_for_tp",
            "param_partition_specs", "fused_partition_specs"]
@@ -493,6 +504,9 @@ def _not_built(kind: str, what: str, module: str) -> str:
 #: context exceeded the selection's size (so that it cut something), and
 #: the positions attended, summed
 SELECT_STATS = ("dsa_rows", "dsa_rows_sparse", "dsa_selected")
+#: what a step of a kind whose layers REUSE earlier picks reports behind
+#: those: the query rows x layers that attended a carried pick set
+REUSE_STATS = ("dsa_rows_reused",)
 
 
 def _keye_dims(cfg) -> dict:
@@ -535,6 +549,41 @@ def _keye_ffn(cfg, i, lp, h, valid, tp):
 
 
 # --------------------------------------------------------------------------
+# Hy4-preview (standalone_hy4's per-layer pieces): latent
+# attention over the positions an indexer picks, the picks of a full layer
+# reused by the shared layers after it, a sink a head and an elementwise
+# gate, hyper-connected residual streams, held experts
+# --------------------------------------------------------------------------
+
+def _hy4_dims(cfg) -> dict:
+    return dict(_axk1_dims(cfg), index=cfg.index_head_dim,
+                index_layers=len(cfg.index_layers))
+
+
+def _hy4_check(cfg) -> None:
+    if not isinstance(cfg, hy4.HY4Config):
+        raise TypeError(
+            f"the 'hy4' kind takes an HY4Config, got "
+            f"{type(cfg).__name__}")
+
+
+def _hy4_rope(cfg, dims, positions, n):
+    if positions is None:
+        positions = jnp.arange(n, dtype=jnp.int32)
+    return {FULL: hy4.rope_cos_sin(cfg, positions),
+            INDEX: hy4.index_rope_cos_sin(cfg, positions)}
+
+
+def _hy4_attn_out(lp, ctx, gate, tp):
+    return hy4.attn_output(lp, ctx, gate)
+
+
+def _hy4_ffn(cfg, i, lp, h, valid, tp):
+    y, stats = hy4.ffn(cfg, i, lp, h.reshape(-1, h.shape[-1]), valid=valid)
+    return y.reshape(h.shape), stats
+
+
+# --------------------------------------------------------------------------
 # the record
 # --------------------------------------------------------------------------
 
@@ -569,6 +618,13 @@ class Latent:
     value_up: Callable
     #: ``cfg -> float``: the softmax scale (no head size gives it)
     scale: Callable
+    #: ``(cfg, lp, h) -> g [..., heads, dv]`` or None: an elementwise gate
+    #: on each head's output, read from the sublayer's normed input and
+    #: handed to ``attn_out`` as its ``extra``
+    gate: Optional[Callable] = None
+    #: ``lp -> [heads]`` float32 or None: each head's sink logit, one more
+    #: term of the softmax's denominator that carries no value
+    sink: Optional[Callable] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -585,6 +641,28 @@ class Select:
     topk: Callable
     #: ``cfg -> int``: query rows a prefill scores and selects at a time
     block: Callable
+    #: ``cfg -> tuple`` or None: per layer, the layer whose picks it
+    #: attends — itself where it scores its own index keys, an earlier one
+    #: where it REUSES that layer's picks and holds no indexer;
+    #: None: every layer picks for itself
+    sources: Optional[Callable] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class Residual:
+    """A residual carried in several streams: ``x [..., n,
+    hidden]`` where the other kinds carry ``h [..., hidden]``."""
+    #: ``(cfg, h) -> x``: the embedding into the streams
+    expand: Callable
+    #: ``(cfg, lp, which, x) -> (u [..., hidden], mix)``: a sublayer's
+    #: input (``which`` is ``"attention"`` or ``"ffn"``) and what its
+    #: output is written back with
+    pre: Callable
+    #: ``(cfg, mix, x, y) -> x``: the streams after the sublayer's output
+    #: ``y``
+    post: Callable
+    #: ``(cfg, p, x) -> h [..., hidden]``: one row before the final norm
+    collapse: Callable
 
 
 @dataclasses.dataclass(frozen=True)
@@ -627,6 +705,8 @@ class Kind:
     #: the learned selection of the positions a layer attends, or None:
     #: every causal (or window) position
     select: Optional[Select] = None
+    #: the residual streams, or None: ``h + sublayer``
+    residual: Optional[Residual] = None
     #: names of the int32 counters a step appends to its tokens
     stats: Tuple[str, ...] = ()
     #: feature -> why it is not built for the kind; the features are
@@ -745,6 +825,49 @@ KINDS = {
                 "models._suffix_attend (a resumed prefill would have to "
                 "score the CACHED index keys and pick among cached rows)"),
         }),
+    "hy4": Kind(
+        dims=_hy4_dims, check=_hy4_check, embed=_token_embed,
+        rope=_hy4_rope, norm=_rms_norm, project=None,
+        attn_out=_hy4_attn_out, ffn=_hy4_ffn,
+        head=lambda p, h, tp: hy4.head(p, h),
+        latent=Latent(expand=hy4.attn_expand, absorb=hy4.attn_absorb,
+                      value_up=hy4.attn_value_up, scale=hy4.softmax_scale,
+                      gate=hy4.attn_gate, sink=hy4.attn_sink),
+        select=Select(index=hy4.index_project,
+                      topk=lambda cfg: cfg.index_topk,
+                      block=lambda cfg: cfg.index_q_chunk,
+                      sources=lambda cfg: cfg.index_sources),
+        residual=Residual(expand=hy4.hc_expand, pre=hy4.hc_pre,
+                          post=hy4.hc_post, collapse=hy4.hc_collapse),
+        stats=EXPERT_STATS + SELECT_STATS + REUSE_STATS,
+        refuses={
+            "dense": "the 'hy4' kind serves from the paged cache only (a "
+                     "latent pool and an index-key pool: "
+                     "kv_cache.KVCache has neither): pass "
+                     "page_size=/num_pages=",
+            "tp": _not_built(
+                "hy4", "tp > 1", "models.param_partition_specs and "
+                "kv_cache.paged_cache_partition_specs (the residual "
+                "streams' mixes read every hidden channel, so a "
+                "head-sharded layer would gather the streams twice a "
+                "sublayer; the held experts' all-to-all)"),
+            "verify": _not_built(
+                "hy4", "speculative verify", "ops/paged_attention.py's "
+                "paged_slab_attention (per-head k/v windows, no picks) and "
+                "kv_cache.append_slab (no latent rows, no index keys)"),
+            "host_tier": _not_built(
+                "hy4", "the host KV tier", "kv_cache.HostPageStore and "
+                "engine.swap_in_pages (they move a k and a v slab)"),
+            "fused": _not_built(
+                "hy4", "fused_block_decode", "ops/paged_attention.py's "
+                "_fused_block_kernel (per-head k/v, one residual stream, "
+                "a dense FFN)"),
+            "prefix_sharing": _not_built(
+                "hy4", "prefix sharing, and with it chunked prefill,",
+                "models._suffix_attend (a resumed prefill would have to "
+                "up-project the cached latent a chunk at a time and pick "
+                "among the cached rows)"),
+        }),
 }
 
 
@@ -771,6 +894,15 @@ def cache_row_values(dims: dict, kv_heads: int) -> int:
     and the index key of a kind that selects."""
     return (dims["latent"] or 2 * kv_heads * dims["head_dim"]) \
         + dims.get("index", 0)
+
+
+def cache_position_values(dims: dict, kv_heads: int) -> int:
+    """Values ONE cached position holds over every pool layer: each
+    layer's rows, and an index key for each layer the index-key pool
+    keeps (every pool layer, or only those that pick)."""
+    layers = dims["pool_layers"]
+    return layers * cache_row_values(dict(dims, index=0), kv_heads) \
+        + dims.get("index_layers", layers) * dims.get("index", 0)
 
 
 def tp_dims(kind: str, cfg, tp: int) -> dict:
@@ -1045,6 +1177,62 @@ def _cache_attend(cache, layer: int, q, live, work):
     return decode_attention(q, cache.k[:, layer], cache.v[:, layer], live)
 
 
+def _sub_in(rec: Kind, cfg, lp, which: str, h):
+    """A sublayer's input and what its output is written back with: ``h``
+    itself and nothing, or the residual streams' mixes."""
+    if rec.residual is None:
+        return h, None
+    return rec.residual.pre(cfg, lp, which, h)
+
+
+def _sub_out(rec: Kind, cfg, mix, h, y):
+    """The residual after a sublayer whose output is ``y``."""
+    if rec.residual is None:
+        return h + y
+    return rec.residual.post(cfg, mix, h, y)
+
+
+def _final(rec: Kind, cfg, p, h):
+    """The final norm, of the streams collapsed to one row where the kind
+    carries several."""
+    if rec.residual is not None:
+        h = rec.residual.collapse(cfg, p, h)
+    return rec.norm(cfg, p, "final", h)
+
+
+def _gate(rec: Kind, cfg, lp, hn):
+    """A latent kind's output gate from the sublayer's normed input (the
+    ``extra`` its ``attn_out`` takes), or None."""
+    return rec.latent.gate(cfg, lp, hn) if rec.latent.gate else None
+
+
+def _sink(rec: Kind, lp):
+    """Each head's sink logit, or None for a kind without one."""
+    return rec.latent.sink(lp) if rec.latent and rec.latent.sink else None
+
+
+def _pick_sources(rec: Kind, cfg, layers: int):
+    """Per layer ``(source, slot)`` for a kind that selects: the layer
+    whose picks it attends, and — where that is itself — which of the
+    index-key pool's layers holds its keys (None where it reuses)."""
+    sources = (rec.select.sources(cfg) if rec.select.sources
+               else tuple(range(layers)))
+    own = [i for i in range(layers) if sources[i] == i]
+    return [(src, own.index(i) if src == i else None)
+            for i, src in enumerate(sources)]
+
+
+def _decode_select(cache, qi, wi, slot: int, work, live, topk: int):
+    """Decode's first two stages: index scores of the live positions
+    against the pool's index keys of ``slot``, then each slot's picked set
+    ``[slots, max_seq]``."""
+    with jax.named_scope("apex_dsa_index"):
+        scores = paged_index_scores(qi, wi, cache.ik, work, layer=slot)
+    with jax.named_scope("apex_dsa_select"):
+        cols = jnp.arange(cache.max_seq, dtype=jnp.int32)
+        return select_top_mask(scores, topk, cols[None] < live[:, None])
+
+
 def stats_tail(names, acc, cache):
     """A step's counters as ``int32[len(names)]``, ``names`` the record's
     ``stats``: the expert counters the loop folded over its layers
@@ -1056,21 +1244,25 @@ def stats_tail(names, acc, cache):
               "moe_experts_hit": acc.get("experts_hit", zero),
               "moe_expert_load_max": acc.get("load_max", zero),
               "window_pages_live": kv_cache.window_pages_live(cache),
-              **{n: acc.get(n, zero) for n in SELECT_STATS}}
+              **{n: acc.get(n, zero) for n in SELECT_STATS + REUSE_STATS}}
     return jnp.stack([values[n] for n in names]).astype(jnp.int32)
 
 
-def _select_stats(acc, counted, context, picked, topk: int):
+def _select_stats(acc, counted, context, picked, topk: int, reused=None):
     """Fold one selecting layer into a step's :data:`SELECT_STATS`: per
     query row its ``context`` (the positions it could attend) and
     ``picked`` (those it did); ``counted`` (bool) the rows that carry a
-    token."""
+    token.  ``reused`` (a kind whose layers reuse picks, static): did this
+    layer attend a carried set — :data:`REUSE_STATS`."""
     layer = {"dsa_rows": jnp.sum(counted, dtype=jnp.int32),
              "dsa_rows_sparse": jnp.sum(counted & (context > topk),
                                         dtype=jnp.int32),
              "dsa_selected": jnp.sum(jnp.where(counted, picked, 0),
                                      dtype=jnp.int32)}
-    return {n: layer[n] + (acc or {}).get(n, 0) for n in SELECT_STATS}
+    if reused is not None:
+        layer["dsa_rows_reused"] = layer["dsa_rows"] if reused \
+            else jnp.int32(0)
+    return {n: layer[n] + (acc or {}).get(n, 0) for n in layer}
 
 
 # --------------------------------------------------------------------------
@@ -1131,6 +1323,8 @@ def prefill_forward(kind: str, cfg, params, tokens, length=None, *,
             + jnp.arange(s, dtype=jnp.int32),
             jnp.int32(cache.max_seq - 1))
     h = rec.embed(p, tokens, positions, tp).transpose(1, 0, 2)  # [s, b, h]
+    if rec.residual:                                    # [s, b, n, h]
+        h = rec.residual.expand(cfg, h)
     tables = rec.rope(cfg, dims, positions, cache.max_seq if suffix else s)
     rope = {t: tuple(c[:, None, None, :] for c in cs)       # [s, 1, 1, r]
             for t, cs in tables.items() if t != INDEX}
@@ -1144,15 +1338,20 @@ def prefill_forward(kind: str, cfg, params, tokens, length=None, *,
     # a latent kind's softmax scale is its own; None is the head size's
     scale = rec.latent.scale(cfg) if rec.latent else None
     ks, vs, wks, wvs, iks, stats, picks = [], [], [], [], [], None, None
+    # a kind whose layers reuse picks: source layer -> (masks, picked)
+    sources, carried = None, {}
+    if rec.select:
+        sources = _pick_sources(rec, cfg, len(dims["layer_types"]))
     for i, (pooled, n) in enumerate(_cache_layers(dims["layer_types"])):
         lp = p[f"layer_{i}"]
-        hn = rec.norm(cfg, lp, "input", h)
+        u, mix = _sub_in(rec, cfg, lp, "attention", h)
+        hn = rec.norm(cfg, lp, "input", u)
         lrope = rope.get(dims["layer_types"][i])
         if rec.latent:
             # expanded: k/v made from the latent, the latent row cached
             q, k, v, latent_row = rec.latent.expand(cfg, lp, hn, *lrope)
             ks.append(latent_row[:, 0])                     # [s, width]
-            extra = None
+            extra = _gate(rec, cfg, lp, hn)
         else:
             q, k, v, extra = rec.project(cfg, dims, i, lp, hn,
                                          lrope)             # [s, b, n, d]
@@ -1166,31 +1365,48 @@ def prefill_forward(kind: str, cfg, params, tokens, length=None, *,
         elif rec.select:
             # each row attends the positions of largest index score among
             # its causal ones, a block of rows at a time; the index keys
-            # are cached with k and v
-            qi, wi, ki = rec.select.index(cfg, lp, hn, *irope)
-            iks.append(ki[:, 0])                            # [s, width]
+            # are cached with k and v — a layer that reuses the picks of
+            # an earlier one scores nothing and caches no index key
+            src = sources[i][0]
+            if src == i:
+                qi, wi, ki = rec.select.index(cfg, lp, hn, *irope)
+                iks.append(ki[:, 0])                        # [s, width]
             heads, topk = q.shape[1], rec.select.topk(cfg)
-            ctx, picked = select_attention(
-                q, _expand_kv(k, heads), _expand_kv(v, heads), qi[:, 0],
-                wi[:, 0], ki[:, 0], topk=topk,
-                block_q=rec.select.block(cfg), sm_scale=scale)
+            block = rec.select.block(cfg)
+            if rec.select.sources is None:
+                ctx, picked = select_attention(
+                    q, _expand_kv(k, heads), _expand_kv(v, heads), qi[:, 0],
+                    wi[:, 0], ki[:, 0], topk=topk, block_q=block,
+                    sm_scale=scale)
+            else:
+                if src == i:
+                    carried[i] = select_picks(qi[:, 0], wi[:, 0], ki[:, 0],
+                                              topk=topk, block_q=block)
+                masks, picked = carried[src]
+                ctx = select_attend(
+                    q, _expand_kv(k, heads), _expand_kv(v, heads), masks,
+                    topk=topk, block_q=block, sm_scale=scale,
+                    sink=_sink(rec, lp))
             rows = jnp.arange(s, dtype=jnp.int32)
             picks = _select_stats(
                 picks, rows >= 0 if length is None else rows < length,
-                rows + 1, picked, topk)
+                rows + 1, picked, topk,
+                None if rec.select.sources is None else src != i)
         else:
             heads = q.shape[1]
             ctx = flash_attention(
                 q, _expand_kv(k, heads), _expand_kv(v, heads), causal=True,
                 sm_scale=scale,
                 window=None if pooled else dims["window"])
-        x = h + rec.attn_out(lp, ctx.transpose(2, 0, 1, 3), extra, tp)
-        y, st = rec.ffn(cfg, i, lp, rec.norm(cfg, lp, "post_attention", x),
+        x = _sub_out(rec, cfg, mix, h,
+                     rec.attn_out(lp, ctx.transpose(2, 0, 1, 3), extra, tp))
+        u, mix = _sub_in(rec, cfg, lp, "ffn", x)
+        y, st = rec.ffn(cfg, i, lp, rec.norm(cfg, lp, "post_attention", u),
                         valid, tp)
         stats = fold_stats(stats, st)
-        h = x + y
+        h = _sub_out(rec, cfg, mix, x, y)
 
-    h = rec.norm(cfg, p, "final", h)
+    h = _final(rec, cfg, p, h)
     if length is not None:      # the slab's own index of the last real row
         h = _last_row(h, length - prefill_from if suffix else length)
     logits = _gather_logits(rec.head(p, h, tp), tp)
@@ -1227,6 +1443,8 @@ def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
     dims = tp_dims(kind, cfg, tp)
     positions = cache.lengths                               # [slots]
     h = rec.embed(p, tokens, positions, tp)                 # [slots, hid]
+    if rec.residual:                                    # [slots, n, hid]
+        h = rec.residual.expand(cfg, h)
     flat = rec.rope(cfg, dims, positions, cache.max_seq)    # [slots, r]
     rope = {t: tuple(c[:, None, :] for c in cs) for t, cs in flat.items()
             if t != INDEX}
@@ -1238,7 +1456,10 @@ def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
         work = paged_work_list(cache.page_table, live,
                                page_size=cache.page_size)
     cos, sin = flat.get(FULL, (None, None))     # the kernel's: unshaped
-    stats, picks = None, None
+    stats, picks, carried = None, None, {}
+    sources = (_pick_sources(rec, cfg, len(dims["layer_types"]))
+               if rec.select else None)
+    counted = live > 0 if active is None else active
     for i, (pooled, n) in enumerate(_cache_layers(dims["layer_types"])):
         if fused is not None:
             out, k_tok, v_tok = fused_block_decode(
@@ -1251,18 +1472,44 @@ def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
             h = rec.fused.tail(cfg, fused[i], h, out) if tp > 1 else out
             continue
         lp = p[f"layer_{i}"]
-        hn = rec.norm(cfg, lp, "input", h)
+        u, mix = _sub_in(rec, cfg, lp, "attention", h)
+        hn = rec.norm(cfg, lp, "input", u)
         lrope = rope.get(dims["layer_types"][i])
         if rec.latent:
             # absorbed: the query against the latent rows, one pool, one
             # DMA a page; the value up-projection behind the softmax
             q, latent_row = rec.latent.absorb(cfg, lp, hn, *lrope)
-            cache = kv_cache.append_layer(cache, n, latent_row, None)
-            u = paged_decode_attention(
-                q, cache.k, None, cache.page_table, live, layer=n,
-                work=work, sm_scale=rec.latent.scale(cfg),
-                values=dims["latent_values"])
-            ctx, extra = rec.latent.value_up(cfg, lp, u), None
+            extra = _gate(rec, cfg, lp, hn)
+            if rec.select:
+                # a layer that picks caches its index key (in its own slot
+                # of a pool that keeps the picking layers' keys), scores
+                # and picks; one that reuses attends the carried set
+                src, slot = sources[i]
+                cache = kv_cache.append_layer(cache, n, latent_row, None)
+                if src == i:
+                    qi, wi, ki = rec.select.index(cfg, lp, hn, *flat[INDEX])
+                    cache = kv_cache.append_index(cache, slot, ki)
+                    carried[i] = _decode_select(
+                        cache, qi, wi, slot, work, live,
+                        rec.select.topk(cfg))
+                picked = carried[src]
+                with jax.named_scope("apex_dsa_attend"):
+                    u = paged_select_attention_latent(
+                        q, cache.k, picked, work, layer=n,
+                        sm_scale=rec.latent.scale(cfg),
+                        values=dims["latent_values"], sink=_sink(rec, lp))
+                picks = _select_stats(
+                    picks, counted, live,
+                    jnp.sum(picked, axis=1, dtype=jnp.int32),
+                    rec.select.topk(cfg),
+                    None if rec.select.sources is None else src != i)
+            else:
+                cache = kv_cache.append_layer(cache, n, latent_row, None)
+                u = paged_decode_attention(
+                    q, cache.k, None, cache.page_table, live, layer=n,
+                    work=work, sm_scale=rec.latent.scale(cfg),
+                    values=dims["latent_values"])
+            ctx = rec.latent.value_up(cfg, lp, u)
         else:
             q, k_tok, v_tok, extra = rec.project(cfg, dims, i, lp, hn,
                                                  lrope)
@@ -1274,18 +1521,12 @@ def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
                 qi, wi, ki = rec.select.index(cfg, lp, hn, *flat[INDEX])
                 cache = kv_cache.append_layer(cache, n, k_tok, v_tok, ki)
                 topk = rec.select.topk(cfg)
-                with jax.named_scope("apex_dsa_index"):
-                    scores = paged_index_scores(qi, wi, cache.ik, work,
-                                                layer=n)
-                with jax.named_scope("apex_dsa_select"):
-                    cols = jnp.arange(cache.max_seq, dtype=jnp.int32)
-                    picked = select_top_mask(scores, topk,
-                                             cols[None] < live[:, None])
+                picked = _decode_select(cache, qi, wi, n, work, live, topk)
                 with jax.named_scope("apex_dsa_attend"):
                     ctx = paged_select_attention(q, cache.k, cache.v,
                                                  picked, work, layer=n)
                 picks = _select_stats(
-                    picks, live > 0 if active is None else active, live,
+                    picks, counted, live,
                     jnp.sum(picked, axis=1, dtype=jnp.int32), topk)
             elif pooled:
                 cache = kv_cache.append_layer(cache, n, k_tok, v_tok)
@@ -1296,13 +1537,14 @@ def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
                 ctx = ring_decode_attention(
                     q, cache.wk[n], cache.wv[n], positions,
                     window=dims["window"])
-        x = h + rec.attn_out(lp, ctx, extra, tp)
-        y, st = rec.ffn(cfg, i, lp, rec.norm(cfg, lp, "post_attention", x),
+        x = _sub_out(rec, cfg, mix, h, rec.attn_out(lp, ctx, extra, tp))
+        u, mix = _sub_in(rec, cfg, lp, "ffn", x)
+        y, st = rec.ffn(cfg, i, lp, rec.norm(cfg, lp, "post_attention", u),
                         active, tp)
         stats = fold_stats(stats, st)
-        h = x + y
+        h = _sub_out(rec, cfg, mix, x, y)
 
-    h = rec.norm(cfg, p, "final", h)
+    h = _final(rec, cfg, p, h)
     if picks:
         stats = {**(stats or {}), **picks}
     return _gather_logits(rec.head(p, h, tp), tp), cache, stats

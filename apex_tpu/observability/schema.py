@@ -228,6 +228,11 @@ METRIC_SPECS: Dict[str, MetricSpec] = {s.name: s for s in [
                "serve_dsa_rows_total: over the positions those rows could "
                "have attended it is the share of the cache a step really "
                "read", labels=("phase",)),
+    MetricSpec("serve_dsa_rows_reused_total", "counter",
+               "those of serve_dsa_rows_total whose layer attended the "
+               "picks of an earlier layer instead of scoring its own "
+               "(a layer that holds no indexer)",
+               labels=("phase",)),
     # -- speculative decoding (ISSUE 15): the verify step's accept/
     #    reject accounting.  Drafted counts what the verify executable
     #    SCORED (k per active slot per round, padding drafts

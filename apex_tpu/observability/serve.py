@@ -44,6 +44,7 @@ SELECT_METRIC_FAMILIES = (
     "serve_dsa_rows_total",
     "serve_dsa_rows_sparse_total",
     "serve_dsa_selected_total",
+    "serve_dsa_rows_reused_total",
 )
 
 #: the ISSUE 30 expert-FFN / window-ring families (same schema-guard
@@ -270,6 +271,7 @@ class ServeTelemetry:
         self.dsa_rows = d("serve_dsa_rows_total")
         self.dsa_rows_sparse = d("serve_dsa_rows_sparse_total")
         self.dsa_selected = d("serve_dsa_selected_total")
+        self.dsa_rows_reused = d("serve_dsa_rows_reused_total")
         # request tracing (ISSUE 13): spans ride the SAME host
         # boundaries the methods below already occupy — arming the
         # tracer (trace= or APEX_TPU_TRACE) adds zero device work
@@ -559,7 +561,8 @@ class ServeTelemetry:
         """One step's device-side counters, BY NAME (a kind's record names
         what its steps report: ``models.EXPERT_STATS``, and
         ``models.SELECT_STATS`` for a kind that selects the positions it
-        attends); ``phase`` ``"prefill"`` or ``"decode"``.  A name the
+        attends, ``models.REUSE_STATS`` for one whose layers reuse
+        picks); ``phase`` ``"prefill"`` or ``"decode"``.  A name the
         telemetry has no family for raises: a counter is never dropped
         in silence."""
         counters = dict(counters)

@@ -63,7 +63,8 @@ from apex_tpu.utils import cdiv, interpret_mode
 __all__ = ["flash_attention", "mha_reference", "decode_attention",
            "ring_decode_attention", "prefix_window_attention",
            "slab_decode_attention", "index_scores",
-           "index_scores_reference", "select_top_mask", "select_attention"]
+           "index_scores_reference", "select_top_mask", "select_attention",
+           "select_picks", "select_attend", "with_sink"]
 
 #: pallas_audit registration (analysis hook only, no behavior change):
 #: every attention kernel carries online-softmax (m/l/acc) or wgrad
@@ -86,6 +87,7 @@ _NEG_INF = -1e30          # finite "masked" score: keeps exp()/where() NaN-free
 # consumed in base 2 strictly inside the kernels; the public API and the
 # oracle stay in natural log.
 _LOG2E = 1.4426950408889634
+_LN2 = 0.6931471805599453
 # a row whose max score is below this is FULLY masked (causal sq > sk,
 # fully-masked varlen rows): it must emit 0 output and 0 grads.  One
 # definition shared by the oracle, the forward kernel, and the backward
@@ -817,7 +819,8 @@ def flash_attention(q, k, v, *, causal: bool = False, mask=None,
                     dropout_seed=None,
                     use_kernel: Optional[bool] = None,
                     xla_max_seq: Optional[int] = None,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None,
+                    return_lse: bool = False):
     """Fused blockwise attention, ``[b, h, s, d]`` layout.
 
     Drop-in fused path for the reference's ``fmhalib`` /
@@ -859,6 +862,12 @@ def flash_attention(q, k, v, *, causal: bool = False, mask=None,
     latent attention scores over 192 channels and sums values of 128):
     the output is ``[b, h, sq, dv]``.  Forward only, like the window;
     ``dv == d`` compiles exactly the kernel it always did.
+
+    ``return_lse``: ``(out, lse)`` with ``lse [b, h, sq]`` float32 each
+    row's natural log-sum-exp of its scaled scores, which the kernel keeps
+    anyway — what a caller needs to add a term to the softmax's
+    denominator afterwards (:func:`with_sink`).  Forward only, always the
+    kernel.
     """
     b, h, sq, d = q.shape
     sk, v_width = k.shape[2], v.shape[3]
@@ -890,6 +899,8 @@ def flash_attention(q, k, v, *, causal: bool = False, mask=None,
                 f"mask must be boolean [b|1, h|1, sq|1, sk|1] "
                 f"(broadcastable to [{b}, {h}, {sq}, {sk}]); got "
                 f"{tuple(mask.shape)}")
+    if return_lse:
+        use_kernel = True
     if use_kernel is None:
         use_kernel = (block_q is not None or block_k is not None
                       or max(sq, sk) > xla_path_max_seq(xla_max_seq)
@@ -946,6 +957,14 @@ def flash_attention(q, k, v, *, causal: bool = False, mask=None,
         if mask3 is not None:   # padding handled by the validity window
             mask3 = jnp.pad(
                 mask3, ((0, 0), (0, sq_pad - sq), (0, sk_pad - sk)))
+
+    if return_lse:
+        out, lse = _fwd(q3, k3, v3, mask3, causal, scale, bq, bk,
+                        causal_off=causal_off, valid=valid,
+                        rate=dropout_rate, seed3=seed3, window=window)
+        # the kernel's lse is base 2 (module note): ln x = log2 x * ln 2
+        return (out[:, :sq, :].reshape(b, h, sq, v_width),
+                (lse[:, :sq] * _LN2).reshape(b, h, sq))
 
     # mask3/seed3 are custom_vjp ARGUMENTS, not closure captures: a
     # traced value closed over by a custom_vjp function leaks its trace
@@ -1085,6 +1104,54 @@ def select_top_mask(scores, k: int, live):
     return above | ties
 
 
+def with_sink(out, lse, sink):
+    """Attention ``out [b, h, sq, dv]`` whose softmax gains one more term
+    in its denominator, ``exp(sink[h])``, that carries no value: ``out *
+    sigmoid(lse - sink)``, exact given each row's natural log-sum-exp
+    ``lse [b, h, sq]`` (``flash_attention(return_lse=True)``).  A row that
+    attended nothing (``lse`` the kernel's masked floor) stays zero."""
+    w = jax.nn.sigmoid(lse - sink.astype(jnp.float32)[None, :, None])
+    return (out.astype(jnp.float32) * w[..., None]).astype(out.dtype)
+
+
+def _attend(q, k, v, *, sm_scale, sink, **kw):
+    """``flash_attention``, with each head's sink where there is one."""
+    if sink is None:
+        return flash_attention(q, k, v, sm_scale=sm_scale, **kw)
+    return with_sink(*flash_attention(q, k, v, sm_scale=sm_scale,
+                                      return_lse=True, **kw), sink)
+
+
+def _select_groups(s: int, topk: int, block_q: int):
+    """The row blocks of a sequence of ``s`` past its first ``topk`` rows:
+    ``(bq, [(r0, r1), ...])``, at most eight groups of whole blocks, a
+    group scoring and attending the keys up to its last row."""
+    bq = int(np.gcd(block_q, s - topk))
+    group = max(bq, cdiv(cdiv(s - topk, 8), bq) * bq)
+    return bq, [(r0, min(r0 + group, s)) for r0 in range(topk, s, group)]
+
+
+def _pick_block(qi, wi, kig, cols, t0, bq: int, topk: int):
+    """The picked set ``[bq, r1]`` of rows ``t0 ..`` among the keys of
+    ``kig [r1, di]`` (positions ``cols``): index scores, then the exact
+    top ``topk`` of the causal ones."""
+    rows = t0 + jnp.arange(bq, dtype=jnp.int32)
+    with jax.named_scope("apex_dsa_index"):
+        scores = index_scores(jax.lax.dynamic_slice_in_dim(qi, t0, bq),
+                              jax.lax.dynamic_slice_in_dim(wi, t0, bq), kig)
+    with jax.named_scope("apex_dsa_select"):
+        return select_top_mask(scores, topk, cols[None, :] <= rows[:, None])
+
+
+def _attend_block(q, kg, vg, picked, t0, bq: int, sm_scale, sink):
+    """Rows ``t0 ..`` of ``q`` over the keys ``kg``/``vg`` they picked."""
+    with jax.named_scope("apex_dsa_attend"):
+        ctx = _attend(jax.lax.dynamic_slice_in_dim(q, t0, bq, axis=2), kg,
+                      vg, mask=~picked[None, None], sm_scale=sm_scale,
+                      sink=sink, use_kernel=True)
+    return ctx[0]
+
+
 def select_attention(q, k, v, qi, wi, ki, *, topk: int, block_q: int = 512,
                      sm_scale: Optional[float] = None):
     """Causal attention of ONE sequence in which query ``t`` attends the
@@ -1102,7 +1169,9 @@ def select_attention(q, k, v, qi, wi, ki, *, topk: int, block_q: int = 512,
     set (:func:`select_top_mask`), the flash kernel under that mask — so
     no ``[s, s]`` array outlives a block.  Blocks are grouped (at most
     eight groups) so that a block scores and attends the keys up to its
-    GROUP's last row, not the sequence's."""
+    GROUP's last row, not the sequence's.  A kind whose later layers
+    attend the same picks makes them once (:func:`select_picks`) and
+    attends under them in each layer (:func:`select_attend`)."""
     b, h, s, d = q.shape
     if b != 1:
         raise ValueError(f"select_attention takes one sequence, got "
@@ -1113,35 +1182,74 @@ def select_attention(q, k, v, qi, wi, ki, *, topk: int, block_q: int = 512,
                                sm_scale=sm_scale)
     if s <= topk:
         return head, jnp.arange(1, s + 1, dtype=jnp.int32)
-    bq = int(np.gcd(block_q, s - topk))
-    group = max(bq, cdiv(cdiv(s - topk, 8), bq) * bq)
+    bq, groups = _select_groups(s, topk, block_q)
     ctxs, counts = [head], [jnp.arange(1, topk + 1, dtype=jnp.int32)]
-    for r0 in range(topk, s, group):
-        r1 = min(r0 + group, s)         # the group's rows; its keys [0, r1)
+    for r0, r1 in groups:               # the group's rows; its keys [0, r1)
         kg, vg, kig = k[:, :, :r1], v[:, :, :r1], ki[:r1]
         cols = jnp.arange(r1, dtype=jnp.int32)
 
         def block(t0, kg=kg, vg=vg, kig=kig, cols=cols):
-            rows = t0 + jnp.arange(bq, dtype=jnp.int32)
-            with jax.named_scope("apex_dsa_index"):
-                scores = index_scores(
-                    jax.lax.dynamic_slice_in_dim(qi, t0, bq),
-                    jax.lax.dynamic_slice_in_dim(wi, t0, bq), kig)
-            with jax.named_scope("apex_dsa_select"):
-                picked = select_top_mask(scores, topk,
-                                         cols[None, :] <= rows[:, None])
-            with jax.named_scope("apex_dsa_attend"):
-                ctx = flash_attention(
-                    jax.lax.dynamic_slice_in_dim(q, t0, bq, axis=2), kg, vg,
-                    mask=~picked[None, None], sm_scale=sm_scale,
-                    use_kernel=True)
-            return ctx[0], jnp.sum(picked, axis=1, dtype=jnp.int32)
+            picked = _pick_block(qi, wi, kig, cols, t0, bq, topk)
+            ctx = _attend_block(q, kg, vg, picked, t0, bq, sm_scale, None)
+            return ctx, jnp.sum(picked, axis=1, dtype=jnp.int32)
 
         ctx, n = jax.lax.map(block, jnp.arange(r0, r1, bq, dtype=jnp.int32))
         # [blocks, h, bq, d] -> [1, h, rows, d]
         ctxs.append(jnp.moveaxis(ctx, 0, 1).reshape(1, h, r1 - r0, d))
         counts.append(n.reshape(-1))
     return jnp.concatenate(ctxs, axis=2), jnp.concatenate(counts)
+
+
+def select_picks(qi, wi, ki, *, topk: int, block_q: int = 512):
+    """The picked sets of ONE sequence, made once for every layer that
+    attends them: ``qi [s, heads, di]``, ``wi [s, heads]``,
+    ``ki [s, di]`` -> ``(masks, picked)``, ``masks`` one bool ``[blocks,
+    bq, r1]`` a group of :func:`select_attention`'s row blocks (empty
+    where ``s <= topk``: every row then attends its causal positions) and
+    ``picked [s]`` int32 the positions each row attends."""
+    s = ki.shape[0]
+    if s <= topk:
+        return (), jnp.arange(1, s + 1, dtype=jnp.int32)
+    bq, groups = _select_groups(s, topk, block_q)
+    masks, counts = [], [jnp.arange(1, topk + 1, dtype=jnp.int32)]
+    for r0, r1 in groups:
+        kig, cols = ki[:r1], jnp.arange(r1, dtype=jnp.int32)
+        m = jax.lax.map(
+            lambda t0, kig=kig, cols=cols: _pick_block(qi, wi, kig, cols, t0,
+                                                       bq, topk),
+            jnp.arange(r0, r1, bq, dtype=jnp.int32))
+        masks.append(m)
+        counts.append(jnp.sum(m, axis=2, dtype=jnp.int32).reshape(-1))
+    return tuple(masks), jnp.concatenate(counts)
+
+
+def select_attend(q, k, v, masks, *, topk: int, block_q: int = 512,
+                  sm_scale: Optional[float] = None, sink=None):
+    """:func:`select_attention`'s attention under picks made before
+    (:func:`select_picks`' ``masks``, of the same ``s``, ``topk`` and
+    ``block_q``): ``q``/``k`` ``[1, h, s, d]``, ``v [1, h, s, dv]`` ->
+    ``[1, h, s, dv]``; ``sink [h]`` (float32, optional) each head's sink
+    logit (:func:`with_sink`)."""
+    b, h, s, _ = q.shape
+    dv = v.shape[3]
+    with jax.named_scope("apex_dsa_attend"):
+        head = _attend(q[:, :, :topk], k[:, :, :topk], v[:, :, :topk],
+                       causal=True, sm_scale=sm_scale, sink=sink)
+    if s <= topk:
+        return head
+    bq, groups = _select_groups(s, topk, block_q)
+    ctxs = [head]
+    for (r0, r1), m in zip(groups, masks):
+        kg, vg = k[:, :, :r1], v[:, :, :r1]
+
+        def block(args, kg=kg, vg=vg):
+            return _attend_block(q, kg, vg, args[1], args[0], bq, sm_scale,
+                                 sink)
+
+        ctx = jax.lax.map(block, (jnp.arange(r0, r1, bq, dtype=jnp.int32),
+                                  m))
+        ctxs.append(jnp.moveaxis(ctx, 0, 1).reshape(1, h, r1 - r0, dv))
+    return jnp.concatenate(ctxs, axis=2)
 
 
 # --------------------------------------------------------------------------

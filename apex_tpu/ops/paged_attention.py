@@ -72,7 +72,11 @@ bytes), rectified, weighted and summed over the index heads, one
 picked positions of each page as one more block (an operand the other
 callers do not trace), so that an unpicked position gets no probability
 mass and a slot that picked every live position gets
-``apex_paged_decode``'s answer bit for bit.  The mathematics is the
+``apex_paged_decode``'s answer bit for bit.  Over a LATENT pool
+the attend stage is :func:`paged_select_attention_latent`, the kernel
+``apex_dsa_attend_latent``: ``apex_paged_decode_latent``'s body and walk
+with the same block, and a sink a head (one more term of the softmax's
+denominator, with no value) where the kind has one.  The mathematics is the
 sparse one; the walk still reads every live page (gathering the picked
 rows costs more than it saves at this pool's layout: PERF.md section 6,
 PR 36).  No stage makes a pool-sized or ``[slots, max_seq, heads,
@@ -98,7 +102,7 @@ from apex_tpu.utils import interpret_mode
 
 __all__ = ["paged_decode_attention", "paged_work_list", "PagedWork",
            "paged_slab_attention", "paged_index_scores",
-           "paged_select_attention",
+           "paged_select_attention", "paged_select_attention_latent",
            "fused_block_decode", "decode_fusion", "fusion_min_pages",
            "resolve_decode_fusion",
            "fused_block_vmem_bytes", "fused_block_refusal",
@@ -294,10 +298,17 @@ def _paged_kernel_call(q, k_pool, v_pool, work, layer, picked=None, *,
 # the latent form: one pool, every head scores the whole row
 # --------------------------------------------------------------------------
 
-def _latent_kernel(scale, ps, dv,
+def _latent_kernel(scale, ps, dv, picks, sinks,
                    slot_ref, page_ref, start_ref, len_ref, layer_ref,
-                   q_ref, c_ref, o_ref, m_scr, l_scr, acc_scr):
-    c_ref = c_ref.at[0, 0]                  # the block [1, 1, width, ps]
+                   q_ref, *refs):
+    # ``picks`` / ``sinks`` (static): one more block before the
+    # pool, the page's PICKED positions, and one after it, the heads' sink
+    # logits.  Without them nothing of either is traced: the kernel is the
+    # one it always was
+    pick_ref = refs[0] if picks else None
+    c_ref = refs[picks].at[0, 0]            # the block [1, 1, width, ps]
+    sink_ref = refs[picks + 1] if sinks else None
+    o_ref, m_scr, l_scr, acc_scr = refs[picks + 1 + sinks:]
     item = pl.program_id(0)
     sid = slot_ref[item]
     p = item - start_ref[sid]               # the page's place in the slot
@@ -318,11 +329,16 @@ def _latent_kernel(scale, ps, dv,
             q, rows,
             preferred_element_type=jnp.float32) * (scale * _LOG2E)
         cols = p * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(cols < length, s, _NEG_INF)
+        keep = cols < length
+        if picks:       # an unpicked position gets no probability mass
+            keep = keep & (pick_ref[0, 0] > 0.0)
+        s = jnp.where(keep, s, _NEG_INF)
         m_prev = m_scr[...]                              # [h, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp2(m_prev - m_new)
         pmat = jnp.exp2(s - m_new)
+        if picks:       # a page may hold no picked position at all
+            pmat = jnp.where(keep, pmat, 0.0)
         l_scr[...] = l_scr[...] * alpha + \
             jnp.sum(pmat, axis=1, keepdims=True)
         # the values are the rows' leading channels: no second block
@@ -335,29 +351,45 @@ def _latent_kernel(scale, ps, dv,
     @pl.when(item == start_ref[sid + 1] - 1)
     def _finish():
         l = l_scr[...]
+        if sinks:
+            # a head's sink is one more term of the denominator, with no
+            # value: exp(sink - max), the max in the base-2 domain
+            l = l + jnp.exp2(sink_ref[...] * _LOG2E - m_scr[...])
         o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)
                     ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "values"))
-def _latent_kernel_call(q, pool, work, layer, *, scale, values):
+def _latent_kernel_call(q, pool, work, layer, picked=None, sink=None, *,
+                        scale, values):
     # as _paged_kernel_call: the layer a traced scalar-prefetch operand,
-    # one trace and one Mosaic lowering for every layer of a step
+    # one trace and one Mosaic lowering for every layer of a step.
+    # ``picked [slots, max_seq]`` (bool) and ``sink [h]`` (float32), where
+    # given, are one more block each and the kernel's other name
     slots, h, width = q.shape
     ps = pool.shape[3]
 
     def slot_index(i, slot, page, start, ln, ly):
         return (slot[i], 0, 0)
 
+    def pick_index(i, slot, page, start, ln, ly):
+        return (slot[i], i - start[slot[i]], 0, 0)
+
     def page_index(i, slot, page, start, ln, ly):
         return (page[i], ly[0], 0, 0)
 
+    picks = () if picked is None else (picked.astype(jnp.float32).reshape(
+        slots, picked.shape[1] // ps, 1, ps),)
+    sinks = () if sink is None else (
+        sink.astype(jnp.float32).reshape(h, 1),)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(work.start[-1],),             # traced: the live items only
         in_specs=[
             pl.BlockSpec((1, h, width), slot_index),
+            *[pl.BlockSpec((1, 1, 1, ps), pick_index) for _ in picks],
             pl.BlockSpec((1, 1, width, ps), page_index),
+            *[pl.BlockSpec((h, 1), lambda i, *_: (0, 0)) for _ in sinks],
         ],
         out_specs=pl.BlockSpec((1, h, values), slot_index),
         scratch_shapes=[
@@ -366,7 +398,9 @@ def _latent_kernel_call(q, pool, work, layer, *, scale, values):
             pltpu.VMEM((h, values), jnp.float32),  # fp32 output accum
         ],
     )
-    kernel = functools.partial(_latent_kernel, scale, ps, values)
+    kernel = functools.partial(_latent_kernel, scale, ps, values,
+                               len(picks), len(sinks))
+    name = "apex_dsa_attend_latent" if picks else "apex_paged_decode_latent"
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -374,8 +408,9 @@ def _latent_kernel_call(q, pool, work, layer, *, scale, values):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret_mode(),
-        name="apex_paged_decode_latent",
-    )(work.slot, work.page, work.start, work.lengths, layer, q, pool)
+        name=name,
+    )(work.slot, work.page, work.start, work.lengths, layer, q, *picks, pool,
+      *sinks)
 
 
 # --------------------------------------------------------------------------
@@ -507,6 +542,44 @@ def paged_select_attention(q, k_pool, v_pool, picked, work: PagedWork, *,
     return _paged_kernel_call(q, k_pool, v_pool, work,
                               jnp.full((1,), layer, jnp.int32), picked,
                               scale=float(scale))
+
+
+def paged_select_attention_latent(q, pool, picked, work: PagedWork, *,
+                                  layer: int, sm_scale: float, values: int,
+                                  sink=None):
+    """Single-token LATENT attention over the PICKED positions of ONE layer
+    of a latent pool: ``q [slots, h, width]`` the absorbed
+    queries, ``pool [pages, layers, width, page_size]`` WHOLE, ``picked
+    [slots, max_seq]`` (bool) the positions each slot attends, ``work`` the
+    step's :func:`paged_work_list`; ``sink [h]`` (float32, optional) each
+    head's sink logit, one more term of the softmax's denominator that
+    carries no value.  Returns ``[slots, h, values]``.
+
+    The Pallas kernel ``apex_dsa_attend_latent`` IS
+    ``apex_paged_decode_latent``'s (one body, ``_latent_kernel``): its walk
+    of the live pages with the picked positions of each page as one more
+    block — so a slot that picked every live position and has no sink gets
+    that kernel's answer — and the sinks, where given, added at the end."""
+    slots, h, width = q.shape
+    if pool.ndim != 4 or pool.shape[2] != width or not 0 < values <= width:
+        raise ValueError(
+            f"the latent form takes the pool [pages, layers, {width}, "
+            f"page_size] and values in (0, {width}]; got pool "
+            f"{tuple(pool.shape)}, values {values}")
+    ps = pool.shape[3]
+    if picked.ndim != 2 or picked.shape[0] != slots or picked.shape[1] % ps:
+        raise ValueError(
+            f"picked must be [{slots}, max_seq] with max_seq whole pages "
+            f"of {ps}, got {tuple(picked.shape)}")
+    if sink is not None and sink.shape != (h,):
+        raise ValueError(f"sink must be [{h}], got {tuple(sink.shape)}")
+    if not 0 <= layer < pool.shape[1]:
+        raise ValueError(f"layer {layer} is outside the pool's "
+                         f"{pool.shape[1]} layers")
+    return _latent_kernel_call(q, pool, work,
+                               jnp.full((1,), layer, jnp.int32), picked,
+                               sink, scale=float(sm_scale),
+                               values=int(values))
 
 
 # --------------------------------------------------------------------------

@@ -57,10 +57,23 @@ __all__ = ["route_top_k", "route_group_limited", "dropless_moe_ffn",
 HELD_ROW_BLOCK = 512
 
 
-def swiglu(x, w_gate, w_up, w_down):
+def _gated(gate, up: Callable, limit: Optional[float]):
+    """``silu(gate) * up()``, the up projection made after the gate's
+    activation; ``limit`` (``swiglu_limit``) clamps the gate from
+    above and the up projection both ways, and ``None`` traces nothing."""
+    if limit is not None:
+        gate = jnp.minimum(gate, limit)
+    act = jax.nn.silu(gate)
+    up = up()
+    if limit is not None:
+        up = jnp.clip(up, -limit, limit)
+    return act * up
+
+
+def swiglu(x, w_gate, w_up, w_down, limit: Optional[float] = None):
     """SwiGLU over ``[out, in]`` weights (the TP layers' layout)."""
-    return jnp.matmul(jax.nn.silu(jnp.matmul(x, w_gate.T))
-                      * jnp.matmul(x, w_up.T), w_down.T)
+    return jnp.matmul(_gated(jnp.matmul(x, w_gate.T),
+                             lambda: jnp.matmul(x, w_up.T), limit), w_down.T)
 
 
 def route_top_k(x, router_w, top_k: int, scale: float):
@@ -103,7 +116,7 @@ def route_group_limited(x, router_w, top_k: int, scale: float, *,
 
 
 def _held_products(x, weights, experts, w_gate, w_up, w_down,
-                   held: Tuple[int, int], valid):
+                   held: Tuple[int, int], valid, limit=None):
     """The routed part of the experts ``[first, first + count)`` — see the
     module docstring — as ``(y [T, hidden] float32, group_sizes
     [count])``."""
@@ -138,8 +151,8 @@ def _held_products(x, weights, experts, w_gate, w_up, w_down,
         # the block's rows of each held expert's group
         sizes = jnp.clip(ends, lo, lo + block) \
             - jnp.clip(ends - group_sizes, lo, lo + block)
-        act = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, sizes)) \
-            * jax.lax.ragged_dot(xs, w_up, sizes)
+        act = _gated(jax.lax.ragged_dot(xs, w_gate, sizes),
+                     lambda: jax.lax.ragged_dot(xs, w_up, sizes), limit)
         ys = jax.lax.ragged_dot(act.astype(x.dtype), w_down, sizes)
         w = jnp.where(live, jnp.take(w_flat, ids), 0.0)
         # rows past the landed ones belong to no group: take none of them
@@ -155,7 +168,8 @@ def _held_products(x, weights, experts, w_gate, w_up, w_down,
 def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                      scale: float = 1.0, shared: Optional[dict] = None,
                      valid=None, held: Optional[Tuple[int, int]] = None,
-                     router: Optional[Callable] = None):
+                     router: Optional[Callable] = None,
+                     limit: Optional[float] = None):
     """``x [T, hidden]`` -> ``(y [T, hidden], stats)``.
 
     ``router_w [E, hidden]``; ``w_gate``/``w_up`` ``[E, hidden, ffn]`` and
@@ -171,6 +185,8 @@ def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     (optional) is ``(first, count)``, the experts whose stacks ``w_gate``
     / ``w_up`` / ``w_down`` ``[count, ...]`` are: ``y`` is then their part
     of the routed sum (module docstring) plus the shared expert.
+    ``limit`` (optional) clamps every SwiGLU's gate and up projection,
+    the shared expert's too (``swiglu_limit``).
 
     ``stats`` are int32 scalars computed on the device: ``assignments``
     (valid tokens x ``top_k``; with ``held``, those that LAND on a held
@@ -184,9 +200,9 @@ def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
             else router(x, router_w))
     if held is not None:
         y, group_sizes = _held_products(x, weights, experts, w_gate, w_up,
-                                        w_down, held, valid)
+                                        w_down, held, valid, limit)
         y = y.astype(x.dtype)
-        return _finish(x, y, shared, group_sizes)
+        return _finish(x, y, shared, group_sizes, limit)
     with jax.named_scope("apex_moe_sort"):
         flat = experts.reshape(-1)
         if valid is not None:
@@ -197,8 +213,9 @@ def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
             jnp.int32)
         xs = jnp.take(x, order // top_k, axis=0)      # [T*k, hidden]
     with jax.named_scope("apex_moe_experts"):
-        act = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, group_sizes)) \
-            * jax.lax.ragged_dot(xs, w_up, group_sizes)
+        act = _gated(jax.lax.ragged_dot(xs, w_gate, group_sizes),
+                     lambda: jax.lax.ragged_dot(xs, w_up, group_sizes),
+                     limit)
         ys = jax.lax.ragged_dot(act.astype(x.dtype), w_down, group_sizes)
     with jax.named_scope("apex_moe_combine"):
         # back to token order: row order[i] of the flat assignments is ys[i]
@@ -211,16 +228,16 @@ def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
             # the grouped product writes nothing: take none of it
             y = jnp.where(valid[:, None, None], y, 0.0)
         y = jnp.sum(y * weights[..., None], axis=1).astype(x.dtype)
-    return _finish(x, y, shared, group_sizes)
+    return _finish(x, y, shared, group_sizes, limit)
 
 
-def _finish(x, y, shared, group_sizes):
+def _finish(x, y, shared, group_sizes, limit=None):
     """The shared expert on top of the routed sum, and the step's stats."""
     if shared is not None:
         with jax.named_scope("apex_moe_shared"):
             y = y + swiglu(x, shared["gate_proj"]["weight"],
                            shared["up_proj"]["weight"],
-                           shared["down_proj"]["weight"])
+                           shared["down_proj"]["weight"], limit)
     stats = {"assignments": jnp.sum(group_sizes),
              "experts_hit": jnp.sum((group_sizes > 0).astype(jnp.int32)),
              "load_max": jnp.max(group_sizes)}

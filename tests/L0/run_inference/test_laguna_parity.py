@@ -248,6 +248,19 @@ WORDS = {
         "fused": "fused_block_decode is not built for the 'keye' kind: "
                  "ops/paged_attention.py",
         "prefix_sharing": "chunked prefill, is not built for the 'keye' "
+                          "kind: models._suffix_attend"},
+    # and the sixth kind, likewise
+    "hy4": {
+        "dense": "serves from the paged cache only",
+        "tp": "tp > 1 is not built for the 'hy4' kind: "
+              "models.param_partition_specs",
+        "verify": "speculative verify is not built for the 'hy4' kind: "
+                  "ops/paged_attention.py",
+        "host_tier": "the host KV tier is not built for the 'hy4' kind: "
+                     "kv_cache.HostPageStore",
+        "fused": "fused_block_decode is not built for the 'hy4' kind: "
+                 "ops/paged_attention.py",
+        "prefix_sharing": "chunked prefill, is not built for the 'hy4' "
                           "kind: models._suffix_attend"}}
 #: how each feature is asked of an engine at construction
 ASKED = {"dense": dict(page_size=None, num_pages=None), "tp": dict(tp=2),
@@ -260,10 +273,15 @@ ASKED = {"dense": dict(page_size=None, num_pages=None), "tp": dict(tp=2),
 def toys(tiny):
     """kind -> (config, params) at toy size; a kind new here: its toy."""
     from apex_tpu.transformer.testing import standalone_axk1 as SA
+    from apex_tpu.transformer.testing import standalone_hy4 as SH
     from apex_tpu.transformer.testing import standalone_keye as SK
-    acfg, kcfg = SA.AXK1Config(), SK.KeyeConfig()
+    acfg, kcfg, hcfg = SA.AXK1Config(), SK.KeyeConfig(), SH.HY4Config()
     tokens = jnp.zeros((1, 8), jnp.int32)
     return {"laguna": tiny[:2],
+            "hy4": (hcfg, {"params": jax.tree.map(
+                lambda shape: jnp.full(shape, 0.02, jnp.float32),
+                SH.hy4_param_shapes(hcfg),
+                is_leaf=lambda x: isinstance(x, tuple))}),
             "axk1": (acfg, SA.axk1_model_provider(acfg).init(
                 jax.random.PRNGKey(0), tokens)),
             "keye": (kcfg, SK.keye_model_provider(kcfg).init(

@@ -67,7 +67,7 @@ _LN = (_s((128, 256), BF16), _s((256,), F32), _s((256,), F32))
 _QKV = (_s((1, 2, 256, 64), BF16),) * 3
 _QKV_LONG = (_s((1, 1, 8192, 128), BF16),) * 3     # past the fused backward
 
-#: name -> () -> (function, abstract arguments): one way to each of the 20
+#: name -> () -> (function, abstract arguments): one way to each of the 21
 #: ``pallas_call`` sites under ``apex_tpu/ops``
 KERNELS = {
     "apex_amp_unscale": lambda: (_unscale, (_flat(),)),
@@ -100,6 +100,8 @@ KERNELS = {
     "apex_dsa_index_fwd": lambda: _registered("dsa_index_scores"),
     "apex_dsa_index": lambda: _registered("paged_index_scores"),
     "apex_dsa_attend": lambda: _registered("paged_select_attention"),
+    "apex_dsa_attend_latent": lambda: _registered(
+        "paged_select_attention_latent"),
 }
 
 
@@ -125,8 +127,8 @@ def test_the_pallas_call_equation_carries_its_stable_name(name):
 
 def test_every_pallas_call_site_is_named_and_no_name_is_used_twice():
     # a site's ``name=`` is a literal, or a variable assigned from literals
-    # (the paged walk runs under two names: with and without picked
-    # positions): 19 sites, 20 names
+    # (the paged walk and the latent walk each run under two names: with
+    # and without picked positions): 19 sites, 21 names
     calls, named, names = 0, 0, []
     for f in sorted(OPS.glob("*.py")):
         src = f.read_text()
@@ -135,7 +137,7 @@ def test_every_pallas_call_site_is_named_and_no_name_is_used_twice():
         for line in re.findall(r"^\s+name ?= ?.*$", src, re.M):
             names += re.findall(r'"(apex_\w+)"', line)
     assert calls == named == 19
-    assert len(names) == 20 and sorted(names) == sorted(KERNELS)
+    assert len(names) == 21 and sorted(names) == sorted(KERNELS)
 
 
 # -- compiled for the described chip (no chip attached, nothing runs) --------
