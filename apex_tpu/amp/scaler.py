@@ -6,9 +6,12 @@ on overflow — and ``_has_inf_or_nan`` overflow detection.
 
 TPU-native design: the scaler is a pytree (``LossScaleState``) carried
 through the jitted train step; overflow detection is the fused non-finite
-flag from :func:`apex_tpu.ops.fused_update.fused_scale` (no device→host
-sync, the classic CUDA perf trap called out in SURVEY §3.1); skip-on-overflow
-is the ``noop_flag`` predicate inside the fused optimizer kernel.
+flag from :func:`apex_tpu.ops.fused_update.fused_scale`, or in the
+flat-native step a read-only reduction with the unscale folded into the
+optimizer's ``grad_scale`` (:func:`check_flat_grads`) — no device→host
+sync either way, the classic CUDA perf trap called out in SURVEY §3.1;
+skip-on-overflow is the ``noop_flag`` predicate inside the fused
+optimizer kernel.
 """
 from __future__ import annotations
 
@@ -20,7 +23,7 @@ from apex_tpu.ops.fused_update import fused_scale
 from apex_tpu.utils import tree_ravel
 
 __all__ = ["LossScaleState", "init_loss_scale", "scale_loss_value",
-           "unscale_grads", "unscale_flat_grads",
+           "unscale_grads", "check_flat_grads",
            "nonfinite_leaf_counts", "update_scale", "LossScaler"]
 
 # Reference constants (apex/amp/scaler.py)
@@ -68,31 +71,42 @@ def unscale_grads(grads, state: LossScaleState):
     return unravel(out), state.replace(found_inf=flag)
 
 
-def unscale_flat_grads(flat_grads, state: LossScaleState, axis_name=None):
-    """Flat-native :func:`unscale_grads`: same fused unscale + overflow
-    detection, but over an already-flat grad buffer — the variant the
-    flat-native train step uses, where autodiff produced flat grads and
-    a tree round-trip would reintroduce the re-ravel concatenate.
+def check_flat_grads(flat_grads, state: LossScaleState, axis_name=None):
+    """Overflow detection of a SCALED flat grad buffer, without writing
+    the unscaled copy: ``found_inf`` flags a non-finite element of
+    ``flat_grads * (1 / scale)``, the product :func:`fused_scale` would
+    write, and nothing else is produced.  The flat-native train step
+    hands the optimizer the scaled buffer as the backward wrote it with
+    ``grad_scale=1/scale``, which every fused update folds into the
+    multiplier its kernel already applies; this is a read-only
+    reduction that XLA fuses with the optimizer's own read of the
+    buffer.  An empty buffer reads clean.
 
     ``axis_name`` reduces the overflow flag across a mesh axis (pmax):
-    under ZeRO each rank unscales only its own grad SHARD, but the
-    skip decision must be replica-uniform — a rank whose shard happens
-    to be finite must still skip when any peer overflowed, or the
-    ranks' masters diverge silently.
+    under ZeRO each rank checks only its own grad SHARD, but the skip
+    decision must be replica-uniform — a rank whose shard happens to be
+    finite must still skip when any peer overflowed, or the ranks'
+    masters diverge silently.
 
-    Returns (unscaled_flat_grads, new_state with found_inf set).
+    Returns the new state with found_inf set.
     """
-    out, flag = fused_scale(flat_grads, 1.0 / state.loss_scale)
+    inv_scale = 1.0 / state.loss_scale
+    # a float max, not a boolean any: the v5e's compiler then merges it
+    # with the optimizer's float sum of squares (and the backward's
+    # build of the flat buffer) into ONE pass over the grads; a pred
+    # reduce stays a pass of its own
+    flag = jnp.max((~jnp.isfinite(flat_grads * inv_scale)).astype(
+        jnp.float32), initial=0.0)
     if axis_name is not None:
         flag = jax.lax.pmax(flag, axis_name)
-    return out, state.replace(found_inf=flag)
+    return state.replace(found_inf=flag)
 
 
 def nonfinite_leaf_counts(flat_grads, sizes, *, axis_name=None, dp=1,
                           shard_len=None, rank=None, spans=None):
     """Per-leaf counts of nonfinite (inf/nan) elements of a flat grad
     buffer — WHICH parameter overflowed, next to
-    :func:`unscale_flat_grads`'s scalar ``found_inf`` that only says
+    :func:`check_flat_grads`'s scalar ``found_inf`` that only says
     THAT one did.  This is the overflow autopsy's attribution signal
     (ISSUE 11): computed in-program as one more scalar-vector output of
     the donated step, resolved one step late by the telemetry, so the

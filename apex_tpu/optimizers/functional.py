@@ -497,11 +497,15 @@ class _LambTx:
         axis, dp = state.shard_axis, state.shard_dp
         mgn = _f32(self.max_grad_norm if max_grad_norm is None
                    else max_grad_norm)
-        g32 = flat_grads.astype(jnp.float32) * _f32(grad_scale)
+        g32 = flat_grads.astype(jnp.float32)    # no-op for fp32 grads
+        gscale = _f32(grad_scale)
         # global grad norm clip (reference: first multi_tensor_l2norm
         # launch); under ZeRO each rank holds one grad shard, so the
-        # shard-local sum of squares is psum'd into the global norm
-        gsq = jnp.sum(g32 * g32)
+        # shard-local sum of squares is psum'd into the global norm.
+        # The product with grad_scale is only reduced here: the kernel
+        # reads the buffer as given and applies grad_scale with the
+        # clip, so no scaled copy of the flat buffer is written
+        gsq = jnp.sum(jnp.square(g32 * gscale))
         if sharded:
             gsq = jax.lax.psum(gsq, axis)
         gnorm = jnp.sqrt(gsq)
@@ -516,7 +520,7 @@ class _LambTx:
             weight_decay=_f32(self.weight_decay if weight_decay is None
                               else weight_decay),
             step=t, bias_correction=self.bias_correction,
-            grad_scale=clip, grad_averaging=self.grad_averaging,
+            grad_scale=clip * gscale, grad_averaging=self.grad_averaging,
             noop_flag=_f32(noop_flag))
 
         if sharded:

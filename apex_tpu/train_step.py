@@ -14,10 +14,12 @@ from the step entirely.  Full pytree materialization happens only at
 checkpoint/eval boundaries (``TrainState.params()``).
 
 amp is carried in-program: the loss is scaled before the backward, the
-flat grads are unscaled by the fused non-finite-detecting kernel
-(:func:`apex_tpu.amp.scaler.unscale_flat_grads`), and the overflow flag
-feeds the update kernel's ``noop_flag`` predicate — no host sync
-anywhere between backward and update.
+flat grads stay scaled and ``1/scale`` rides the optimizer's
+``grad_scale`` (the multiplier its kernel already applies), a read-only
+reduction flags a non-finite grad
+(:func:`apex_tpu.amp.scaler.check_flat_grads`), and that flag feeds the
+update kernel's ``noop_flag`` predicate — no host sync anywhere between
+backward and update, and no unscaled copy of the flat grads.
 
 Typical use (the shape ``examples/bert/pretrain_bert.py`` runs)::
 
@@ -40,8 +42,8 @@ import numpy as np
 
 from apex_tpu.amp.scaler import (
     LossScaleState,
+    check_flat_grads,
     init_loss_scale,
-    unscale_flat_grads,
     update_scale,
 )
 from apex_tpu.observability import xla_stats
@@ -167,11 +169,11 @@ def make_train_step(loss_fn, tx, *, has_aux: bool = False,
     ``(loss, aux)`` with ``has_aux=True``).  ``metrics`` is the UNSCALED
     loss (or ``(loss, aux)``).
 
-    ``grad_transform(flat_grads)`` runs between backward and unscale —
-    the hook for data-parallel ``pmean`` or per-leaf collective fixups
-    (see :func:`leaf_offsets`); it must stay on-device and flat.  Under
-    ``zero=True`` it receives the local grad SHARD (already dp-meaned),
-    so per-leaf offset fixups do not apply there.
+    ``grad_transform(flat_grads)`` runs between backward and the
+    overflow check — the hook for data-parallel ``pmean`` or per-leaf
+    collective fixups (see :func:`leaf_offsets`); it must stay on-device
+    and flat.  Under ``zero=True`` it receives the local grad SHARD
+    (already dp-meaned), so per-leaf offset fixups do not apply there.
 
     ``zero=True`` is the ZeRO-sharded step: the state's optimizer must
     be dp-sharded (``init_train_state(..., shard=(axis, dp))``) and the
@@ -180,9 +182,9 @@ def make_train_step(loss_fn, tx, *, has_aux: bool = False,
     consumes ``all_gather(shard.astype(bf16))`` — so autodiff's
     transpose IS the ``psum_scatter`` of the flat bf16 grads (comm
     bytes match the old all-reduce: RS(2N) + AG(2N) vs AR(4N) in ring
-    terms) — the fused unscale + overflow flag run on the shard with
-    the flag pmax'd replica-uniform, and the Pallas fused update touches
-    only the local ``1/dp`` of master/slots.  Per-chip optimizer state,
+    terms) — the overflow flag is reduced over the shard and pmax'd
+    replica-uniform, and the Pallas fused update touches only the local
+    ``1/dp`` of master/slots.  Per-chip optimizer state,
     update FLOPs, and update HBM traffic all drop dp×; everything still
     composes into ONE donated XLA program.  A state built with
     ``prefetch`` spans (``init_train_state(..., prefetch=K)`` /
@@ -276,17 +278,22 @@ def make_train_step(loss_fn, tx, *, has_aux: bool = False,
             flat_g = flat_g / dp
         if grad_transform is not None:
             flat_g = grad_transform(flat_g)
+        grad_scale = jnp.float32(1.0)
         if scaler is not None:
-            # fused unscale + overflow detection; found_inf feeds the
-            # update kernel's noop predicate in-program (pmax'd
-            # replica-uniform under ZeRO)
+            # the grads stay scaled: 1/scale rides the update's own
+            # grad_scale, so no unscaled copy of the flat buffer is
+            # written.  found_inf (a read-only reduction, pmax'd
+            # replica-uniform under ZeRO) feeds the update kernel's
+            # noop predicate in-program
+            grad_scale = 1.0 / scaler.loss_scale
             with jax.named_scope("apex_train_unscale"):
-                flat_g, scaler = unscale_flat_grads(
+                scaler = check_flat_grads(
                     flat_g, scaler,
                     axis_name=axis if zero and dp > 1 else None)
             with jax.named_scope("apex_train_optimizer"):
                 new_opt = tx.update(opt, flat_g,
-                                    noop_flag=scaler.found_inf)
+                                    noop_flag=scaler.found_inf,
+                                    grad_scale=grad_scale)
                 scaler = update_scale(scaler)
         else:
             with jax.named_scope("apex_train_optimizer"):
@@ -295,10 +302,11 @@ def make_train_step(loss_fn, tx, *, has_aux: bool = False,
         if numerics:
             # in-program numerics probes over the UNSCALED grads the
             # update consumed and the pre/post masters — extra scalar
-            # outputs of the same ONE donated executable
+            # outputs of the same ONE donated executable (the probes
+            # only reduce, so the multiply fuses into them)
             from apex_tpu.observability.numerics import compute_probes
             probes = compute_probes(
-                opt, new_opt.master, flat_g,
+                opt, new_opt.master, flat_g * grad_scale,
                 axis_name=axis if zero and dp > 1 else None)
         new_state = state.replace(opt=new_opt, scaler=scaler)
         if zero and dp > 1:

@@ -600,3 +600,141 @@ def test_v5e_evict_writes_the_metadata_and_copies_no_pool(pool, one_chip):
     small = 4 * (slots * mpps + 2 * slots)
     assert stats.argument_size_in_bytes - stats.alias_size_in_bytes \
         <= small, stats
+
+
+def _mlp_loss(params, batch):
+    h = batch["x"]
+    for i in range(len(params) // 2):
+        h = jnp.tanh(h @ params[f"w{i}"] + params[f"b{i}"])
+    return jnp.mean((h - batch["y"]) ** 2)
+
+
+def _unscaled_copy_step(loss_fn, tx):
+    """The train step as it was spelled before the loss scale's 1/scale
+    rode LAMB's ``grad_scale``: ``fused_scale`` writes an unscaled copy of
+    the flat grads, and the update reads that copy."""
+    from apex_tpu.amp.scaler import update_scale
+
+    def step(state, batch):
+        opt, scaler = state.opt, state.scaler
+        g = jax.grad(lambda flat: loss_fn(opt.unravel(flat), batch)
+                     * scaler.loss_scale)(opt.master)
+        g, flag = fu.fused_scale(g, 1.0 / scaler.loss_scale)
+        return state.replace(
+            opt=tx.update(opt, g, noop_flag=flag),
+            scaler=update_scale(scaler.replace(found_inf=flag)))
+    return step
+
+
+def _lamb_step_hlo(one_chip, make_step):
+    """Optimized HLO of a dense LAMB + dynamic loss-scale train step of a
+    16-layer MLP of width 1024 (16.8M parameters, 67 MB a flat buffer:
+    at a quarter of that the compiler keeps the buffers in on-chip
+    memory and fuses what it would not fuse at BERT-large's size),
+    compiled for the described chip, and the flat length."""
+    from apex_tpu import train_step
+    from apex_tpu.optimizers import functional
+
+    tx = functional.fused_lamb(lr=1e-3)
+    d = 1024
+    state = jax.eval_shape(lambda: train_step.init_train_state(
+        tx, {f"{w}{i}": jnp.zeros((d, d) if w == "w" else (d,))
+             for i in range(16) for w in "wb"}, loss_scale="dynamic"))
+    on = lambda x: _s(x.shape, x.dtype, sharding=one_chip)  # noqa: E731
+    batch = {"x": _s((16, d), F32), "y": _s((16, d), F32)}
+    step = jax.jit(make_step(_mlp_loss, tx), donate_argnums=(0,))
+    hlo = step.lower(jax.tree.map(on, state),
+                     jax.tree.map(on, batch)).compile().as_text()
+    return hlo, state.opt.master.shape[0]
+
+
+def _hlo_graph(hlo):
+    """``(ENTRY instruction -> (opcode, operands, line), fused
+    computation -> its instruction lines)`` of an optimized HLO text."""
+    bodies = {m.group(1): m.group(2).splitlines() for m in re.finditer(
+        r"\n(%[\w.\-]+) \([^\n]*\{\n(.*?)\n\}", hlo, re.S)}
+    entry = {}
+    for line in hlo[hlo.index("\nENTRY"):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = .*? ([\w\-]+)\((.*?)\)",
+                     line)
+        if m:
+            operands = re.findall(r"%[\w.\-]+", m.group(3))
+            entry[m.group(1)] = (m.group(2), operands, line)
+    return entry, bodies
+
+
+def _producer(entry, bodies, name):
+    """The instruction line that computes ENTRY value ``name``: inside a
+    fusion, the root (or the root tuple's element) that yields it."""
+    op, operands, line = entry[name]
+    index = None
+    if op == "get-tuple-element":
+        index = int(re.search(r"index=(\d+)", line).group(1))
+        op, operands, line = entry[operands[0]]
+    calls = re.search(r"calls=(%[\w.\-]+)", line)
+    if op != "fusion" or not calls:
+        return line
+    body = bodies[calls.group(1)]
+    root = next(lb for lb in body if lb.lstrip().startswith("ROOT"))
+    if index is not None:
+        element = re.findall(r"%[\w.\-]+",
+                             root.split(" tuple(", 1)[1])[index]
+        root = next(lb for lb in body
+                    if re.match(rf"\s*{re.escape(element)} = ", lb))
+    return root
+
+
+def _lamb_grad_reads(hlo):
+    """What the compiled step does with the flat grads before LAMB's
+    kernel: ``(the line that yields the kernel's grad operand, the ENTRY
+    fusions that reduce over those grads)``."""
+    entry, bodies = _hlo_graph(hlo)
+    kernel = next(v for k, v in entry.items()
+                  if k.startswith("%apex_lamb_stage1"))
+    grad = kernel[1][1]
+    source = entry[grad][1][0] if entry[grad][0] == "get-tuple-element" \
+        else grad
+    readers = []
+    for name, (op, operands, line) in entry.items():
+        calls = re.search(r"calls=(%[\w.\-]+)", line)
+        if op == "fusion" and calls and (name == source
+                                         or source in operands):
+            reduces = [lb for lb in bodies[calls.group(1)]
+                       if " reduce(" in lb]
+            if reduces:
+                readers.append(reduces)
+    return _producer(entry, bodies, grad), readers
+
+
+def test_v5e_lamb_reads_the_grads_the_backward_wrote(one_chip, monkeypatch):
+    """The loss scale's 1/scale rides LAMB's ``grad_scale``: compiled for
+    the chip, the train step holds no ``apex_amp_unscale``, the kernel's
+    grad operand is what the backward wrote (not a multiply: no scaled
+    or unscaled copy of the flat grads), and ONE fusion reduces over
+    those grads before the kernel — the overflow flag and LAMB's sum of
+    squares in the same pass."""
+    from apex_tpu import train_step
+
+    monkeypatch.setattr(fu, "interpret_mode", lambda: False)
+    hlo, n = _lamb_step_hlo(one_chip, train_step.make_train_step)
+    assert "apex_amp_unscale" not in hlo
+    source, readers = _lamb_grad_reads(hlo)
+    assert f"f32[{n}]" in source, source[:200]
+    assert " multiply(" not in source, source[:200]
+    assert "transpose(jvp(apex_train_forward))" in source, source[:200]
+    assert len(readers) == 1, [r[:1] for r in readers]
+    scopes = " ".join(readers[0])
+    assert "apex_train_unscale/reduce_max" in scopes
+    assert "apex_train_optimizer/reduce_sum" in scopes
+
+
+def test_v5e_lamb_grad_reads_tell_the_unscaled_copy(one_chip, monkeypatch):
+    """Positive control of the test above: the step that writes an
+    unscaled copy first trips it — the copy's kernel is in the program,
+    and LAMB reads its output, not the backward's."""
+    monkeypatch.setattr(fu, "interpret_mode", lambda: False)
+    hlo, _ = _lamb_step_hlo(one_chip, _unscaled_copy_step)
+    assert re.search(r"%apex_amp_unscale[\w.]* = ", hlo)
+    source, readers = _lamb_grad_reads(hlo)
+    assert "transpose(jvp(apex_train_forward))" not in source, source[:200]
+    assert not any("apex_train_unscale" in " ".join(r) for r in readers)

@@ -10,8 +10,10 @@ Pins the three properties the flat-native path buys (ISSUE 2 acceptance):
    exactly ONE executable, vs >= 3 for the old class-API loop
    (grad jit + eager unscale + optimizer-step jit).
 
-Plus end-to-end behavior: the scanned loop learns, and an overflow step
-is skipped in-program (noop_flag) with the scale backed off.
+Plus end-to-end behavior: the scanned loop learns, an overflow step is
+skipped in-program (noop_flag) with the scale backed off, and with the
+loss scale's 1/scale riding the update's ``grad_scale`` every functional
+tx gives the update an unscaled copy of the grads gave.
 """
 import os
 import sys
@@ -25,9 +27,9 @@ sys.path.insert(0, os.path.abspath(
     os.path.join(os.path.dirname(__file__), "..", "..")))
 
 from apex_tpu import train_step
-from apex_tpu.amp.scaler import LossScaler
+from apex_tpu.amp.scaler import LossScaler, update_scale
 from apex_tpu.analysis.jaxpr_audit import FORBIDDEN_PRIMS
-from apex_tpu.ops.fused_update import _BLOCK
+from apex_tpu.ops.fused_update import _BLOCK, fused_scale
 from apex_tpu.optimizers import FusedAdam, functional
 from apex_tpu.utils import tree_ravel
 
@@ -231,22 +233,21 @@ def test_lamb_step_selects_only_the_master():
     assert len(_flat_selects(old_jaxpr, n)) == 3
 
 
+def _poisoned_loss(params, batch):
+    # batch["poison"] = 0 -> clean loss; huge -> inf grads
+    return _loss_fn(params, batch) + jnp.sum(params["w0"]) * batch["poison"]
+
+
 @pytest.mark.parametrize("make_tx", [functional.fused_adam,
                                      functional.fused_lamb],
                          ids=["adam", "lamb"])
 def test_overflow_step_skips_in_program_and_backs_off_scale(make_tx):
-    """A non-finite grad must be caught by the fused unscale flag and
+    """A non-finite grad must be caught by the overflow flag and
     skipped by the update kernel's noop predicate — all in-program —
     with the dynamic scale halved afterwards."""
     params = _make_params()
     tx = make_tx(lr=1e-2)
-
-    def loss_fn(params, batch):
-        # batch["poison"] = 0 -> clean loss; huge -> inf grads
-        return _loss_fn(params, batch) + jnp.sum(
-            params["w0"]) * batch["poison"]
-
-    step = jax.jit(train_step.make_train_step(loss_fn, tx))
+    step = jax.jit(train_step.make_train_step(_poisoned_loss, tx))
     state = train_step.init_train_state(tx, params, loss_scale="dynamic")
     clean = dict(_batch(), poison=jnp.float32(0.0))
     poisoned = dict(_batch(), poison=jnp.float32(1e38))
@@ -270,3 +271,102 @@ def test_overflow_step_skips_in_program_and_backs_off_scale(make_tx):
     # and the loop recovers on the next clean batch
     state, _ = step(state, clean)
     assert not np.array_equal(np.asarray(state.opt.master), master_before)
+
+
+def _folded_step(tx):
+    """The train step, also returning the ``noop_flag`` and
+    ``grad_scale`` it handed the update."""
+    def step(state, batch):
+        seen = {}
+
+        class Spy:
+            def update(self, opt, flat_grads, **kw):
+                seen.update(kw)
+                return tx.update(opt, flat_grads, **kw)
+
+        new, loss = train_step.make_train_step(_poisoned_loss, Spy())(
+            state, batch)
+        return new, (loss, seen["noop_flag"], seen["grad_scale"])
+    return step
+
+
+def _unscaled_copy_step(tx):
+    """The same step spelled as it was before the unscale rode the
+    update's ``grad_scale``: ``fused_scale`` writes an unscaled copy of
+    the flat grads and flags it, then the update runs at
+    ``grad_scale=1``.  Returns the flag too."""
+    def step(state, batch):
+        opt, scaler = state.opt, state.scaler
+
+        def flat_loss(flat):
+            loss = _poisoned_loss(opt.unravel(flat.astype(opt.flat_dtype)),
+                                  batch)
+            return loss * scaler.loss_scale, loss
+
+        (_, loss), g = jax.value_and_grad(flat_loss, has_aux=True)(
+            opt.master)
+        g, flag = fused_scale(g, 1.0 / scaler.loss_scale)
+        new_opt = tx.update(opt, g, noop_flag=flag, grad_scale=1.0)
+        scaler = update_scale(scaler.replace(found_inf=flag))
+        return state.replace(opt=new_opt, scaler=scaler), (loss, flag)
+    return step
+
+
+FUNCTIONAL_TXS = {
+    "lamb": lambda: functional.fused_lamb(lr=1e-2),
+    "adam": lambda: functional.fused_adam(lr=1e-2),
+    "sgd": lambda: functional.fused_sgd(lr=1e-2, momentum=0.9),
+    "adagrad": lambda: functional.fused_adagrad(lr=1e-2),
+    "novograd": lambda: functional.fused_novograd(lr=1e-2),
+}
+
+
+@pytest.mark.parametrize("overflow", [False, True],
+                         ids=["clean", "overflow"])
+@pytest.mark.parametrize("name", sorted(FUNCTIONAL_TXS))
+def test_folded_unscale_matches_the_unscaled_copy(name, overflow):
+    """1/scale rides the update's ``grad_scale`` and the flag is a
+    read-only reduction: every functional tx gives the update it gave
+    when the step wrote an unscaled copy of the flat grads first — the
+    dynamic scale is a power of two, so the fold rounds as the copy did
+    — and an overflowed step leaves master and slots as they were, sets
+    the flag and halves the scale.  Both spellings run one primitive at
+    a time: jitted on the CPU, XLA fuses the interpreted kernel's
+    arithmetic into what surrounds it and contracts its multiply-adds
+    differently in the two programs (a few ulp apart), where on the chip
+    the kernel is the same compiled program either way."""
+    tx = FUNCTIONAL_TXS[name]()
+    params = _make_params()
+    clean = dict(_batch(), poison=jnp.float32(0.0))
+    batch = dict(_batch(seed=2),
+                 poison=jnp.float32(1e38 if overflow else 0.0))
+    folded, copied = _folded_step(tx), _unscaled_copy_step(tx)
+
+    # one clean step first, so the slots hold something to keep
+    state = train_step.init_train_state(tx, params, loss_scale="dynamic")
+    state, (_, flag, grad_scale) = folded(state, clean)
+    assert float(flag) == 0.0
+    assert float(grad_scale) == 2.0 ** -16
+    before = jax.tree.map(np.asarray, state)
+
+    new, (loss_new, flag_new, _) = folded(state, batch)
+    old, (loss_old, flag_old) = copied(before, batch)
+    assert float(flag_new) == float(flag_old) == float(overflow)
+    assert float(loss_new) == float(loss_old)
+    for got, want in zip(jax.tree.leaves(new.opt),
+                         jax.tree.leaves(old.opt)):
+        np.testing.assert_array_max_ulp(np.asarray(got), np.asarray(want),
+                                        maxulp=1)
+    for field in ("loss_scale", "growth_tracker", "found_inf"):
+        assert getattr(new.scaler, field) == getattr(old.scaler, field)
+    if overflow:
+        assert float(new.scaler.loss_scale) == 2.0 ** 15
+        np.testing.assert_array_equal(np.asarray(new.opt.master),
+                                      before.opt.master)
+        for k, v in before.opt.slots.items():
+            np.testing.assert_array_equal(np.asarray(new.opt.slots[k]), v,
+                                          err_msg=k)
+    else:
+        assert float(new.scaler.loss_scale) == 2.0 ** 16
+        assert not np.array_equal(np.asarray(new.opt.master),
+                                  before.opt.master)
