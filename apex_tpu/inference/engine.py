@@ -742,7 +742,8 @@ class InferenceEngine:
                     dtype=self.cache_dtype,
                     window_layers=d["window_layers"], window=d["window"],
                     latent=d["latent"], index=d["index"],
-                    index_layers=d.get("index_layers"))
+                    index_layers=d.get("index_layers"),
+                    window_latent=d.get("window_latent", 0))
 
             if self.tp == 1:
                 return build()
@@ -789,7 +790,7 @@ class InferenceEngine:
             return ((self.num_pages + 1) * self.page_size
                     * models.cache_position_values(d, kvh) * itemsize
                     + self.slots * ring * d["window_layers"]
-                    * per_layer_tok)
+                    * models.ring_row_values(d, kvh) * itemsize)
         return self.slots * self.max_seq * d["layers"] * per_layer_tok
 
     # -- generative path -----------------------------------------------------

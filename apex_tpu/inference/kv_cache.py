@@ -57,7 +57,14 @@ Shared design positions:
   context, nothing to free at eviction (a stale row is masked by its
   position).  Lengths, capacity and the page table are shared.  Models
   without window layers leave ``wk``/``wv`` ``None`` and keep the one
-  pool, op for op.
+  pool, op for op.  A model whose window layers attend in LATENT form
+  keeps one row a position there too, of the window layers' own width
+  (``init_paged_cache(..., window_latent=width)``)::
+
+      wk : [window_layers, slots, ring, window_latent]      wv : None
+
+  beside a latent pool of another width: the rows of two latent
+  geometries under one manager.
 * **A latent pool (ISSUE 34).**  A model with latent attention caches ONE
   row a position a layer — the normed latent beside the roped key
   channels every head shares — and no per-head keys or values.  Its pool
@@ -519,7 +526,8 @@ class PagedKVCache:
     # the window layers' rings (ISSUE 30), None without window layers:
     # [window_layers, slots, kv_heads, ring, head_dim] (layer-major: a
     # decode step rewrites one layer at a time), ring a whole number of
-    # pages; position t of a slot sits at ring row t % ring
+    # pages; position t of a slot sits at ring row t % ring.  Rings of
+    # LATENT rows are [window_layers, slots, ring, width] with wv None
     wk: Optional[jax.Array] = None
     wv: Optional[jax.Array] = None
     # the index keys of a kind whose layers SELECT (ISSUE 36), None
@@ -535,7 +543,7 @@ class PagedKVCache:
     @property
     def ring(self) -> int:
         """Positions one slot's window ring holds (0 without one)."""
-        return 0 if self.wk is None else self.wk.shape[3]
+        return 0 if self.wk is None else self.wk.shape[-2]
 
     @property
     def pages(self) -> int:
@@ -596,7 +604,8 @@ def init_paged_cache(pages: int, layers: int, kv_heads: int,
                      max_pages_per_slot: int, dtype=jnp.bfloat16,
                      window_layers: int = 0, window: int = 0,
                      latent: int = 0, index: int = 0,
-                     index_layers: Optional[int] = None) -> PagedKVCache:
+                     index_layers: Optional[int] = None,
+                     window_latent: int = 0) -> PagedKVCache:
     """Allocate an empty pool: ``pages`` allocatable pages (+1 trash
     page appended), every page-table entry pointing at the trash page,
     every slot empty.  ``layers`` counts the layers the POOL holds;
@@ -607,13 +616,19 @@ def init_paged_cache(pages: int, layers: int, kv_heads: int,
     ``kv_heads`` / ``head_dim`` are then not read.  ``index`` (an index
     key's width) adds the index-key pool ``[pages + 1, index_layers,
     index, page_size]`` under the same table (``index_layers`` defaults to
-    ``layers``); 0 adds no array."""
+    ``layers``); 0 adds no array.  ``window_latent`` (a window row's width)
+    makes the rings LATENT ones, ``[window_layers, slots, ring,
+    window_latent]`` with no value array — what a latent pool's window
+    layers need."""
     if pages < 1 or page_size < 1 or max_pages_per_slot < 1:
         raise ValueError(
             f"pages ({pages}), page_size ({page_size}) and "
             f"max_pages_per_slot ({max_pages_per_slot}) must be >= 1")
-    if latent and window_layers:
-        raise ValueError("a latent pool has no window rings")
+    if window_layers and bool(latent) != bool(window_latent):
+        raise ValueError(
+            f"the window layers of a latent pool keep latent rows and "
+            f"those of a per-head pool per-head k/v: latent={latent} with "
+            f"window_latent={window_latent}")
     shape = ((pages + 1, layers, latent, page_size) if latent
              else (pages + 1, layers, kv_heads, page_size, head_dim))
     rings = {}
@@ -622,9 +637,11 @@ def init_paged_cache(pages: int, layers: int, kv_heads: int,
             raise ValueError(f"window layers need window >= 1, got "
                              f"{window}")
         ring = ring_rows(window, page_size)
-        wshape = (window_layers, slots, kv_heads, ring, head_dim)
+        wshape = ((window_layers, slots, ring, window_latent)
+                  if window_latent
+                  else (window_layers, slots, kv_heads, ring, head_dim))
         rings = dict(wk=jnp.zeros(wshape, dtype),
-                     wv=jnp.zeros(wshape, dtype))
+                     wv=None if window_latent else jnp.zeros(wshape, dtype))
     return PagedKVCache(
         k=jnp.zeros(shape, dtype),
         v=None if latent else jnp.zeros(shape, dtype),
@@ -928,11 +945,26 @@ def insert_tokens(cache: PagedKVCache, slot, k, v, length, row,
             cache.capacity, (owned * ps)[None], (slot,)))
 
 
+def _ring_row_shape(cache: PagedKVCache) -> tuple:
+    """One position's values in one window layer's ring: ``(kv_heads,
+    head_dim)``, or ``(width,)`` for a ring of latent rows."""
+    return cache.wk.shape[2:-2] + cache.wk.shape[-1:]
+
+
+def _ring_pair(cache: PagedKVCache, fn, k, v) -> dict:
+    """``fn(rings, rows)`` over the key rings and — where the cache has
+    them — the value rings: the ``wk=``/``wv=`` of a ``cache.replace``."""
+    return {"wk": fn(cache.wk, k),
+            "wv": None if cache.wv is None else fn(cache.wv, v)}
+
+
 def insert_window(cache: PagedKVCache, slot, k, v,
                   length) -> PagedKVCache:
     """Prefill write of the WINDOW layers (ISSUE 30): of a prompt's k/v
-    ``[window_layers, kv_heads, s, head_dim]`` only the last ``ring``
-    positions below ``length`` are kept, each at ring row ``t % ring``.
+    ``[window_layers, kv_heads, s, head_dim]`` — or its latent rows
+    ``[window_layers, s, width]`` with ``v`` None, for latent rings — only
+    the last ``ring`` positions below ``length`` are kept, each at ring
+    row ``t % ring``.
 
     Ring row ``r`` receives position ``(length - 1) - ((length - 1 - r)
     mod ring)`` — the newest one congruent to ``r``; where that is
@@ -940,15 +972,21 @@ def insert_window(cache: PagedKVCache, slot, k, v,
     gather that every reader masks by position.  One gather along the
     sequence and one whole-ring write: the slot's ring is rewritten, so
     nothing of its previous occupant survives an admission."""
-    ring, s = cache.ring, k.shape[2]
-    if not ring or k.shape != v.shape \
-            or k.shape[:2] != (cache.wk.shape[0], cache.wk.shape[2]) \
-            or k.shape[3] != cache.head_dim:
+    ring = cache.ring
+    row = () if cache.wk is None else _ring_row_shape(cache)
+    if not ring or k.ndim != len(row) + 2 \
+            or (k.shape[0],) + k.shape[1:-2] + k.shape[-1:] \
+            != (cache.wk.shape[0],) + row \
+            or (v is None) != (cache.wv is None) \
+            or (v is not None and v.shape != k.shape):
         raise ValueError(
-            f"window k/v must be [window_layers, kv_heads, s, head_dim]"
-            f" of a cache with rings; got k {tuple(k.shape)} v "
-            f"{tuple(v.shape)}, rings "
+            f"window k/v must be [window_layers, kv_heads, s, head_dim] "
+            f"(latent rings: rows [window_layers, s, width] and v None) "
+            f"of a cache with rings; got k {tuple(k.shape)} v "
+            f"{None if v is None else tuple(v.shape)}, rings "
             f"{None if cache.wk is None else tuple(cache.wk.shape)}")
+    seq = k.ndim - 2                    # the prompt's positions
+    s = k.shape[seq]
     last = jnp.asarray(length, jnp.int32) - 1
     held = last - jnp.mod(last - jnp.arange(ring, dtype=jnp.int32), ring)
     src = jnp.clip(held, 0, s - 1)
@@ -956,11 +994,11 @@ def insert_window(cache: PagedKVCache, slot, k, v,
     zero = jnp.int32(0)
 
     def write(rings, x):
-        rows = jnp.take(x, src, axis=2).astype(rings.dtype)
+        rows = jnp.take(x, src, axis=seq).astype(rings.dtype)
         return jax.lax.dynamic_update_slice(
-            rings, rows[:, None], (zero, slot, zero, zero, zero))
+            rings, rows[:, None], (zero, slot) + (zero,) * (rings.ndim - 2))
 
-    return cache.replace(wk=write(cache.wk, k), wv=write(cache.wv, v))
+    return cache.replace(**_ring_pair(cache, write, k, v))
 
 
 def append_window(cache: PagedKVCache, layer: int, k_tok,
@@ -968,23 +1006,27 @@ def append_window(cache: PagedKVCache, layer: int, k_tok,
     """Decode write for ONE window layer (``layer`` counts window layers
     only): slot ``i``'s token row lands at ring row ``lengths[i] %
     ring`` — where the position ``ring`` back, by now outside every
-    window, used to be.  The dense cache's one-row-per-slot update."""
-    if k_tok.shape != (cache.slots, cache.kv_heads, cache.head_dim):
+    window, used to be.  The dense cache's one-row-per-slot update.
+    Latent rings take ``k_tok [slots, width]`` and ``v_tok`` None."""
+    want = (cache.slots,) + _ring_row_shape(cache)
+    if k_tok.shape != want or (v_tok is None) != (cache.wv is None):
         raise ValueError(
-            f"token k/v must be [slots={cache.slots}, "
-            f"kv_heads={cache.kv_heads}, head_dim={cache.head_dim}], "
-            f"got {tuple(k_tok.shape)}")
+            f"token k/v must be {list(want)} (latent rings: v None), got "
+            f"k {tuple(k_tok.shape)} v "
+            f"{None if v_tok is None else tuple(v_tok.shape)}")
     rows = jnp.mod(cache.lengths, cache.ring)
 
     def write(buf, tok, row):
+        # buf [kv_heads, ring, d] and tok [kv_heads, d], or a latent
+        # ring's [ring, w] and [w]: the row at the ring's second-to-last
         return jax.lax.dynamic_update_slice(
-            buf, tok[:, None, :].astype(buf.dtype),
-            (jnp.int32(0), row, jnp.int32(0)))
+            buf, tok[..., None, :].astype(buf.dtype),
+            (jnp.int32(0),) * (buf.ndim - 2) + (row, jnp.int32(0)))
 
     upd = jax.vmap(write)
-    return cache.replace(
-        wk=cache.wk.at[layer].set(upd(cache.wk[layer], k_tok, rows)),
-        wv=cache.wv.at[layer].set(upd(cache.wv[layer], v_tok, rows)))
+    return cache.replace(**_ring_pair(
+        cache, lambda rings, tok: rings.at[layer].set(
+            upd(rings[layer], tok, rows)), k_tok, v_tok))
 
 
 def window_pages_live(cache: PagedKVCache):

@@ -58,7 +58,12 @@ decode), with the picks of one layer REUSED by the layers after it that
 hold no indexer (``Select.sources``) — so the index-key pool keeps the
 picking layers' keys alone — and a residual carried in several streams
 (``Kind.residual``: where the other kinds write ``h + sublayer``, the loops
-ask the record for the sublayer's input and for the streams after it).
+ask the record for the sublayer's input and for the streams after it).  A
+latent kind may give each layer TYPE a geometry of its own
+(``Latent.geometry``, ``dots3``): its full layers attend through the latent
+pool under a learned indexer, its window layers through per-slot rings of
+latent rows of another width (``ring_decode_attention_latent`` in decode,
+the windowed flash kernel in prefill).
 
 Unsupported training-only configs (scan_layers, the capacity-slot MoE
 FFN of ``transformer/moe/MoELayer``, sequence/context parallelism) fail
@@ -88,6 +93,7 @@ from apex_tpu.ops.attention import (
     flash_attention,
     prefix_window_attention,
     ring_decode_attention,
+    ring_decode_attention_latent,
     select_attend,
     select_attention,
     select_picks,
@@ -109,6 +115,7 @@ from apex_tpu.transformer.functional.fused_rope import (
 from apex_tpu.transformer.moe.dropless import fold_stats
 from apex_tpu.transformer.parallel_state import TENSOR_AXIS
 from apex_tpu.transformer.testing import standalone_axk1 as axk1
+from apex_tpu.transformer.testing import standalone_dots3 as dots3
 from apex_tpu.transformer.testing import standalone_hy4 as hy4
 from apex_tpu.transformer.testing import standalone_keye as keye
 from apex_tpu.transformer.testing import standalone_laguna as laguna
@@ -116,8 +123,9 @@ from apex_tpu.transformer.testing.standalone_llama import _rope_cos_sin
 
 __all__ = ["Kind", "KINDS", "model_dims", "tp_dims", "check_supported",
            "prefill_forward", "EXPERT_STATS", "SELECT_STATS", "REUSE_STATS",
-           "stats_tail", "Latent", "Select", "Residual", "cache_row_values",
-           "cache_position_values", "decode_forward",
+           "WINDOW_STATS", "stats_tail", "Latent", "Select", "Residual",
+           "cache_row_values", "cache_position_values", "ring_row_values",
+           "decode_forward",
            "verify_forward",
            "fused_layer_params", "expand_kv_for_tp",
            "param_partition_specs", "fused_partition_specs"]
@@ -575,11 +583,65 @@ def _hy4_rope(cfg, dims, positions, n):
 
 
 def _hy4_attn_out(lp, ctx, gate, tp):
+    """The gated output projection (elementwise gate, or dots3's
+    headwise one broadcast over each head's values)."""
     return hy4.attn_output(lp, ctx, gate)
 
 
 def _hy4_ffn(cfg, i, lp, h, valid, tp):
     y, stats = hy4.ffn(cfg, i, lp, h.reshape(-1, h.shape[-1]), valid=valid)
+    return y.reshape(h.shape), stats
+
+
+# --------------------------------------------------------------------------
+# dots3-note (standalone_dots3's per-layer pieces): latent attention of two
+# geometries — the full layers' under a learned indexer, the window layers'
+# in per-slot rings — a headwise gate on both, a selection-biased router
+# over held experts
+# --------------------------------------------------------------------------
+
+#: what a step of a kind whose window layers attend in latent form reports
+#: behind the selection's counters: the ring positions its window layers
+#: attended, summed over query rows and window layers
+WINDOW_STATS = ("swa_attended",)
+
+
+def _dots3_dims(cfg) -> dict:
+    full, window = (dots3.geometry(cfg, t) for t in (FULL, laguna.SLIDING))
+    return {"layers": cfg.num_layers,
+            "heads": tuple(dots3.geometry(cfg, t).num_heads
+                           for t in cfg.layer_types),
+            "kv_heads": 0, "head_dim": full.qk_head_dim,
+            "layer_types": tuple(cfg.layer_types),
+            "pool_layers": len(cfg.full_layers),
+            "window_layers": len(cfg.window_layers),
+            "window": cfg.sliding_window,
+            "latent": full.latent_dim, "latent_values": full.kv_lora_rank,
+            "window_latent": window.latent_dim,
+            "window_latent_values": window.kv_lora_rank,
+            "index": cfg.index_head_dim,
+            "index_layers": len(cfg.full_layers)}
+
+
+def _dots3_check(cfg) -> None:
+    if not isinstance(cfg, dots3.Dots3Config):
+        raise TypeError(
+            f"the 'dots3' kind takes a Dots3Config, got "
+            f"{type(cfg).__name__}")
+
+
+def _dots3_rope(cfg, dims, positions, n):
+    """One table per layer type (each its own base), and the indexer's."""
+    if positions is None:
+        positions = jnp.arange(n, dtype=jnp.int32)
+    return {**{t: dots3.rope_cos_sin(cfg, t, positions)
+               for t in dict.fromkeys(cfg.layer_types)},
+            INDEX: dots3.index_rope_cos_sin(cfg, positions)}
+
+
+def _dots3_ffn(cfg, i, lp, h, valid, tp):
+    y, stats = dots3.ffn(cfg, i, lp, h.reshape(-1, h.shape[-1]),
+                         valid=valid)
     return y.reshape(h.shape), stats
 
 
@@ -618,13 +680,20 @@ class Latent:
     value_up: Callable
     #: ``cfg -> float``: the softmax scale (no head size gives it)
     scale: Callable
-    #: ``(cfg, lp, h) -> g [..., heads, dv]`` or None: an elementwise gate
-    #: on each head's output, read from the sublayer's normed input and
-    #: handed to ``attn_out`` as its ``extra``
+    #: ``(cfg, lp, h) -> g [..., heads, dv]`` (elementwise) or ``[...,
+    #: heads, 1]`` (headwise) or None: a gate on each head's output, read
+    #: from the sublayer's normed input and handed to ``attn_out`` as its
+    #: ``extra``
     gate: Optional[Callable] = None
     #: ``lp -> [heads]`` float32 or None: each head's sink logit, one more
     #: term of the softmax's denominator that carries no value
     sink: Optional[Callable] = None
+    #: ``(cfg, layer type) -> g`` or None: what the forms, the gate and the
+    #: scale are handed in place of ``cfg`` for a layer of that type — a
+    #: geometry a layer type, whose window layers' rows (``dims
+    #: ["window_latent"]`` wide, the leading ``dims["window_latent_values"]``
+    #: the values) live in per-slot rings; None: ``cfg`` for every layer
+    geometry: Optional[Callable] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -644,7 +713,8 @@ class Select:
     #: ``cfg -> tuple`` or None: per layer, the layer whose picks it
     #: attends — itself where it scores its own index keys, an earlier one
     #: where it REUSES that layer's picks and holds no indexer;
-    #: None: every layer picks for itself
+    #: None: every pool layer picks for itself (a window layer picks
+    #: nothing: it attends its window)
     sources: Optional[Callable] = None
 
 
@@ -868,6 +938,48 @@ KINDS = {
                 "up-project the cached latent a chunk at a time and pick "
                 "among the cached rows)"),
         }),
+    "dots3": Kind(
+        dims=_dots3_dims, check=_dots3_check, embed=_token_embed,
+        rope=_dots3_rope, norm=_rms_norm, project=None,
+        attn_out=_hy4_attn_out, ffn=_dots3_ffn, head=_untied_head,
+        latent=Latent(expand=dots3.attn_expand, absorb=dots3.attn_absorb,
+                      value_up=dots3.attn_value_up,
+                      scale=dots3.softmax_scale, gate=dots3.attn_gate,
+                      geometry=dots3.geometry),
+        select=Select(index=dots3.index_project,
+                      topk=lambda cfg: cfg.index_topk,
+                      block=lambda cfg: cfg.index_q_chunk),
+        stats=EXPERT_STATS + SELECT_STATS + WINDOW_STATS,
+        refuses={
+            "dense": "the 'dots3' kind serves from the paged cache only (a "
+                     "latent pool, an index-key pool and rings of latent "
+                     "rows: kv_cache.KVCache has none of them): pass "
+                     "page_size=/num_pages=",
+            "tp": _not_built(
+                "dots3", "tp > 1", "models.param_partition_specs and "
+                "kv_cache.paged_cache_partition_specs (two latent "
+                "geometries, each with head-sharded up-projections over a "
+                "replicated latent, the rings and the index keys "
+                "replicated, the held experts' all-to-all)"),
+            "verify": _not_built(
+                "dots3", "speculative verify", "ops/paged_attention.py's "
+                "paged_slab_attention (per-head k/v windows, no picks) and "
+                "kv_cache.append_slab (no latent rows, no index keys; a "
+                "rejected slab would have to roll the rings back)"),
+            "host_tier": _not_built(
+                "dots3", "the host KV tier", "kv_cache.HostPageStore and "
+                "engine.swap_in_pages (they move a k and a v slab; a "
+                "prefix over window rings cannot be shared)"),
+            "fused": _not_built(
+                "dots3", "fused_block_decode", "ops/paged_attention.py's "
+                "_fused_block_kernel (per-head k/v, no window, a dense "
+                "FFN)"),
+            "prefix_sharing": _not_built(
+                "dots3", "prefix sharing, and with it chunked prefill,",
+                "models._suffix_attend (the rings hold no positions a "
+                "shared prefix's pages could hand back, and a resumed "
+                "prefill would have to pick among the cached rows)"),
+        }),
 }
 
 
@@ -894,6 +1006,12 @@ def cache_row_values(dims: dict, kv_heads: int) -> int:
     and the index key of a kind that selects."""
     return (dims["latent"] or 2 * kv_heads * dims["head_dim"]) \
         + dims.get("index", 0)
+
+
+def ring_row_values(dims: dict, kv_heads: int) -> int:
+    """Values ONE position holds in ONE window layer's ring: a key and a
+    value per KV head, or the window layers' latent row."""
+    return dims.get("window_latent") or 2 * kv_heads * dims["head_dim"]
 
 
 def cache_position_values(dims: dict, kv_heads: int) -> int:
@@ -1206,18 +1324,29 @@ def _gate(rec: Kind, cfg, lp, hn):
     return rec.latent.gate(cfg, lp, hn) if rec.latent.gate else None
 
 
+def _geometry(rec: Kind, cfg, layer_type: str):
+    """What a latent kind's forms read for a layer of ``layer_type``: its
+    type's geometry, or the config itself."""
+    return (rec.latent.geometry(cfg, layer_type) if rec.latent.geometry
+            else cfg)
+
+
 def _sink(rec: Kind, lp):
     """Each head's sink logit, or None for a kind without one."""
     return rec.latent.sink(lp) if rec.latent and rec.latent.sink else None
 
 
-def _pick_sources(rec: Kind, cfg, layers: int):
+def _pick_sources(rec: Kind, cfg, layer_types):
     """Per layer ``(source, slot)`` for a kind that selects: the layer
     whose picks it attends, and — where that is itself — which of the
-    index-key pool's layers holds its keys (None where it reuses)."""
-    sources = (rec.select.sources(cfg) if rec.select.sources
-               else tuple(range(layers)))
-    own = [i for i in range(layers) if sources[i] == i]
+    index-key pool's layers holds its keys (None where it reuses).  Where
+    every pool layer picks for itself, its keys are its pool layer's and a
+    window layer picks nothing: ``(None, None)``."""
+    if rec.select.sources is None:
+        return [(i, n) if pooled else (None, None)
+                for i, (pooled, n) in enumerate(_cache_layers(layer_types))]
+    sources = rec.select.sources(cfg)
+    own = [i for i in range(len(layer_types)) if sources[i] == i]
     return [(src, own.index(i) if src == i else None)
             for i, src in enumerate(sources)]
 
@@ -1244,7 +1373,8 @@ def stats_tail(names, acc, cache):
               "moe_experts_hit": acc.get("experts_hit", zero),
               "moe_expert_load_max": acc.get("load_max", zero),
               "window_pages_live": kv_cache.window_pages_live(cache),
-              **{n: acc.get(n, zero) for n in SELECT_STATS + REUSE_STATS}}
+              **{n: acc.get(n, zero)
+                 for n in SELECT_STATS + REUSE_STATS + WINDOW_STATS}}
     return jnp.stack([values[n] for n in names]).astype(jnp.int32)
 
 
@@ -1263,6 +1393,14 @@ def _select_stats(acc, counted, context, picked, topk: int, reused=None):
         layer["dsa_rows_reused"] = layer["dsa_rows"] if reused \
             else jnp.int32(0)
     return {n: layer[n] + (acc or {}).get(n, 0) for n in layer}
+
+
+def _window_stats(acc, counted, attended):
+    """Fold one window layer into a step's :data:`WINDOW_STATS`: per query
+    row the positions it ``attended``, summed over the ``counted`` rows."""
+    return {"swa_attended": jnp.sum(jnp.where(counted, attended, 0),
+                                    dtype=jnp.int32)
+            + (acc or {}).get("swa_attended", 0)}
 
 
 # --------------------------------------------------------------------------
@@ -1335,23 +1473,27 @@ def prefill_forward(kind: str, cfg, params, tokens, length=None, *,
     valid = None if length is None or not rec.stats else (
         jnp.arange(s, dtype=jnp.int32) < length)
 
-    # a latent kind's softmax scale is its own; None is the head size's
-    scale = rec.latent.scale(cfg) if rec.latent else None
     ks, vs, wks, wvs, iks, stats, picks = [], [], [], [], [], None, None
     # a kind whose layers reuse picks: source layer -> (masks, picked)
-    sources, carried = None, {}
+    sources, carried, window = None, {}, None
     if rec.select:
-        sources = _pick_sources(rec, cfg, len(dims["layer_types"]))
+        sources = _pick_sources(rec, cfg, dims["layer_types"])
     for i, (pooled, n) in enumerate(_cache_layers(dims["layer_types"])):
         lp = p[f"layer_{i}"]
         u, mix = _sub_in(rec, cfg, lp, "attention", h)
         hn = rec.norm(cfg, lp, "input", u)
         lrope = rope.get(dims["layer_types"][i])
+        # a latent kind's softmax scale is its own (its layer type's);
+        # None is the head size's
+        scale = None
         if rec.latent:
-            # expanded: k/v made from the latent, the latent row cached
-            q, k, v, latent_row = rec.latent.expand(cfg, lp, hn, *lrope)
-            ks.append(latent_row[:, 0])                     # [s, width]
-            extra = _gate(rec, cfg, lp, hn)
+            # expanded: k/v made from the latent, the latent row cached —
+            # in the pool, or a window layer's in its ring
+            lcfg = _geometry(rec, cfg, dims["layer_types"][i])
+            scale = rec.latent.scale(lcfg)
+            q, k, v, latent_row = rec.latent.expand(lcfg, lp, hn, *lrope)
+            (ks if pooled else wks).append(latent_row[:, 0])  # [s, width]
+            extra = _gate(rec, lcfg, lp, hn)
         else:
             q, k, v, extra = rec.project(cfg, dims, i, lp, hn,
                                          lrope)             # [s, b, n, d]
@@ -1362,7 +1504,7 @@ def prefill_forward(kind: str, cfg, params, tokens, length=None, *,
             (vs if pooled else wvs).append(v[0])
         if suffix:
             ctx = _suffix_attend(cache, n, row, q, k, v, prefill_from)
-        elif rec.select:
+        elif rec.select and pooled:
             # each row attends the positions of largest index score among
             # its causal ones, a block of rows at a time; the index keys
             # are cached with k and v — a layer that reuses the picks of
@@ -1392,12 +1534,22 @@ def prefill_forward(kind: str, cfg, params, tokens, length=None, *,
                 picks, rows >= 0 if length is None else rows < length,
                 rows + 1, picked, topk,
                 None if rec.select.sources is None else src != i)
-        else:
+        elif pooled:
             heads = q.shape[1]
             ctx = flash_attention(
                 q, _expand_kv(k, heads), _expand_kv(v, heads), causal=True,
-                sm_scale=scale,
-                window=None if pooled else dims["window"])
+                sm_scale=scale)
+        else:
+            heads = q.shape[1]
+            with jax.named_scope("apex_swa_attend"):
+                ctx = flash_attention(
+                    q, _expand_kv(k, heads), _expand_kv(v, heads),
+                    causal=True, sm_scale=scale, window=dims["window"])
+            if "swa_attended" in rec.stats:
+                rows = jnp.arange(s, dtype=jnp.int32)
+                window = _window_stats(
+                    window, rows >= 0 if length is None else rows < length,
+                    jnp.minimum(rows + 1, dims["window"]))
         x = _sub_out(rec, cfg, mix, h,
                      rec.attn_out(lp, ctx.transpose(2, 0, 1, 3), extra, tp))
         u, mix = _sub_in(rec, cfg, lp, "ffn", x)
@@ -1410,8 +1562,8 @@ def prefill_forward(kind: str, cfg, params, tokens, length=None, *,
     if length is not None:      # the slab's own index of the last real row
         h = _last_row(h, length - prefill_from if suffix else length)
     logits = _gather_logits(rec.head(p, h, tp), tp)
-    if picks:
-        stats = {**(stats or {}), **picks}
+    if picks or window:
+        stats = {**(stats or {}), **(picks or {}), **(window or {})}
     return (logits, *(jnp.stack(x) if x else None
                       for x in (ks, vs, wks, wvs, iks)), stats)
 
@@ -1456,8 +1608,8 @@ def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
         work = paged_work_list(cache.page_table, live,
                                page_size=cache.page_size)
     cos, sin = flat.get(FULL, (None, None))     # the kernel's: unshaped
-    stats, picks, carried = None, None, {}
-    sources = (_pick_sources(rec, cfg, len(dims["layer_types"]))
+    stats, picks, carried, window = None, None, {}, None
+    sources = (_pick_sources(rec, cfg, dims["layer_types"])
                if rec.select else None)
     counted = live > 0 if active is None else active
     for i, (pooled, n) in enumerate(_cache_layers(dims["layer_types"])):
@@ -1478,17 +1630,38 @@ def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
         if rec.latent:
             # absorbed: the query against the latent rows, one pool, one
             # DMA a page; the value up-projection behind the softmax
-            q, latent_row = rec.latent.absorb(cfg, lp, hn, *lrope)
-            extra = _gate(rec, cfg, lp, hn)
-            if rec.select:
-                # a layer that picks caches its index key (in its own slot
-                # of a pool that keeps the picking layers' keys), scores
-                # and picks; one that reuses attends the carried set
+            lcfg = _geometry(rec, cfg, dims["layer_types"][i])
+            q, latent_row = rec.latent.absorb(lcfg, lp, hn, *lrope)
+            extra = _gate(rec, lcfg, lp, hn)
+            if not pooled:
+                # a window layer's row goes to its ring, whose last
+                # `window` positions the query attends whole
+                cache = kv_cache.append_window(cache, n, latent_row, None)
+                with jax.named_scope("apex_swa_attend"):
+                    u = ring_decode_attention_latent(
+                        q, cache.wk[n], positions, window=dims["window"],
+                        sm_scale=rec.latent.scale(lcfg),
+                        values=dims["window_latent_values"])
+                if "swa_attended" in rec.stats:
+                    window = _window_stats(
+                        window, counted, jnp.minimum(live, dims["window"]))
+            elif rec.select:
+                # a layer that picks caches its index key — with its row
+                # where every pool layer picks, else in its own slot of a
+                # pool that keeps the picking layers' keys — scores and
+                # picks; one that reuses attends the carried set
                 src, slot = sources[i]
-                cache = kv_cache.append_layer(cache, n, latent_row, None)
-                if src == i:
+                if rec.select.sources is None:
                     qi, wi, ki = rec.select.index(cfg, lp, hn, *flat[INDEX])
-                    cache = kv_cache.append_index(cache, slot, ki)
+                    cache = kv_cache.append_layer(cache, n, latent_row, None,
+                                                  ki)
+                else:
+                    cache = kv_cache.append_layer(cache, n, latent_row, None)
+                    if src == i:
+                        qi, wi, ki = rec.select.index(cfg, lp, hn,
+                                                      *flat[INDEX])
+                        cache = kv_cache.append_index(cache, slot, ki)
+                if src == i:
                     carried[i] = _decode_select(
                         cache, qi, wi, slot, work, live,
                         rec.select.topk(cfg))
@@ -1496,7 +1669,7 @@ def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
                 with jax.named_scope("apex_dsa_attend"):
                     u = paged_select_attention_latent(
                         q, cache.k, picked, work, layer=n,
-                        sm_scale=rec.latent.scale(cfg),
+                        sm_scale=rec.latent.scale(lcfg),
                         values=dims["latent_values"], sink=_sink(rec, lp))
                 picks = _select_stats(
                     picks, counted, live,
@@ -1507,9 +1680,9 @@ def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
                 cache = kv_cache.append_layer(cache, n, latent_row, None)
                 u = paged_decode_attention(
                     q, cache.k, None, cache.page_table, live, layer=n,
-                    work=work, sm_scale=rec.latent.scale(cfg),
+                    work=work, sm_scale=rec.latent.scale(lcfg),
                     values=dims["latent_values"])
-            ctx = rec.latent.value_up(cfg, lp, u)
+            ctx = rec.latent.value_up(lcfg, lp, u)
         else:
             q, k_tok, v_tok, extra = rec.project(cfg, dims, i, lp, hn,
                                                  lrope)
@@ -1534,9 +1707,10 @@ def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
                 ctx = _cache_attend(cache, n, q, live, work)
             else:
                 cache = kv_cache.append_window(cache, n, k_tok, v_tok)
-                ctx = ring_decode_attention(
-                    q, cache.wk[n], cache.wv[n], positions,
-                    window=dims["window"])
+                with jax.named_scope("apex_swa_attend"):
+                    ctx = ring_decode_attention(
+                        q, cache.wk[n], cache.wv[n], positions,
+                        window=dims["window"])
         x = _sub_out(rec, cfg, mix, h, rec.attn_out(lp, ctx, extra, tp))
         u, mix = _sub_in(rec, cfg, lp, "ffn", x)
         y, st = rec.ffn(cfg, i, lp, rec.norm(cfg, lp, "post_attention", u),
@@ -1545,8 +1719,8 @@ def decode_forward(kind: str, cfg, params, cache, tokens, fused=None,
         h = _sub_out(rec, cfg, mix, x, y)
 
     h = _final(rec, cfg, p, h)
-    if picks:
-        stats = {**(stats or {}), **picks}
+    if picks or window:
+        stats = {**(stats or {}), **(picks or {}), **(window or {})}
     return _gather_logits(rec.head(p, h, tp), tp), cache, stats
 
 

@@ -233,6 +233,11 @@ METRIC_SPECS: Dict[str, MetricSpec] = {s.name: s for s in [
                "picks of an earlier layer instead of scoring its own "
                "(a layer that holds no indexer)",
                labels=("phase",)),
+    MetricSpec("serve_swa_attended_total", "counter",
+               "positions attended by the window layers of a kind whose "
+               "window layers attend latent rows in per-slot rings, summed "
+               "over query rows and window layers (a row attends at most "
+               "the window)", labels=("phase",)),
     # -- speculative decoding (ISSUE 15): the verify step's accept/
     #    reject accounting.  Drafted counts what the verify executable
     #    SCORED (k per active slot per round, padding drafts

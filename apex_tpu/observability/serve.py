@@ -45,6 +45,7 @@ SELECT_METRIC_FAMILIES = (
     "serve_dsa_rows_sparse_total",
     "serve_dsa_selected_total",
     "serve_dsa_rows_reused_total",
+    "serve_swa_attended_total",
 )
 
 #: the ISSUE 30 expert-FFN / window-ring families (same schema-guard
@@ -272,6 +273,9 @@ class ServeTelemetry:
         self.dsa_rows_sparse = d("serve_dsa_rows_sparse_total")
         self.dsa_selected = d("serve_dsa_selected_total")
         self.dsa_rows_reused = d("serve_dsa_rows_reused_total")
+        # window layers that attend latent rows in rings: counted the same
+        # way, behind the selection's
+        self.swa_attended = d("serve_swa_attended_total")
         # request tracing (ISSUE 13): spans ride the SAME host
         # boundaries the methods below already occupy — arming the
         # tracer (trace= or APEX_TPU_TRACE) adds zero device work
@@ -562,7 +566,8 @@ class ServeTelemetry:
         what its steps report: ``models.EXPERT_STATS``, and
         ``models.SELECT_STATS`` for a kind that selects the positions it
         attends, ``models.REUSE_STATS`` for one whose layers reuse
-        picks); ``phase`` ``"prefill"`` or ``"decode"``.  A name the
+        picks, ``models.WINDOW_STATS`` for one whose window layers attend
+        latent rows); ``phase`` ``"prefill"`` or ``"decode"``.  A name the
         telemetry has no family for raises: a counter is never dropped
         in silence."""
         counters = dict(counters)

@@ -61,7 +61,8 @@ from jax.experimental.pallas import tpu as pltpu
 from apex_tpu.utils import cdiv, interpret_mode
 
 __all__ = ["flash_attention", "mha_reference", "decode_attention",
-           "ring_decode_attention", "prefix_window_attention",
+           "ring_decode_attention", "ring_decode_attention_latent",
+           "prefix_window_attention",
            "slab_decode_attention", "index_scores",
            "index_scores_reference", "select_top_mask", "select_attention",
            "select_picks", "select_attend", "with_sink"]
@@ -1158,10 +1159,10 @@ def select_attention(q, k, v, qi, wi, ki, *, topk: int, block_q: int = 512,
     ``min(topk, t + 1)`` causal positions of largest index score and no
     other (the others get no probability mass — not a bias).
 
-    ``q``/``k``/``v``: ``[1, h, s, d]`` (k/v per query head); ``qi [s,
-    heads, di]``, ``wi [s, heads]``, ``ki [s, di]`` the indexer's.
-    Returns ``(ctx [1, h, s, d], picked [s] int32)``, ``picked`` the
-    positions each row attended.
+    ``q``/``k``: ``[1, h, s, d]``, ``v [1, h, s, dv]`` (k/v per query
+    head); ``qi [s, heads, di]``, ``wi [s, heads]``, ``ki [s, di]`` the
+    indexer's.  Returns ``(ctx [1, h, s, dv], picked [s] int32)``,
+    ``picked`` the positions each row attended.
 
     Rows under ``topk`` are plain causal rows (one flash call over the
     first ``topk`` keys).  The others go ``block_q`` rows at a time: index
@@ -1194,8 +1195,9 @@ def select_attention(q, k, v, qi, wi, ki, *, topk: int, block_q: int = 512,
             return ctx, jnp.sum(picked, axis=1, dtype=jnp.int32)
 
         ctx, n = jax.lax.map(block, jnp.arange(r0, r1, bq, dtype=jnp.int32))
-        # [blocks, h, bq, d] -> [1, h, rows, d]
-        ctxs.append(jnp.moveaxis(ctx, 0, 1).reshape(1, h, r1 - r0, d))
+        # [blocks, h, bq, dv] -> [1, h, rows, dv]
+        ctxs.append(jnp.moveaxis(ctx, 0, 1).reshape(1, h, r1 - r0,
+                                                    v.shape[3]))
         counts.append(n.reshape(-1))
     return jnp.concatenate(ctxs, axis=2), jnp.concatenate(counts)
 
@@ -1406,9 +1408,7 @@ def ring_decode_attention(q, k, v, positions, *, window: int,
             f"{d}] and equal-shaped; got k {tuple(k.shape)} v "
             f"{tuple(v.shape)}")
     scale = (d ** -0.5) if sm_scale is None else sm_scale
-    p = positions.astype(jnp.int32)[:, None]                  # [b, 1]
-    held = p - jnp.mod(p - jnp.arange(ring, dtype=jnp.int32)[None], ring)
-    live = ((held >= 0) & (held > p - window))[:, None, None, :]
+    live = _ring_live(positions, ring, window)[:, None, None, :]
     qg = q.reshape(b, kvh, h // kvh, d)
     s = jax.lax.dot_general(
         qg, k, (((3,), (3,)), ((0, 1), (0, 1))),
@@ -1421,6 +1421,56 @@ def ring_decode_attention(q, k, v, positions, *, window: int,
         pr.astype(v.dtype), v, (((3,), (2,)), ((0, 1), (0, 1))),
         preferred_element_type=jnp.float32)           # [b, kvh, g, d]
     return out.reshape(b, h, d).astype(q.dtype)
+
+
+def _ring_live(positions, ring: int, window: int):
+    """``[b, ring]`` bool: does ring row ``r`` of each slot hold a position
+    its token attends?  Row ``r`` holds ``p - ((p - r) mod ring)`` — the
+    newest position congruent to ``r`` that is not ahead of ``p`` — and is
+    attended iff it exists (``>= 0``) and lies inside the window (``> p -
+    window``)."""
+    p = positions.astype(jnp.int32)[:, None]                  # [b, 1]
+    held = p - jnp.mod(p - jnp.arange(ring, dtype=jnp.int32)[None], ring)
+    return (held >= 0) & (held > p - window)
+
+
+def ring_decode_attention_latent(q, ring, positions, *, window: int,
+                                 sm_scale: float, values: int):
+    """Single-token LATENT attention of a sliding-window layer against
+    per-slot rings of latent rows: :func:`ring_decode_attention`
+    for a layer that caches one row a position and no per-head k/v.
+
+    * ``q``: ``[b, h, width]`` — the ABSORBED queries (the key
+      up-projection folded in, the roped channels beside it) of the token
+      at ``positions[b]``, whose own row is already in the ring;
+    * ``ring``: ``[b, ring, width]`` with ``ring >= window``, position
+      ``t`` at row ``t % ring``;
+    * returns ``[b, h, values]``: the softmax-weighted sum of the rows'
+      leading ``values`` channels (the latent; the caller applies the
+      value up-projection a head).
+
+    Every head scores every row whole; the window rule is
+    :func:`ring_decode_attention`'s.  Plain XLA: two products over
+    ``ring`` rows a slot, float32 accumulation and softmax."""
+    b, h, width = q.shape
+    n = ring.shape[1]
+    if ring.ndim != 3 or ring.shape[0] != b or ring.shape[2] != width \
+            or n < window or not 0 < values <= width:
+        raise ValueError(
+            f"the ring must be [b={b}, ring >= {window}, {width}] and "
+            f"values in (0, {width}]; got ring {tuple(ring.shape)}, "
+            f"values {values}")
+    live = _ring_live(positions, n, window)[:, None, :]        # [b, 1, n]
+    s = jnp.einsum("bhw,bnw->bhn", q, ring,
+                   preferred_element_type=jnp.float32) * sm_scale
+    s = jnp.where(live, s, _NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    pr = jnp.exp(s - m)
+    pr = pr / jnp.sum(pr, axis=-1, keepdims=True)
+    out = jnp.einsum("bhn,bnv->bhv", pr.astype(ring.dtype),
+                     ring[..., :values],
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
 
 
 def slab_decode_attention(q, win_k, win_v, lengths,

@@ -40,8 +40,8 @@ none is dropped.  ``held=None`` is the path above, text for text.
 
 Two routers: :func:`route_top_k` (softmax, top-k renormalised) and
 :func:`route_group_limited` (sigmoid scores, the best groups kept, top-k
-among their experts); ``router=`` takes any ``(x, router_w) -> (weights,
-experts)``.
+among their experts, optionally chosen under a selection bias);
+``router=`` takes any ``(x, router_w) -> (weights, experts)``.
 """
 from __future__ import annotations
 
@@ -90,7 +90,7 @@ def route_top_k(x, router_w, top_k: int, scale: float):
 
 
 def route_group_limited(x, router_w, top_k: int, scale: float, *,
-                        n_group: int, topk_group: int):
+                        n_group: int, topk_group: int, bias=None):
     """Group-limited sigmoid router: ``(weights [T, k] float32, experts
     [T, k] int32)``.
 
@@ -99,17 +99,26 @@ def route_group_limited(x, router_w, top_k: int, scale: float, *,
     of its two largest ``sigma``; the ``topk_group`` best groups are
     kept; the ``top_k`` largest ``sigma`` among their experts are the
     token's experts, weighted ``scale * sigma_e / (sum of the chosen +
-    1e-20)``.  No selection bias term."""
+    1e-20)``.
+
+    ``bias [E]`` (optional) is a SELECTION bias (``topk_method:
+    noaux_tc``, ``e_score_correction_bias``): groups and experts are
+    CHOSEN by ``sigma + bias``, and the chosen are still weighted by their
+    ``sigma``.  ``None`` traces none of it."""
     n_exp = router_w.shape[0]
     logits = jnp.matmul(x, router_w.T, preferred_element_type=jnp.float32)
     sig = jax.nn.sigmoid(logits.astype(jnp.float32))            # [T, E]
-    grouped = sig.reshape(-1, n_group, n_exp // n_group)
+    choose = sig if bias is None else sig + bias.astype(jnp.float32)
+    grouped = choose.reshape(-1, n_group, n_exp // n_group)
     group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
     _, keep = jax.lax.top_k(group_score, topk_group)            # [T, g]
     kept = jnp.any(keep[..., None] == jnp.arange(n_group), axis=1)
+    # sigma > 0: never chosen; a biased score may lie below -1
     masked = jnp.where(jnp.repeat(kept, n_exp // n_group, axis=-1),
-                       sig, -1.0)              # sigma > 0: never chosen
+                       choose, -1.0 if bias is None else -jnp.inf)
     top_s, top_e = jax.lax.top_k(masked, top_k)
+    if bias is not None:
+        top_s = jnp.take_along_axis(sig, top_e, axis=-1)
     weights = scale * top_s / (jnp.sum(top_s, axis=-1, keepdims=True)
                                + 1e-20)
     return weights, top_e.astype(jnp.int32)

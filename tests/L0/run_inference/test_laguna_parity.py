@@ -261,6 +261,19 @@ WORDS = {
         "fused": "fused_block_decode is not built for the 'hy4' kind: "
                  "ops/paged_attention.py",
         "prefix_sharing": "chunked prefill, is not built for the 'hy4' "
+                          "kind: models._suffix_attend"},
+    # and the seventh, likewise
+    "dots3": {
+        "dense": "serves from the paged cache only",
+        "tp": "tp > 1 is not built for the 'dots3' kind: "
+              "models.param_partition_specs",
+        "verify": "speculative verify is not built for the 'dots3' kind: "
+                  "ops/paged_attention.py",
+        "host_tier": "the host KV tier is not built for the 'dots3' kind: "
+                     "kv_cache.HostPageStore",
+        "fused": "fused_block_decode is not built for the 'dots3' kind: "
+                 "ops/paged_attention.py",
+        "prefix_sharing": "chunked prefill, is not built for the 'dots3' "
                           "kind: models._suffix_attend"}}
 #: how each feature is asked of an engine at construction
 ASKED = {"dense": dict(page_size=None, num_pages=None), "tp": dict(tp=2),
@@ -273,15 +286,20 @@ ASKED = {"dense": dict(page_size=None, num_pages=None), "tp": dict(tp=2),
 def toys(tiny):
     """kind -> (config, params) at toy size; a kind new here: its toy."""
     from apex_tpu.transformer.testing import standalone_axk1 as SA
+    from apex_tpu.transformer.testing import standalone_dots3 as SD
     from apex_tpu.transformer.testing import standalone_hy4 as SH
     from apex_tpu.transformer.testing import standalone_keye as SK
     acfg, kcfg, hcfg = SA.AXK1Config(), SK.KeyeConfig(), SH.HY4Config()
+    dcfg = SD.Dots3Config()
     tokens = jnp.zeros((1, 8), jnp.int32)
+
+    def filled(shapes):
+        return {"params": jax.tree.map(
+            lambda shape: jnp.full(shape, 0.02, jnp.float32), shapes,
+            is_leaf=lambda x: isinstance(x, tuple))}
     return {"laguna": tiny[:2],
-            "hy4": (hcfg, {"params": jax.tree.map(
-                lambda shape: jnp.full(shape, 0.02, jnp.float32),
-                SH.hy4_param_shapes(hcfg),
-                is_leaf=lambda x: isinstance(x, tuple))}),
+            "hy4": (hcfg, filled(SH.hy4_param_shapes(hcfg))),
+            "dots3": (dcfg, filled(SD.dots3_param_shapes(dcfg))),
             "axk1": (acfg, SA.axk1_model_provider(acfg).init(
                 jax.random.PRNGKey(0), tokens)),
             "keye": (kcfg, SK.keye_model_provider(kcfg).init(

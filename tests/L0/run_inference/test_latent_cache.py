@@ -38,7 +38,9 @@ def test_one_array_and_no_value_array():
         PAGES + 1, LAYERS, PS, WIDTH, 0)
     assert c.row_shape == (WIDTH,) and c.max_seq == MPPS * PS
     assert c.ring == 0 and int(kv_cache.window_pages_live(c)) == 0
-    with pytest.raises(ValueError, match="no window rings"):
+    # the window layers of a latent pool keep latent rows of their own
+    # width: asked for without one, rings are refused
+    with pytest.raises(ValueError, match="keep latent rows"):
         kv_cache.init_paged_cache(4, 1, 0, 4, 0, slots=1,
                                   max_pages_per_slot=2, latent=8,
                                   window_layers=1, window=4)

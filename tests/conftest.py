@@ -283,6 +283,17 @@ SLOW = {
     "tests/L0/run_attention/test_flash_attention.py::test_fused_and_split_backward_agree",
     "tests/L0/run_contrib/test_contrib.py::TestMultiheadAttn::test_self_attn_impls_match",
     "tests/L0/run_contrib/test_contrib.py::TestMultiheadAttn::test_self_attn_norm_add",
+    # the dots3 kind's benchmark files: a toy rehearsal is ~50 s on the CPU
+    # (engine build, warm-up compiles, the reference) and a control ~10 s;
+    # the fast lane keeps one rehearsal (the large seed, with the window
+    # layers' counter), each control that has to fail on one seed, and
+    # the unbiased router's finding
+    "tests/benchmark/test_benchmark_dots3.py::test_rehearsal_of_the_kind_is_correct[3]",
+    "tests/benchmark/test_benchmark_dots3.py::test_the_one_command_runs_the_cell",
+    "tests/benchmark/test_benchmark_dots3.py::test_every_control_fails_the_toy_limits[9-fp8]",
+    "tests/benchmark/test_benchmark_dots3.py::test_every_control_fails_the_toy_limits[9-attend_all]",
+    "tests/benchmark/test_benchmark_dots3.py::test_every_control_fails_the_toy_limits[9-recent_topk]",
+    "tests/benchmark/test_benchmark_dots3.py::test_every_control_fails_the_toy_limits[9-swa_all]",
 }
 
 
